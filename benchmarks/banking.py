@@ -7,12 +7,11 @@ and the recovery bench end in one machine-readable
 ``KIND-SUMMARY {json}`` line that CI greps and asserts — and then the
 evidence evaporates with the log.  This module
 is the persistence half: ``--bank`` appends each summary to
-``SUMMARY_BANK.json`` at the repo root, NEXT TO the ``BENCH_r*.json``
-round records it contextualizes, so a later session (or a reviewer)
+``SUMMARY_BANK.json`` at the repo root, so a later session (or a reviewer)
 can diff today's verdicts against the banked history without re-running
 anything.
 
-Staleness discipline (the ``bench.py`` banked-fallback rules): every
+Staleness discipline: every
 record carries its wall-clock stamp, the git commit it measured (when
 resolvable), the jax platform (``cpu`` sim vs real ``tpu`` — a sim
 number must never be relabeled silicon), and the argv that produced
@@ -79,9 +78,8 @@ def bank_summary(kind, summary, *, path=None, argv=None, round=None):
     """Append one ``kind`` (e.g. ``"GUARD-SUMMARY"``) record to the
     bank, newest first, atomically.  Returns the stamped record.
 
-    ``round`` stamps the bench round the record belongs to (the
-    ``BENCH_r<N>`` numbering — ``collectives_bench --round N`` /
-    ``bench.py``'s per-round micro-ladder pass both set it); when
+    ``round`` stamps the bench round the record belongs to
+    (``collectives_bench --round N`` sets it); when
     omitted it falls back to ``TORCHMPI_TPU_BENCH_ROUND`` so every
     banking call inside one round agrees without threading the number
     through each CLI.  Consumers (``latest`` callers, CI) read it to

@@ -813,8 +813,7 @@ def main():
     if is_cpu:
         from jax.experimental.pallas import tpu as pltpu
 
-        if hasattr(pltpu, "InterpretParams"):
-            ring.set_interpret(pltpu.InterpretParams())
+        ring.set_interpret(pltpu.InterpretParams())
     print(f"# mesh {dict(zip(mesh.axis_names, mesh.devices.shape))} "
           f"({'cpu-sim' if is_cpu else 'tpu'})", file=sys.stderr)
 

@@ -107,8 +107,6 @@ def main():
         if cfg.obs == "off":
             cfg.obs = "metrics"
     mesh = mpi.init(cfg)
-    budget_cm = mpi.compile_budget()  # watcher-supervised client
-    budget_cm.__enter__()
     n_dev = mpi.device_count()
     n_classes = 1000 if args.model == "resnet50" else 10
     model = (ResNet50(dtype=jnp.bfloat16) if args.model == "resnet50"

@@ -69,7 +69,7 @@ def main():
             # Server-loop cycle-cost decomposition (VERDICT r4 #8): the
             # measured split behind the loopback numbers — syscall
             # (recv+send) vs memcpy/rule-apply vs mutex contention.
-            # The scaling model (docs/ROUND3_NOTES.md) rests on these
+            # The scaling model rests on these
             # constants: apply_ns/byte is the per-core shard-work floor,
             # recv/send the TCP stack share that a real NIC replaces.
             st = ps.stats()

@@ -124,8 +124,8 @@ struct Server {
   // write.  elastic_bytes_out tracks RULE_ELASTIC response payloads
   // separately so consumers (ps_bench's apply ns/B denominator) can
   // subtract bytes the apply loop never touched as extra work.  Backs
-  // benchmarks/ps_bench.py's loopback breakdown and the ROUND3_NOTES
-  // scaling model with measured constants.
+  // benchmarks/ps_bench.py's loopback breakdown and its scaling model
+  // with measured constants.
   //
   // Snapshot consistency (ADVICE round 5): counters update in GROUPS
   // under the existing shard mutex — the request-side group

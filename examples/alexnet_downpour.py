@@ -22,8 +22,7 @@ def main():
         num_classes=dict(type=int, default=10),
         # lr: AlexNet has no normalization layers; Adam above ~1e-3 on this
         # cold start oscillates in place (loss pinned at ln C) while 3e-4
-        # trains to 100% on the synthetic task — measured, see
-        # docs/ROUND2_NOTES.md.
+        # trains to 100% on the synthetic task (measured).
         defaults={"steps": 80, "batch_size": 32, "lr": 3e-4},
     )
     import jax

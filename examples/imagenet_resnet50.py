@@ -72,7 +72,7 @@ def main():
         num_classes=args.num_classes, seed=args.seed)
 
     # Host batches stage onto the mesh from a background thread, so the
-    # (slow on relay hosts) host->device copy of batch N+1 overlaps step N.
+    # host->device copy of batch N+1 overlaps step N.
     from jax.sharding import PartitionSpec as P
 
     from torchmpi_tpu.utils.input_pipeline import prefetch_to_mesh

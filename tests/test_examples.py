@@ -2,8 +2,7 @@
 representative subset end to end at their default, convergence-asserting
 settings as part of the pytest suite (slow-marked — skipped by
 ``-m 'not slow'`` runs).  Each example exits nonzero if its convergence
-assertion fails, so subprocess rc is the whole check.  The full sweep
-(all 13 scripts + variants) is documented in docs/ROUND2_NOTES.md.
+assertion fails, so subprocess rc is the whole check.
 """
 
 import os

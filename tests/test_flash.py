@@ -282,7 +282,7 @@ def test_ring_flash_grad_matches_dense_ring(flat_runtime, causal):
     suite's heaviest interpreted-Pallas workload (flash kernels per ring
     step, each crossing the interpreter's N-party barriers), and at 8
     parties it is where the flaky full-suite abort struck in two
-    containers (docs/ROUND4_NOTES.md).  The rotating-accumulator VJP
+    containers.  The rotating-accumulator VJP
     math is ring-size-independent; 8-device ring FORWARD coverage
     remains elsewhere in the suite."""
     import jax

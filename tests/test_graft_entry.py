@@ -3,8 +3,6 @@ multi-chip dry run executes a full hierarchical DP step on 8 devices."""
 
 import jax
 import jax.numpy as jnp
-import pytest
-from jax.experimental.pallas import tpu as pltpu
 
 import __graft_entry__ as ge
 
@@ -16,12 +14,5 @@ def test_entry_traces():
     assert out.dtype == jnp.float32
 
 
-@pytest.mark.xfail(
-    condition=not hasattr(pltpu, "InterpretParams"),
-    reason="jax<0.5 boolean pallas interpreter cannot simulate ring RDMA "
-           "over a 2-axis mesh (dma_start LOGICAL device_id with >1 named "
-           "axes raises NotImplementedError); the modern InterpretParams "
-           "interpreter handles it",
-    strict=False)
 def test_dryrun_multichip_8():
     ge.dryrun_multichip(8)

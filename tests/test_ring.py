@@ -22,8 +22,6 @@ from torchmpi_tpu.ops import ring
 
 @pytest.fixture(autouse=True)
 def _interpret_mode():
-    if not hasattr(pltpu, "InterpretParams"):
-        pytest.skip("pallas TPU interpreter unavailable on this jax")
     ring.set_interpret(pltpu.InterpretParams())
     yield
     ring.set_interpret(None)
@@ -138,8 +136,8 @@ def test_chunk_bytes_changes_schedule():
 # deadlock when many device threads block in io_callbacks simultaneously —
 # the per-config outcome is deterministic but the safe boundary is an
 # interleaving artifact, not a protocol property (dev0 was observed
-# completing all iterations while 7 peers sat in _allocate_buffer; see
-# docs/ROUND2_NOTES.md).  Executed chunked tests therefore stay at C=2,
+# completing all iterations while 7 peers sat in _allocate_buffer).
+# Executed chunked tests therefore stay at C=2,
 # K=28, small rows — empirically stable; the >=100 MB bounded-VMEM case is
 # covered compile-side by test_chunked_large_tensor_plan_and_lowering.
 

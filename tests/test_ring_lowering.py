@@ -10,7 +10,7 @@ if any kernel stops lowering, without needing TPU hardware.
 This is also where the >=100 MB chunked-allreduce case is proven compile-
 side: the full-depth plan (C=4) lowers for TPU with VMEM scratch bounded by
 the plan, while the interpreter on this single-core host cannot execute
-configs that large (see test_ring.py's NOTE and docs/ROUND2_NOTES.md).
+configs that large (see test_ring.py's NOTE).
 """
 
 import jax

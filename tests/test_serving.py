@@ -85,6 +85,44 @@ def test_slot_pool_lifecycle():
 
 
 # ---------------------------------------------------------------------------
+# Default replica placement: one device each, wrapping round
+# ---------------------------------------------------------------------------
+
+
+def _homes(srv):
+    """The single device each replica's params AND cache live on."""
+    homes = []
+    for e in srv.router.replicas:
+        on = {d for leaf in jax.tree.leaves((e.params, e._cache))
+              for d in leaf.devices()}
+        assert len(on) == 1, (e.name, on)
+        homes.append(on.pop())
+    return homes
+
+
+@pytest.mark.parametrize("replicas", [1, 3, 8, 11])
+def test_default_replicas_spread_over_local_devices(lm, replicas):
+    model, params = lm
+    local = jax.local_devices()  # the conftest's eight CPU devices
+    srv = serving.Server(model, params, replicas=replicas, slots=1,
+                         slot_tokens=16)
+    homes = _homes(srv)
+    assert homes == [local[i % len(local)] for i in range(replicas)]
+    assert len(set(homes)) == min(replicas, len(local))
+
+
+def test_callers_own_devices_are_honoured(lm):
+    model, params = lm
+    local = jax.local_devices()
+    srv = serving.Server(model, params, replicas=2, slots=1,
+                         slot_tokens=16, devices=[local[5], local[2]])
+    assert _homes(srv) == [local[5], local[2]]
+    with pytest.raises(ValueError, match="only 1 devices"):
+        serving.Server(model, params, replicas=2, slots=1, slot_tokens=16,
+                       devices=local[:1])
+
+
+# ---------------------------------------------------------------------------
 # Continuous batching == offline generate, token for token
 # ---------------------------------------------------------------------------
 

@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from torchmpi_tpu.utils import checkpoint, metrics, tracing
+from torchmpi_tpu.utils import checkpoint, compilecache, metrics, tracing
 
 
 def tree():
@@ -97,3 +97,63 @@ def test_trace_failed_start_propagates_body_error(tmp_path):
         with pytest.raises(ValueError, match="the real error"):
             with tracing.trace(str(tmp_path / "inner")):
                 raise ValueError("the real error")
+
+
+# ---------------------------------------------------------------------------
+# Persistent compile cache: one rule for where it lives
+# ---------------------------------------------------------------------------
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture()
+def cache_config(monkeypatch):
+    """Record what the code sets, and leave this process's jax config as
+    it was (the rest of the suite runs with the persistent cache off)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    prev = {n: getattr(jax.config, n) for n in names}
+    updates = {}
+    real = jax.config.update
+
+    def recording_update(name, value):
+        updates[name] = value
+        real(name, value)
+
+    monkeypatch.setattr(jax.config, "update", recording_update)
+    yield updates
+    for n, v in prev.items():
+        jax.config.update(n, v)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("calls", [1, 2])
+def test_compile_cache_env_set_code_sets_no_directory(
+        cache_config, monkeypatch, tmp_path, calls):
+    # JAX_COMPILATION_CACHE_DIR set: jax already uses it; repo code
+    # reports it and never touches jax_compilation_cache_dir.
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    got = {compilecache.enable_persistent_cache() for _ in range(calls)}
+    assert got == {str(tmp_path)}
+    assert "jax_compilation_cache_dir" not in cache_config
+    assert cache_config["jax_persistent_cache_min_compile_time_secs"] == 1.0
+
+
+@pytest.mark.parametrize("calls", [1, 2])
+@pytest.mark.parametrize("old_knob", [None, "/somewhere/else"])
+def test_compile_cache_env_unset_fixed_checkout_path(
+        cache_config, monkeypatch, calls, old_knob):
+    # Unset: the fixed <checkout>/.jax_compile_cache, the same on every
+    # call (the path is part of the cache's key).  The removed
+    # TORCHMPI_TPU_COMPILE_CACHE knob places nothing.
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    if old_knob:
+        monkeypatch.setenv("TORCHMPI_TPU_COMPILE_CACHE", old_knob)
+    want = os.path.join(_REPO, ".jax_compile_cache")
+    got = {compilecache.enable_persistent_cache() for _ in range(calls)}
+    assert got == {want} and compilecache.DEFAULT_DIR == want
+    assert cache_config["jax_compilation_cache_dir"] == want
+    assert jax.config.jax_compilation_cache_dir == want

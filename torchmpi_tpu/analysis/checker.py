@@ -1,8 +1,7 @@
 """The analyzer entry points: trace, walk, run rules, report.
 
 ``check(fn, *args)`` is the whole pipeline: trace ``fn`` to a
-``ClosedJaxpr`` (``jax.make_jaxpr`` — the compat-shimmed jax surface of
-``utils/jaxcompat.py`` applies), walk it into a collective-event stream
+``ClosedJaxpr`` (``jax.make_jaxpr``), walk it into a collective-event stream
 (:mod:`events`), collect the trace-time fusion/ZeRO layout records, and
 run the rule registry (:mod:`rules`).  Everything is trace-time only:
 nothing here ever runs device code or touches the step's runtime cost.

@@ -88,7 +88,7 @@ def _user_source(source_info) -> str:
     try:
         from jax._src import source_info_util
 
-        fr = source_info_util.user_frame(source_info)
+        fr = source_info_util.user_frame(source_info.traceback)
         if fr is None:
             return ""
         name = getattr(fr, "function_name", "") or ""

@@ -115,13 +115,6 @@ H2_DOC_IGNORE = frozenset({
     # The PS server's native stats-struct name, mentioned in the
     # tm_ps_{...}_total row's description — not itself a metric.
     "tm_ps_server_stats",
-    # Per-stage ladder outcome counters: the bench supervisor writes
-    # these BY HAND in the obs dump format (it never imports the
-    # package, so they are not registry metrics — see bench.py
-    # bank_stage_counters).
-    "tm_bench_stage_live_total",
-    "tm_bench_stage_banked_total",
-    "tm_bench_stage_wedged_total",
 })
 
 # Fault-injection wrapper spellings whose first literal argument is a

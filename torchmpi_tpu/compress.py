@@ -52,9 +52,7 @@ from jax import lax
 from . import fusion, runtime
 
 # Codec name -> wire dtype.  fp8 is e4m3 (the gradient-friendly wide-
-# mantissa variant); jaxcompat guarantees nothing here — an older jax
-# without float8 support fails validate_wire loudly instead of
-# miscompiling.
+# mantissa variant).
 CODECS = ("bf16", "int8", "fp8")
 _WIRE_DTYPES = {
     "bf16": jnp.bfloat16,

@@ -136,9 +136,9 @@ class Config:
     # xent defaults.
     flash_block_q: int = 512
     flash_block_k: int = 512
-    # 256-token xent tiles measured above the noise gate on a real v5e
-    # (2026-07-31 live autotune, docs/artifacts/autotune_20260731_*.json:
-    # 14.6 ms median vs 15.4 at 128, jitter ~0.6 ms); the VMEM block-fit
+    # 256-token xent tiles measured above the noise gate on a v5e in
+    # July 2026 (14.6 ms median vs 15.4 at 128, jitter ~0.6 ms; not
+    # re-measured on today's code); the VMEM block-fit
     # clamp (ops/xent._fit_blocks) shrinks them automatically where E is
     # too large for the scoped budget.
     xent_block_n: int = 256
@@ -430,9 +430,8 @@ class Config:
     gradsync_buckets: int = 1
     # Chain buckets through optimization barriers so they stay distinct
     # through XLA's all-reduce combiner (measured: the combiner otherwise
-    # merges sub-threshold buckets into one collective — see
-    # docs/artifacts/overlap_summary.md).  Off by default: one fused
-    # all-reduce is usually fastest below the combine threshold.
+    # merges sub-threshold buckets into one collective).  Off by default:
+    # one fused all-reduce is usually fastest below the combine threshold.
     gradsync_barrier: bool = False
     # Backprop-overlapped gradient sync (docs/OVERLAP.md): "off"
     # (default — the step builders run the post-backward

@@ -71,8 +71,8 @@ _INTERPRET = None
 # in np.array() on XLA-computed initial values, and with one XLA CPU
 # execution thread a synchronously-blocking semaphore-wait callback starves
 # the executor that would materialize them (observed: dev0 completed all 56
-# iterations while 7 peers sat in _allocate_buffer; faulthandler dump in
-# docs/ROUND2_NOTES.md).  Real Mosaic lowering has no such limit; when the
+# iterations while 7 peers sat in _allocate_buffer).  Real Mosaic
+# lowering has no such limit; when the
 # plan exceeds the cap under interpret, subchunks are coarsened (C shrinks,
 # sub_elems grows) — the simulated schedule stays chunked, just shallower.
 _INTERPRET_MAX_ITERS = 28
@@ -104,8 +104,8 @@ def local_kernel_params(interpret):
     fused-xent — in the ring/ulysses stacks the rotation happens OUTSIDE
     the kernel via ppermute) touch no remote memory, so that pre-kernel
     barrier is pure interpreter overhead, and on a starved host it is
-    where the flaky full-suite abort parks its threads
-    (docs/ROUND4_NOTES.md).  Declaring a collective_id under interpret
+    where the flaky full-suite abort parks its threads.
+    Declaring a collective_id under interpret
     skips it; real TPU lowering is untouched (collective_id there
     allocates a cross-chip barrier semaphore local kernels must not
     claim).  Lives here next to :func:`_interpret_mode`, the shared
@@ -133,10 +133,7 @@ def _interpret_mode():
         else:
             platform = jax.default_backend()
         if platform == "cpu":
-            if hasattr(pltpu, "InterpretParams"):
-                return pltpu.InterpretParams()
-            # Older jax (no InterpretParams): the boolean interpreter.
-            return True
+            return pltpu.InterpretParams()
     except Exception:
         pass
     return False
@@ -709,8 +706,9 @@ def _ring_reduce_scatter_chunked_kernel(x_ref, o_ref, work_ref, comm, acc,
                                         send_sem, recv_sem, ack_sem,
                                         *, n: int, C: int, axis: str,
                                         mesh_axes: Tuple[str, ...]):
-    """Chunked RS phase only: x/work ``[n, C, rows, 128]`` in HBM, o
-    ``[C, rows, 128]`` (the fully-reduced chunk ``my``).  The shared
+    """Chunked RS phase only: x/work ``[n, C, rows, 128]`` in HBM (work
+    is the call's second output), o ``[C, rows, 128]`` (the
+    fully-reduced chunk ``my``).  The shared
     :func:`_chunked_pipeline` with the shifted RS schedule."""
     my, left, right, coords = _neighbor_setup(axis, mesh_axes, n)
 
@@ -769,13 +767,16 @@ def _ring_reduce_scatter_chunked(xin, n: int, axis: str,
     x = xin.reshape(n, C, rows, _LANES)
     kernel = functools.partial(_ring_reduce_scatter_chunked_kernel, n=n, C=C,
                                axis=axis, mesh_axes=mesh_axes)
-    out = pl.pallas_call(
+    # The HBM work buffer is a second, discarded OUTPUT: the chip's
+    # compiler allocates scratch only in VMEM/SMEM/semaphore memory.
+    out, _work = pl.pallas_call(
         kernel,
-        out_shape=_out_sds((C, rows, _LANES), x),
+        out_shape=(_out_sds((C, rows, _LANES), x),
+                   _out_sds((n, C, rows, _LANES), x)),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_specs=(pl.BlockSpec(memory_space=pl.ANY),
+                   pl.BlockSpec(memory_space=pl.ANY)),
         scratch_shapes=[
-            pltpu.MemorySpace.HBM((n, C, rows, _LANES), x.dtype),  # work
             pltpu.VMEM((2, rows, _LANES), x.dtype),                # comm
             pltpu.VMEM((2, rows, _LANES), x.dtype),                # acc
             pltpu.SemaphoreType.DMA((2,)),                         # copy_in

@@ -218,8 +218,8 @@ def _bucketed_allreduce(grads: PyTree, axes: Tuple[str, ...], *, op: str,
     output (across dtype groups too) through ``lax.optimization_barrier``,
     which keeps the K all-reduces DISTINCT through XLA's all-reduce
     combiner (measured: below the combine threshold the combiner
-    otherwise merges every bucket into one collective —
-    docs/artifacts/overlap_summary.md) and issues them in order, so the
+    otherwise merges every bucket into one collective) and issues them
+    in order, so the
     latency-hiding scheduler can overlap bucket i's downstream use with
     bucket i+1's collective.  The cost is serialization of the
     collectives themselves; leave it off when one fused all-reduce is

@@ -316,8 +316,8 @@ class ShardedParameterServer:
         response payloads inside ``bytes_out``, tracked separately so
         throughput models don't count them as apply work —
         benchmarks/ps_bench.py).  The idle wait between requests is in
-        no bucket.  Backs ps_bench's loopback breakdown and the scaling
-        model in docs/ROUND3_NOTES.md.
+        no bucket.  Backs ps_bench's loopback breakdown and its scaling
+        model.
 
         Consistency (ADVICE round 5): the native counters update in
         groups under the shard mutex and the snapshot reads under the

@@ -706,13 +706,6 @@ def init(config: Optional[Config] = None, **overrides) -> Mesh:
             )
             _state.distributed_initialized = True
 
-        # Re-assert the relay compile-budget gate (armed at package
-        # import; a client may have uninstalled it or imported around
-        # the package __init__).  See utils/compilegate.py.
-        from .utils import compilegate
-
-        compilegate.install()
-
         # Arm (or disarm a stale) fault layer BEFORE the runtime marks
         # itself initialized: a corrupt/missing fault plan must fail
         # init outright — never leave a half-armed runtime behind a

@@ -41,6 +41,7 @@ import time
 from collections import deque
 from typing import List, Optional, Sequence
 
+import jax
 import numpy as np
 
 from .. import runtime
@@ -124,8 +125,10 @@ class Server:
 
     Replica count / slots / slot block size default from the active
     Config (``serving_replicas`` / ``serving_slots`` /
-    ``serving_slot_tokens``).  ``devices`` optionally pins replica i to
-    ``devices[i]`` (data-parallel spread on a multi-chip host).
+    ``serving_slot_tokens``).  Replica i lives on
+    ``jax.local_devices()[i % n_local]`` (data-parallel spread on a
+    multi-chip host); ``devices`` pins replica i to ``devices[i]``
+    instead.
     """
 
     # Class-level defaults so a hand-assembled Server (tests build one
@@ -155,14 +158,18 @@ class Server:
                     else cfg.serving_replicas)
             if n < 1:
                 raise ValueError(f"need >= 1 replica, got {n}")
-            if devices is not None and len(devices) < n:
+            if devices is None:
+                # Spread over this host's chips, wrapping round when
+                # there are more replicas than devices.
+                local = jax.local_devices()
+                devices = [local[i % len(local)] for i in range(n)]
+            elif len(devices) < n:
                 raise ValueError(
                     f"{n} replicas but only {len(devices)} devices")
             engines = [
                 ReplicaEngine(model, params, name=f"replica{i}",
                               slots=slots, slot_tokens=slot_tokens,
-                              device=devices[i] if devices is not None
-                              else None, sample=sample,
+                              device=devices[i], sample=sample,
                               prefill_bucket=prefill_bucket,
                               spec_k=spec_k, draft=draft,
                               prefix_cache=prefix_cache,

@@ -6,9 +6,9 @@ the platform-forcing quirks live in exactly one place:
 
 - ``XLA_FLAGS`` is read at backend-init time, so appending the forced host
   device count here works even if jax was already imported;
-- ``JAX_PLATFORMS`` may have been consumed at import (e.g. a sitecustomize
-  pinning a real TPU platform), so the platform is forced via ``jax.config``
-  instead of the environment.
+- ``JAX_PLATFORMS`` is read when jax is imported, which may already have
+  happened, so the platform is forced via ``jax.config`` instead of the
+  environment.
 """
 
 from __future__ import annotations
