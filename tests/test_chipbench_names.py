@@ -1,0 +1,307 @@
+"""The benchmark's readers of the program's own names (``chipbench/``):
+``xplane_meta`` (what the profile's event metadata says of each operation:
+``tf_op``, ``hlo_category``, ``flops``, ``bytes_accessed``), ``xplane_scopes``
+(device time under a transform, a module or a Pallas kernel's ``tm_kernel``
+identity) and ``program_span`` (the program's ``tm.step`` host span).
+
+Here and not under ``chipbench/tests/`` so that the tier-1 command collects
+them.  No test imports the chip's library; the rehearsal runs the command
+as the driver does, in a process of its own.
+"""
+
+import json
+import os
+import re
+import subprocess
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from chipbench import harness, xplane, xplane_meta  # noqa: E402
+
+MANIFEST = harness.load_manifest()
+TESTDATA = os.path.join(harness.BENCH, "testdata")
+SCOPES = harness.load_module(MANIFEST, "readers", "xplane_scopes")
+SPAN = harness.load_module(MANIFEST, "readers", "program_span")
+
+# what this PR added to BENCHMARK.json: metric -> the cells that report it
+TOK, IMG = ["sc2-3b-t8k", "sc2-3b-t1k"], ["rn50-1chip", "rn50-dp4"]
+NEW_METRICS = {
+    **{f"{k}_ms_per_step.tok": TOK
+       for k in ("flash_fwd", "flash_bwd", "xent_fwd", "xent_bwd", "fwd",
+                 "bwd", "mlp", "attn_proj")},
+    "fwd_ms_per_step.img": IMG, "bwd_ms_per_step.img": IMG,
+    "step_span_ms.tok": TOK, "step_span_ms.img": IMG,
+}
+
+with open(os.path.join(TESTDATA, "expected_names.json")) as _f:
+    WANT = json.load(_f)
+
+
+@pytest.fixture(scope="module")
+def recorded():
+    """The recorded ``sc2-3b-t8k`` step: (Trace, metadata of its plane)."""
+    path = os.path.join(TESTDATA, WANT["file"])
+    return xplane.load(path), xplane_meta.load(path)
+
+
+# ------------------------------------------------------------ the manifest
+
+
+@pytest.mark.parametrize("metric", sorted(NEW_METRICS))
+def test_new_metric_resolves_to_a_file_and_a_reader(metric):
+    entry = harness.by_name(MANIFEST["per_layer"], metric, "metric")
+    assert entry["workloads"] == NEW_METRICS[metric]
+    assert entry["unit"] == "ms" and entry["better"] == "lower"
+    spec = harness.load_json(MANIFEST, "layer_metrics", metric)
+    reader = harness.load_module(MANIFEST, "readers", spec["reader"])
+    if spec["reader"] == "program_span":
+        assert entry["source"] == "program_span"
+        assert spec["args"] == {"span": "tm.step"}
+    else:
+        assert entry["source"] == "device_trace" and reader is SCOPES
+        assert set(spec["args"]) <= {"op_name", "not_op_name", "kernel"}
+    for cell in entry["workloads"]:
+        mine = harness.by_name(harness.resolve(MANIFEST, cell).per_layer,
+                               metric, "metric")
+        assert mine["args"] == spec["args"]
+
+
+def test_benchmark_json_only_gained_entries_at_the_end():
+    """What the benchmark had (PR 23) is still there, first and unchanged
+    in order; the new metrics follow it."""
+    names = [m["name"] for m in MANIFEST["per_layer"]]
+    assert names[10:] and set(names[10:]) == set(NEW_METRICS)
+    assert names[:10] == [
+        "dispatch_ms_per_step.img", "dispatch_ms_per_step.tok",
+        "collective_ms_per_step.img", "collective_exposed_ms_per_step.img",
+        "flash_roofline_pct.tok", "xent_roofline_pct.tok", "mfu_pct.img",
+        "mfu_pct.tok", "device_idle_pct.img", "device_idle_pct.tok"]
+    layers = {m["layer"] for m in MANIFEST["per_layer"][:10]}
+    assert {m["layer"] for m in MANIFEST["per_layer"][10:]} <= layers
+
+
+# --------------------------------------------------- file -> metadata, sums
+
+
+def test_xplane_meta_reads_the_recorded_metadata(recorded):
+    trace, meta = recorded
+    assert sorted(meta) == WANT["planes"]
+    ops = meta[WANT["device"]]
+    assert len(ops) == WANT["operations"]
+    assert sum("tf_op" in v for v in ops.values()) == WANT["with_tf_op"]
+    assert all(set(v) <= set(xplane_meta.KEEP) for v in ops.values())
+    for name_start, want in WANT["samples"].items():
+        (name,) = [n for n in ops if n.startswith(name_start)]
+        assert ops[name] == want
+    # every event of the trace finds its operation by name
+    events = trace.devices[WANT["device"]]
+    assert len(events) == WANT["events"]
+    assert all(e.name in ops for e in events)
+    categories = {}
+    for e in events:
+        c = ops[e.name].get("hlo_category")
+        categories[c] = categories.get(c, 0) + 1
+    assert categories == WANT["events_by_hlo_category"]
+
+
+def test_scopes_sum_the_recorded_step(recorded):
+    trace, meta = recorded
+    ctx = {"trace": trace, "traced_steps": 1}
+    got = {}
+    for metric, want_ms in WANT["ms_per_step"].items():
+        args = harness.load_json(MANIFEST, "layer_metrics", metric)["args"]
+        seconds, named, total = SCOPES.scope_s(trace.devices, meta, **args)
+        got[metric] = 1e3 * seconds / ctx["traced_steps"]
+        assert got[metric] == pytest.approx(want_ms, rel=1e-9), metric
+    assert 100.0 * named / total == pytest.approx(WANT["tf_op_pct"])
+    assert named / total > 0.9
+    busy_ms = 1e3 * xplane.device_busy(trace)["busy_s"]
+    g = {k.split("_ms_")[0]: v for k, v in got.items()}
+    # the kernels' two halves are the time the roofline metrics divide by
+    ops_r = harness.load_module(MANIFEST, "readers", "xplane_ops")
+    for family, parts in (("flash", ("flash_fwd", "flash_bwd")),
+                          ("xent", ("xent_fwd", "xent_bwd"))):
+        pattern = harness.load_json(
+            MANIFEST, "layer_metrics",
+            f"{family}_roofline_pct.tok")["args"]["pattern"]
+        rx = re.compile(pattern)
+        by_name = 1e3 * sum(
+            (e.end - e.start) / 1e9 for es in trace.devices.values()
+            for e in es if ops_r.matches(e, rx))
+        assert sum(g[p] for p in parts) == pytest.approx(by_name, rel=1e-9)
+    # forward and backward are most of the step, and inside it
+    assert 0.9 * busy_ms < g["fwd"] + g["bwd"] < busy_ms
+    assert g["mlp"] + g["attn_proj"] + g["flash_fwd"] + g["flash_bwd"] \
+        < g["fwd"] + g["bwd"]
+
+
+def test_scopes_reader_end_to_end_on_the_recorded_step(recorded, monkeypatch):
+    """``read`` as the runner calls it, the profile being the fixture."""
+    trace, _ = recorded
+    monkeypatch.setattr(
+        SCOPES, "raw_trace", lambda ctx: os.path.join(TESTDATA, WANT["file"]))
+    ctx = {"trace": trace, "traced_steps": 1}
+    for metric in ("flash_fwd_ms_per_step.tok", "bwd_ms_per_step.tok"):
+        args = harness.load_json(MANIFEST, "layer_metrics", metric)["args"]
+        assert SCOPES.read(ctx, **args) == pytest.approx(
+            WANT["ms_per_step"][metric], rel=1e-9)
+    # a program without the identity (the parent commit): no number
+    assert SCOPES.read(ctx, kernel=r"flash\.nothing") is None
+
+
+def test_program_span_reads_the_recorded_host_plane(recorded):
+    trace, _ = recorded
+    spans = SPAN.host_spans(os.path.join(TESTDATA, WANT["file"]), "tm.step")
+    assert [st["step_num"] for _, _, st in spans] == WANT["step_nums"]
+    assert SPAN.span_ms(spans, trace.window) == pytest.approx(
+        WANT["step_span_ms"], rel=1e-9)
+    assert SPAN.host_spans(os.path.join(TESTDATA, WANT["file"]),
+                           "tm.step.throttle") == []
+
+
+# ------------------------------------------------- events -> numbers, by hand
+
+E = xplane.Event
+FWD = "jit(wrapped)/jvp(TransformerLM)/"
+BWD = "jit(wrapped)/transpose(jvp(TransformerLM))/"
+
+
+def kernel(name, identity):
+    return (f"%{name} = f32[8]{{0}} custom-call(f32[8]{{0}} %p), "
+            'custom_call_target="tpu_custom_call", frontend_attributes='
+            f'{{kernel_metadata={{\n"tm_kernel":"{identity}"\n}}}}')
+
+
+HAND_META = {"/device:TPU:0": {
+    "%fusion.1 = dot": {"tf_op": FWD + "Block_0/Dense_0/dot_general:"},
+    "%fusion.2 = dot": {"tf_op": BWD + "Block_0/Dense_1/dot_general:"},
+    "%fusion.3 = qkv": {"tf_op": FWD + "Block_0/SPAttention_0/q/dot_general:"},
+    kernel("SPAttention_0.4", "flash.fwd"): {
+        "tf_op": FWD + "Block_0/SPAttention_0/pallas_call:"},
+    kernel("SPAttention_0.5", "flash.dq"): {
+        "tf_op": BWD + "Block_0/SPAttention_0/pallas_call:"},
+    kernel("SPAttention_0.6", "flash.dkv"): {
+        "tf_op": BWD + "Block_0/SPAttention_0/pallas_call:"},
+    kernel("jvp__.7", "xent.fwd"): {"tf_op": "jit(wrapped)/jvp()/pallas_call:"},
+    "%fusion.8 = adamw": {"tf_op": "jit(wrapped)/add:"},
+    "%copy-done.9 = copy": {},
+}}
+
+
+def hand_trace():
+    ns = 1_000_000
+    names = list(HAND_META["/device:TPU:0"])
+    events = [E(n, i * ns, (i + 1) * ns, {}) for i, n in enumerate(names)]
+    return xplane.Trace({"/device:TPU:0": events}, {}, (0, 9 * ns))
+
+
+SCOPE_CASES = {
+    "forward": ({"op_name": r"jvp\(", "not_op_name": r"transpose\("}, 4),
+    "backward": ({"op_name": r"transpose\(jvp\("}, 3),
+    "mlp": ({"op_name": r"/Block_\d+/Dense_\d+/"}, 2),
+    "attention_outside_the_kernel": (
+        {"op_name": r"/SPAttention_\d+/", "not_op_name": "pallas_call"}, 1),
+    "one_kernel": ({"kernel": r"flash\.fwd"}, 1),
+    "two_kernels": ({"kernel": r"flash\.d(q|kv)"}, 2),
+    "kernel_and_pass": ({"kernel": r"flash\..*",
+                         "op_name": r"transpose\("}, 2),
+    "a_prefix_is_not_the_identity": ({"kernel": "flash"}, 0),
+    "everything": ({}, 9),
+    "nothing": ({"op_name": "no such scope"}, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SCOPE_CASES))
+def test_scopes_on_hand_made_events(case):
+    args, want_ms = SCOPE_CASES[case]
+    trace = hand_trace()
+    seconds, named, total = SCOPES.scope_s(trace.devices, HAND_META, **args)
+    assert seconds == pytest.approx(want_ms * 1e-3)
+    assert named == pytest.approx(8e-3) and total == pytest.approx(9e-3)
+
+
+def test_scopes_reader_gives_no_number_without_a_device_plane():
+    # a rehearsal on the CPU: no device events, so no file is looked for
+    ctx = {"trace": xplane.Trace({}, {}, (0, 0)), "traced_steps": 10}
+    assert SCOPES.read(ctx, op_name=r"jvp\(") is None
+    assert SCOPES.read({**ctx, "trace": hand_trace(), "traced_steps": 0},
+                       kernel="flash.fwd") is None
+
+
+def test_program_span_on_hand_made_spans():
+    ms = 1_000_000
+    spans = [(0 * ms, 1 * ms, {"step_num": 3}),       # before the window
+             (10 * ms, 12 * ms, {"step_num": 4}),
+             (20 * ms, 21 * ms, {"step_num": 5}),
+             (29 * ms, 31 * ms, {"step_num": 6})]     # runs past its end
+    assert SPAN.span_ms(spans, (5 * ms, 30 * ms)) == pytest.approx(1.5)
+    assert SPAN.span_ms(spans, (40 * ms, 50 * ms)) is None
+    assert SPAN.span_ms([], (0, 50 * ms)) is None
+
+
+def test_wire_reader_on_a_hand_made_message():
+    def varint(n):
+        out = bytearray()
+        while True:
+            out.append((n & 0x7F) | (0x80 if n > 0x7F else 0))
+            n >>= 7
+            if not n:
+                return bytes(out)
+
+    def field(number, payload):
+        if isinstance(payload, int):
+            return varint(number << 3) + varint(payload)
+        return varint(number << 3 | 2) + varint(len(payload)) + payload
+
+    def entry(key, message):
+        return field(1, key) + field(2, message)
+
+    stat_names = {1: b"tf_op", 2: b"flops", 4: b"other"}
+    plane = field(2, b"/device:TPU:0")
+    plane += field(3, b"\x08\x01" * 40)              # a line: skipped
+    for key, name in stat_names.items():
+        plane += field(5, entry(key, field(1, key) + field(2, name)))
+    op = (field(1, 7) + field(2, b"%fusion.1 = f32[] fusion()")
+          + field(5, field(1, 1) + field(5, b"jit(f)/mul:"))
+          + field(5, field(1, 2) + field(3, 300))   # flops, a uint64
+          + field(5, field(1, 4) + field(5, b"not kept"))
+          + bytes([9 << 3 | 1]) + b"\x00" * 8)     # a fixed64: skipped
+    plane += field(4, entry(7, op))
+    plane += field(4, entry(8, field(1, 8) + field(2, b"%copy.2 = copy()")))
+    space = field(1, plane) + field(1, field(2, b"/host:CPU"))
+    got = dict(xplane_meta.plane_meta(memoryview(v))
+               for n, _, v in xplane_meta.fields(memoryview(space)) if n == 1)
+    assert got == {
+        "/device:TPU:0": {
+            "%fusion.1 = f32[] fusion()": {"tf_op": "jit(f)/mul:",
+                                           "flops": 300},
+            "%copy.2 = copy()": {}},
+        "/host:CPU": {}}
+    with pytest.raises(ValueError, match="not an XSpace"):
+        list(xplane_meta.fields(memoryview(b"\x0b")))    # a group: never
+
+
+# ---------------------------------------------------------------- rehearsal
+
+
+def test_traced_rehearsal_leaves_the_new_device_metrics_out():
+    """On the CPU there is no device plane: every ``device_trace`` metric
+    this PR added is left out and nothing fails; the program's span is on
+    the host plane, so ``step_span_ms`` is there."""
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    p = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "chipbench", "run.py"),
+         "--workload", "sc2-3b-t8k", "--seed", "4294967301", "--seconds",
+         "0.3", "--trace", "1", "--rehearse"],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=300)
+    assert p.returncode == 0, p.stderr[-2000:]
+    out = json.loads(p.stdout.strip().splitlines()[-1])
+    assert out["correct"] is True and out["rehearsal"] is True
+    new = {m for m, cells in NEW_METRICS.items() if "sc2-3b-t8k" in cells}
+    assert new & set(out["metrics"]) == {"step_span_ms.tok"}
+    assert out["metrics"]["step_span_ms.tok"]["value"] > 0
+    assert "dispatch_ms_per_step.tok" in out["metrics"]

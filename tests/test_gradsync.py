@@ -548,3 +548,87 @@ def test_overlap_bucket_bytes_from_tuning_plan(flat_runtime, tmp_path):
         assert gradsync.overlap_bucket_bytes(mesh) == 1 << 20
     finally:
         tuning.reset()
+
+
+# ---- the step span on the profiler's clock (throttle_dispatch) ----------
+
+
+def _traced_spans(trace_dir, body):
+    """Run ``body`` under ``jax.profiler``; -> the host plane's ``tm.*``
+    events in order of their start, as ``[(name, stats)]``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=options)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
+    events = [(e.start_ns, e.name, dict(e.stats))
+              for plane in ProfileData.from_file(path).planes
+              if plane.name == "/host:CPU"
+              for line in plane.lines for e in line.events
+              if e.name.startswith("tm.")]
+    return [(name, stats) for _, name, stats in sorted(
+        events, key=lambda e: e[0])]
+
+
+def test_dp_step_writes_one_tm_step_span_a_call(flat_runtime, tmp_path):
+    def step_fn(params, opt_state, batch):
+        grads = jax.grad(lambda p: ((batch @ p) ** 2).mean())(params)
+        grads = gradsync.synchronize_gradients(grads)
+        return params - 0.1 * grads, opt_state
+
+    # five calls never fill a window of eight: no throttle span, however
+    # slowly the steps run (the CPU default of two would wait for some)
+    step = gradsync.data_parallel_step(step_fn, donate_argnums=(),
+                                       max_inflight=8)
+    state = [jnp.ones((8, 3)), jnp.zeros(())]
+    batch = jnp.ones((16, 8))
+    state = step(*state, batch)       # call 0: compiles, untraced
+
+    def four_calls():
+        s = state
+        for _ in range(4):
+            s = step(*s, batch)
+        jax.block_until_ready(s)
+
+    spans = _traced_spans(tmp_path, four_calls)
+    assert [name for name, _ in spans] == ["tm.step"] * 4
+    # the stepper's own count, from its first call on
+    assert [stats["step_num"] for _, stats in spans] == [1, 2, 3, 4]
+
+
+@pytest.mark.parametrize("running,held_back", [(False, 0), (True, 3)])
+def test_throttle_span_marks_only_a_wait_for_a_running_step(
+        tmp_path, running, held_back):
+    """``tm.step.throttle`` counts the steps the throttle held back: past
+    ``max_inflight`` calls the window is always full, but a token that is
+    ready when its turn comes was not waited for."""
+    waited = []
+
+    class Token:
+        def is_ready(self):
+            return not running
+
+        def block_until_ready(self):
+            waited.append(self)
+            return self
+
+    step = gradsync.throttle_dispatch(lambda: ("out", Token()),
+                                      max_inflight=2)
+    spans = _traced_spans(
+        tmp_path, lambda: [step() for _ in range(5)])
+    names = [name for name, _ in spans]
+    assert names.count("tm.step") == 5
+    assert names.count("tm.step.throttle") == held_back
+    assert len(waited) == 3     # calls 3, 4, 5 each retire the oldest token
+    assert [s["step_num"] for n, s in spans if n == "tm.step"] == [
+        0, 1, 2, 3, 4]
+    if held_back:               # the wait comes before that call's dispatch
+        assert names[:4] == ["tm.step", "tm.step", "tm.step.throttle",
+                             "tm.step"]

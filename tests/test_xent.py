@@ -1,5 +1,7 @@
 """Fused linear+cross-entropy kernel (ops/xent.py) vs the dense oracle."""
 
+import re
+
 import numpy as np
 import pytest
 
@@ -184,3 +186,49 @@ def test_xent_matches_dense_with_clamped_blocks(flat_runtime):
     got = fused_linear_cross_entropy(x, w, labels, block_n=16, block_v=32)
     np.testing.assert_allclose(got, _dense(x, w, labels), rtol=2e-5,
                                atol=2e-5)
+
+
+def _flash_case():
+    from torchmpi_tpu.ops.flash import flash_attention_grad
+    from torchmpi_tpu.parallel.sequence import reference_attention
+
+    q, k, v = (_rand((1, 32, 2, 8), s) for s in (20, 21, 22))
+
+    def kernel(q, k, v):
+        return flash_attention_grad(q, k, v, causal=True, block_q=16,
+                                    block_k=16).sum()
+
+    def dense(q, k, v):
+        return reference_attention(q, k, v, causal=True).sum()
+
+    return kernel, dense, (q, k, v), ["flash.fwd", "flash.dq", "flash.dkv"]
+
+
+def _xent_case():
+    x, w = _rand((24, 16), 23), _rand((16, 48), 24)
+    labels = jnp.asarray(np.random.RandomState(25).randint(0, 48, 24))
+
+    def kernel(x, w):
+        return fused_linear_cross_entropy(x, w, labels, block_n=8,
+                                          block_v=16).sum()
+
+    return (kernel, lambda x, w: _dense(x, w, labels).sum(), (x, w),
+            ["xent.fwd", "xent.dx", "xent.dw"])
+
+
+@pytest.mark.parametrize("family", ["flash", "xent"])
+def test_kernels_carry_their_identity_and_still_interpret(flat_runtime,
+                                                          family):
+    """Every pallas_call passes ``metadata={"tm_kernel": ...}``
+    (ops/ring.kernel_identity: what a device trace finds a kernel by), and
+    the interpreter, which has no use for it, runs them as before."""
+    kernel, dense, args, identities = {"flash": _flash_case,
+                                       "xent": _xent_case}[family]()
+    argnums = tuple(range(len(args)))
+    text = str(jax.make_jaxpr(jax.grad(kernel, argnums=argnums))(*args))
+    assert re.findall(r"'tm_kernel': '([^']+)'", text) == identities
+    got = jax.value_and_grad(kernel, argnums=argnums)(*args)
+    want = jax.value_and_grad(dense, argnums=argnums)(*args)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4)

@@ -115,6 +115,10 @@ H2_DOC_IGNORE = frozenset({
     # The PS server's native stats-struct name, mentioned in the
     # tm_ps_{...}_total row's description — not itself a metric.
     "tm_ps_server_stats",
+    # The key of a Pallas kernel's identity in a profile
+    # (ops/ring.kernel_identity; "What a profile shows") — read by the
+    # profiler, never by the registry.
+    "tm_kernel",
 })
 
 # Fault-injection wrapper spellings whose first literal argument is a

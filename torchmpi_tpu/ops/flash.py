@@ -615,6 +615,7 @@ def flash_attention(q, k, v, *, causal: bool = False,
         ],
         interpret=interpret,
         compiler_params=_flash_params(interpret),
+        metadata=ring.kernel_identity("flash.fwd"),
     )(qo, ko, qt, kt, vt)
     out = result if single else result[0]
     if pad_q:
@@ -713,6 +714,7 @@ def flash_attention_bwd(q, k, v, do, lse, dvec, *, causal: bool,
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
         compiler_params=_flash_params(interpret),
+        metadata=ring.kernel_identity("flash.dq"),
     )(qo, ko, qt, dot_, lse_l, d_l, kt, vt)
 
     # dkv grid puts the q-block dimension minor; index maps swap i and j
@@ -744,6 +746,7 @@ def flash_attention_bwd(q, k, v, do, lse, dvec, *, causal: bool,
                         pltpu.VMEM((block_k, D), jnp.float32)],
         interpret=interpret,
         compiler_params=_flash_params(interpret),
+        metadata=ring.kernel_identity("flash.dkv"),
     )(qo, ko, kt, vt, qt, dot_, lse_l, d_l)
     if group > 1:
         dk = dk.reshape(B, Hkv, group, Tkvp, D).sum(axis=2)

@@ -117,6 +117,24 @@ def local_kernel_params(interpret):
     return None
 
 
+def kernel_identity(name: str):
+    """``metadata=`` of every ``pallas_call`` in ``ops/``: the kernel's
+    identity, ``<family>.<role>`` (``flash.dq``, ``ring.allreduce.chunked``).
+
+    Pallas carries it into the custom call's
+    ``frontend_attributes={kernel_metadata={"tm_kernel":"<name>"}}``, which
+    is part of the instruction's HLO text and therefore of the name a TPU
+    profile gives the kernel's device events: a trace reader finds a kernel
+    by it whatever module or transform the call was traced under.  Not
+    ``name=``, and no ``named_scope`` around a kernel: both go through the
+    name stack and rename the instruction (``%SPAttention_0.<n>``,
+    ``%jvp__.<n>``), which existing readers match on.  Beside
+    :func:`local_kernel_params`: the one place the kernels' shared
+    ``pallas_call`` arguments are decided.
+    """
+    return {"tm_kernel": name}
+
+
 def _interpret_mode():
     """Explicit setting wins; in auto mode, enable the interpreter when the
     devices actually executing (the runtime mesh when initialized, else the
@@ -662,6 +680,7 @@ def _ring_allreduce_bidir_chunked(flat, n: int, axis: str,
         ],
         compiler_params=pltpu.CompilerParams(collective_id=12),
         interpret=_interpret_mode(),
+        metadata=kernel_identity("ring.allreduce.bidir_chunked"),
     )(x1, x2)
     f1 = o1.reshape(-1)[:L1]
     f2 = o2.reshape(-1)[:L2]
@@ -697,6 +716,7 @@ def _ring_allreduce_chunked(flat, n: int, axis: str,
         ],
         compiler_params=pltpu.CompilerParams(collective_id=11),
         interpret=_interpret_mode(),
+        metadata=kernel_identity("ring.allreduce.chunked"),
     )(x)
     return out.reshape(-1)[:L]
 
@@ -788,6 +808,7 @@ def _ring_reduce_scatter_chunked(xin, n: int, axis: str,
         ],
         compiler_params=pltpu.CompilerParams(collective_id=13),
         interpret=_interpret_mode(),
+        metadata=kernel_identity("ring.reduce_scatter.chunked"),
     )(x)
     return out.reshape(-1)[:per]
 
@@ -819,6 +840,7 @@ def _ring_all_gather_chunked(xin, n: int, axis: str,
         ],
         compiler_params=pltpu.CompilerParams(collective_id=14),
         interpret=_interpret_mode(),
+        metadata=kernel_identity("ring.all_gather.chunked"),
     )(x)
     return out.reshape(n, -1)[:, :L]
 
@@ -842,6 +864,7 @@ def _ring_allreduce_padded(x, n: int, axis: str,
         ],
         compiler_params=pltpu.CompilerParams(collective_id=7),
         interpret=_interpret_mode(),
+        metadata=kernel_identity("ring.allreduce.padded"),
     )(x)
     return out.reshape(-1)
 
@@ -875,6 +898,7 @@ def _ring_allreduce_bidir_padded(flat, n: int, axis: str,
         ],
         compiler_params=pltpu.CompilerParams(collective_id=10),
         interpret=_interpret_mode(),
+        metadata=kernel_identity("ring.allreduce.bidir_padded"),
     )(x1, x2)
     f1 = o1.reshape(-1)
     f2 = o2.reshape(-1)
@@ -1057,6 +1081,7 @@ def ring_reduce_scatter(x, axis_names, *, op: str = "sum"):
         ],
         compiler_params=pltpu.CompilerParams(collective_id=8),
         interpret=_interpret_mode(),
+        metadata=kernel_identity("ring.reduce_scatter"),
     )(xin)
     return out.reshape(-1)[:per].reshape(out_shape)
 
@@ -1108,6 +1133,7 @@ def ring_all_gather(x, axis_names):
             ],
             compiler_params=pltpu.CompilerParams(collective_id=9),
             interpret=_interpret_mode(),
+            metadata=kernel_identity("ring.all_gather"),
         )(xin)
     out = gathered.reshape(n, -1)[:, :L].reshape((n,) + shape)
     for a in reversed(outer_axes):

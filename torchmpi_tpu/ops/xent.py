@@ -243,6 +243,7 @@ def _fused_xent_fwd(x, w, labels, block_n: int, block_v: int, interpret):
         scratch_shapes=[pltpu.VMEM((block_n, _LANES), jnp.float32)] * 3,
         interpret=interpret,
         compiler_params=_kernel_params(interpret),
+        metadata=ring.kernel_identity("xent.fwd"),
     )(labp, xp, wp)
     return loss[:N, 0], lse[:N, 0]
 
@@ -322,6 +323,7 @@ def _xent_vjp(embed: int, block_n: int, block_v: int, interp_key):
             scratch_shapes=[pltpu.VMEM((bn, E), jnp.float32)],
             interpret=interp_key,
             compiler_params=_kernel_params(interp_key),
+            metadata=ring.kernel_identity("xent.dx"),
         )(labp, xp, wp, lse_l, dl_l)
 
         dw_kern = functools.partial(_xent_bwd_dw_kernel, block_n=bn,
@@ -342,6 +344,7 @@ def _xent_vjp(embed: int, block_n: int, block_v: int, interp_key):
             scratch_shapes=[pltpu.VMEM((E, bv), jnp.float32)],
             interpret=interp_key,
             compiler_params=_kernel_params(interp_key),
+            metadata=ring.kernel_identity("xent.dw"),
         )(labp, xp, wp, lse_l, dl_l)
         if pad_v:
             dw = dw[:, :V]

@@ -962,21 +962,43 @@ def throttle_dispatch(jitted: Callable, *, mesh: Optional[Mesh] = None,
     """Bound the dispatched-but-unfinished step window of a jitted step that
     returns ``(out, completion_token)`` — see :func:`data_parallel_step` for
     why (CPU collective-rendezvous starvation; device-memory pressure from
-    donated buffers).  Returns a callable yielding ``out`` only."""
+    donated buffers).  Returns a callable yielding ``out`` only.
+
+    The program's own step span, on the profiler's clock: every call into
+    ``jitted`` (the enqueue, not the step's execution) sits in a
+    ``jax.profiler.StepTraceAnnotation("tm.step", step_num=<n>)``, ``n``
+    counting this stepper's calls from 0, and every wait for a step that
+    is still running when the in-flight window is full in a
+    ``TraceAnnotation("tm.step.throttle")``: the number of those spans is
+    the number of steps the throttle held back (a token that is ready when
+    its turn comes is dropped without one: past the first ``max_inflight``
+    calls the window is always full, of steps that mostly finished).
+    Unconditional: with no profiler attached an annotation is a flag test
+    (docs/OBSERVABILITY.md, "What a profile shows").  ``obs.record_step``
+    is the host-clock ring, gated by ``Config.obs``; this is neither."""
     if max_inflight is None:
         m = _default_mesh(mesh)
         platform = list(m.devices.flat)[0].platform
         max_inflight = 2 if platform == "cpu" else 16
 
     from collections import deque
+    from itertools import count
 
     window: deque = deque()
+    step_nums = count()
 
     def throttled(*args):
         # Throttle *before* dispatch so donated inputs are still live.
         while len(window) >= max_inflight:
-            jax.block_until_ready(window.popleft())
-        out, token = jitted(*args)
+            token = window.popleft()
+            if token.is_ready():    # finished long ago: nothing held back
+                jax.block_until_ready(token)
+            else:
+                with jax.profiler.TraceAnnotation("tm.step.throttle"):
+                    jax.block_until_ready(token)
+        with jax.profiler.StepTraceAnnotation("tm.step",
+                                              step_num=next(step_nums)):
+            out, token = jitted(*args)
         window.append(token)
         return out
 
