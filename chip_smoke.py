@@ -398,8 +398,9 @@ def phase_lm_flash_xent(ctx):
     cost = compiled.cost_analysis()
     if ctx.on_tpu:
         # flash fwd + dq + dkv per layer are fused by XLA into repeated
-        # calls; xent adds fwd + dx + dw.  At least one of each kind:
-        check(n_custom >= 6, f"{n_custom} tpu_custom_call in the step")
+        # calls; xent adds its forward and its one backward.  At least
+        # one of each kind:
+        check(n_custom >= 5, f"{n_custom} tpu_custom_call in the step")
     box = {"s": (p, o)}
 
     def call():

@@ -175,11 +175,15 @@ def test_flash_compiles_at_the_cells_shapes_under_its_module(one_chip, case):
 
 
 @pytest.mark.parametrize("tokens,embed,vocab", [
-    (8188, 2048, 32768), (32768, 1024, 32768), (8191, 3072, 49152)])
+    (8188, 2048, 32768), (32768, 1024, 32768), (8191, 3072, 49152),
+    (8184, 3072, 49152), (100, 512, 1000)])
 def test_fused_xent_value_and_grad_compiles(one_chip, tokens, embed, vocab):
     # 8188 x 2048 is the flagship LM step's head (B=4, T=2048, minus the
     # shifted token); 32768 x 1024 is the LM-head scale of the export test;
-    # 8191 x 3072 x 49152 is the benchmark's sc2-3b cells' head.
+    # 8191 and 8184 x 3072 x 49152 are the heads of the benchmark's
+    # sc2-3b-t8k and sc2-3b-t1k (eight sequences, each minus one token);
+    # 100 rows are fewer than a block and off the sublane tiling of 8, which
+    # the backward's DMA of its dx rows must not meet.
     from torchmpi_tpu.ops.xent import fused_linear_cross_entropy
 
     def fn(x, w, labels):
@@ -189,10 +193,10 @@ def test_fused_xent_value_and_grad_compiles(one_chip, tokens, embed, vocab):
     compiled = _compile(fn, _sds((tokens, embed), jnp.bfloat16, one_chip),
                         _sds((embed, vocab), jnp.bfloat16, one_chip),
                         _sds((tokens,), jnp.int32, one_chip),
-                        kernels=3)  # fwd + dx + dw
+                        kernels=2)
     found = _kernels(compiled)
-    assert sorted(ident for _, ident in found) == [
-        "xent.dw", "xent.dx", "xent.fwd"]
+    # the forward and ONE backward, which carries xent.dw's identity
+    assert sorted(ident for _, ident in found) == ["xent.dw", "xent.fwd"]
     # outside any module the custom_vjp's empty scope names them: what
     # xent_roofline_pct.tok's pattern finds, and flash's does not
     assert all(XENT_NAME.match(name) and not FLASH_NAME.match(name)

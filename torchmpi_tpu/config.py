@@ -140,7 +140,8 @@ class Config:
     # July 2026 (14.6 ms median vs 15.4 at 128, jitter ~0.6 ms; not
     # re-measured on today's code); the VMEM block-fit
     # clamp (ops/xent._fit_blocks) shrinks them automatically where E is
-    # too large for the scoped budget.
+    # too large for the scoped budget.  They tile the forward kernel; the
+    # backward has a tile of its own (ops/xent._BWD_BLOCK_N/V).
     xent_block_n: int = 256
     xent_block_v: int = 512
     # Fold the attention scale into q once at the kernel boundary
