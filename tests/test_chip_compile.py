@@ -203,6 +203,61 @@ def test_fused_xent_value_and_grad_compiles(one_chip, tokens, embed, vocab):
                for name, _ in found), found
 
 
+def test_smallthinker_step_compiles_at_the_cells_sizes(chip):
+    # The whole train step of the benchmark's st-21b-ep4-t8k
+    # (chipbench/configs/smallthinker-21b-a3b.json) at its real sizes, as
+    # chipbench/tools/compile_cells.py builds it: 28 q / 4 kv heads of 128
+    # with and without the 4096 window in one program, 16 of 64 experts held
+    # dropless (lax.ragged_dot, which the chip's compiler turns into
+    # grouped-matmul kernels of its own), and a 37984-row vocabulary slice,
+    # no multiple of fused xent's tile.  It must fit what the chip's
+    # allocator gives (bytes_limit 16,909,336,064 on a v5e; PERF.md).
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    from chipbench import harness
+
+    manifest = harness.load_manifest()
+    cell = harness.resolve(manifest, "st-21b-ep4-t8k")
+    mesh = Mesh(np.asarray(chip.devices[:1]).reshape((1, 1)), mpi.WORLD_AXES)
+    prog = harness.load_module(manifest, "steps",
+                               cell.config["step"]).programs(cell, mesh)
+    key = jax.ShapeDtypeStruct((2,), np.uint32)
+
+    def put(spec):
+        return lambda s: _sds(s.shape, s.dtype, NamedSharding(mesh, spec))
+
+    state = jax.tree.map(put(P()), jax.eval_shape(prog.init, key))
+    batch = jax.tree.map(put(P(mesh.axis_names)),
+                         jax.eval_shape(prog.batches, key)[0])
+    compiled = prog.step.jitted.lower(*state, *batch).compile()
+    ma = compiled.memory_analysis()
+    assert (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes) < 16_909_336_064
+    text = compiled.as_text()
+    found = KERNEL.findall(text)
+    layers = cell.config["num_hidden_layers"]
+    # every Pallas kernel of the library carries its identity ...
+    idents = [ident for _, ident in found]
+    assert sorted(set(idents)) == ["flash.dkv", "flash.dq", "flash.fwd",
+                                   "xent.dw", "xent.fwd"]
+    assert all(idents.count(f"flash.{k}") == layers
+               for k in ("fwd", "dq", "dkv"))
+    assert all(FLASH_NAME.match(name) for name, ident in found
+               if ident.startswith("flash."))
+    # ... and the rest of the Mosaic calls are the compiler's grouped
+    # matmuls: gate, up and down forward, again in the backward pass's
+    # recomputation, and two products each backward, in every layer
+    grouped = re.findall(r"^\s*(?:ROOT )?%(ragged-dot-none\S*) = ", text,
+                         re.M)
+    assert len(grouped) == 12 * layers
+    assert text.count('custom_call_target="tpu_custom_call"') == len(
+        found) + len(grouped) + len(re.findall(
+            r"^\s*(?:ROOT )?%ragged-dot-metadata\S* = ", text, re.M))
+
+
 def _rank_major_program(mesh, body):
     spec = P(mesh.axis_names)
     return shard_map(lambda xs: body(xs[0], mesh.axis_names)[None],

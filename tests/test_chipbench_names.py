@@ -27,15 +27,42 @@ TESTDATA = os.path.join(harness.BENCH, "testdata")
 SCOPES = harness.load_module(MANIFEST, "readers", "xplane_scopes")
 SPAN = harness.load_module(MANIFEST, "readers", "program_span")
 
-# what this PR added to BENCHMARK.json: metric -> the cells that report it
+# what PR 24 added to BENCHMARK.json: metric -> the cells that report it.
+# PR 26's cell joined the lists of the metrics whose reader is right for it
+# unchanged (not ``mlp``: its feed-forward is no ``/Block_n/Dense_n/``).
+ST = "st-21b-ep4-t8k"
 TOK, IMG = ["sc2-3b-t8k", "sc2-3b-t1k"], ["rn50-1chip", "rn50-dp4"]
 NEW_METRICS = {
-    **{f"{k}_ms_per_step.tok": TOK
+    **{f"{k}_ms_per_step.tok": TOK + [ST]
        for k in ("flash_fwd", "flash_bwd", "xent_fwd", "xent_bwd", "fwd",
-                 "bwd", "mlp", "attn_proj")},
+                 "bwd", "attn_proj")},
+    "mlp_ms_per_step.tok": TOK,
     "fwd_ms_per_step.img": IMG, "bwd_ms_per_step.img": IMG,
-    "step_span_ms.tok": TOK, "step_span_ms.img": IMG,
+    "step_span_ms.tok": TOK + [ST], "step_span_ms.img": IMG,
 }
+# what PR 26 added, in order: metric -> (reader, layer, source)
+ST_METRICS = {
+    "mfu_moe_pct.tok": ("mfu_module", "model step", "host_clock"),
+    "moe_route_ms_per_step.tok": ("xplane_scopes", "expert layer",
+                                  "device_trace"),
+    "moe_permute_ms_per_step.tok": ("xplane_scopes_named", "expert layer",
+                                    "device_trace"),
+    "moe_experts_ms_per_step.tok": ("xplane_scopes_named", "expert layer",
+                                    "device_trace"),
+    "moe_experts_roofline_pct.tok": ("xplane_roofline", "expert layer",
+                                     "device_trace"),
+    "flash_mixed_roofline_pct.tok": ("xplane_roofline", "kernels",
+                                     "device_trace"),
+    "moe_rows_computed_over_routed.tok": ("counter_ratio", "expert layer",
+                                          "program_counter"),
+    "moe_routes_held_per_token.tok": ("step_counts", "expert layer",
+                                      "program_counter"),
+}
+# the accepted metrics PR 26's cell reports besides (PR 23's)
+ST_JOINS = ["dispatch_ms_per_step.tok", "xent_roofline_pct.tok",
+            "device_idle_pct.tok"]
+ST_STAYS_OUT = ["mfu_pct.tok", "flash_roofline_pct.tok",
+                "mlp_ms_per_step.tok"]
 
 with open(os.path.join(TESTDATA, "expected_names.json")) as _f:
     WANT = json.load(_f)
@@ -70,18 +97,66 @@ def test_new_metric_resolves_to_a_file_and_a_reader(metric):
         assert mine["args"] == spec["args"]
 
 
+@pytest.mark.parametrize("metric", sorted(ST_METRICS))
+def test_expert_cell_metric_resolves_to_a_file_and_a_reader(metric):
+    reader, layer, source = ST_METRICS[metric]
+    entry = harness.by_name(MANIFEST["per_layer"], metric, "metric")
+    assert entry["workloads"] == [ST]
+    assert (entry["layer"], entry["source"]) == (layer, source)
+    assert entry["moves"] == "tokens_per_s_chip"
+    assert (entry["unit"], entry["better"]) == (
+        ("%", "higher") if "_pct." in metric else
+        ("ms", "lower") if "_ms_" in metric else ("1", "lower"))
+    spec = harness.load_json(MANIFEST, "layer_metrics", metric)
+    assert spec["reader"] == reader
+    assert callable(harness.load_module(MANIFEST, "readers", reader).read)
+    mine = harness.by_name(harness.resolve(MANIFEST, ST).per_layer, metric,
+                           "metric")
+    assert mine["args"] == spec.get("args", {})
+    for pattern in ("op_name", "name", "not_name", "pattern"):
+        re.compile(spec.get("args", {}).get(pattern, ""))
+
+
+def test_expert_cell_joins_the_lists_whose_readers_are_right_for_it():
+    mine = {m["name"] for m in harness.resolve(MANIFEST, ST).per_layer}
+    joined = {m for m, cells in NEW_METRICS.items() if ST in cells}
+    assert mine == joined | set(ST_JOINS) | set(ST_METRICS)
+    for metric in ST_JOINS:
+        assert harness.by_name(MANIFEST["per_layer"], metric,
+                               "metric")["workloads"] == TOK + [ST]
+    # dense, one-window counts and patterns: not this cell's
+    for metric in ST_STAYS_OUT:
+        assert harness.by_name(MANIFEST["per_layer"], metric,
+                               "metric")["workloads"] == TOK
+    e2e = {m["name"] for m in harness.resolve(MANIFEST, ST).end_to_end}
+    assert e2e == {"tokens_per_s_chip", "step_ms_p90", "setup_s"}
+    cell = harness.by_name(MANIFEST["workloads"], ST, "workload")
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "smallthinker-21b-a3b", "b1-t8192", 1)
+    assert [w["name"] for w in MANIFEST["workloads"]][-1] == ST
+    assert sum(w["chips"] == 4 for w in MANIFEST["workloads"]) == 1
+
+
 def test_benchmark_json_only_gained_entries_at_the_end():
-    """What the benchmark had (PR 23) is still there, first and unchanged
-    in order; the new metrics follow it."""
+    """What the benchmark had (PR 23, then PR 24) is still there, first
+    and unchanged in order; PR 26's metrics follow it."""
     names = [m["name"] for m in MANIFEST["per_layer"]]
-    assert names[10:] and set(names[10:]) == set(NEW_METRICS)
-    assert names[:10] == [
+    assert set(names[10:22]) == set(NEW_METRICS)
+    assert names[:22] == [
         "dispatch_ms_per_step.img", "dispatch_ms_per_step.tok",
         "collective_ms_per_step.img", "collective_exposed_ms_per_step.img",
         "flash_roofline_pct.tok", "xent_roofline_pct.tok", "mfu_pct.img",
-        "mfu_pct.tok", "device_idle_pct.img", "device_idle_pct.tok"]
+        "mfu_pct.tok", "device_idle_pct.img", "device_idle_pct.tok",
+        "flash_fwd_ms_per_step.tok", "flash_bwd_ms_per_step.tok",
+        "xent_fwd_ms_per_step.tok", "xent_bwd_ms_per_step.tok",
+        "fwd_ms_per_step.img", "fwd_ms_per_step.tok", "bwd_ms_per_step.img",
+        "bwd_ms_per_step.tok", "mlp_ms_per_step.tok",
+        "attn_proj_ms_per_step.tok", "step_span_ms.img", "step_span_ms.tok"]
+    assert names[22:] == list(ST_METRICS)
     layers = {m["layer"] for m in MANIFEST["per_layer"][:10]}
-    assert {m["layer"] for m in MANIFEST["per_layer"][10:]} <= layers
+    assert {m["layer"] for m in MANIFEST["per_layer"][10:22]} <= layers
+    assert {m["layer"] for m in MANIFEST["per_layer"][22:]} <= layers | {
+        "expert layer"}
 
 
 # --------------------------------------------------- file -> metadata, sums
@@ -305,3 +380,32 @@ def test_traced_rehearsal_leaves_the_new_device_metrics_out():
     assert new & set(out["metrics"]) == {"step_span_ms.tok"}
     assert out["metrics"]["step_span_ms.tok"]["value"] > 0
     assert "dispatch_ms_per_step.tok" in out["metrics"]
+
+
+def test_traced_rehearsal_of_the_expert_cell_reads_its_counters():
+    """The program's counters need no device plane: the ratio of rows
+    computed to routes held is there on the CPU too, and reads 1, and the
+    routes every traced step held are a share of the k a token sends."""
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    p = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "chipbench", "run.py"),
+         "--workload", ST, "--seed", "4294967301", "--seconds", "0.3",
+         "--trace", "1", "--rehearse"],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=300)
+    assert p.returncode == 0, p.stderr[-2000:]
+    out = json.loads(p.stdout.strip().splitlines()[-1])
+    assert out["correct"] is True and out["rehearsal"] is True
+    check = out["checks"]["reference"]
+    assert check["routes_held"] == check["routes_chosen_in_range"]
+    assert check["routes_held"] == check["rows_computed"]
+    assert 0 < sum(check["routes_held"])
+    assert set(ST_METRICS) & set(out["metrics"]) == {
+        "moe_rows_computed_over_routed.tok", "moe_routes_held_per_token.tok"}
+    assert out["metrics"]["moe_rows_computed_over_routed.tok"] == {
+        "value": 1.0, "unit": "1"}
+    # the rehearsal holds 2 of 8 experts and sends 3 routes a token
+    assert 0 < out["metrics"]["moe_routes_held_per_token.tok"]["value"] < 3
+    assert min(check["routing_agree"]) == 1.0
+    assert max(check["router_rel_err"]) < 1e-5
+    assert {"step_span_ms.tok", "dispatch_ms_per_step.tok"} <= set(
+        out["metrics"])

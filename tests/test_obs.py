@@ -152,6 +152,10 @@ def test_ring_resize_preserves_history():
 def test_sigterm_dump(tmp_path):
     from torchmpi_tpu import obs
 
+    # A file that ran earlier in this process may have left obs activated
+    # (mpi.stop() does not deactivate it): its SIGTERM handler would be
+    # hidden under the one installed below and activate() would not re-arm.
+    obs.deactivate()
     hits = []
     prev = signal.signal(signal.SIGTERM, lambda s, f: hits.append(s))
     try:

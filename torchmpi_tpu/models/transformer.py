@@ -89,10 +89,13 @@ class SPAttention(nn.Module):
     # (pos_offset + local index; decode uses the cache index).  The
     # caller (TransformerLM(pos_emb="rope")) then adds no position table.
     rope: bool = False
+    rope_base: float = 10000.0
+    use_bias: bool = True
 
     @nn.compact
     def __call__(self, x, pos_offset=0):  # x: [B, T_local, E]
         B, T, E = x.shape
+        bias, base = self.use_bias, self.rope_base
         H, D = self.num_heads, self.head_dim
         Hkv = self.num_kv_heads if self.num_kv_heads is not None else H
         if Hkv != H:
@@ -104,21 +107,22 @@ class SPAttention(nn.Module):
                     f"num_kv_heads= supports attn_impl='local'/'flash' "
                     f"(got {self.attn_impl!r})")
             q = nn.DenseGeneral((H, D), axis=-1, dtype=self.dtype,
+                                use_bias=bias,
                                 name="q")(x).astype(jnp.float32)
             kv = nn.DenseGeneral((2, Hkv, D), axis=-1, dtype=self.dtype,
-                                 name="kv")(x)
+                                 use_bias=bias, name="kv")(x)
             k = kv[:, :, 0].astype(jnp.float32)
             v = kv[:, :, 1].astype(jnp.float32)
         else:
             qkv = nn.DenseGeneral((3, H, D), axis=-1, dtype=self.dtype,
-                                  name="qkv")(x)
+                                  use_bias=bias, name="qkv")(x)
             q, k, v = (qkv[:, :, 0].astype(jnp.float32),
                        qkv[:, :, 1].astype(jnp.float32),
                        qkv[:, :, 2].astype(jnp.float32))
         if self.rope and not self.decode:
             rpos = pos_offset + jnp.arange(T)
-            q = apply_rope(q, rpos)
-            k = apply_rope(k, rpos)
+            q = apply_rope(q, rpos, base=base)
+            k = apply_rope(k, rpos, base=base)
         if self.decode:
             # Autoregressive KV-cache step: x is the NEW token(s) ([B, 1]
             # in the steady state); keys/values append into this layer's
@@ -195,8 +199,8 @@ class SPAttention(nn.Module):
                 # re-rotation as decoding advances.
                 rpos = (starts[:, None] + jnp.arange(T) if per_row
                         else start + jnp.arange(T))
-                q = apply_rope(q, rpos)
-                k = apply_rope(k, rpos)
+                q = apply_rope(q, rpos, base=base)
+                k = apply_rope(k, rpos, base=base)
             if per_row:
                 row_upd = jax.vmap(
                     lambda c, u, s: lax.dynamic_update_slice(c, u,
@@ -300,7 +304,7 @@ class SPAttention(nn.Module):
         else:
             raise ValueError(f"unknown attn_impl {self.attn_impl!r}")
         o = o.astype(self.dtype).reshape(B, T, H * D)
-        return nn.Dense(E, dtype=self.dtype, name="out")(o)
+        return nn.Dense(E, dtype=self.dtype, use_bias=bias, name="out")(o)
 
 
 class MoEMLP(nn.Module):
@@ -366,6 +370,59 @@ class MoEMLP(nn.Module):
         return out.reshape(B, T, E).astype(self.dtype)
 
 
+class ExpertFFN(nn.Module):
+    """Top-k gated (ReGLU) expert feed-forward whose weights exist only for
+    the experts ``held`` = ``(first, count)`` of ``n_experts`` (None: all):
+    one chip's share of an expert-parallel layer, routed over all experts
+    by a float32 router, dropless (``parallel/expert.held_experts``).  What
+    the experts held elsewhere would add is left out; under an expert axis
+    the exchange that brings it in wraps this module.
+
+    ``router_in`` is what the router reads: ``Block`` hands it the
+    attention's normed input, the block's pre-attention state.  The
+    counters ``routes_held`` and ``rows_computed``, the chosen ``experts``
+    and the float32 ``router_logits`` are sown to the ``moe`` collection
+    (``mutable=["moe"]``), as ``MoEMLP`` sows its loss.
+    """
+
+    n_experts: int
+    k: int
+    width: int
+    held: Optional[Tuple[int, int]] = None
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, u, router_in):  # both [B, T, E]
+        B, T, E = u.shape
+        first, count = self.held or (0, self.n_experts)
+        init = nn.initializers.lecun_normal(batch_axis=(0,))
+        router = self.param("router", nn.initializers.lecun_normal(),
+                            (E, self.n_experts), jnp.float32)
+        w_gate = self.param("w_gate", init, (count, E, self.width),
+                            jnp.float32)
+        w_up = self.param("w_up", init, (count, E, self.width), jnp.float32)
+        w_down = self.param("w_down", init, (count, self.width, E),
+                            jnp.float32)
+        with jax.named_scope("route"):
+            logits = jnp.dot(router_in.reshape(B * T, E).astype(jnp.float32),
+                             router, precision=lax.Precision.HIGHEST)
+        out, stats = eplib.held_experts(
+            u.reshape(B * T, E).astype(self.dtype), logits, self.k, first,
+            w_gate, w_up, w_down)
+        if not self.is_initializing():
+            for name, value in {**stats, "router_logits": logits}.items():
+                self.sow("moe", name, value)
+        return out.reshape(B, T, E)
+
+
+def _norm(kind: str, eps: float):
+    if kind == "layernorm":
+        return nn.LayerNorm(epsilon=eps, dtype=jnp.float32)
+    if kind == "rmsnorm":
+        return nn.RMSNorm(epsilon=eps, dtype=jnp.float32)
+    raise ValueError(f"unknown norm {kind!r}")
+
+
 class Block(nn.Module):
     num_heads: int
     head_dim: int
@@ -383,25 +440,41 @@ class Block(nn.Module):
     window: Optional[int] = None
     num_kv_heads: Optional[int] = None
     rope: bool = False
+    rope_base: float = 10000.0
+    norm: str = "layernorm"
+    norm_eps: float = 1e-6
+    use_bias: bool = True
+    # n_experts > 0: the feed-forward is an ExpertFFN (top-``moe_k`` of
+    # ``n_experts``, ``experts_held`` of them here, each ``expert_width``
+    # wide) whose router reads the attention's input.
+    n_experts: int = 0
+    experts_held: Optional[Tuple[int, int]] = None
+    expert_width: int = 0
 
     @nn.compact
     def __call__(self, x, pos_offset=0):
         E = x.shape[-1]
-        h = nn.LayerNorm(dtype=jnp.float32)(x)
+        a = _norm(self.norm, self.norm_eps)(x)
         x = x + SPAttention(self.num_heads, self.head_dim, self.attn_impl,
                             self.seq_axis, self.dtype, decode=self.decode,
                             max_len=self.max_len, window=self.window,
                             num_kv_heads=self.num_kv_heads,
-                            rope=self.rope)(h, pos_offset)
-        h = nn.LayerNorm(dtype=jnp.float32)(x)
+                            rope=self.rope, rope_base=self.rope_base,
+                            use_bias=self.use_bias)(a, pos_offset)
+        h = _norm(self.norm, self.norm_eps)(x)
+        if self.n_experts:
+            return x + ExpertFFN(self.n_experts, self.moe_k,
+                                 self.expert_width, self.experts_held,
+                                 dtype=self.dtype)(h, a)
         if self.moe_axis is not None:
             return x + MoEMLP(self.moe_experts_per_device, self.mlp_ratio,
                               self.moe_axis,
                               capacity_factor=self.moe_capacity_factor,
                               k=self.moe_k, dtype=self.dtype)(h)
-        h = nn.Dense(E * self.mlp_ratio, dtype=self.dtype)(h)
+        h = nn.Dense(E * self.mlp_ratio, dtype=self.dtype,
+                     use_bias=self.use_bias)(h)
         h = nn.gelu(h)
-        return x + nn.Dense(E, dtype=self.dtype)(h)
+        return x + nn.Dense(E, dtype=self.dtype, use_bias=self.use_bias)(h)
 
 
 class TransformerLM(nn.Module):
@@ -432,6 +505,22 @@ class TransformerLM(nn.Module):
     # "rope" (rotary embeddings applied to q/k in every attention layer;
     # no position table - max_len then only bounds the decode cache).
     pos_emb: str = "learned"
+    # RoPE's base, the norm ("layernorm" | "rmsnorm") with its epsilon, and
+    # whether the projections and the MLP carry a bias.
+    rope_base: float = 10000.0
+    norm: str = "layernorm"
+    norm_eps: float = 1e-6
+    use_bias: bool = True
+    # Per-layer layouts, one entry a layer (None: every layer alike): a
+    # layer with 0 in ``window_layout`` attends over the whole context, one
+    # with 0 in ``rope_layout`` rotates nothing (no positions at all).
+    window_layout: Optional[Tuple[int, ...]] = None
+    rope_layout: Optional[Tuple[int, ...]] = None
+    # Sparse experts told which they hold (see Block / ExpertFFN): top
+    # ``moe_k`` of ``n_experts``, ``experts_held`` = (first, count) here.
+    n_experts: int = 0
+    experts_held: Optional[Tuple[int, int]] = None
+    expert_width: int = 0
 
     @nn.compact
     def __call__(self, tokens, pos_offset=0, return_prehead: bool = False):
@@ -450,7 +539,14 @@ class TransformerLM(nn.Module):
                 x = x + table(pos_offset + jnp.arange(T))[None]
         elif self.pos_emb != "rope":
             raise ValueError(f"unknown pos_emb {self.pos_emb!r}")
-        for _ in range(self.depth):
+        for name in ("window_layout", "rope_layout"):
+            layout = getattr(self, name)
+            if layout is not None and len(layout) != self.depth:
+                raise ValueError(f"{name} has {len(layout)} entries for "
+                                 f"{self.depth} layers")
+        for i in range(self.depth):
+            windowed = self.window_layout is None or self.window_layout[i]
+            rotated = self.rope_layout is None or self.rope_layout[i]
             x = Block(self.num_heads, self.head_dim,
                       attn_impl=self.attn_impl, seq_axis=self.seq_axis,
                       moe_axis=self.moe_axis,
@@ -458,10 +554,15 @@ class TransformerLM(nn.Module):
                       moe_capacity_factor=self.moe_capacity_factor,
                       moe_k=self.moe_k, dtype=self.dtype,
                       decode=self.decode, max_len=self.max_len,
-                      window=self.window,
+                      window=self.window if windowed else None,
                       num_kv_heads=self.num_kv_heads,
-                      rope=self.pos_emb == "rope")(x, pos_offset)
-        x = nn.LayerNorm(dtype=jnp.float32)(x)
+                      rope=self.pos_emb == "rope" and bool(rotated),
+                      rope_base=self.rope_base, norm=self.norm,
+                      norm_eps=self.norm_eps, use_bias=self.use_bias,
+                      n_experts=self.n_experts,
+                      experts_held=self.experts_held,
+                      expert_width=self.expert_width)(x, pos_offset)
+        x = _norm(self.norm, self.norm_eps)(x)
         # Bias-free explicit unembedding (standard for LMs) so callers can
         # feed (pre-head activations, head matrix) to the fused
         # linear+cross-entropy kernel (ops/xent.py) and never materialize

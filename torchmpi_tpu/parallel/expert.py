@@ -16,6 +16,11 @@ k>=2 GShard-style renormalized combine):
 
 The communication pattern (dispatch all-to-all, combine all-to-all) is the
 EP analog of the reference's allreduce: one collective pair per MoE layer.
+
+:func:`held_experts` is the layer a device runs when it is TOLD which
+experts it holds (one chip's share of a larger deployment, or the part an
+expert axis's exchange would wrap): it routes over all experts, drops no
+route to a held one, and computes the held experts' part of the result.
 """
 
 from __future__ import annotations
@@ -149,3 +154,156 @@ def moe_layer(x, gate_w, expert_fn: Callable, expert_params,
         return out, load_balance_loss(gate_logits, expert_of, E,
                                       probs=probs)
     return out
+
+
+def _unique_rows(x, idx):
+    """``x[idx]`` for a permutation ``idx`` of the rows."""
+    return x.at[idx].get(unique_indices=True, mode="promise_in_bounds")
+
+
+def _rank_slices(x, tokens):
+    """[k * T, D] -> its k rank-major slices [T, D].  Summing over them as
+    a Python loop lets XLA fuse the casts and weights of every slice into
+    one pass; a reshape to [k, T, D] and a reduction makes it write a
+    float32 copy of the buffer first (seen in the trace, PR 26)."""
+    return [x[j:j + tokens] for j in range(0, x.shape[0], tokens)]
+
+
+@jax.custom_vjp
+def _gather_routes(u, order, inverse):
+    """Rows of ``u`` [T, D] in sorted route order: route ``r`` of the
+    rank-major ``[k * T]`` routes belongs to token ``r % T``.  Its
+    transpose is written as a gather too (``inverse`` is the inverse
+    permutation): a scatter-add of ``k * T`` rows is the slow way on a TPU."""
+    return u[order % u.shape[0]]
+
+
+def _gather_routes_fwd(u, order, inverse):
+    return _gather_routes(u, order, inverse), (inverse, u.shape[0])
+
+
+def _gather_routes_bwd(res, g):
+    inverse, tokens = res
+    du = sum(s.astype(jnp.float32)
+             for s in _rank_slices(_unique_rows(g, inverse), tokens))
+    return du.astype(g.dtype), None, None
+
+
+_gather_routes.defvjp(_gather_routes_fwd, _gather_routes_bwd)
+
+
+@jax.custom_vjp
+def _permute_rows(x, index, inverse):
+    """``x[index]`` for a permutation, with its transpose ``g[inverse]``."""
+    return _unique_rows(x, index)
+
+
+def _permute_rows_fwd(x, index, inverse):
+    return _unique_rows(x, index), inverse
+
+
+def _permute_rows_bwd(inverse, g):
+    return _unique_rows(g, inverse), None, None
+
+
+_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
+def _held_part(u, probs, group, w_gate, w_up, w_down):
+    """``sum_j probs[t, j] * f_group[t, j](u[t])`` over the routes whose
+    ``group`` (the expert's index among those held) is below the number
+    held; ``group == count`` marks a route to an expert held elsewhere."""
+    tokens, k = group.shape
+    count, routes = w_gate.shape[0], tokens * k
+    # Routes are laid out rank-major, [k, T] flat: splitting ``k * T`` rows
+    # into [k, T, D] is then free, where [T, k, D] would be a relayout of
+    # the whole buffer (k pads to the sublane tile).
+    group, probs = group.T, probs.T
+    with jax.named_scope("dispatch"):
+        key = group.reshape(-1)
+        order = jnp.argsort(key, stable=True)        # held first, by expert
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(routes, dtype=order.dtype), unique_indices=True)
+        sizes = (key[:, None] == jnp.arange(count)).sum(0, dtype=jnp.int32)
+        # Rows past the last held route are never multiplied (the grouped
+        # product follows ``sizes``), so what they hold is undefined: both
+        # ways through, they are cut off here and in ``combine``.
+        live = (jnp.arange(routes) < sizes.sum())[:, None]
+        rows = jnp.where(live, _gather_routes(u, order, inverse), 0)
+    with jax.named_scope("experts"):
+        def product(x, w):
+            return lax.ragged_dot(x, w.astype(x.dtype), sizes,
+                                  preferred_element_type=jnp.float32)
+
+        hidden = jax.nn.relu(product(rows, w_gate)) * product(rows, w_up)
+        y = product(hidden.astype(u.dtype), w_down).astype(u.dtype)
+    with jax.named_scope("combine"):
+        y = _permute_rows(jnp.where(live, y, 0), inverse, order)
+        weight = jnp.where(group < count, probs, 0)
+        out = sum(s.astype(jnp.float32) * weight[j][:, None]
+                  for j, s in enumerate(_rank_slices(y, tokens)))
+    return out, sizes.sum()
+
+
+def held_experts(u, router_logits, k: int, first: int, w_gate, w_up,
+                 w_down):
+    """The held experts' part of a top-k gated (ReGLU) expert layer.
+
+    u: [T, D] tokens; router_logits: [T, n_experts] over ALL experts;
+    ``w_gate``, ``w_up`` [count, D, F] and ``w_down`` [count, F, D] are the
+    weights of the experts ``[first, first + count)``, the only ones that
+    exist here.  Returns ``(out, stats)`` with
+
+        out[t] = sum_{j: e_j held} p_j * w_down[e_j] (relu(w_gate[e_j] u_t)
+                                                      * (w_up[e_j] u_t))
+
+    where ``e`` are token t's top-k experts and ``p`` the softmax of their
+    k logits, normalised over all k whether held or not.  What the other
+    experts would add is left out: under an expert axis this is what
+    :func:`moe_layer`'s exchange would wrap, and nothing here stands in
+    for it.
+
+    Dropless: the routes are sorted by expert into a buffer of ``T * k``
+    rows, the worst case, so no imbalance drops a route; the three grouped
+    products (``lax.ragged_dot``, which the TPU compiler turns into a
+    grouped-matmul kernel that skips the tiles past the last routed row)
+    work in proportion to the routes held.  Router top-k and softmax run in
+    float32, the products in ``u``'s type with float32 accumulation.  The
+    buffers are recomputed in the backward pass, not kept: at the worst
+    case they are ``n_experts / count`` times what the routes need.
+
+    ``stats``: ``routes_held`` (routes to a held expert), ``rows_computed``
+    (rows the grouped products are told to compute) and ``experts``
+    ([T, k] chosen experts), all computed on the device.
+    """
+    n_experts, count = router_logits.shape[-1], w_gate.shape[0]
+    if not (0 <= first and first + count <= n_experts and 1 <= k <= n_experts):
+        raise ValueError(
+            f"held experts [{first}, {first + count}) with k={k} do not "
+            f"fit a router over {n_experts}")
+    with jax.named_scope("route"):
+        scores, experts = lax.top_k(router_logits.astype(jnp.float32), k)
+        probs = jax.nn.softmax(scores, axis=-1)
+        held = (experts >= first) & (experts < first + count)
+        group = jnp.where(held, experts - first, count)
+    out, rows = jax.checkpoint(_held_part)(u, probs, group, w_gate, w_up,
+                                           w_down)
+    return out.astype(u.dtype), {"routes_held": held.sum(dtype=jnp.int32),
+                                 "rows_computed": rows, "experts": experts}
+
+
+def record_counters(moe_collection) -> None:
+    """A model's sown ``moe`` collection (``apply(..., mutable=["moe"])``)
+    into the ``obs`` registry: ``tm_moe_routes_held_total`` and
+    ``tm_moe_rows_computed_total``, one series a layer (label ``layer`` =
+    the module's path).  Fetches the counters from the device: call it
+    beside a step, not inside one."""
+    from flax.traverse_util import flatten_dict
+
+    from .. import obs
+
+    for (*layer, name), sown in flatten_dict(moe_collection).items():
+        if name in ("routes_held", "rows_computed"):
+            for value in sown:      # one entry a call of the module
+                obs.registry().counter_inc(f"tm_moe_{name}_total",
+                                           int(value), layer="/".join(layer))
