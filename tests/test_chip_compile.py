@@ -203,6 +203,40 @@ def test_fused_xent_value_and_grad_compiles(one_chip, tokens, embed, vocab):
                for name, _ in found), found
 
 
+@pytest.mark.parametrize("call", ["gather", "gather_weighted", "combine"])
+def test_expert_row_kernels_compile_at_the_cells_shapes(one_chip, call):
+    # st-21b-ep4-t8k's expert layer: 8192 tokens of 2560 bf16, six routes
+    # a token.  The side indexed at random is resident in VMEM whole (the
+    # 42 MB token table; the 84 MB of float32 sums), which only the chip's
+    # compiler can refuse.
+    from torchmpi_tpu.ops import moe
+
+    tokens, width, routes = 8192, 2560, 6 * 8192
+    assert moe.block_rows(routes) == 256
+    assert moe.token_parts(tokens, width) == 1
+    u = _sds((tokens, width), jnp.bfloat16, one_chip)
+    y = _sds((routes, width), jnp.float32, one_chip)    # the down product
+    w = _sds((routes,), jnp.float32, one_chip)
+    order = _sds((routes,), jnp.int32, one_chip)
+    n_live = _sds((), jnp.int32, one_chip)
+    if call == "gather":
+        compiled = _compile(moe.rows_from_tokens, u, order, n_live,
+                            kernels=1)
+    elif call == "gather_weighted":
+        compiled = _compile(
+            lambda u, o, n, w, y: moe.rows_from_tokens(u, o, n, weight=w,
+                                                       against=y),
+            u, order, n_live, w, y, kernels=1)
+    else:
+        compiled = _compile(
+            lambda y, o, n, w: moe.tokens_from_rows(
+                y, o, n, tokens, weight=w, round_to=jnp.bfloat16),
+            y, order, n_live, w, kernels=1)
+    ((name, ident),) = _kernels(compiled)
+    assert ident == ("moe.combine" if call == "combine" else "moe.gather")
+    assert not XENT_NAME.match(name) and not FLASH_NAME.match(name)
+
+
 def test_smallthinker_step_compiles_at_the_cells_sizes(chip):
     # The whole train step of the benchmark's st-21b-ep4-t8k
     # (chipbench/configs/smallthinker-21b-a3b.json) at its real sizes, as
@@ -242,11 +276,20 @@ def test_smallthinker_step_compiles_at_the_cells_sizes(chip):
     # every Pallas kernel of the library carries its identity ...
     idents = [ident for _, ident in found]
     assert sorted(set(idents)) == ["flash.dkv", "flash.dq", "flash.fwd",
+                                   "moe.combine", "moe.gather",
                                    "xent.dw", "xent.fwd"]
     assert all(idents.count(f"flash.{k}") == layers
                for k in ("fwd", "dq", "dkv"))
     assert all(FLASH_NAME.match(name) for name, ident in found
                if ident.startswith("flash."))
+    # the expert layer's rows, a layer: gathered forward, again in the
+    # recomputation and as the combine's transpose; combined forward and
+    # as the dispatch's transpose.  Named after the scope they run in, so
+    # that neither roofline metric counts them as its own.
+    assert idents.count("moe.gather") == 3 * layers
+    assert idents.count("moe.combine") == 2 * layers
+    moved = [name for name, ident in found if ident.startswith("moe.")]
+    assert not any(XENT_NAME.match(n) or FLASH_NAME.match(n) for n in moved)
     # ... and the rest of the Mosaic calls are the compiler's grouped
     # matmuls: gate, up and down forward, again in the backward pass's
     # recomputation, and two products each backward, in every layer

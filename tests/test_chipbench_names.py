@@ -58,6 +58,8 @@ ST_METRICS = {
     "moe_routes_held_per_token.tok": ("step_counts", "expert layer",
                                       "program_counter"),
 }
+# what PR 27 added: the live share of the rows the dispatch moved
+LIVE_SHARE = "moe_live_share_of_rows_moved.tok"
 # the accepted metrics PR 26's cell reports besides (PR 23's)
 ST_JOINS = ["dispatch_ms_per_step.tok", "xent_roofline_pct.tok",
             "device_idle_pct.tok"]
@@ -117,10 +119,33 @@ def test_expert_cell_metric_resolves_to_a_file_and_a_reader(metric):
         re.compile(spec.get("args", {}).get(pattern, ""))
 
 
+def test_live_share_of_rows_moved_is_a_ratio_of_the_programs_counters():
+    entry = harness.by_name(MANIFEST["per_layer"], LIVE_SHARE, "metric")
+    assert entry == {
+        "name": LIVE_SHARE, "unit": "1", "better": "higher",
+        "source": "program_counter", "layer": "expert layer",
+        "moves": "tokens_per_s_chip", "workloads": [ST]}
+    assert MANIFEST["per_layer"][-1] == entry      # appended, nothing moved
+    spec = harness.load_json(MANIFEST, "layer_metrics", LIVE_SHARE)
+    assert spec == {"reader": "counter_ratio", "args": {
+        "numerator": "tm_moe_routes_held_total",
+        "denominator": "tm_moe_rows_moved_total"}}
+    # a program without the counter (the parent) gives no number and no
+    # error; with it, the ratio
+    from torchmpi_tpu import obs
+    read = harness.load_module(MANIFEST, "readers", "counter_ratio").read
+    obs.reset()
+    assert read({}, **spec["args"]) is None
+    obs.registry().counter_inc("tm_moe_routes_held_total", 250, layer="a")
+    obs.registry().counter_inc("tm_moe_rows_moved_total", 256, layer="a")
+    assert read({}, **spec["args"]) == 250 / 256
+    obs.reset()
+
+
 def test_expert_cell_joins_the_lists_whose_readers_are_right_for_it():
     mine = {m["name"] for m in harness.resolve(MANIFEST, ST).per_layer}
     joined = {m for m, cells in NEW_METRICS.items() if ST in cells}
-    assert mine == joined | set(ST_JOINS) | set(ST_METRICS)
+    assert mine == joined | set(ST_JOINS) | set(ST_METRICS) | {LIVE_SHARE}
     for metric in ST_JOINS:
         assert harness.by_name(MANIFEST["per_layer"], metric,
                                "metric")["workloads"] == TOK + [ST]
@@ -139,7 +164,7 @@ def test_expert_cell_joins_the_lists_whose_readers_are_right_for_it():
 
 def test_benchmark_json_only_gained_entries_at_the_end():
     """What the benchmark had (PR 23, then PR 24) is still there, first
-    and unchanged in order; PR 26's metrics follow it."""
+    and unchanged in order; PR 26's metrics follow it, then PR 27's."""
     names = [m["name"] for m in MANIFEST["per_layer"]]
     assert set(names[10:22]) == set(NEW_METRICS)
     assert names[:22] == [
@@ -152,7 +177,7 @@ def test_benchmark_json_only_gained_entries_at_the_end():
         "fwd_ms_per_step.img", "fwd_ms_per_step.tok", "bwd_ms_per_step.img",
         "bwd_ms_per_step.tok", "mlp_ms_per_step.tok",
         "attn_proj_ms_per_step.tok", "step_span_ms.img", "step_span_ms.tok"]
-    assert names[22:] == list(ST_METRICS)
+    assert names[22:] == list(ST_METRICS) + [LIVE_SHARE]
     layers = {m["layer"] for m in MANIFEST["per_layer"][:10]}
     assert {m["layer"] for m in MANIFEST["per_layer"][10:22]} <= layers
     assert {m["layer"] for m in MANIFEST["per_layer"][22:]} <= layers | {
@@ -403,6 +428,8 @@ def test_traced_rehearsal_of_the_expert_cell_reads_its_counters():
         "moe_rows_computed_over_routed.tok", "moe_routes_held_per_token.tok"}
     assert out["metrics"]["moe_rows_computed_over_routed.tok"] == {
         "value": 1.0, "unit": "1"}
+    # every block of the buffer the dispatch wrote holds a live route
+    assert 0 < out["metrics"][LIVE_SHARE]["value"] <= 1
     # the rehearsal holds 2 of 8 experts and sends 3 routes a token
     assert 0 < out["metrics"]["moe_routes_held_per_token.tok"]["value"] < 3
     assert min(check["routing_agree"]) == 1.0

@@ -20,6 +20,7 @@ sys.path.insert(0, ROOT)
 from chipbench import harness  # noqa: E402
 from torchmpi_tpu.models.transformer import (Block, ExpertFFN,  # noqa: E402
                                              TransformerLM)
+from torchmpi_tpu.ops import moe  # noqa: E402
 from torchmpi_tpu.ops.xent import fused_linear_cross_entropy  # noqa: E402
 from torchmpi_tpu.parallel import expert as ep  # noqa: E402
 
@@ -226,6 +227,178 @@ def test_gradients_of_the_held_part_match_the_dense_oracle():
         assert rel(g, w) < 1e-5
 
 
+# -------------------- (c') the live-row kernels against whole-buffer passes
+
+MOVE_T, MOVE_BLOCK = 16, 8            # 48 routes, six blocks of eight rows
+# case -> (live routes, whether they all go to ONE expert of the three held)
+LIVE_CASES = {"none": (0, False), "one": (1, False),
+              "one_short_of_a_block": (MOVE_BLOCK - 1, False),
+              "a_block_exactly": (MOVE_BLOCK, False),
+              "a_block_and_one": (MOVE_BLOCK + 1, False),
+              "every_row": (MOVE_T * K, False),
+              "one_expert_takes_all": (MOVE_T * K, True)}
+
+
+def routing(case, seed=11):
+    """Rank-major routes of which the case's number go to a held expert:
+    ``order`` and ``n_live`` as ``held_experts`` builds them, and the
+    inverse permutation the whole-buffer passes un-permute by."""
+    n_live, one_expert = LIVE_CASES[case]
+    rng = np.random.default_rng(seed)
+    key = np.full(MOVE_T * K, 3)                    # 3: held elsewhere
+    live = rng.permutation(MOVE_T * K)[:n_live]
+    key[live] = 0 if one_expert else rng.integers(0, 3, n_live)
+    order = np.argsort(key, kind="stable")
+    return (jnp.asarray(order, jnp.int32),
+            jnp.asarray(np.argsort(order), jnp.int32), jnp.int32(n_live))
+
+
+def whole_buffer_dispatch(u, order, n_live):
+    """The formulation the kernels replace: gather every row, mask."""
+    rows = u[order % u.shape[0]]
+    return jnp.where((jnp.arange(order.shape[0]) < n_live)[:, None], rows, 0)
+
+
+def whole_buffer_combine(y, weight, inverse, n_live):
+    """Mask, un-permute every row, the k weighted slices summed in order."""
+    tokens = weight.shape[1]
+    y = jnp.where((jnp.arange(y.shape[0]) < n_live)[:, None], y, 0)[inverse]
+    weight = jnp.where(inverse.reshape(weight.shape) < n_live, weight, 0)
+    return sum(y[j * tokens:(j + 1) * tokens].astype(jnp.float32)
+               * weight[j][:, None] for j in range(weight.shape[0]))
+
+
+def moved(dtype, seed=12):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (jax.random.normal(ks[0], (MOVE_T, E)).astype(dtype),
+            jax.random.normal(ks[1], (MOVE_T * K, E)).astype(dtype),
+            jax.nn.softmax(jax.random.normal(ks[2], (MOVE_T, K)), -1).T,
+            jax.random.normal(ks[3], (MOVE_T, E)))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", sorted(LIVE_CASES))
+def test_dispatch_writes_the_live_rows_of_the_sorted_buffer(case, dtype):
+    order, _, n_live = routing(case)
+    u = moved(dtype)[0]
+    got = moe.rows_from_tokens(u, order, n_live, block=MOVE_BLOCK)
+    want = whole_buffer_dispatch(u, order, n_live)
+    assert got.dtype == dtype and got.shape == want.shape
+    # a row is moved, not computed: the live prefix is the same bits
+    assert np.array_equal(np.asarray(got[:int(n_live)], np.float32),
+                          np.asarray(want[:int(n_live)], np.float32))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", sorted(LIVE_CASES))
+def test_combine_sums_the_live_rows_and_reads_no_other(case, dtype):
+    order, inverse, n_live = routing(case)
+    _, y, weight, _ = moved(dtype)
+    want = whole_buffer_combine(y, weight, inverse, n_live)
+    # NaN in every row that holds no route: nothing may read it
+    y = y.at[int(n_live):].set(jnp.nan)
+    got = moe.tokens_from_rows(y, order, n_live, MOVE_T,
+                               weight=weight.reshape(-1), block=MOVE_BLOCK)
+    assert got.dtype == jnp.float32
+    if dtype == jnp.bfloat16:
+        # the float32 buffer, rounded as it is read, is the bfloat16 one
+        wide = moe.tokens_from_rows(
+            y.astype(jnp.float32), order, n_live, MOVE_T,
+            weight=weight.reshape(-1), round_to=dtype, block=MOVE_BLOCK)
+        assert np.array_equal(np.asarray(wide), np.asarray(got))
+    if int(n_live):
+        assert rel(got, want) < 1e-6
+    else:
+        assert not np.asarray(got).any() and not np.asarray(want).any()
+    # a token none of whose routes is live reads exactly zero
+    dead = np.asarray((inverse.reshape(K, MOVE_T) >= n_live).all(0))
+    assert not np.asarray(got)[dead].any()
+
+
+@pytest.mark.parametrize("case", ["a_block_and_one", "every_row"])
+def test_sums_too_large_to_be_resident_go_through_in_parts(case, monkeypatch):
+    order, _, n_live = routing(case)
+    _, y, weight, _ = moved(jnp.float32)
+
+    def combine():
+        return moe.tokens_from_rows(y, order, n_live, MOVE_T,
+                                    weight=weight.reshape(-1),
+                                    block=MOVE_BLOCK)
+
+    assert moe.token_parts(MOVE_T, E) == 1
+    whole = combine()
+    monkeypatch.setattr(moe, "_RESIDENT_BYTES", MOVE_T * E * 4 // 2)
+    assert moe.token_parts(MOVE_T, E) == 2
+    assert np.array_equal(np.asarray(combine()), np.asarray(whole))
+
+
+@pytest.mark.parametrize("case", ["one", "a_block_and_one", "every_row"])
+def test_the_combines_transpose_rounds_as_the_casts_it_replaces(case):
+    """bfloat16 rows against the float32 down product, as the benchmark's
+    cell runs them: the scaled rows are rounded to bfloat16 and handed back
+    in float32, the weights' cotangents use the product rounded likewise."""
+    order, _, n_live = routing(case)
+    n = int(n_live)
+    g, y, weight, _ = moved(jnp.bfloat16)
+    y = y.astype(jnp.float32) * 1.001          # no longer bfloat16 values
+    dy, dots = moe.rows_from_tokens(g, order, n_live,
+                                    weight=weight.reshape(-1), against=y,
+                                    block=MOVE_BLOCK)
+    assert dy.dtype == jnp.float32 and dots.shape == (MOVE_T * K,)
+    rows = g[order % MOVE_T].astype(jnp.float32)
+    want = (rows * weight.reshape(-1)[order][:, None]).astype(jnp.bfloat16)
+    assert np.array_equal(np.asarray(dy[:n]),
+                          np.asarray(want[:n].astype(jnp.float32)))
+    rounded = y.astype(jnp.bfloat16).astype(jnp.float32)
+    np.testing.assert_allclose(np.asarray(dots[:n]),
+                               np.asarray((rows * rounded).sum(1)[:n]),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("case", sorted(LIVE_CASES))
+def test_gradients_of_dispatch_and_combine_match_the_whole_buffer_passes(
+        case, monkeypatch):
+    """Through the layer's own custom_vjps, with blocks of eight rows so
+    that the prefix ends inside, at the end of and past a block."""
+    monkeypatch.setattr(moe, "_BLOCK", MOVE_BLOCK)
+    order, inverse, n_live = routing(case)
+    u, _, weight, cot = moved(jnp.float32)
+
+    def between(rows):          # a row of the result from its own row only
+        return jnp.tanh(rows) * 1.5 + rows
+
+    def program(u, weight):
+        rows = ep._dispatch(u, order, n_live)
+        out = ep._combine(jnp.float32, between(rows), weight, order, n_live)
+        return (out * cot).sum()
+
+    def whole_buffer(u, weight):
+        rows = whole_buffer_dispatch(u, order, n_live)
+        return (whole_buffer_combine(between(rows), weight, inverse, n_live)
+                * cot).sum()
+
+    got, g_got = jax.value_and_grad(program, argnums=(0, 1))(u, weight)
+    want, g_want = jax.value_and_grad(whole_buffer, argnums=(0, 1))(u, weight)
+    assert abs(float(got) - float(want)) <= 1e-5 * max(abs(float(want)), 1e-6)
+    for g, w in zip(g_got, g_want):
+        if int(n_live):
+            assert float(jnp.linalg.norm(w)) > 0 and rel(g, w) < 1e-5
+        else:
+            assert not np.asarray(g).any() and not np.asarray(w).any()
+
+
+def test_a_row_type_the_kernels_cannot_move_is_refused():
+    order, _, n_live = routing("one")
+    with pytest.raises(TypeError, match="float32 or bfloat16"):
+        moe.rows_from_tokens(jnp.zeros((MOVE_T, E), jnp.float16), order,
+                             n_live)
+    with pytest.raises(ValueError, match="divides the buffer"):
+        moe.tokens_from_rows(jnp.zeros((MOVE_T * K, E)), order, n_live,
+                             MOVE_T, block=7)
+
+
 def test_a_range_outside_the_router_is_refused():
     u, logits = jnp.zeros((4, E)), jnp.zeros((4, N_EXPERTS))
     with pytest.raises(ValueError, match="do not fit a router over 8"):
@@ -246,8 +419,9 @@ def test_counters_read_what_the_routing_did():
     total = 0
     for i in range(len(LAYOUT)):
         layer = sown["moe"][f"Block_{i}"]["ExpertFFN_0"]
-        (chosen,), (routes,), (rows,) = (
-            layer["experts"], layer["routes_held"], layer["rows_computed"])
+        (chosen,), (routes,), (rows,), (moved_rows,) = (
+            layer["experts"], layer["routes_held"], layer["rows_computed"],
+            layer["rows_moved"])
         assert chosen.shape == (SEQ, K)
         # the program chose the experts the reference chose
         assert np.array_equal(np.sort(chosen, -1),
@@ -255,6 +429,11 @@ def test_counters_read_what_the_routing_did():
         in_range = int(((chosen >= held[0])
                         & (chosen < held[0] + held[1])).sum())
         assert int(routes) == in_range == int(rows)
+        # the dispatch wrote whole blocks: every live row and under one
+        # block more
+        block = moe.block_rows(SEQ * K)
+        assert int(moved_rows) % block == 0
+        assert in_range <= int(moved_rows) < in_range + block
         total += in_range
     # half the experts are held: about half the routes, neither none nor all
     assert 0 < total < len(LAYOUT) * SEQ * K

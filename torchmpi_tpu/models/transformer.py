@@ -380,9 +380,9 @@ class ExpertFFN(nn.Module):
 
     ``router_in`` is what the router reads: ``Block`` hands it the
     attention's normed input, the block's pre-attention state.  The
-    counters ``routes_held`` and ``rows_computed``, the chosen ``experts``
-    and the float32 ``router_logits`` are sown to the ``moe`` collection
-    (``mutable=["moe"]``), as ``MoEMLP`` sows its loss.
+    counters ``routes_held``, ``rows_computed`` and ``rows_moved``, the
+    chosen ``experts`` and the float32 ``router_logits`` are sown to the
+    ``moe`` collection (``mutable=["moe"]``), as ``MoEMLP`` sows its loss.
     """
 
     n_experts: int

@@ -6,7 +6,9 @@ collective algorithms (SURVEY.md §3 C4: ring/tree over MPI_Isend/Irecv +
 CUDA IPC).  On TPU the point-to-point transport is inter-chip RDMA issued
 from Pallas kernels; the ring algorithm is the same one the reference
 pipelined over MPI p2p.  ``flash`` is the blocked-attention compute kernel
-serving the beyond-reference long-context stack.
+serving the beyond-reference long-context stack; ``xent`` the fused
+linear + cross-entropy head; ``moe`` the dropless expert layer's row
+movement over the routed rows only.
 """
 
 from . import ring  # noqa: F401  (registers the "pallas" backend)

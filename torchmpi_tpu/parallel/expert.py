@@ -25,11 +25,14 @@ route to a held one, and computes the held experts' part of the result.
 
 from __future__ import annotations
 
+import functools
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from ..ops import moe
 
 
 def topk_dispatch(x, gate_logits, n_experts_global: int, capacity: int,
@@ -156,93 +159,81 @@ def moe_layer(x, gate_w, expert_fn: Callable, expert_params,
     return out
 
 
-def _unique_rows(x, idx):
-    """``x[idx]`` for a permutation ``idx`` of the rows."""
-    return x.at[idx].get(unique_indices=True, mode="promise_in_bounds")
-
-
-def _rank_slices(x, tokens):
-    """[k * T, D] -> its k rank-major slices [T, D].  Summing over them as
-    a Python loop lets XLA fuse the casts and weights of every slice into
-    one pass; a reshape to [k, T, D] and a reduction makes it write a
-    float32 copy of the buffer first (seen in the trace, PR 26)."""
-    return [x[j:j + tokens] for j in range(0, x.shape[0], tokens)]
-
-
 @jax.custom_vjp
-def _gather_routes(u, order, inverse):
-    """Rows of ``u`` [T, D] in sorted route order: route ``r`` of the
-    rank-major ``[k * T]`` routes belongs to token ``r % T``.  Its
-    transpose is written as a gather too (``inverse`` is the inverse
-    permutation): a scatter-add of ``k * T`` rows is the slow way on a TPU."""
-    return u[order % u.shape[0]]
+def _dispatch(u, order, n_live):
+    """``u[order[s] % T]`` for the sorted rows ``s`` of the live prefix
+    (``ops/moe.rows_from_tokens``); its transpose sums a token's live rows
+    (``ops/moe.tokens_from_rows``, every weight 1)."""
+    return moe.rows_from_tokens(u, order, n_live)
 
 
-def _gather_routes_fwd(u, order, inverse):
-    return _gather_routes(u, order, inverse), (inverse, u.shape[0])
+def _dispatch_fwd(u, order, n_live):
+    return _dispatch(u, order, n_live), (order, n_live, u.shape[0])
 
 
-def _gather_routes_bwd(res, g):
-    inverse, tokens = res
-    du = sum(s.astype(jnp.float32)
-             for s in _rank_slices(_unique_rows(g, inverse), tokens))
+def _dispatch_bwd(res, g):
+    order, n_live, tokens = res
+    with jax.named_scope("dispatch"):
+        du = moe.tokens_from_rows(g, order, n_live, tokens)
     return du.astype(g.dtype), None, None
 
 
-_gather_routes.defvjp(_gather_routes_fwd, _gather_routes_bwd)
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-@jax.custom_vjp
-def _permute_rows(x, index, inverse):
-    """``x[index]`` for a permutation, with its transpose ``g[inverse]``."""
-    return _unique_rows(x, index)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _combine(dtype, y, weight, order, n_live):
+    """``out[t] = sum_j weight[j, t] * y[s(j, t)]`` over token t's live
+    routes, ``s`` their sorted rows, in float32: ``weight`` [k, T]
+    rank-major like the routes, ``y`` the down product as it comes
+    (float32), rounded to ``dtype`` row by row as it is read.  Its
+    transpose gathers the cotangent's row of every live route, scaled by
+    the route's weight and rounded to ``dtype``, and the weight's own
+    cotangent beside it (``ops/moe.rows_from_tokens``)."""
+    return moe.tokens_from_rows(y, order, n_live, weight.shape[1],
+                                weight=weight.reshape(-1), round_to=dtype)
 
 
-def _permute_rows_fwd(x, index, inverse):
-    return _unique_rows(x, index), inverse
+def _combine_fwd(dtype, y, weight, order, n_live):
+    return (_combine(dtype, y, weight, order, n_live),
+            (y, weight, order, n_live))
 
 
-def _permute_rows_bwd(inverse, g):
-    return _unique_rows(g, inverse), None, None
+def _combine_bwd(dtype, res, g):
+    y, weight, order, n_live = res
+    with jax.named_scope("combine"):
+        dy, dots = moe.rows_from_tokens(
+            g.astype(dtype), order, n_live, weight=weight.reshape(-1),
+            against=y)
+        # by sorted row -> by route: sorting by ``order`` undoes the sort
+        # (a gather of k * T scalars by the inverse permutation takes eight
+        # times as long on a TPU).  Past n_live the dots are undefined.
+        live = jnp.arange(order.shape[0]) < n_live
+        _, d_weight = lax.sort((order, jnp.where(live, dots, 0)), num_keys=1)
+    return dy, d_weight.reshape(weight.shape), None, None
 
 
-_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def _held_part(u, probs, group, w_gate, w_up, w_down):
-    """``sum_j probs[t, j] * f_group[t, j](u[t])`` over the routes whose
-    ``group`` (the expert's index among those held) is below the number
-    held; ``group == count`` marks a route to an expert held elsewhere."""
-    tokens, k = group.shape
-    count, routes = w_gate.shape[0], tokens * k
-    # Routes are laid out rank-major, [k, T] flat: splitting ``k * T`` rows
-    # into [k, T, D] is then free, where [T, k, D] would be a relayout of
-    # the whole buffer (k pads to the sublane tile).
-    group, probs = group.T, probs.T
+def _held_part(u, probs, order, sizes, w_gate, w_up, w_down):
+    """``sum_j probs[j, t] * f_e(u[t])`` over token t's live routes:
+    ``order`` sorts the rank-major routes ``[k * T]`` by expert, the live
+    ones (``sizes`` of them an expert held) first."""
+    n_live = sizes.sum()
     with jax.named_scope("dispatch"):
-        key = group.reshape(-1)
-        order = jnp.argsort(key, stable=True)        # held first, by expert
-        inverse = jnp.zeros_like(order).at[order].set(
-            jnp.arange(routes, dtype=order.dtype), unique_indices=True)
-        sizes = (key[:, None] == jnp.arange(count)).sum(0, dtype=jnp.int32)
-        # Rows past the last held route are never multiplied (the grouped
-        # product follows ``sizes``), so what they hold is undefined: both
-        # ways through, they are cut off here and in ``combine``.
-        live = (jnp.arange(routes) < sizes.sum())[:, None]
-        rows = jnp.where(live, _gather_routes(u, order, inverse), 0)
+        rows = _dispatch(u, order, n_live)
     with jax.named_scope("experts"):
         def product(x, w):
             return lax.ragged_dot(x, w.astype(x.dtype), sizes,
                                   preferred_element_type=jnp.float32)
 
         hidden = jax.nn.relu(product(rows, w_gate)) * product(rows, w_up)
-        y = product(hidden.astype(u.dtype), w_down).astype(u.dtype)
+        y = product(hidden.astype(u.dtype), w_down)
     with jax.named_scope("combine"):
-        y = _permute_rows(jnp.where(live, y, 0), inverse, order)
-        weight = jnp.where(group < count, probs, 0)
-        out = sum(s.astype(jnp.float32) * weight[j][:, None]
-                  for j, s in enumerate(_rank_slices(y, tokens)))
-    return out, sizes.sum()
+        # the cast of ``y`` to the rows' type rides on the kernel, and its
+        # transpose on the gather: no pass over the whole buffer for either
+        return _combine(u.dtype, y, probs, order, n_live)
 
 
 def held_experts(u, router_logits, k: int, first: int, w_gate, w_up,
@@ -264,17 +255,37 @@ def held_experts(u, router_logits, k: int, first: int, w_gate, w_up,
     for it.
 
     Dropless: the routes are sorted by expert into a buffer of ``T * k``
-    rows, the worst case, so no imbalance drops a route; the three grouped
-    products (``lax.ragged_dot``, which the TPU compiler turns into a
-    grouped-matmul kernel that skips the tiles past the last routed row)
-    work in proportion to the routes held.  Router top-k and softmax run in
-    float32, the products in ``u``'s type with float32 accumulation.  The
-    buffers are recomputed in the backward pass, not kept: at the worst
-    case they are ``n_experts / count`` times what the routes need.
+    rows, the worst case, so no imbalance drops a route.  The routes to a
+    held expert come first: ``n_live`` of them, counted on the device, and
+    ALL the row movement follows that count.  The three grouped products
+    (``lax.ragged_dot``, which the TPU compiler turns into a grouped-matmul
+    kernel that skips the tiles past the last routed row) compute the live
+    rows.  ``dispatch`` writes them (``moe.gather``: each live row from its
+    token) and ``combine`` reads them (``moe.combine``: a token's live rows
+    of the down product, rounded to ``u``'s type, times their weights,
+    summed in float32).  Their transposes are the same two kernels the
+    other way round: the dispatch's sums a token's live cotangent rows,
+    the combine's gathers a live route's cotangent row times its weight,
+    with the weight's own cotangent (a row's dot with the down product)
+    riding on it.  Rows past ``n_live`` (rounded up to the block
+    ``ops/moe.block_rows`` gives) are never written by either gather, and
+    what they hold is UNDEFINED, NaN included.  Nothing carries them into
+    a result: a row of the grouped products and of the gate between them
+    depends on its own row alone, the weight gradients' contraction over
+    rows stops at ``sizes`` (shown on the chip with NaN there, PERF.md),
+    and both combines stop at ``n_live``.  With every expert held every
+    row is live and the same code moves them all.
+
+    Router top-k and softmax run in float32, the products in ``u``'s type
+    with float32 accumulation.  The sort runs once, outside the part that
+    the backward pass recomputes (its integers are the residuals); the
+    buffers are recomputed, not kept: at the worst case they are
+    ``n_experts / count`` times what the routes need.
 
     ``stats``: ``routes_held`` (routes to a held expert), ``rows_computed``
-    (rows the grouped products are told to compute) and ``experts``
-    ([T, k] chosen experts), all computed on the device.
+    (rows the grouped products are told to compute), ``rows_moved`` (rows
+    of the buffer the dispatch wrote: ``n_live`` rounded up to its block)
+    and ``experts`` ([T, k] chosen experts), all computed on the device.
     """
     n_experts, count = router_logits.shape[-1], w_gate.shape[0]
     if not (0 <= first and first + count <= n_experts and 1 <= k <= n_experts):
@@ -286,24 +297,34 @@ def held_experts(u, router_logits, k: int, first: int, w_gate, w_up,
         probs = jax.nn.softmax(scores, axis=-1)
         held = (experts >= first) & (experts < first + count)
         group = jnp.where(held, experts - first, count)
-    out, rows = jax.checkpoint(_held_part)(u, probs, group, w_gate, w_up,
-                                           w_down)
-    return out.astype(u.dtype), {"routes_held": held.sum(dtype=jnp.int32),
-                                 "rows_computed": rows, "experts": experts}
+    with jax.named_scope("dispatch"):
+        # Routes are laid out rank-major, [k, T] flat, so that the stable
+        # sort keeps a rank's tokens in order within an expert.
+        key = group.T.reshape(-1)
+        order = jnp.argsort(key, stable=True)        # held first, by expert
+        sizes = (key[:, None] == jnp.arange(count)).sum(0, dtype=jnp.int32)
+    out = jax.checkpoint(_held_part)(u, probs.T, order, sizes, w_gate, w_up,
+                                     w_down)
+    rows = sizes.sum()
+    block = moe.block_rows(key.shape[0])
+    return out.astype(u.dtype), {
+        "routes_held": held.sum(dtype=jnp.int32), "rows_computed": rows,
+        "rows_moved": (rows + block - 1) // block * block,
+        "experts": experts}
 
 
 def record_counters(moe_collection) -> None:
     """A model's sown ``moe`` collection (``apply(..., mutable=["moe"])``)
-    into the ``obs`` registry: ``tm_moe_routes_held_total`` and
-    ``tm_moe_rows_computed_total``, one series a layer (label ``layer`` =
-    the module's path).  Fetches the counters from the device: call it
-    beside a step, not inside one."""
+    into the ``obs`` registry: ``tm_moe_routes_held_total``,
+    ``tm_moe_rows_computed_total`` and ``tm_moe_rows_moved_total``, one
+    series a layer (label ``layer`` = the module's path).  Fetches the
+    counters from the device: call it beside a step, not inside one."""
     from flax.traverse_util import flatten_dict
 
     from .. import obs
 
     for (*layer, name), sown in flatten_dict(moe_collection).items():
-        if name in ("routes_held", "rows_computed"):
+        if name in ("routes_held", "rows_computed", "rows_moved"):
             for value in sown:      # one entry a call of the module
                 obs.registry().counter_inc(f"tm_moe_{name}_total",
                                            int(value), layer="/".join(layer))
