@@ -176,7 +176,7 @@ def main():
         evidence["backend"] = ev
     elif cutover is not None:
         # The selector compares custom_min_bytes against PER-RANK bytes:
-        # the eager path picks on x[0] (collectives.py `_pick(op, x[0],..)`)
+        # the eager plan picks on one rank's aval (`selector.pick`)
         # and the in-axis path picks on the local shard — so the measured
         # per-rank cutover is exactly the right knob value, unscaled.
         rec["backend"] = "pallas"

@@ -1,8 +1,8 @@
 """Bank assertable ``*-SUMMARY`` benchmark lines with staleness stamps.
 
 The compare modes of ``collectives_bench.py`` (``--guard-compare``,
-``--plan-compare``, ``--dcn-compare``, ``--obs-compare``,
-``--faults-compare``, ``--watchdog-compare``, ``--overlap-compare``)
+``--dcn-compare``, ``--obs-compare``, ``--faults-compare``,
+``--watchdog-compare``, ``--overlap-compare``)
 and the recovery bench end in one machine-readable
 ``KIND-SUMMARY {json}`` line that CI greps and asserts — and then the
 evidence evaporates with the log.  This module

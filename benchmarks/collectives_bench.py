@@ -345,115 +345,6 @@ def _guard_compare_mode(args, mpi, n):
     _maybe_bank(args, "GUARD-SUMMARY", summary)
 
 
-def _plan_compare_mode(args, mpi, n):
-    """Dispatch overhead of the CollectivePlan replay path
-    (docs/PLANNER.md acceptance): the same small eager allreduce timed
-    planned vs pre-planner (``planner.set_enabled(False)``), each under
-    every-layer-off and every-layer-ON (tuning ``backend="auto"`` +
-    ``analysis=warn`` + ``obs=metrics`` + ``faults=policy``).  Small
-    payload on purpose — the Python dispatch path is what the planner
-    compresses; large tensors would bury it under transfer time.
-
-    Also asserts (and emits as a ``PLAN-SUMMARY`` JSON line for CI) the
-    steady-state contract: after one warm dispatch, ``--steady`` more
-    dispatches produce exactly that many plan hits and ZERO re-plans,
-    and every path's result is bit-identical to the pre-planner path.
-    """
-    import tempfile
-
-    import numpy as np
-
-    from torchmpi_tpu import planner
-    from torchmpi_tpu.utils import metrics as umetrics
-
-    x = np.random.RandomState(0).rand(n, 1024).astype(np.float32)
-    plan_db = os.path.join(tempfile.mkdtemp(prefix="tm_plan_bench_"),
-                           "plans.json")
-    layer_cfgs = {
-        "off": dict(backend="xla", analysis="off", obs="off", faults="off"),
-        "all-on": dict(backend="auto", tuning_plan_path=plan_db,
-                       analysis="warn", obs="metrics", faults="policy"),
-    }
-    results = {}
-    bitwise_ok = True
-    for lname, cfg in layer_cfgs.items():
-        ref = None
-        for pname, enabled in (("pre-planner", False), ("planned", True)):
-            planner.set_enabled(enabled)
-            mpi.set_config(**cfg)  # bumps the epoch + clears every plan
-            out = np.asarray(mpi.allreduce(x))  # warm (auto measures here)
-            if ref is None:
-                ref = out
-            elif not np.array_equal(ref, out):
-                bitwise_ok = False
-            r = umetrics.timed(lambda: mpi.allreduce(x),
-                               iters=args.iters, rounds=5)
-            results[(lname, pname)] = r
-            line = {"layers": lname, "path": pname,
-                    "us_per_dispatch": round(r.median * 1e6, 2),
-                    "jitter_us": round(r.jitter * 1e6, 2)}
-            print(json.dumps(line) if args.json else
-                  f"layers={lname:7s} {pname:11s} "
-                  f"{r.median * 1e6:9.2f} us/dispatch "
-                  f"(jitter {r.jitter * 1e6:.2f} us)")
-        planner.set_enabled(True)
-
-    # Steady-state: one warm dispatch, then N replays — all hits.
-    mpi.set_config(**layer_cfgs["all-on"])
-    mpi.allreduce(x)
-    planner.reset_stats()
-    steady = args.steady
-    for _ in range(steady):
-        mpi.allreduce(x)
-    st = planner.stats()
-
-    # Verdict A/B: the grid rows above are drift-sensitive (each cell
-    # is measured seconds apart, and on a small container the scheduler
-    # moves more than the planner overhead between cells).  The
-    # acceptance comparison interleaves the two PLANNED configs
-    # round-by-round so load/thermal drift hits both equally.
-    meds = {name: [] for name in layer_cfgs}
-    for _ in range(5):
-        for lname, cfg in layer_cfgs.items():
-            mpi.set_config(**cfg)
-            mpi.allreduce(x)  # re-plan + warm under this config
-            meds[lname].append(
-                umetrics.timed(lambda: mpi.allreduce(x),
-                               iters=args.iters, rounds=1).median)
-    base = umetrics.TimedResult(meds["off"])
-    allon = umetrics.TimedResult(meds["all-on"])
-    # Min-of-rounds (TimedResult's float value) is the stable dispatch
-    # estimator on a loaded container — medians here still carry XLA
-    # execution tail noise several times the planner overhead.  The
-    # acceptance is ONE-sided: overhead at or below the floor (all-on
-    # measuring faster than off is noise, not a failure).
-    delta = float(allon) - float(base)
-    floor = base.jitter + allon.jitter
-    within = delta <= floor
-    summary = {"steady_steps": steady, "hits": st["hits"],
-               "misses": st["misses"], "entries": st["entries"],
-               "bitwise_identical": bitwise_ok,
-               "all_on_vs_off_us": round(delta * 1e6, 2),
-               "noise_floor_us": round(floor * 1e6, 2),
-               "within_noise": bool(within)}
-    print("PLAN-SUMMARY " + json.dumps(summary))
-    _maybe_bank(args, "PLAN-SUMMARY", summary)
-    print(f"# all-layers-on planned vs off planned delta "
-          f"{delta * 1e6:+.2f} us (noise floor {floor * 1e6:.2f} us): "
-          f"{'WITHIN NOISE' if within else 'MEASURABLE'}; "
-          f"steady-state {st['hits']} hits / {st['misses']} re-plans "
-          f"over {steady} dispatches; bitwise identical to "
-          f"pre-planner: {bitwise_ok}", file=sys.stderr)
-    mpi.set_config(backend="xla", analysis="off", obs="off", faults="off")
-    if not bitwise_ok:
-        raise SystemExit("plan-compare: planned results diverged from "
-                         "the pre-planner path")
-    if st["misses"]:
-        raise SystemExit(
-            f"plan-compare: {st['misses']} steady-state re-plans "
-            f"(expected zero)")
-
-
 def _overlap_compare_mode(args, mpi, mesh):
     """Sync vs backprop-overlapped gradient dispatch (docs/OVERLAP.md)
     on the same mixed fp32/bf16 MLP: per-step wall time, all-reduce
@@ -755,15 +646,8 @@ def main():
                         "and a jitted gradient sync under "
                         "guard=off/numeric (fused tripwire cost) — "
                         "docs/GUARD.md")
-    p.add_argument("--plan-compare", action="store_true",
-                   help="planner overhead mode: the same small eager "
-                        "allreduce, planned vs pre-planner dispatch, "
-                        "under all-layers-off and all-layers-on "
-                        "(tuning+analysis+obs+faults), plus a "
-                        "steady-state zero-re-plan assertion "
-                        "(docs/PLANNER.md)")
     p.add_argument("--steady", type=int, default=100,
-                   help="plan-compare mode: steady-state dispatches to "
+                   help="dcn-compare mode: steady-state dispatches to "
                         "assert zero re-plans over")
     p.add_argument("--overlap-compare", action="store_true",
                    help="gradsync schedule mode: sync vs "
@@ -819,11 +703,6 @@ def main():
 
     backends = args.backends.split(",")
     sizes = [int(s) for s in args.sizes.split(",")]
-
-    if args.plan_compare:
-        _plan_compare_mode(args, mpi, n)
-        mpi.stop()
-        return
 
     if args.obs_compare:
         _obs_compare_mode(args, mpi, n)

@@ -653,7 +653,7 @@ def phase_collectives(ctx):
                 deadline(300)
                 # The plan is the library's own record of what it will
                 # run: a request that degraded shows another backend.
-                plan = planner.plan_for(
+                plan = mpi.collectives.plan_for(
                     verb, jax.ShapeDtypeStruct(x.shape, x.dtype), mesh, n,
                     backend, kw)
                 check(plan.backend == backend,

@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 import torchmpi_tpu as mpi
-from torchmpi_tpu import collectives
+from torchmpi_tpu import collectives, selector
 
 N = 8
 SIZES = [1, 7, 128, 1000, 4096]  # non-pow2 + straddling shapes
@@ -496,9 +496,9 @@ def test_explicit_backend_bypasses_cutover(hier_runtime):
     # tiny tensors (the cutover only governs the config-driven default).
     mpi.set_config(custom_min_bytes=1 << 30)
     x = rank_data(4, np.float32)
-    impl = collectives._pick("allreduce", x[0], "hierarchical",
-                             mpi.world_mesh().axis_names,
-                             mesh=mpi.world_mesh())
+    impl = selector.pick("allreduce", x[0], "hierarchical",
+                         mpi.world_mesh().axis_names,
+                         mesh=mpi.world_mesh())
     from torchmpi_tpu.parallel.hierarchical import hier_allreduce
     assert impl is hier_allreduce
     out = np.asarray(mpi.allreduce(x, backend="hierarchical"))
@@ -521,15 +521,15 @@ def test_backend_per_op_override(hier_runtime):
                    backend_per_op={"allreduce": "hierarchical"})
     x = rank_data(64, np.float32)
     from torchmpi_tpu.parallel.hierarchical import hier_allreduce
-    impl = collectives._pick("allreduce", x[0], None,
-                             mpi.world_mesh().axis_names,
-                             mesh=mpi.world_mesh())
+    impl = selector.pick("allreduce", x[0], None,
+                         mpi.world_mesh().axis_names,
+                         mesh=mpi.world_mesh())
     assert impl is hier_allreduce
     # other ops keep the default backend
     from torchmpi_tpu.collectives import _xla_broadcast
-    impl_b = collectives._pick("broadcast", x[0], None,
-                               mpi.world_mesh().axis_names,
-                               mesh=mpi.world_mesh())
+    impl_b = selector.pick("broadcast", x[0], None,
+                           mpi.world_mesh().axis_names,
+                           mesh=mpi.world_mesh())
     assert impl_b is _xla_broadcast
     out = np.asarray(mpi.allreduce(x))
     np.testing.assert_allclose(out[0], x.sum(axis=0), rtol=1e-6)
@@ -554,9 +554,9 @@ def test_backend_per_op_bypasses_cutover_and_validates(hier_runtime):
                    custom_min_bytes=1 << 30)
     x = rank_data(4, np.float32)  # tiny: under any cutover
     from torchmpi_tpu.ops.ring import ring_allreduce
-    impl = collectives._pick("allreduce", x[0], None,
-                             mpi.world_mesh().axis_names,
-                             mesh=mpi.world_mesh())
+    impl = selector.pick("allreduce", x[0], None,
+                         mpi.world_mesh().axis_names,
+                         mesh=mpi.world_mesh())
     assert impl is ring_allreduce
     with pytest.raises(ValueError):
         mpi.set_config(backend_per_op={"broadcast": "pallas"})  # no impl

@@ -4,9 +4,10 @@ The dispatch-path planner's contract (docs/PLANNER.md): plan once per
 (op, tree structure, mesh, config epoch), replay thereafter —
 hit/miss on same-structure different-values calls, invalidation on
 mesh change / config-epoch bump / clear_cache(), plan reuse across the
-eager and in-axis entry points, and bit-identical results vs the
-preserved pre-planner dispatch path for every routed consumer (eager,
-in-axis, gradsync, ZeRO).
+eager and in-axis entry points, and for every routed consumer (eager,
+in-axis, gradsync, overlap, ZeRO) the same results as a reference that
+shares no code with the library: the plain ``lax`` collective per leaf
+under the same ``shard_map``, NumPy, or optax on the unsharded tree.
 """
 
 import jax
@@ -14,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
-from jax import shard_map
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 import torchmpi_tpu as mpi
@@ -36,20 +37,28 @@ def mixed_tree(seed=0):
     }
 
 
+AXES = ("dcn", "ici")
+
+
 @pytest.fixture()
 def planned_runtime(flat_runtime):
     planner.reset_stats()
-    yield flat_runtime
-    planner.set_enabled(True)
+    return flat_runtime
 
 
-def _unplanned(fn, *args, **kw):
-    """Run fn with the planner disabled (the pre-planner path)."""
-    prev = planner.set_enabled(False)
-    try:
-        return fn(*args, **kw)
-    finally:
-        planner.set_enabled(prev)
+def _replicated(mesh, body, *args):
+    """``body`` under shard_map on replicated arguments: how every
+    in-axis case below runs the library and its plain reference."""
+    return jax.jit(shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(),
+                             check_vma=False))(*args)
+
+
+def _assert_trees_bit_equal(got, want):
+    got, want = jax.tree.leaves(got), jax.tree.leaves(want)
+    assert len(got) == len(want)
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 # ---------------------------------------------------------------------------
@@ -76,15 +85,27 @@ def test_eager_new_shape_or_dtype_is_new_plan(planned_runtime):
     assert planner.stats()["misses"] == 3
 
 
-def test_eager_bitwise_vs_preplanner(planned_runtime):
+def test_eager_bitwise_vs_plain_reference(planned_runtime):
+    """Bit-equal throughout: the ``xla`` backend issues the same one
+    collective as the plain ``lax`` call, a broadcast copies, and the
+    ``host`` backend reduces with NumPy's own sum."""
+    mesh = planned_runtime
     x = rank_major()
-    for op_fn in (lambda: mpi.allreduce(x),
-                  lambda: mpi.broadcast(x, root=2),
-                  lambda: mpi.reduce_scatter(x),
-                  lambda: mpi.allreduce(x, backend="host")):
-        planned = np.asarray(op_fn())
-        unplanned = np.asarray(_unplanned(op_fn))
-        np.testing.assert_array_equal(planned, unplanned)
+
+    def plain(fn):
+        return np.asarray(jax.jit(shard_map(
+            lambda xs: fn(xs[0])[None], mesh=mesh, in_specs=P(AXES),
+            out_specs=P(AXES), check_vma=False))(x))
+
+    for got, want in (
+            (mpi.allreduce(x), plain(lambda v: lax.psum(v, AXES))),
+            (mpi.broadcast(x, root=2), np.broadcast_to(x[2], x.shape)),
+            (mpi.reduce_scatter(x),
+             plain(lambda v: lax.psum_scatter(v, AXES, scatter_dimension=0,
+                                              tiled=True))),
+            (mpi.allreduce(x, backend="host"),
+             np.broadcast_to(x.sum(axis=0), x.shape))):
+        np.testing.assert_array_equal(np.asarray(got), want)
 
 
 def test_in_axis_plan_reuse_across_retraces(planned_runtime):
@@ -107,28 +128,63 @@ def test_in_axis_plan_reuse_across_retraces(planned_runtime):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_in_axis_bitwise_vs_preplanner(planned_runtime):
+def test_in_axis_bitwise_vs_plain_lax(planned_runtime):
+    """The fused buckets against one plain collective per leaf.
+    Bit-equal: the reductions are elementwise, so repacking the leaves
+    changes no element's cross-device order, and no dtype is promoted
+    on the wire (the bf16 leaf reduces in bf16 on both sides)."""
     mesh = planned_runtime
     tree = mixed_tree()
-    axes = ("dcn", "ici")
-
-    def run(verb, **kw):
-        def body(t):
-            return verb(t, axes, **kw)
-
-        return jax.jit(shard_map(body, mesh=mesh, in_specs=P(),
-                                 out_specs=P(), check_vma=False))(tree)
-
     C = mpi.collectives
-    for verb, kw in ((C.allreduce_in_axis, {"op": "sum"}),
-                     (C.broadcast_in_axis, {"root": 1}),
-                     (C.reduce_scatter_in_axis, {}),
-                     (C.allgather_in_axis, {})):
-        planned = run(verb, **kw)
-        unplanned = _unplanned(run, verb, **kw)
-        for a, b in zip(jax.tree.leaves(planned),
-                        jax.tree.leaves(unplanned)):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for verb, kw, plain in (
+            (C.allreduce_in_axis, {"op": "sum"},
+             lambda v: lax.psum(v, AXES)),
+            (C.reduce_scatter_in_axis, {},
+             lambda v: lax.psum_scatter(v, AXES, scatter_dimension=0,
+                                        tiled=True)),
+            (C.allgather_in_axis, {},
+             lambda v: lax.all_gather(v, AXES, axis=0, tiled=False))):
+        _assert_trees_bit_equal(
+            _replicated(mesh, lambda t: verb(t, AXES, **kw), tree),
+            _replicated(mesh, lambda t: jax.tree.map(plain, t), tree))
+    # A broadcast of a replicated tree is the tree (NumPy's copy).
+    _assert_trees_bit_equal(
+        _replicated(mesh, lambda t: C.broadcast_in_axis(t, AXES, root=1),
+                    tree),
+        jax.tree.map(np.asarray, tree))
+
+
+@pytest.mark.parametrize("entry", ["allreduce_in_axis",
+                                   "synchronize_gradients"])
+@pytest.mark.parametrize("case", ["python_scalar_leaf", "empty_tree"])
+def test_in_axis_trees_without_array_leaves(planned_runtime, entry, case):
+    """A Python-scalar leaf is planned as the array ``lax.psum`` makes
+    of it (one miss, then hits); an empty tree comes back as it is and
+    plans nothing."""
+    mesh = planned_runtime
+    w = jnp.asarray(np.random.RandomState(3).randn(8, 4), np.float32)
+    if entry == "allreduce_in_axis":
+        verb = lambda t: mpi.collectives.allreduce_in_axis(t, AXES)  # noqa: E731
+        plain = lambda v: lax.psum(v, AXES)  # noqa: E731
+    else:
+        verb = lambda t: gradsync.synchronize_gradients(t, AXES)  # noqa: E731
+        plain = lambda v: lax.pmean(v, AXES)  # noqa: E731
+    if case == "empty_tree":
+        empty = {"a": {}, "b": []}
+        assert _replicated(mesh, lambda v: verb(empty), w) == empty
+        assert planner.stats()["misses"] == 0 and not planner.describe()
+        return
+
+    def tree(v):   # built in the body: jit would make the 2.5 an array
+        return {"w": v, "s": 2.5}
+
+    got = _replicated(mesh, lambda v: verb(tree(v)), w)
+    assert planner.stats()["misses"] == 1
+    _assert_trees_bit_equal(
+        got, _replicated(mesh, lambda v: jax.tree.map(plain, tree(v)), w))
+    _replicated(mesh, lambda v: verb(tree(v)), w)   # a fresh jit retraces
+    st = planner.stats()
+    assert st["misses"] == 1 and st["hits"] >= 1
 
 
 def test_eager_and_in_axis_entry_points_share_the_table(planned_runtime):
@@ -214,7 +270,7 @@ def test_set_config_fuse_bytes_replans_regression(planned_runtime):
 def test_selector_reregister_strands_stale_plans(planned_runtime):
     """Re-registering an implementation at runtime must re-plan (the
     selector generation is part of every key — the planner analog of
-    the legacy cache keying on the resolved impl object)."""
+    a cache keyed on the resolved impl object)."""
     from torchmpi_tpu import selector
 
     x = rank_major()
@@ -274,23 +330,24 @@ def test_pushed_communicator_is_its_own_key(planned_runtime):
 
 
 def test_gradsync_bucketed_planned_bitwise(planned_runtime):
+    """Three buckets against ``lax.pmean`` per leaf: bit-equal, the
+    mean being elementwise like the sum (see the in-axis case)."""
     mesh = planned_runtime
     tree = mixed_tree()
 
     def run():
-        def body(t):
-            return gradsync.synchronize_gradients(t, ("dcn", "ici"),
-                                                  n_buckets=3)
-
-        return jax.jit(shard_map(body, mesh=mesh, in_specs=P(),
-                                 out_specs=P(), check_vma=False))(tree)
+        return _replicated(
+            mesh, lambda t: gradsync.synchronize_gradients(t, AXES,
+                                                           n_buckets=3),
+            tree)
 
     planner.reset_stats()
     planned = run()
     assert any(r["kind"] == "gradsync" for r in planner.describe())
-    unplanned = _unplanned(run)
-    for a, b in zip(jax.tree.leaves(planned), jax.tree.leaves(unplanned)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    _assert_trees_bit_equal(
+        planned,
+        _replicated(mesh, lambda t: jax.tree.map(
+            lambda v: lax.pmean(v, AXES), t), tree))
     # Second step build replays the gradsync plan.
     planner.reset_stats()
     run()
@@ -322,9 +379,19 @@ def test_overlap_grad_fn_decision_planned(planned_runtime):
     misses_after_first = planner.stats()["misses"]
     l2, g2 = run()  # same structure: the overlap decision replays
     assert planner.stats()["misses"] == misses_after_first
-    l3, g3 = _unplanned(run)
-    for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g3)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    # Reference: jax.value_and_grad, then lax.pmean per leaf.  Bit-equal:
+    # the bucket syncs are identities in the forward pass, so each
+    # device's gradient is the same computation, and the mean of a
+    # concatenation is the concatenation of the means.
+    def plain(p, xb):
+        loss_v, g = jax.value_and_grad(loss)(p, xb)
+        return loss_v, jax.tree.map(lambda v: lax.pmean(v, AXES), g)
+
+    l3, g3 = jax.jit(shard_map(
+        plain, mesh=mesh, in_specs=(P(), P(AXES)), out_specs=(P(), P()),
+        check_vma=False))(params, x)
+    _assert_trees_bit_equal((l1, g1), (l3, g3))
 
 
 def test_zero_update_planned_bitwise(planned_runtime):
@@ -350,9 +417,16 @@ def test_zero_update_planned_bitwise(planned_runtime):
     planner.reset_stats()
     p1, _ = run()
     assert any(r["kind"] == "flatspec" for r in planner.describe())
-    p2, _ = _unplanned(run)
-    for a, b in zip(jax.tree.leaves(p1), jax.tree.leaves(p2)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # Reference: optax on the unsharded tree, as tests/test_zero.py does
+    # (the gradients are replicated, so their mean is themselves).  Its
+    # tolerance too: the sharded update runs on a flat float32 view
+    # inside one compiled program, whose multiply-add XLA may contract
+    # where the eager reference rounds twice.
+    updates, _ = tx.update(grads, tx.init(params), params)
+    p2 = optax.apply_updates(params, updates)
+    for k in params:
+        np.testing.assert_allclose(np.asarray(p1[k]), np.asarray(p2[k]),
+                                   rtol=2e-6, atol=2e-6)
 
 
 # ---------------------------------------------------------------------------
@@ -400,3 +474,19 @@ def test_describe_rows_shape(planned_runtime):
                   "build_ms", "hits", "staged", "obs", "faults",
                   "analysis"):
         assert field in row
+
+
+def test_plan_tool_dump_live_prints_the_table(planned_runtime, capsys):
+    """``scripts/plan_tool.py dump-live`` (its own warm-up dispatches,
+    each made twice) prints one row a plan and the hit/miss line."""
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))), "scripts"))
+    import plan_tool
+
+    assert plan_tool.main(["dump-live"]) == 0
+    out = capsys.readouterr().out
+    assert "3 live plan(s); 3 hits / 3 misses" in out
+    assert out.count("eager") == 3 and "broadcast" in out

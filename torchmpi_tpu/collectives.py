@@ -38,7 +38,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Any, Dict, Optional, Tuple, Union
+from typing import Any, Callable, Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -47,7 +47,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax import shard_map
 
-from . import fusion, planner, runtime, selector
+from . import planner, runtime, selector
 
 AxisNames = Union[str, Tuple[str, ...]]
 
@@ -302,89 +302,21 @@ for _op, _fn in [
 # ---------------------------------------------------------------------------
 
 
-def _config_backend(op_name: str, cfg) -> Tuple[str, bool]:
-    """Resolve the config-level backend for ``op_name``: per-op table
-    first (a deliberate choice, carrying explicit/per-call authority),
-    then the hierarchical flag, then the config default.  The ONE home
-    of this precedence — shared by _pick and the eager "auto" trigger
-    so they can never drift apart."""
-    if cfg.backend_per_op:
-        b = cfg.backend_per_op.get(op_name)
-        if b is not None:
-            return b, True
-    return ("hierarchical" if cfg.hierarchical else cfg.backend), False
-
-
-def _pick(op_name: str, x, backend: Optional[str], axes: Tuple[str, ...],
-          mesh: Optional[Mesh] = None, cfg=None):
-    explicit = backend is not None
-    if cfg is not None or runtime.is_initialized():
-        if cfg is None:
-            cfg = runtime.config()
-        if backend is None:
-            # A per-op table entry bypasses the size cutover like a
-            # per-call backend (topology fallback still applies).
-            backend, explicit = _config_backend(op_name, cfg)
-        custom_min = cfg.custom_min_bytes
-    else:
-        backend = backend or "xla"
-        custom_min = 0
-    # Hierarchical staging only helps when the outer axis really spans more
-    # than one slice; use the actual mesh extent, not the axis-name count.
-    n_dcn = 1
-    if len(axes) > 1:
-        m = mesh
-        if m is None and runtime.is_initialized():
-            m = runtime.current_mesh()
-        n_dcn = int(m.shape[axes[0]]) if (m is not None
-                                          and axes[0] in m.shape) else 2
-    return selector.select(
-        op_name,
-        backend,
-        nbytes=selector.nbytes_of(x),
-        custom_min_bytes=custom_min,
-        n_dcn=n_dcn,
-        explicit=explicit,
-        dtype=getattr(x, "dtype", None),
-        axes=axes,
-    )
-
-
-def _obs_in_axis(op_name: str, x, axes: Tuple[str, ...]) -> None:
-    """Telemetry note for one in-axis call (``torchmpi_tpu.obs``).
-    Trace-time only — jit replays never re-enter — and one branch per
-    call when obs is off (the module is never imported then).  Gates on
-    ``effective_config`` like every other trace-time hook (fusion,
-    ZeRO, ps): live config when initialized, defaults (off) otherwise."""
-    if runtime.effective_config().obs != "off":
-        from . import obs
-
-        obs.record_in_axis(op_name, selector.nbytes_of(x), axes)
-
-
 def _in_axis(op_name: str, x, axes: Tuple[str, ...],
              backend: Optional[str], params: dict):
-    """Shared dispatch for the nine in-axis verbs: replay a cached
+    """Shared dispatch for the nine in-axis verbs: replay the cached
     :class:`~torchmpi_tpu.planner.CollectivePlan` (one table lookup —
     fusion bucketing, per-bucket/per-leaf backend choice, and obs
-    enablement all pre-resolved), or fall back to the legacy per-call
-    derivation for unplannable trees / a disabled planner."""
-    plan = planner.plan_in_axis(op_name, x, axes, backend, params)
-    if plan is not None:
-        return plan.replay(x)
-    _obs_in_axis(op_name, x, axes)
-    if op_name in fusion.ELEMENTWISE_OPS:
-        fused = fusion.maybe_fuse(op_name, x, axes, backend=backend,
-                                  **params)
-        if fused is not None:
-            return fused
-    elif op_name == "reduce_scatter":
-        fused = fusion.maybe_fuse_reduce_scatter(x, axes, backend=backend,
-                                                 **params)
-        if fused is not None:
-            return fused
-    return jax.tree.map(lambda v: _pick(op_name, v, backend, axes)(
-        v, axes, **params), x)
+    enablement all resolved at its build)."""
+    leaves, treedef = jax.tree.flatten(x)
+    if not leaves:
+        return x
+    if not all(hasattr(v, "shape") and hasattr(v, "dtype") for v in leaves):
+        # A Python scalar becomes the array lax.psum would make of it;
+        # the plan is keyed on avals.
+        x = jax.tree.unflatten(treedef, [jnp.asarray(v) for v in leaves])
+    return planner.plan_in_axis(op_name, x, axes, backend,
+                                params).replay(x)
 
 
 def allreduce_in_axis(x, axis_names: AxisNames, *, op: str = "sum",
@@ -464,17 +396,11 @@ _jit_cache: Dict[Any, Any] = planner._table  # alias: THE plan table
 # one costs Python-side work on EVERY eager dispatch).
 _sharding_cache: Dict[Mesh, NamedSharding] = planner._shardings
 
-# Executables of the pre-planner dispatch path (kept for
-# `planner.set_enabled(False)` — the --plan-compare bench baseline and
-# the bit-identity tests).
-_legacy_jit_cache: Dict[Any, Any] = {}
-
 
 def clear_cache() -> None:
-    """Drop every cached collective plan (and legacy executable) — the
-    single invalidation point (``planner.invalidate``)."""
+    """Drop every cached collective plan — the single invalidation
+    point (``planner.invalidate``)."""
     planner.invalidate()
-    _legacy_jit_cache.clear()
 
 
 def _rank_major_sharding(m: Mesh) -> NamedSharding:
@@ -578,47 +504,44 @@ def _place_rank_major(x, m: Mesh, sharding: Optional[NamedSharding] = None):
     return jax.device_put(x, sharding)
 
 
-def _obs_record_eager(cfg, op_name: str, x, m: Mesh, impl=None) -> None:
-    """Telemetry record for one eager dispatch (``torchmpi_tpu.obs``):
-    one branch on the off path, recorded BEFORE dispatch so a
-    collective the gang never completes is the last flight event.
-    ``impl=None`` means the staged-host path.  Per-rank size comes from
-    metadata — ``x[0]`` would enqueue a device slice on the hot path
-    purely to read shape/dtype."""
+def _obs_record_eager(cfg, op_name: str, x, m: Mesh) -> None:
+    """Telemetry record for one staged-host exchange of the async
+    worker (``torchmpi_tpu.obs``; the plans bind their own recorders at
+    build): one branch on the off path, recorded BEFORE the exchange so
+    a collective the gang never completes is the last flight event.
+    Per-rank size comes from metadata — ``x[0]`` would enqueue a device
+    slice purely to read shape/dtype."""
     if cfg is None or cfg.obs == "off":
         return
     from . import obs
 
-    backend = "host" if impl is None else selector.name_of(op_name, impl)
     obs.record_eager(op_name,
                      int(np.prod(x.shape[1:])) * x.dtype.itemsize,
-                     backend, m, dtype=x.dtype)
+                     "host", m, dtype=x.dtype)
 
 
-def _obs_record_eager_done(cfg, op_name: str, x, m: Mesh,
-                           impl=None) -> None:
+def _obs_record_eager_done(cfg, op_name: str, x, m: Mesh) -> None:
     """The matching completion edge (flight ring only): recorded AFTER
-    the dispatch/exchange returns, so ``obs_tool blame`` can tell
-    "launched and stuck" from "launched and done, next never
-    launched" (docs/OBSERVABILITY.md)."""
+    the exchange returns, so ``obs_tool blame`` can tell "launched and
+    stuck" from "launched and done, next never launched"
+    (docs/OBSERVABILITY.md)."""
     if cfg is None or cfg.obs == "off":
         return
     from . import obs
 
-    backend = "host" if impl is None else selector.name_of(op_name, impl)
     obs.record_eager_done(op_name,
                           int(np.prod(x.shape[1:])) * x.dtype.itemsize,
-                          backend, m)
+                          "host", m)
 
 
 def _staged_leaf(cfg, op_name: str, x, n: int, params: dict):
     """One leaf's host-staged exchange: the faults-instrumented (sites
-    ``host_staged.gather``/``scatter``) or plain host compute, shared by
-    the synchronous eager path and the async handle dispatch.  ``x`` may
-    be a device array (retries re-stage from it) or, on the async
-    worker, an already-staged host master wrapped in
-    :class:`_RestageView` so each fault-layer attempt still re-stages a
-    fresh writable copy."""
+    ``host_staged.gather``/``scatter``) or plain host compute, on the
+    async handle's worker (the synchronous staged plan binds the same
+    pieces at its build, ``_build_eager``).  ``x`` is the already-staged
+    host master, wrapped in :class:`_RestageView` when the fault layer
+    is armed so each of its attempts still re-stages a fresh writable
+    copy."""
     wire = cfg is not None and cfg.guard in ("wire", "full")
     wd = None
     wd_tok = -1
@@ -675,98 +598,196 @@ def _eager_collective(op_name: str, x, *, mesh: Optional[Mesh] = None,
     m, n = _mesh_and_n(mesh)
     x = jnp.asarray(x)
     _check_rank_axis(op_name, x.shape, n)
-    if planner.enabled():
-        # The steady-state hot path: one plan-table lookup, then the
-        # pre-bound replay (impl/executable/sharding/obs/faults all
-        # resolved at build — docs/PLANNER.md).
-        return planner.plan_for(op_name, x, m, n, backend, params).replay(x)
-    return _eager_collective_unplanned(op_name, x, m, n, backend=backend,
-                                       **params)
+    # One plan-table lookup, then the pre-bound replay (impl/executable/
+    # sharding/obs/faults all resolved at build — docs/PLANNER.md).
+    return plan_for(op_name, x, m, n, backend, params).replay(x)
 
 
-def _eager_collective_unplanned(op_name: str, x, m: Mesh, n: int, *,
-                                backend: Optional[str] = None, **params):
-    """The pre-planner dispatch path, preserved verbatim: every call
-    re-derives staged/auto/selector/obs decisions in sequence and only
-    the compiled executable is memoized.  Runs only under
-    ``planner.set_enabled(False)`` — the ``--plan-compare`` baseline
-    and the planned-vs-unplanned bit-identity tests."""
-    # ONE config read per dispatch (it feeds the staged check, the
-    # "auto" trigger, and _pick's cutover below — re-reading it three
-    # times was measurable Python overhead on the eager hot path).
+def _wd_wrap(replay: Callable, site: str, op: str,
+             nbytes: int) -> Callable:
+    """Bind the watchdog in-flight window around a BLOCKING replay (the
+    staged-host exchange): resolved once at plan build — the off path
+    never reaches here — so the armed replay pays one begin/end pair
+    and the deferred-raise boundary check, and the off replay pays
+    nothing at all (docs/WATCHDOG.md)."""
+    from . import watchdog
+
+    def wrapped(x):
+        watchdog.raise_pending()
+        tok = watchdog.begin(site, op=op, peer="gang", nbytes=nbytes)
+        try:
+            return replay(x)
+        finally:
+            watchdog.end(tok)
+
+    return wrapped
+
+
+def _wd_boundary(replay: Callable) -> Callable:
+    """Bind only the deferred-raise boundary into a NON-blocking replay
+    (the direct eager dispatch, which XLA enqueues asynchronously):
+    a stall a background thread is wedged in surfaces at the main
+    thread's next eager dispatch — the guard-style raise_pending
+    delivery point."""
+    from . import watchdog
+
+    def wrapped(x):
+        watchdog.raise_pending()
+        return replay(x)
+
+    return wrapped
+
+
+def plan_for(op: str, x, m: Mesh, n: int, backend: Optional[str],
+             params: dict) -> planner.CollectivePlan:
+    """Plan (or replay-hit) one eager rank-major collective dispatch.
+
+    ``x`` is the rank-major array (leading axis already validated),
+    ``params`` the op's static keyword arguments.  The returned plan's
+    ``replay(x)`` accepts any same-shape/dtype array.
+    """
+    key = ("eager", op, m, x.shape, x.dtype.name, backend,
+           tuple(sorted(params.items())), planner.epoch())
+    return planner.get_or_build(
+        key, lambda: _build_eager(key, op, x, m, n, backend, params))
+
+
+def _build_eager(key: tuple, op: str, x, m: Mesh, n: int,
+                 backend_arg: Optional[str],
+                 params: dict) -> planner.CollectivePlan:
     cfg = runtime.config() if runtime.is_initialized() else None
-    # Staged mode: devices -> host -> compute -> devices, the
-    # reference's staged data path.
-    if _staged_requested(cfg, backend):
-        _obs_record_eager(cfg, op_name, x, m)
-        out = _staged_leaf(cfg, op_name, x, n, params)
-        placed = _place_rank_major(np.ascontiguousarray(out), m)
-        _obs_record_eager_done(cfg, op_name, x, m)
-        return placed
-    # Online "auto" mode (config default, per-op table, or an explicit
-    # backend="auto"): resolve against the persistent tuning plan.  The
-    # first eager call of an uncached (op, size bucket, mesh, platform)
-    # key measures the registered candidates and persists the winner;
-    # every later call — this process or any future one — replays the
-    # plan (torchmpi_tpu/tuning/).  A degraded plan resolves to None and
-    # the static selector path below applies.
-    eff = backend
+    obs_on = cfg is not None and cfg.obs != "off"
+    nbytes = int(np.prod(x.shape[1:])) * x.dtype.itemsize
+    sharding = planner.rank_major_sharding(m)
+    pd = dict(params)
+
+    if _staged_requested(cfg, backend_arg):
+        # Host-staged mode (the reference's staged data path): the
+        # faults AND guard enablement are resolved HERE — the replay
+        # carries no Config.faults/Config.guard compare (injection/
+        # retry/verify decisions inside an armed layer remain
+        # per-attempt, as they must).
+        faults_on = cfg is not None and cfg.faults != "off"
+        wire_on = cfg is not None and cfg.guard in ("wire", "full")
+        wd_on = cfg is not None and cfg.watchdog != "off"
+        rec = None
+        done = None
+        if obs_on:
+            from . import obs
+
+            rec = obs.eager_recorder(op, nbytes, "host", m, x.dtype)
+            done = obs.eager_done_recorder(op, nbytes, "host", m)
+        if faults_on or wire_on:
+            from . import faults
+
+            def _replay(x, _faults=faults):
+                if rec is not None:
+                    rec()
+                out = _faults.staged_exchange(op, x, n, pd, _host_staged,
+                                              wire_guard=wire_on)
+                out = _place_rank_major(np.ascontiguousarray(out), m,
+                                        sharding)
+                if done is not None:
+                    done()
+                return out
+        else:
+
+            def _replay(x):
+                if rec is not None:
+                    rec()
+                out = _host_staged(op, np.asarray(x), n, **pd)
+                out = _place_rank_major(np.ascontiguousarray(out), m,
+                                        sharding)
+                if done is not None:
+                    done()
+                return out
+
+        if wd_on:
+            # Resolved HERE, at plan build (the one string compare):
+            # the off replay above carries zero watchdog branches.
+            _replay = _wd_wrap(_replay, "host_staged", op, nbytes)
+        return planner.CollectivePlan(
+            key, "eager-staged", op, backend="host", nbytes=nbytes,
+            staged=True, obs=obs_on, faults=faults_on, guard=wire_on,
+            watchdog=wd_on, topology=planner.topology_of(m), replay=_replay)
+
+    # Direct mode.  Resolve backend="auto" against the persistent tuning
+    # plan ONCE at build: the first uncached (op, size bucket, mesh,
+    # platform) key measures candidates and persists the winner; the
+    # plan then replays the measured decision with zero per-call lookups
+    # (torchmpi_tpu/tuning/).
+    eff = backend_arg
     if eff is None and cfg is not None:
-        eff, _ = _config_backend(op_name, cfg)
+        eff, _ = selector.config_backend(op, cfg)
+    resolved = backend_arg
     if eff == "auto":
         from . import tuning
 
-        resolved = tuning.resolve_eager(
-            op_name, selector.nbytes_of(x[0]), x.dtype, m,
-            lambda b: _eager_collective(op_name, x, mesh=m, backend=b,
-                                        **params))
-        if resolved is not None:
+        measured = tuning.resolve_eager(
+            op, nbytes, x.dtype, m,
+            lambda b: _eager_collective(op, x, mesh=m, backend=b, **pd))
+        if measured is not None:
             # A measured decision carries per-call-backend authority
             # (bypasses the size cutover; topology fallback still
             # applies in the selector).
-            backend = resolved
+            resolved = measured
     axes = m.axis_names
-    # Resolve the implementation *before* the cache lookup: the key must
-    # include the resolved impl, or runtime set_config() backend switches
-    # would silently reuse a stale executable.
-    impl = _pick(op_name, x[0], backend, axes, mesh=m, cfg=cfg)
-    _obs_record_eager(cfg, op_name, x, m, impl=impl)
-    key = (op_name, m, impl, x.shape, x.dtype.name,
-           tuple(sorted(params.items())))
-    entry = _legacy_jit_cache.get(key)
-    if entry is None:
+    aval = jax.ShapeDtypeStruct(x.shape[1:], x.dtype)
+    impl = selector.pick(op, aval, resolved, axes, mesh=m, cfg=cfg)
 
-        def body(xs):
-            y = impl(xs[0], axes, **params)
-            return y[None]
+    def body(xs):
+        return impl(xs[0], axes, **pd)[None]
 
-        lead = P(axes)
-        out_spec = lead
-        in_spec = lead
+    lead = P(axes)
+    # check_vma=False: the rank-major eager mode states its shardings
+    # fully explicitly, and custom (pallas) backends cannot express vma
+    # through pallas_call uniformly.
+    shmapped = shard_map(body, mesh=m, in_specs=(lead,), out_specs=lead,
+                         check_vma=False)
+    # Opt-in static analysis, once per plan (Config.analysis;
+    # docs/ANALYSIS.md).  An error-severity finding in "error" mode
+    # raises BEFORE the plan enters the table, so the next call
+    # re-checks — the retry contract the hook tests assert.
+    verdict = "off"
+    mode = getattr(cfg, "analysis", "off") if cfg is not None else "off"
+    if mode in ("warn", "error"):
+        from . import analysis
 
-        # check_vma=False: the rank-major eager mode states its shardings
-        # fully explicitly, and custom (pallas) backends cannot express vma
-        # through pallas_call uniformly.
-        shmapped = shard_map(body, mesh=m, in_specs=(in_spec,),
-                             out_specs=out_spec, check_vma=False)
-        # Opt-in static analysis, once per cache entry (Config.analysis;
-        # docs/ANALYSIS.md).  Trace-time only — the executable below is
-        # what every later call replays, so the steady state pays
-        # nothing; with the default "off" this branch never imports the
-        # analyzer at all.
-        mode = getattr(cfg, "analysis", "off") if cfg is not None else "off"
-        if mode in ("warn", "error"):
-            from . import analysis
+        findings = analysis.check_once(
+            f"eager {op}", shmapped,
+            jax.ShapeDtypeStruct(x.shape, x.dtype), mode=mode)
+        verdict = "clean" if not findings else f"findings:{len(findings)}"
+    fn = jax.jit(shmapped)
+    backend_name = selector.name_of(op, impl)
+    rec = None
+    done = None
+    if obs_on:
+        from . import obs
 
-            analysis.check_once(
-                f"eager {op_name}", shmapped,
-                jax.ShapeDtypeStruct(x.shape, x.dtype), mode=mode)
-        entry = (jax.jit(shmapped), _rank_major_sharding(m))
-        _legacy_jit_cache[key] = entry
-    fn, sharding = entry
-    out = fn(_place_rank_major(x, m, sharding))
-    _obs_record_eager_done(cfg, op_name, x, m, impl=impl)
-    return out
+        rec = obs.eager_recorder(op, nbytes, backend_name, m, x.dtype)
+        done = obs.eager_done_recorder(op, nbytes, backend_name, m)
+
+    def _replay(x):
+        if rec is not None:
+            rec()
+        out = fn(_place_rank_major(x, m, sharding))
+        if done is not None:
+            # The dispatch-returned edge (XLA enqueue is async; the
+            # blocking completion surface is AsyncHandle.wait /
+            # block_until_ready, which record their own events).
+            done()
+        return out
+
+    wd_on = cfg is not None and cfg.watchdog != "off"
+    if wd_on:
+        # The direct dispatch never blocks — bind only the
+        # deferred-raise boundary (one string compare at build; zero
+        # branches in the off replay).
+        _replay = _wd_boundary(_replay)
+    return planner.CollectivePlan(
+        key, "eager", op, backend=backend_name, nbytes=nbytes, obs=obs_on,
+        watchdog=wd_on, analysis=verdict, topology=planner.topology_of(m),
+        extra={"executable": fn}, replay=_replay)
 
 
 def allreduce(x, *, op: str = "sum", mesh: Optional[Mesh] = None,

@@ -93,7 +93,7 @@ def _record_source() -> str:
 # In-axis ops with elementwise, shape-preserving semantics: reducing (or
 # copying) a concatenated buffer is exactly the concatenation of the
 # per-leaf results, so coalescing is transparent.  reduce_scatter has
-# its own tile-interleaved path (:func:`maybe_fuse_reduce_scatter`);
+# its own tile-interleaved path (:func:`fused_reduce_scatter`);
 # gather/allgather/scatter/alltoall change shapes per-leaf and stay on
 # the tree.map path.
 ELEMENTWISE_OPS = ("allreduce", "reduce", "broadcast")
@@ -242,9 +242,8 @@ def _unpack_group(flat, g: _DtypeGroup, out_leaves: List) -> None:
 
 
 def fuse_tree(op_name: str, tree: PyTree, axes: Tuple[str, ...], *,
-              backend: Optional[str] = None, barrier: bool = False,
-              spec: Optional[FusedSpec] = None,
-              impls: Optional[Sequence] = None, **params) -> PyTree:
+              spec: FusedSpec, impls: Sequence, barrier: bool = False,
+              **params) -> PyTree:
     """One selector-routed collective per (dtype group x bucket).
 
     ``barrier=True`` chains each bucket's input on the previous bucket's
@@ -256,16 +255,12 @@ def fuse_tree(op_name: str, tree: PyTree, axes: Tuple[str, ...], *,
     XLA's all-reduce combiner, exactly as the old single-concat chain
     kept them.
 
-    ``impls`` is the planner's replay mode (torchmpi_tpu/planner.py):
-    one pre-picked implementation per bucket, in this function's
-    iteration order (group-major, then bucket order) — the per-bucket
-    ``_pick`` is then skipped entirely.
+    ``spec`` and ``impls`` are the plan's record
+    (torchmpi_tpu/planner.py): the layout, and one picked
+    implementation per bucket in this function's iteration order
+    (group-major, then bucket order).
     """
-    from .collectives import _pick  # lazy: collectives imports us
-
     leaves = jax.tree.leaves(tree)
-    if spec is None:
-        spec = FusedSpec(tree)
     out_leaves: List = [None] * spec.n_leaves
     prev = None
     links = 0
@@ -278,10 +273,8 @@ def fuse_tree(op_name: str, tree: PyTree, axes: Tuple[str, ...], *,
             if barrier and prev is not None:
                 part, _ = lax.optimization_barrier((part, prev))
                 links += 1
-            impl = (impls[launch] if impls is not None
-                    else _pick(op_name, part, backend, axes))
+            prev = impls[launch](part, axes, **params)
             launch += 1
-            prev = impl(part, axes, **params)
             parts.append(prev)
         gout = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
         _unpack_group(gout, g, out_leaves)
@@ -310,84 +303,27 @@ def fuse_tree(op_name: str, tree: PyTree, axes: Tuple[str, ...], *,
     return jax.tree.unflatten(spec.treedef, out_leaves)
 
 
-def _fusable_leaves(leaves: Sequence) -> bool:
-    return all(hasattr(l, "shape") and hasattr(l, "dtype") for l in leaves)
-
-
-def maybe_fuse(op_name: str, tree: PyTree, axes: Tuple[str, ...], *,
-               backend: Optional[str] = None, **params) -> Optional[PyTree]:
-    """Fuse an in-axis pytree collective, or return ``None`` for the
-    per-leaf path: fusion disabled (``config.fuse_max_bytes == 0``),
-    fewer than two array leaves, non-array leaves (python scalars), or
-    a bucketing that would not reduce the launch count anyway."""
-    max_bytes = runtime.effective_config().fuse_max_bytes
-    if max_bytes <= 0 or op_name not in ELEMENTWISE_OPS:
-        return None
-    leaves = jax.tree.leaves(tree)
-    if len(leaves) < 2 or not _fusable_leaves(leaves):
-        return None
-    spec = FusedSpec(tree, max_bytes=max_bytes)
-    if spec.n_launches >= spec.n_leaves:
-        return None  # pure overhead: as many launches as tree.map
-    return fuse_tree(op_name, tree, axes, backend=backend, spec=spec,
-                     **params)
-
-
 # ---------------------------------------------------------------------------
 # Fused reduce_scatter: tile-interleaved layout
 # ---------------------------------------------------------------------------
 
 
-def maybe_fuse_reduce_scatter(tree: PyTree, axes: Tuple[str, ...], *,
-                              backend: Optional[str] = None,
-                              op: str = "sum") -> Optional[PyTree]:
-    """Fused per-leaf-preserving reduce_scatter, or ``None`` for the
-    per-leaf path.
+def fused_reduce_scatter(tree: PyTree, axes: Tuple[str, ...], *,
+                         spec: FusedSpec, n: int, impls: Sequence,
+                         op: str = "sum") -> PyTree:
+    """Fused reduce_scatter that keeps each leaf's own tiling, one
+    collective per leaf bucket of ``spec``; ``impls`` holds the picked
+    implementation of each, in group-major leaf-bucket order, and ``n``
+    is the spanned axis-size product the tiling divides by.
 
     A scatter of a plain concat would hand device ``i`` one contiguous
     extent of the fused buffer — not each leaf's tile ``i``.  Instead
     each leaf is viewed as its ``n`` tiles (``leaf.reshape(n, -1)``)
     and the bucket concatenates ALONG the tile axis, so the scattered
     extent ``i`` is exactly ``[leaf0_tile_i | leaf1_tile_i | ...]`` —
-    bit-for-bit the per-leaf result, one collective per bucket.
-    Requires every leaf's leading dim divisible by the group size (the
-    same precondition the per-leaf tiled scatter imposes); trees that
-    do not satisfy it fall back per-leaf.
-    """
-    max_bytes = runtime.effective_config().fuse_max_bytes
-    if max_bytes <= 0:
-        return None
-    leaves = jax.tree.leaves(tree)
-    if len(leaves) < 2 or not _fusable_leaves(leaves):
-        return None
-    try:
-        n = 1
-        for a in axes:
-            n *= lax.axis_size(a)
-    except Exception:  # noqa: BLE001 — outside an axis binding: per-leaf
-        return None
-    if n <= 0 or any(l.ndim < 1 or l.shape[0] % n != 0 for l in leaves):
-        return None
-    spec = FusedSpec(tree, max_bytes=max_bytes)
-    n_launches = sum(len(g.leaf_buckets) for g in spec.groups)
-    if n_launches >= spec.n_leaves:
-        return None
-    return fused_reduce_scatter(tree, axes, spec=spec, n=n,
-                                backend=backend, op=op)
-
-
-def fused_reduce_scatter(tree: PyTree, axes: Tuple[str, ...], *,
-                         spec: FusedSpec, n: int,
-                         backend: Optional[str] = None,
-                         impls: Optional[Sequence] = None,
-                         op: str = "sum") -> PyTree:
-    """Execute the fused tile-interleaved reduce_scatter for a tree
-    whose layout decision (``spec``, and optionally the per-bucket
-    ``impls`` in group-major leaf-bucket order — the planner's replay
-    mode) was already taken; ``n`` is the spanned axis-size product the
-    tiling divides by."""
-    from .collectives import _pick  # lazy: collectives imports us
-
+    bit-for-bit the per-leaf result.  Requires every leaf's leading dim
+    divisible by ``n`` (the planner checks it and plans per leaf
+    otherwise)."""
     leaves = jax.tree.leaves(tree)
     out_leaves: List = [None] * spec.n_leaves
     launch = 0
@@ -397,10 +333,8 @@ def fused_reduce_scatter(tree: PyTree, axes: Tuple[str, ...], *,
                      for pos in bucket]
             flat = (tiles[0] if len(tiles) == 1
                     else jnp.concatenate(tiles, axis=1)).reshape(-1)
-            impl = (impls[launch] if impls is not None
-                    else _pick("reduce_scatter", flat, backend, axes))
+            shard = impls[launch](flat, axes, op=op)
             launch += 1
-            shard = impl(flat, axes, op=op)
             off = 0
             for pos in bucket:
                 i, shape = g.indices[pos], g.shapes[pos]

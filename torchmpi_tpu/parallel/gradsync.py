@@ -231,13 +231,9 @@ def _bucketed_allreduce(grads: PyTree, axes: Tuple[str, ...], *, op: str,
     """
     if not jax.tree.leaves(grads):
         return grads
-    plan = planner.plan_gradsync(grads, axes, op=op, n_buckets=n_buckets,
-                                 backend=backend, barrier=barrier)
-    if plan is not None:
-        return plan.replay(grads)
-    spec = fusion.FusedSpec(grads, n_buckets=n_buckets)
-    return fusion.fuse_tree("allreduce", grads, axes, backend=backend,
-                            barrier=barrier, spec=spec, op=op)
+    return planner.plan_gradsync(grads, axes, op=op, n_buckets=n_buckets,
+                                 backend=backend,
+                                 barrier=barrier).replay(grads)
 
 
 def synchronize_gradients(grads: PyTree, axis_names: Optional[AxisNames] = None,
@@ -452,9 +448,8 @@ def assign_overlap_buckets(leaves: Sequence, max_bytes: int
 
 
 def _make_bucket_sync(idx: int, total: int, axes: Tuple[str, ...],
-                      op: str, backend: Optional[str],
-                      compress: Optional[str],
-                      impl: Optional[Callable] = None,
+                      op: str, compress: Optional[str],
+                      impl: Optional[Callable],
                       dcn_codec: Optional[str] = None):
     """One bucket's sync op: identity in forward, THE bucket's
     allreduce in backward.  ``token`` threads the optimization-barrier
@@ -463,8 +458,8 @@ def _make_bucket_sync(idx: int, total: int, axes: Tuple[str, ...],
     and derives its outgoing token from the allreduce result — so the
     collectives stay distinct through the combiner and issue in firing
     order, each eligible the moment its cotangents exist.  ``impl`` is
-    the planner's pre-picked allreduce implementation for this bucket
-    (None falls back to a per-trace selector pick).
+    the plan's picked allreduce implementation for this bucket (None
+    with ``dcn_codec``, whose two-level schedule is fixed).
 
     ``dcn_codec`` switches the backward rule to the error-feedback
     two-level allreduce (``compress.ef_bucket_allreduce``): the sync
@@ -564,11 +559,7 @@ def _make_bucket_sync(idx: int, total: int, axes: Tuple[str, ...],
         orig_dtype = flat.dtype
         if compress == "bf16":
             flat = flat.astype(jnp.bfloat16)
-        bucket_impl = impl
-        if bucket_impl is None:
-            bucket_impl = collectives._pick(  # noqa: SLF001 — shared route
-                "allreduce", flat, backend, axes)
-        red = bucket_impl(flat, axes, op=op)
+        red = impl(flat, axes, op=op)
         if compress == "bf16":
             red = red.astype(orig_dtype)
         if runtime.effective_config().guard in ("numeric", "full"):
@@ -716,18 +707,14 @@ def make_overlapped_grad_fn(loss_fn: Callable, params_template: PyTree,
     # (torchmpi_tpu/planner.py — a decision-only plan).  The EF path
     # uses the firing assignment only: its collective is the fixed
     # two-level schedule, not a selector pick.
-    oplan = planner.plan_overlap(template_leaves, axes, op=op,
+    oplan = planner.plan_overlap(template_leaves, axes,
+                                 assign_overlap_buckets, op=op,
                                  backend=backend, compress=compress,
                                  max_bytes=max_bytes, dcn_codec=codec)
-    if oplan is not None:
-        firing = oplan.extra["firing"]
-        bucket_impls: Sequence[Optional[Callable]] = oplan.impls
-    else:
-        firing = assign_overlap_buckets(template_leaves, max_bytes)
-        bucket_impls = [None] * len(firing)
+    firing = oplan.extra["firing"]
     total = len(firing)
-    syncs = [_make_bucket_sync(k, total, axes, op, backend, compress,
-                               impl=bucket_impls[k], dcn_codec=codec)
+    syncs = [_make_bucket_sync(k, total, axes, op, compress,
+                               impl=oplan.impls[k], dcn_codec=codec)
              for k in range(total)]
     if cfg is not None and cfg.obs != "off":
         from .. import obs

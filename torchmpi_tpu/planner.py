@@ -27,10 +27,16 @@ This module lifts the full decision record into an explicit, immutable
 - **replay** — the minimal residual work: one table lookup, then the
   pre-bound closure.
 
-Consumers: ``collectives._eager_collective`` and the nine ``*_in_axis``
-verbs (hence ``async_`` / ``async_in_axis`` on top of them),
-``gradsync.synchronize_gradients`` / ``make_overlapped_grad_fn``, and
-the ZeRO flatten/reduce-scatter leg.  Invalidation has ONE point:
+This module holds the table, the record, and the builders that need
+only ``fusion`` and ``selector`` below them: the nine ``*_in_axis``
+verbs (hence ``async_in_axis`` on top of them), the bucketed
+``gradsync.synchronize_gradients``, the decision record of
+``make_overlapped_grad_fn`` (which hands in its own bucket
+assignment), the ZeRO flatten/reduce-scatter leg, and the serving
+replica rows.  The eager rank-major plan (``collectives.plan_for``) is
+built in ``collectives``, beside the staging and placement code it
+binds, through :func:`get_or_build`.  Nothing here imports
+``collectives`` or ``parallel``.  Invalidation has ONE point:
 :func:`invalidate` (``collectives.clear_cache`` and ``runtime.stop``
 route here; ``set_config`` bumps the epoch *and* routes here) — the
 seam serving, elasticity, and cross-slice topology (ROADMAP items 2-4)
@@ -45,14 +51,14 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
-from jax import lax, shard_map
+from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import fusion, runtime, selector
 
 # ---------------------------------------------------------------------------
-# The plan table: the ONE cache behind the dispatch path (it subsumes
-# the old ad-hoc collectives._jit_cache / _sharding_cache pair).  Reads
+# The plan table: the ONE cache behind the dispatch path
+# (collectives._jit_cache / _sharding_cache are aliases of it).  Reads
 # are lock-free dict gets (GIL-atomic); builds run under an RLock —
 # re-entrant because building an eager backend="auto" plan measures
 # candidates by dispatching them, which plans recursively.
@@ -61,7 +67,6 @@ from . import fusion, runtime, selector
 _lock = threading.RLock()
 _table: Dict[tuple, "CollectivePlan"] = {}
 _shardings: Dict[Mesh, NamedSharding] = {}
-_enabled = True
 _stats = {"hits": 0, "misses": 0, "invalidations": 0}
 
 
@@ -154,20 +159,6 @@ class CollectivePlan:
         }
 
 
-def enabled() -> bool:
-    return _enabled
-
-
-def set_enabled(flag: bool) -> bool:
-    """Switch the planner off (the pre-planner dispatch path runs
-    instead) or back on.  Exists for the ``--plan-compare`` bench mode
-    and the bit-identity tests; production code leaves it on.  Returns
-    the previous value."""
-    global _enabled
-    prev, _enabled = _enabled, bool(flag)
-    return prev
-
-
 def invalidate() -> None:
     """THE invalidation point: drop every plan and cached sharding.
 
@@ -181,15 +172,6 @@ def invalidate() -> None:
         _table.clear()
         _shardings.clear()
         _stats["invalidations"] += 1
-    # The preserved pre-planner executables (collectives._legacy_jit_cache)
-    # pin compiled programs + mesh references too; a lifecycle caller
-    # invoking invalidate() directly (docs/PLANNER.md) must drop them as
-    # well.  sys.modules lookup, not an import: no cycle with collectives.
-    import sys
-
-    mod = sys.modules.get(__package__ + ".collectives")
-    if mod is not None:
-        mod._legacy_jit_cache.clear()
 
 
 def stats() -> dict:
@@ -234,8 +216,8 @@ def _lookup(key: tuple) -> Optional[CollectivePlan]:
     return plan
 
 
-def _get_or_build(key: tuple, builder: Callable[[], CollectivePlan]
-                  ) -> CollectivePlan:
+def get_or_build(key: tuple, builder: Callable[[], CollectivePlan]
+                 ) -> CollectivePlan:
     """Lock-free hit, else build-and-insert under the planner lock.
 
     Builds are deliberately serialized (one at a time, lock held across
@@ -266,11 +248,10 @@ def _get_or_build(key: tuple, builder: Callable[[], CollectivePlan]
     return plan
 
 
-def _epoch() -> tuple:
+def epoch() -> tuple:
     """The staleness component of every plan key: the config epoch
     (init/set_config/stop bumps) plus the selector registry generation
-    (a runtime re-register strands plans that resolved the old impl —
-    the planner analog of the legacy cache keying on the impl object)."""
+    (a runtime re-register strands plans that resolved the old impl)."""
     return (runtime.config_epoch(), selector.generation())
 
 
@@ -278,21 +259,10 @@ def _cfg():
     return runtime.config() if runtime.is_initialized() else None
 
 
-def _avals(leaves) -> Optional[tuple]:
-    """Hashable (shape, dtype) signature of a leaf list; None when some
-    leaf is not array-like (python scalars) — unplannable, the caller
-    falls back to the legacy path."""
-    out = []
-    for leaf in leaves:
-        shape = getattr(leaf, "shape", None)
-        dtype = getattr(leaf, "dtype", None)
-        if shape is None or dtype is None:
-            return None
-        try:
-            out.append((tuple(int(d) for d in shape), np.dtype(dtype).name))
-        except (TypeError, ValueError):
-            return None  # polymorphic/abstract dims
-    return tuple(out)
+def _avals(leaves) -> tuple:
+    """Hashable (shape, dtype) signature of a list of array leaves."""
+    return tuple((tuple(int(d) for d in leaf.shape),
+                  np.dtype(leaf.dtype).name) for leaf in leaves)
 
 
 def topology_of(mesh=None, sizes=None) -> str:
@@ -352,228 +322,23 @@ def _in_axis_recorder(cfg, op: str, nbytes: int, axes) -> Optional[Callable]:
 
 
 # ---------------------------------------------------------------------------
-# Eager rank-major plans (collectives._eager_collective)
-# ---------------------------------------------------------------------------
-
-
-def _wd_wrap(replay: Callable, site: str, op: str,
-             nbytes: int) -> Callable:
-    """Bind the watchdog in-flight window around a BLOCKING replay (the
-    staged-host exchange): resolved once at plan build — the off path
-    never reaches here — so the armed replay pays one begin/end pair
-    and the deferred-raise boundary check, and the off replay pays
-    nothing at all (docs/WATCHDOG.md)."""
-    from . import watchdog
-
-    def wrapped(x):
-        watchdog.raise_pending()
-        tok = watchdog.begin(site, op=op, peer="gang", nbytes=nbytes)
-        try:
-            return replay(x)
-        finally:
-            watchdog.end(tok)
-
-    return wrapped
-
-
-def _wd_boundary(replay: Callable) -> Callable:
-    """Bind only the deferred-raise boundary into a NON-blocking replay
-    (the direct eager dispatch, which XLA enqueues asynchronously):
-    a stall a background thread is wedged in surfaces at the main
-    thread's next eager dispatch — the guard-style raise_pending
-    delivery point."""
-    from . import watchdog
-
-    def wrapped(x):
-        watchdog.raise_pending()
-        return replay(x)
-
-    return wrapped
-
-
-def plan_for(op: str, x, m: Mesh, n: int, backend: Optional[str],
-             params: dict) -> CollectivePlan:
-    """Plan (or replay-hit) one eager rank-major collective dispatch.
-
-    ``x`` is the rank-major array (leading axis already validated),
-    ``params`` the op's static keyword arguments.  The returned plan's
-    ``replay(x)`` accepts any same-shape/dtype array.
-    """
-    key = ("eager", op, m, x.shape, x.dtype.name, backend,
-           tuple(sorted(params.items())), _epoch())
-    return _get_or_build(
-        key, lambda: _build_eager(key, op, x, m, n, backend, params))
-
-
-def _build_eager(key: tuple, op: str, x, m: Mesh, n: int,
-                 backend_arg: Optional[str], params: dict) -> CollectivePlan:
-    from . import collectives as C
-
-    cfg = _cfg()
-    obs_on = cfg is not None and cfg.obs != "off"
-    nbytes = int(np.prod(x.shape[1:])) * x.dtype.itemsize
-    sharding = rank_major_sharding(m)
-    pd = dict(params)
-
-    if C._staged_requested(cfg, backend_arg):
-        # Host-staged mode (the reference's staged data path): the
-        # faults AND guard enablement are resolved HERE — the replay
-        # carries no Config.faults/Config.guard compare (injection/
-        # retry/verify decisions inside an armed layer remain
-        # per-attempt, as they must).
-        faults_on = cfg is not None and cfg.faults != "off"
-        wire_on = cfg is not None and cfg.guard in ("wire", "full")
-        wd_on = cfg is not None and cfg.watchdog != "off"
-        rec = None
-        done = None
-        if obs_on:
-            from . import obs
-
-            rec = obs.eager_recorder(op, nbytes, "host", m, x.dtype)
-            done = obs.eager_done_recorder(op, nbytes, "host", m)
-        if faults_on or wire_on:
-            from . import faults
-
-            def _replay(x, _faults=faults):
-                if rec is not None:
-                    rec()
-                out = _faults.staged_exchange(op, x, n, pd, C._host_staged,
-                                              wire_guard=wire_on)
-                out = C._place_rank_major(np.ascontiguousarray(out), m,
-                                          sharding)
-                if done is not None:
-                    done()
-                return out
-        else:
-
-            def _replay(x):
-                if rec is not None:
-                    rec()
-                out = C._host_staged(op, np.asarray(x), n, **pd)
-                out = C._place_rank_major(np.ascontiguousarray(out), m,
-                                          sharding)
-                if done is not None:
-                    done()
-                return out
-
-        if wd_on:
-            # Resolved HERE, at plan build (the one string compare):
-            # the off replay above carries zero watchdog branches.
-            _replay = _wd_wrap(_replay, "host_staged", op, nbytes)
-        return CollectivePlan(key, "eager-staged", op, backend="host",
-                              nbytes=nbytes, staged=True, obs=obs_on,
-                              faults=faults_on, guard=wire_on,
-                              watchdog=wd_on,
-                              topology=topology_of(m),
-                              replay=_replay)
-
-    # Direct mode.  Resolve backend="auto" against the persistent tuning
-    # plan ONCE at build: the first uncached (op, size bucket, mesh,
-    # platform) key measures candidates and persists the winner; the
-    # plan then replays the measured decision with zero per-call lookups
-    # (torchmpi_tpu/tuning/ — the per-call fingerprint/DB consults the
-    # pre-planner path paid on EVERY dispatch).
-    eff = backend_arg
-    if eff is None and cfg is not None:
-        eff, _ = C._config_backend(op, cfg)
-    resolved = backend_arg
-    if eff == "auto":
-        from . import tuning
-
-        measured = tuning.resolve_eager(
-            op, nbytes, x.dtype, m,
-            lambda b: C._eager_collective(op, x, mesh=m, backend=b, **pd))
-        if measured is not None:
-            # A measured decision carries per-call-backend authority
-            # (bypasses the size cutover; topology fallback still
-            # applies in the selector).
-            resolved = measured
-    axes = m.axis_names
-    aval = jax.ShapeDtypeStruct(x.shape[1:], x.dtype)
-    impl = C._pick(op, aval, resolved, axes, mesh=m, cfg=cfg)
-
-    def body(xs):
-        return impl(xs[0], axes, **pd)[None]
-
-    lead = P(axes)
-    # check_vma=False: the rank-major eager mode states its shardings
-    # fully explicitly, and custom (pallas) backends cannot express vma
-    # through pallas_call uniformly.
-    shmapped = shard_map(body, mesh=m, in_specs=(lead,), out_specs=lead,
-                         check_vma=False)
-    # Opt-in static analysis, once per plan (Config.analysis;
-    # docs/ANALYSIS.md).  An error-severity finding in "error" mode
-    # raises BEFORE the plan enters the table, so the next call
-    # re-checks — the retry contract the hook tests assert.
-    verdict = "off"
-    mode = getattr(cfg, "analysis", "off") if cfg is not None else "off"
-    if mode in ("warn", "error"):
-        from . import analysis
-
-        findings = analysis.check_once(
-            f"eager {op}", shmapped,
-            jax.ShapeDtypeStruct(x.shape, x.dtype), mode=mode)
-        verdict = "clean" if not findings else f"findings:{len(findings)}"
-    fn = jax.jit(shmapped)
-    backend_name = selector.name_of(op, impl)
-    rec = None
-    done = None
-    if obs_on:
-        from . import obs
-
-        rec = obs.eager_recorder(op, nbytes, backend_name, m, x.dtype)
-        done = obs.eager_done_recorder(op, nbytes, backend_name, m)
-
-    def _replay(x):
-        if rec is not None:
-            rec()
-        out = fn(C._place_rank_major(x, m, sharding))
-        if done is not None:
-            # The dispatch-returned edge (XLA enqueue is async; the
-            # blocking completion surface is AsyncHandle.wait /
-            # block_until_ready, which record their own events).
-            done()
-        return out
-
-    wd_on = cfg is not None and cfg.watchdog != "off"
-    if wd_on:
-        # The direct dispatch never blocks — bind only the
-        # deferred-raise boundary (one string compare at build; zero
-        # branches in the off replay).
-        _replay = _wd_boundary(_replay)
-    return CollectivePlan(key, "eager", op, backend=backend_name,
-                          nbytes=nbytes, obs=obs_on, watchdog=wd_on,
-                          analysis=verdict,
-                          topology=topology_of(m),
-                          extra={"executable": fn}, replay=_replay)
-
-
-# ---------------------------------------------------------------------------
 # In-axis plans (the nine *_in_axis verbs; async_in_axis rides them)
 # ---------------------------------------------------------------------------
 
 
 def plan_in_axis(op: str, tree, axes: Tuple[str, ...],
-                 backend: Optional[str],
-                 params: dict) -> Optional[CollectivePlan]:
-    """Plan (or replay-hit) one in-axis pytree collective, or None for
-    an unplannable tree (non-array leaves) / a disabled planner —
-    the verb then runs its legacy per-call derivation.
+                 backend: Optional[str], params: dict) -> CollectivePlan:
+    """Plan (or replay-hit) one in-axis pytree collective over a
+    non-empty tree of array leaves.
 
     Called at trace time; the plan replays across retraces, re-jits,
     and repeated step builds of the same tree structure."""
-    if not _enabled:
-        return None
     leaves, treedef = jax.tree.flatten(tree)
-    if not leaves:
-        return None
     avals = _avals(leaves)
-    if avals is None:
-        return None
     mesh = runtime.current_mesh() if runtime.is_initialized() else None
     key = ("in_axis", op, treedef, avals, axes, _axis_sizes(axes), backend,
-           tuple(sorted(params.items())), mesh, _epoch())
-    return _get_or_build(
+           tuple(sorted(params.items())), mesh, epoch())
+    return get_or_build(
         key, lambda: _build_in_axis(key, op, tree, leaves, treedef, avals,
                                     axes, backend, params, mesh))
 
@@ -582,11 +347,9 @@ def _bucket_impls(op: str, spec: fusion.FusedSpec, backend, axes, mesh,
                   cfg) -> List[Callable]:
     """The selector/tuning backend choice per fused bucket, resolved
     from each bucket's true nbytes (iteration order == fuse_tree's)."""
-    from . import collectives as C
-
     return [
-        C._pick(op, jax.ShapeDtypeStruct((hi - lo,), g.dtype), backend,
-                axes, mesh=mesh, cfg=cfg)
+        selector.pick(op, jax.ShapeDtypeStruct((hi - lo,), g.dtype),
+                      backend, axes, mesh=mesh, cfg=cfg)
         for g in spec.groups for (lo, hi) in g.bounds
     ]
 
@@ -610,8 +373,6 @@ def _resolved_backend(op: str, backend: Optional[str],
 def _build_in_axis(key: tuple, op: str, tree, leaves, treedef, avals,
                    axes: Tuple[str, ...], backend: Optional[str],
                    params: dict, mesh) -> CollectivePlan:
-    from . import collectives as C
-
     cfg = _cfg()
     eff = runtime.effective_config()
     obs_on = eff.obs != "off"
@@ -620,8 +381,9 @@ def _build_in_axis(key: tuple, op: str, tree, leaves, treedef, avals,
     pd = dict(params)
     max_bytes = eff.fuse_max_bytes
 
-    # Fused elementwise (allreduce/reduce/broadcast): the maybe_fuse
-    # decision, taken once.
+    # Fused elementwise (allreduce/reduce/broadcast), unless fusion is
+    # off (fuse_max_bytes == 0), the tree has one leaf, or the buckets
+    # would be as many launches as the leaves.
     if (op in fusion.ELEMENTWISE_OPS and max_bytes > 0 and len(leaves) >= 2):
         spec = fusion.FusedSpec(tree, max_bytes=max_bytes)
         if spec.n_launches < spec.n_leaves:
@@ -630,8 +392,8 @@ def _build_in_axis(key: tuple, op: str, tree, leaves, treedef, avals,
             def _replay(tree):
                 if rec is not None:
                     rec()
-                return fusion.fuse_tree(op, tree, axes, backend=backend,
-                                        spec=spec, impls=impls, **pd)
+                return fusion.fuse_tree(op, tree, axes, spec=spec,
+                                        impls=impls, **pd)
 
             return CollectivePlan(key, "in_axis-fused", op,
                                   backend=_resolved_backend(
@@ -643,7 +405,7 @@ def _build_in_axis(key: tuple, op: str, tree, leaves, treedef, avals,
                                   replay=_replay)
 
     # Fused reduce_scatter: tile-interleaved layout, leaf-granularity
-    # buckets (the maybe_fuse_reduce_scatter decision, taken once).
+    # buckets, where every leaf's leading dim divides by the span.
     if op == "reduce_scatter" and max_bytes > 0 and len(leaves) >= 2:
         sizes = _axis_sizes(axes)
         n = int(np.prod(sizes)) if sizes else 0
@@ -653,11 +415,12 @@ def _build_in_axis(key: tuple, op: str, tree, leaves, treedef, avals,
             n_launches = sum(len(g.leaf_buckets) for g in spec.groups)
             if n_launches < spec.n_leaves:
                 impls = [
-                    C._pick("reduce_scatter",
-                            jax.ShapeDtypeStruct(
-                                (sum(g.sizes[pos] for pos in bucket),),
-                                g.dtype),
-                            backend, axes, mesh=mesh, cfg=cfg)
+                    selector.pick(
+                        "reduce_scatter",
+                        jax.ShapeDtypeStruct(
+                            (sum(g.sizes[pos] for pos in bucket),),
+                            g.dtype),
+                        backend, axes, mesh=mesh, cfg=cfg)
                     for g in spec.groups for bucket in g.leaf_buckets
                 ]
 
@@ -676,11 +439,10 @@ def _build_in_axis(key: tuple, op: str, tree, leaves, treedef, avals,
                                           mesh, _topo_sizes(mesh, axes)),
                                       replay=_replay)
 
-    # Per-leaf: one pre-picked implementation per leaf (the tree.map
-    # path, minus the per-call config/selector/nbytes work).
+    # Per-leaf: one picked implementation per leaf.
     impls = [
-        C._pick(op, jax.ShapeDtypeStruct(s, d), backend, axes, mesh=mesh,
-                cfg=cfg)
+        selector.pick(op, jax.ShapeDtypeStruct(s, d), backend, axes,
+                      mesh=mesh, cfg=cfg)
         for s, d in avals
     ]
 
@@ -706,21 +468,15 @@ def _build_in_axis(key: tuple, op: str, tree, leaves, treedef, avals,
 
 def plan_gradsync(grads, axes: Tuple[str, ...], *, op: str, n_buckets: int,
                   backend: Optional[str],
-                  barrier: bool) -> Optional[CollectivePlan]:
-    """Plan the bucketed gradient allreduce: FusedSpec with the
-    count-driven (``gradsync_buckets``) bucketing plus per-bucket
-    backend choices, replayed across step builds."""
-    if not _enabled:
-        return None
+                  barrier: bool) -> CollectivePlan:
+    """Plan the bucketed gradient allreduce of a non-empty tree:
+    FusedSpec with the count-driven (``gradsync_buckets``) bucketing
+    plus per-bucket backend choices, replayed across step builds."""
     leaves, treedef = jax.tree.flatten(grads)
-    if not leaves:
-        return None
     avals = _avals(leaves)
-    if avals is None:
-        return None
     mesh = runtime.current_mesh() if runtime.is_initialized() else None
     key = ("gradsync", treedef, avals, axes, _axis_sizes(axes), op,
-           int(n_buckets), backend, bool(barrier), mesh, _epoch())
+           int(n_buckets), backend, bool(barrier), mesh, epoch())
 
     def build():
         cfg = _cfg()
@@ -731,9 +487,8 @@ def plan_gradsync(grads, axes: Tuple[str, ...], *, op: str, n_buckets: int,
                      for s, d in avals)
 
         def _replay(tree):
-            return fusion.fuse_tree("allreduce", tree, axes,
-                                    backend=backend, barrier=barrier,
-                                    spec=spec, impls=impls, op=op)
+            return fusion.fuse_tree("allreduce", tree, axes, spec=spec,
+                                    impls=impls, barrier=barrier, op=op)
 
         return CollectivePlan(key, "gradsync", "allreduce",
                               backend=backend or "", nbytes=nbytes,
@@ -742,37 +497,33 @@ def plan_gradsync(grads, axes: Tuple[str, ...], *, op: str, n_buckets: int,
                                                    _topo_sizes(mesh, axes)),
                               obs=eff.obs != "off", replay=_replay)
 
-    return _get_or_build(key, build)
+    return get_or_build(key, build)
 
 
-def plan_overlap(template_leaves, axes: Tuple[str, ...], *, op: str,
+def plan_overlap(template_leaves, axes: Tuple[str, ...],
+                 assign: Callable[[list, int], List[List[int]]], *, op: str,
                  backend: Optional[str], compress: Optional[str],
                  max_bytes: int,
-                 dcn_codec: Optional[str] = None) -> Optional[CollectivePlan]:
-    """Decision-only plan for the backprop-overlap schedule: the
-    reverse-order bucket assignment (``extra["firing"]``) and each
-    bucket's pre-picked allreduce implementation (``impls``, indexed in
-    firing order).  ``gradsync.make_overlapped_grad_fn`` consumes both
+                 dcn_codec: Optional[str] = None) -> CollectivePlan:
+    """Decision-only plan for the backprop-overlap schedule: the bucket
+    assignment ``assign(template_leaves, max_bytes)`` makes
+    (``extra["firing"]``; the schedule's owner,
+    ``gradsync.make_overlapped_grad_fn``, hands in its reverse-order
+    rule) and each bucket's pre-picked allreduce implementation
+    (``impls``, indexed in firing order).  The caller consumes both
     when building its custom_vjp chain.  With ``dcn_codec`` (the
     error-feedback path) the buckets dispatch the FIXED two-level
     schedule — no selector picks are made and the plan row reports the
     codec, not a backend that never runs."""
-    if not _enabled:
-        return None
     avals = _avals(template_leaves)
-    if avals is None:
-        return None
     mesh = runtime.current_mesh() if runtime.is_initialized() else None
     key = ("overlap", avals, axes, op, backend, compress, int(max_bytes),
-           dcn_codec, mesh, _epoch())
+           dcn_codec, mesh, epoch())
 
     def build():
-        from . import collectives as C
-        from .parallel import gradsync
-
         cfg = _cfg()
         eff = runtime.effective_config()
-        firing = gradsync.assign_overlap_buckets(template_leaves, max_bytes)
+        firing = assign(template_leaves, max_bytes)
         if dcn_codec is not None:
             impls = [None] * len(firing)
             label = f"dcn-{dcn_codec}"
@@ -782,7 +533,7 @@ def plan_overlap(template_leaves, axes: Tuple[str, ...], *, op: str,
                 total = sum(int(np.prod(avals[i][0])) for i in bucket)
                 wire_dt = (np.dtype("bfloat16") if compress == "bf16"
                            else np.dtype(avals[bucket[0]][1]))
-                impls.append(C._pick(
+                impls.append(selector.pick(
                     "allreduce", jax.ShapeDtypeStruct((total,), wire_dt),
                     backend, axes, mesh=mesh, cfg=cfg))
             label = backend or ""
@@ -796,7 +547,7 @@ def plan_overlap(template_leaves, axes: Tuple[str, ...], *, op: str,
                               extra={"firing": firing,
                                      "max_bytes": int(max_bytes)})
 
-    return _get_or_build(key, build)
+    return get_or_build(key, build)
 
 
 # ---------------------------------------------------------------------------
@@ -810,12 +561,8 @@ def flat_spec_for(tree, n_shards: int) -> fusion.FusedSpec:
     ZeRO update legs and ``zero.flat_spec`` used to rebuild on every
     trace.  Config-independent (no epoch in the key): the layout is a
     pure function of the avals and the shard count."""
-    if not _enabled:
-        return fusion.FusedSpec(tree, int(n_shards))
     leaves, treedef = jax.tree.flatten(tree)
     avals = _avals(leaves)
-    if avals is None:
-        return fusion.FusedSpec(tree, int(n_shards))
     key = ("flatspec", treedef, avals, int(n_shards))
 
     def build():
@@ -828,7 +575,7 @@ def flat_spec_for(tree, n_shards: int) -> fusion.FusedSpec:
                               obs=eff.obs != "off",
                               extra={"n_shards": int(n_shards)})
 
-    return _get_or_build(key, build).spec
+    return get_or_build(key, build).spec
 
 
 # ---------------------------------------------------------------------------
@@ -837,8 +584,7 @@ def flat_spec_for(tree, n_shards: int) -> fusion.FusedSpec:
 
 
 def plan_serving_replica(replica: str, mesh, axes: Tuple[str, ...],
-                         *, op: str = "tp_decode"
-                         ) -> Optional[CollectivePlan]:
+                         *, op: str = "tp_decode") -> CollectivePlan:
     """Decision-only plan row for one mesh-parallel serving replica:
     keyed per replica MESH via the topology fingerprint, so two
     replicas carved from different device slices — or the same replica
@@ -848,9 +594,7 @@ def plan_serving_replica(replica: str, mesh, axes: Tuple[str, ...],
     (``shard_map`` over ``axes``); the engine's compiled executables
     key on the same (mesh, axis) tuple, so plan row and executable can
     never describe different topologies."""
-    if not _enabled:
-        return None
-    key = ("serving", replica, mesh, tuple(axes), op, _epoch())
+    key = ("serving", replica, mesh, tuple(axes), op, epoch())
 
     def build():
         eff = runtime.effective_config()
@@ -865,4 +609,4 @@ def plan_serving_replica(replica: str, mesh, axes: Tuple[str, ...],
             extra={"replica": replica, "axes": tuple(axes),
                    "devices": int(np.prod(mesh.devices.shape))})
 
-    return _get_or_build(key, build)
+    return get_or_build(key, build)

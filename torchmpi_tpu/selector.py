@@ -33,6 +33,8 @@ from typing import Callable, Dict, Optional, Tuple
 
 import numpy as np
 
+from . import runtime
+
 # op name -> backend name -> implementation fn.  Implementation signature is
 # op-specific; see collectives.py _IN_AXIS_OPS.
 _REGISTRY: Dict[str, Dict[str, Callable]] = {}
@@ -40,7 +42,7 @@ _REGISTRY: Dict[str, Dict[str, Callable]] = {}
 # Bumped by every register(): part of each CollectivePlan key
 # (torchmpi_tpu/planner.py), so re-registering an implementation at
 # runtime strands the plans that resolved the old one — the planner's
-# analog of the legacy jit-cache keying on the resolved impl object.
+# analog of a cache keyed on the resolved impl object.
 _generation = 0
 
 
@@ -150,6 +152,59 @@ def select(
     return impls[name]
 
 
+def config_backend(op: str, cfg) -> Tuple[str, bool]:
+    """Resolve the config-level backend for ``op``: per-op table first
+    (a deliberate choice, carrying explicit/per-call authority), then
+    the hierarchical flag, then the config default.  The ONE home of
+    this precedence — shared by :func:`pick` and the eager "auto"
+    trigger so they can never drift apart."""
+    if cfg.backend_per_op:
+        b = cfg.backend_per_op.get(op)
+        if b is not None:
+            return b, True
+    return ("hierarchical" if cfg.hierarchical else cfg.backend), False
+
+
+def pick(op: str, x, backend: Optional[str], axes: Tuple[str, ...],
+         mesh=None, cfg=None) -> Callable:
+    """Which implementation serves ``op`` on ``x`` (an array or its
+    aval) over ``axes``: the per-call ``backend`` if given, else the
+    config's (:func:`config_backend`), then :func:`select` with the
+    payload's size and the mesh's real outer extent.  ``cfg`` / ``mesh``
+    default to the runtime's."""
+    explicit = backend is not None
+    if cfg is not None or runtime.is_initialized():
+        if cfg is None:
+            cfg = runtime.config()
+        if backend is None:
+            # A per-op table entry bypasses the size cutover like a
+            # per-call backend (topology fallback still applies).
+            backend, explicit = config_backend(op, cfg)
+        custom_min = cfg.custom_min_bytes
+    else:
+        backend = backend or "xla"
+        custom_min = 0
+    # Hierarchical staging only helps when the outer axis really spans more
+    # than one slice; use the actual mesh extent, not the axis-name count.
+    n_dcn = 1
+    if len(axes) > 1:
+        m = mesh
+        if m is None and runtime.is_initialized():
+            m = runtime.current_mesh()
+        n_dcn = int(m.shape[axes[0]]) if (m is not None
+                                          and axes[0] in m.shape) else 2
+    return select(
+        op,
+        backend,
+        nbytes=nbytes_of(x),
+        custom_min_bytes=custom_min,
+        n_dcn=n_dcn,
+        explicit=explicit,
+        dtype=getattr(x, "dtype", None),
+        axes=axes,
+    )
+
+
 # (op, backend) pairs already warned about this process: the warning is
 # one-time per pair (a hot loop degrading every dispatch must not spam),
 # while the obs counter counts every degradation.
@@ -175,8 +230,6 @@ def _note_fallback(op: str, backend: str, reason: str, *,
             f"to {target} ({reason}); check dcn_size/mesh_shape "
             f"if a two-level topology was intended",
             RuntimeWarning, stacklevel=4)
-    from . import runtime
-
     if runtime.effective_config().obs != "off":
         from . import obs
 
