@@ -281,7 +281,7 @@ def main():
     # -- 4. flash-attention block sizes (real TPU only: Mosaic tiling) ----
     # Timed through value_and_grad over flash_attention_grad — the
     # training path the knobs primarily serve — so a tiling that wins the
-    # forward but loses the dq/dkv backward kernels cannot be recommended.
+    # forward but loses the backward kernel cannot be recommended.
     if not is_cpu:
         from torchmpi_tpu.ops.flash import flash_attention_grad
 

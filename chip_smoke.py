@@ -397,10 +397,10 @@ def phase_lm_flash_xent(ctx):
     n_custom = compiled.as_text().count("tpu_custom_call")
     cost = compiled.cost_analysis()
     if ctx.on_tpu:
-        # flash fwd + dq + dkv per layer are fused by XLA into repeated
-        # calls; xent adds its forward and its one backward.  At least
-        # one of each kind:
-        check(n_custom >= 5, f"{n_custom} tpu_custom_call in the step")
+        # flash's forward and its one backward per layer are fused by
+        # XLA into repeated calls; xent adds its forward and its one
+        # backward.  At least one of each kind:
+        check(n_custom >= 4, f"{n_custom} tpu_custom_call in the step")
     box = {"s": (p, o)}
 
     def call():

@@ -116,8 +116,10 @@ def _kernels(compiled):
 FLASH_CASES = {
     "fwd_causal": (16, 16, 2048, None, False, 1),
     "fwd_gqa_window": (16, 4, 2048, 1024, False, 1),
-    "grad_gqa_window": (16, 4, 2048, 1024, True, 3),   # fwd + dq + dkv
-    "grad_t4096": (8, 8, 4096, None, True, 3),
+    "grad_gqa_window": (16, 4, 2048, 1024, True, 2),   # fwd + ONE backward
+    "grad_t4096": (8, 8, 4096, None, True, 2),
+    # st-21b-ep4-t8k's full layer: the 4 MB resident dq block a head
+    "grad_t8192_full": (28, 4, 8192, None, True, 2),
 }
 
 
@@ -137,8 +139,8 @@ def test_flash_attention_compiles(one_chip, case):
         def fn(q, k, v):
             return flash_attention(q, k, v, causal=True, window=window)
     compiled = _compile(fn, q, kv, kv, kernels=kernels)
-    want = {"flash.fwd", "flash.dq", "flash.dkv"} if grad else {"flash.fwd"}
-    assert {ident for _, ident in _kernels(compiled)} == want
+    want = ["flash.dkv", "flash.fwd"] if grad else ["flash.fwd"]
+    assert sorted(ident for _, ident in _kernels(compiled)) == want
 
 
 # The benchmark's cells (chipbench/configs/starcoder2-3b.json): 24 q / 2 kv
@@ -167,9 +169,8 @@ def test_flash_compiles_at_the_cells_shapes_under_its_module(one_chip, case):
         return jax.grad(lambda p: block.apply(p, x).astype(
             jnp.float32).sum())(params)
 
-    found = _kernels(_compile(fn, params, x, kernels=3))
-    assert sorted(ident for _, ident in found) == [
-        "flash.dkv", "flash.dq", "flash.fwd"]
+    found = _kernels(_compile(fn, params, x, kernels=2))
+    assert sorted(ident for _, ident in found) == ["flash.dkv", "flash.fwd"]
     assert all(FLASH_NAME.match(name) and not XENT_NAME.match(name)
                for name, _ in found), found
 
@@ -275,11 +276,11 @@ def test_smallthinker_step_compiles_at_the_cells_sizes(chip):
     layers = cell.config["num_hidden_layers"]
     # every Pallas kernel of the library carries its identity ...
     idents = [ident for _, ident in found]
-    assert sorted(set(idents)) == ["flash.dkv", "flash.dq", "flash.fwd",
-                                   "moe.combine", "moe.gather",
-                                   "xent.dw", "xent.fwd"]
-    assert all(idents.count(f"flash.{k}") == layers
-               for k in ("fwd", "dq", "dkv"))
+    assert sorted(set(idents)) == ["flash.dkv", "flash.fwd", "moe.combine",
+                                   "moe.gather", "xent.dw", "xent.fwd"]
+    # ... the forward and ONE backward (flash.dkv, which writes dq too) a
+    # layer, the full layer's with its 4 MB dq block resident ...
+    assert all(idents.count(f"flash.{k}") == layers for k in ("fwd", "dkv"))
     assert all(FLASH_NAME.match(name) for name, ident in found
                if ident.startswith("flash."))
     # the expert layer's rows, a layer: gathered forward, again in the

@@ -9,7 +9,8 @@ import pytest
 import jax.numpy as jnp
 
 from torchmpi_tpu.ops.flash import flash_attention
-from torchmpi_tpu.parallel.sequence import reference_attention
+from torchmpi_tpu.parallel.sequence import (causal_window_mask,
+                                            reference_attention)
 
 
 def _oracle(q, k, v, *, causal=False, q_offset=0, kv_offset=0):
@@ -648,3 +649,144 @@ def test_transformer_gqa_local_vs_flash_and_decode(flat_runtime):
         nxt = jnp.argmax(logits[:, -1], axis=-1).astype(cur.dtype)
         cur = jnp.concatenate([cur, nxt[:, None]], axis=1)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(cur))
+
+
+# --- the one-pass backward (flash.dkv writes dq too) ----------------------
+
+def _dense_attention(q, k, v, *, q_offset=0, kv_offset=0, window=None):
+    """Causal attention at global positions, dense, for autodiff; a row
+    with every key masked reads zeros (the kernel's convention).  Returns
+    the output and each row's log-sum-exp ([B, H, T_q], +1e30 on such a
+    row: lse_from_residuals' convention)."""
+    g = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    keep = causal_window_mask(q_offset + jnp.arange(q.shape[1]),
+                              kv_offset + jnp.arange(k.shape[1]), window)
+    s = jnp.where(keep[None, None], s, -jnp.inf)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    m = jnp.where(jnp.isfinite(m), m, 0.0)
+    e = jnp.exp(s - m)
+    l = e.sum(axis=-1, keepdims=True)
+    o = jnp.einsum("bhqk,bkhd->bqhd", e / jnp.where(l > 0, l, 1.0), v)
+    lse = jnp.where(l > 0, m + jnp.log(jnp.where(l > 0, l, 1.0)), 1e30)
+    return o, lse[..., 0]
+
+
+# (T_q, T_kv, q heads, kv heads, block, window, q offset, kv offset,
+#  offsets traced, resident dq budget in bytes or None for the module's)
+BWD_CASES = {
+    "full_causal": (64, 64, 2, 2, 16, None, 0, 0, False, None),
+    "window_banded_static": (128, 128, 1, 1, 16, 20, 0, 0, False, None),
+    "traced_offsets_ring_shard": (32, 32, 2, 2, 16, 40, 64, 32, True, None),
+    "gqa_24_over_2": (32, 32, 24, 2, 16, None, 0, 0, False, None),
+    "seq_not_a_multiple_of_the_block": (40, 40, 2, 1, 16, None, 0, 0,
+                                        False, None),
+    # two q blocks of 16 x 8 float32, twice: four spans of the 128 rows,
+    # the later ones banded from a static q offset that is not 0
+    "q_spans_banded": (128, 128, 2, 1, 16, 20, 0, 0, False, 2048),
+    "q_spans_traced_offsets": (64, 32, 1, 1, 16, None, 32, 0, True, 1024),
+}
+
+
+@pytest.mark.parametrize("case", sorted(BWD_CASES))
+def test_flash_one_pass_backward_matches_dense_autodiff(flat_runtime, case):
+    """dq, dk and dv of the ONE backward kernel == autodiff through the
+    dense oracle, on every path the kernel's grid takes."""
+    import jax
+
+    from torchmpi_tpu.ops import flash
+
+    Tq, Tkv, H, Hkv, blk, window, qo, ko, traced, dq_bytes = BWD_CASES[case]
+    D = 8
+    q, do = _rand((1, Tq, H, D), 70) * 0.5, _rand((1, Tq, H, D), 73)
+    k, v = (_rand((1, Tkv, Hkv, D), s) * 0.5 for s in (71, 72))
+    nq = -(-Tq // blk)
+    if dq_bytes is None:
+        dq_bytes = flash._DQ_RESIDENT_BYTES
+        assert flash._q_span_blocks(nq, blk, D, dq_bytes) == nq
+    else:
+        assert flash._q_span_blocks(nq, blk, D, dq_bytes) < nq
+
+    def dense(q, k, v):
+        return _dense_attention(q, k, v, q_offset=qo, kv_offset=ko,
+                                window=window)
+
+    (o, lse), vjp = jax.vjp(dense, *map(jnp.asarray, (q, k, v)))
+    want = vjp((jnp.asarray(do), jnp.zeros_like(lse)))
+    dvec = jnp.einsum("bqhd,bqhd->bhq", do, o)
+
+    def bwd(qo, ko):
+        return flash._flash_bwd(
+            q, k, v, do, lse, dvec, causal=True, scale=1.0 / np.sqrt(D),
+            q_offset=qo, kv_offset=ko, block_q=blk, block_k=blk,
+            window=window, interpret=None, dq_bytes=dq_bytes)
+
+    got = jax.jit(bwd)(qo, ko) if traced else bwd(qo, ko)
+    for name, g, w in zip("q k v".split(), got, want):
+        assert g.dtype == jnp.float32 and g.shape == w.shape
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=5e-5,
+                                   atol=5e-5, err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("window,scale", [(None, 1.0), (20, 1.0),
+                                          (20, 8 ** -0.5)])
+def test_flash_one_pass_backward_keeps_the_tile_order(flat_runtime, window,
+                                                      scale):
+    """Bit-equal to the two-kernel backward's sums, written out in plain
+    jnp: float32 accumulators from zero, a q block's dq over its kv
+    blocks in ascending j, a kv block's dk/dv over its q blocks in
+    ascending i, dead tiles skipped, dk/dv per q head and group-summed
+    after."""
+    import jax
+
+    from torchmpi_tpu.ops import flash
+
+    T, H, Hkv, D, blk = 64, 4, 2, 8, 16
+    q, do = _rand((1, T, H, D), 80) * 0.5, _rand((1, T, H, D), 83)
+    k, v = (_rand((1, T, Hkv, D), s) * 0.5 for s in (81, 82))
+    o, lse = _dense_attention(*map(jnp.asarray, (q, k, v)), window=window)
+    dvec = jnp.einsum("bqhd,bqhd->bhq", do, o)
+    got = flash.flash_attention_bwd(q, k, v, do, lse, dvec, causal=True,
+                                    scale=scale, block_q=blk, block_k=blk,
+                                    window=window)
+
+    def dot(a, b, dims):
+        return jax.lax.dot_general(a, b, (dims, ((), ())),
+                                   preferred_element_type=jnp.float32)
+
+    @jax.jit  # one program a tile, as the kernel's body is one
+    def tile(q_i, do_i, lse_i, dvec_i, k_j, v_j, valid, dq_i, dk_j, dv_j):
+        s = dot(q_i, k_j, ((1,), (1,)))
+        if scale != 1.0:
+            s = s * scale
+        p = jnp.exp(jnp.where(valid, s, flash.NEG_INF) - lse_i)
+        ds = p * (dot(do_i, v_j, ((1,), (1,))) - dvec_i)
+        dkq, dqk = dot(ds, q_i, ((0,), (0,))), dot(ds, k_j, ((1,), (0,)))
+        return (dq_i + (scale * dqk if scale != 1.0 else dqk),
+                dk_j + (scale * dkq if scale != 1.0 else dkq),
+                dv_j + dot(p, do_i, ((0,), (0,))))
+
+    keep = causal_window_mask(np.arange(T), np.arange(T), window)
+    n = T // blk
+    rows = [slice(i * blk, (i + 1) * blk) for i in range(n)]
+    dq = np.zeros((1, T, H, D), np.float32)
+    dkv = np.zeros((2, 1, T, H, D), np.float32)  # per q head
+    for h in range(H):
+        kh, vh = (jnp.asarray(x[0, :, h // (H // Hkv)]) for x in (k, v))
+        for j in range(n):
+            dk_j = dv_j = jnp.zeros((blk, D), jnp.float32)
+            for i in range(n):
+                if not keep[rows[i], rows[j]].any():
+                    continue
+                dq[0, rows[i], h], dk_j, dv_j = tile(
+                    q[0, rows[i], h], do[0, rows[i], h],
+                    lse[0, h, rows[i], None], dvec[0, h, rows[i], None],
+                    kh[rows[j]], vh[rows[j]], keep[rows[i], rows[j]],
+                    dq[0, rows[i], h], dk_j, dv_j)
+            dkv[0, 0, rows[j], h], dkv[1, 0, rows[j], h] = dk_j, dv_j
+    dk, dv = (jnp.asarray(x).reshape(1, T, Hkv, H // Hkv, D).sum(axis=3)
+              for x in dkv)
+    for name, g, w in zip("q k v".split(), got, (dq, dk, dv)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                      err_msg=f"d{name}")

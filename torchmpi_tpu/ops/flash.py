@@ -14,6 +14,18 @@ the last dimension minor, so the (m, l, acc) scratch carries the running
 softmax state across the k-block dimension for one (batch, head, q-block)
 triple, exactly the flash-attention recurrence.
 
+What carries across which grid axis.  Forward, grid (B, H, q block, kv
+block): (m, l, acc) across the minor kv axis only, so the three outer axes
+are ``"parallel"``.  Backward, ONE kernel on the grid (B, H, kv block, q
+block) that computes each live tile's s, p, dp and ds once and issues all
+five products from them: dk/dv carry in scratch across the minor q axis
+for one kv block; dq carries across BOTH minor axes for one (batch, head),
+in an output block that holds every q row of the head and stays in VMEM
+until the head is done.  Two grid steps of one head may add into the same
+dq rows, so the two minor axes are ``"arbitrary"`` (sequential, in grid
+order: that order is also what keeps every sum's order fixed); batch and
+head stay ``"parallel"``.
+
 ``q_offset``/``kv_offset`` place the local q and kv blocks at global
 sequence positions and may be TRACED scalars (they ride in SMEM), so the
 same kernel computes ring attention's per-step blocks inside ``shard_map``
@@ -52,21 +64,37 @@ _STAT_LANES = 8
 
 
 
-def _flash_params(interpret):
+# What carries across which grid axis (module docstring): the forward's
+# scratch across the minor kv axis only; the backward's dk/dv across the
+# minor q axis and its resident dq across both minor axes.
+_FWD_SEMANTICS = ("parallel", "parallel", "parallel", "arbitrary")
+_BWD_SEMANTICS = ("parallel", "parallel", "arbitrary", "arbitrary")
+
+# The backward keeps the float32 dq of one (batch, head) resident in VMEM
+# (flash_attention_bwd): two pipeline buffers of T_q x D x 4 bytes, 8 MB at
+# 8192 x 128, beside about 4 MB of [512, 512] float32 intermediates and
+# 2-3 MB of blocks.  Of the chip's 128 MiB the buffers may take 64 (q
+# spans beyond that, _q_span_blocks).  Under Mosaic's scoped default of
+# 16 MiB the chip's compiler takes 8192 rows and refuses 16384 ("Ran out
+# of memory in memory space vmem"), so the limit is raised as ops/xent.py
+# and ops/moe.py raise theirs: 65,536 rows then compile.
+_DQ_RESIDENT_BYTES = 64 * 1024 * 1024
+_BWD_VMEM_LIMIT = 100 * 1024 * 1024
+
+
+def _flash_params(interpret, semantics=_FWD_SEMANTICS,
+                  vmem_limit_bytes=None):
     """Compiler params for the flash kernels.  Interpret: the device-
     local barrier skip (ring.local_kernel_params).  Real Mosaic
-    lowering: mark the (batch, head, major-block) grid dims ``parallel``
-    and only the minor accumulation dim ``arbitrary`` — the scratch
-    state carries ONLY across the minor dim (re-initialized at its
-    first step), so declaring the outer dims parallel is sound and lets
-    Mosaic schedule/pipeline across grid steps instead of assuming a
-    serial carried dependency (the jax TPU flash kernels mark their
-    grids the same way)."""
+    lowering: ``semantics`` marks a grid dim ``"arbitrary"`` when kernel
+    state carries across it and ``"parallel"`` when it does not, which
+    lets Mosaic schedule/pipeline across those grid steps instead of
+    assuming a serial carried dependency (the jax TPU flash kernels mark
+    their grids the same way)."""
     if interpret:
         return ring.local_kernel_params(interpret)
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "parallel",
-                             "arbitrary"))
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=vmem_limit_bytes)
 
 
 def _resolve_blocks(block_a, block_b, field_a: str, field_b: str):
@@ -355,93 +383,44 @@ def _flash_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, o_ref, *rest,
             o_ref[0, 0] = (acc_ref[:] / denom).astype(o_ref.dtype)
 
 
-def _flash_bwd_dq_kernel(qo_ref, ko_ref, q_ref, do_ref, lse_ref, d_ref,
-                         k_ref, v_ref, dq_ref, dq_acc, *, scale: float,
-                         causal: bool, block_q: int, block_k: int,
-                         kv_len: int, window: Optional[int] = None,
-                         band_j0=None):
-    """dq = scale * sum_j [p_ij * (dO_i . v_j - D_i)] k_j, p recomputed
-    blockwise from lse.  Grid (B, H, nq, nk) — or (B, H, nq, n_band) on
-    the banded window path; the dq accumulator carries across the (minor)
-    kv dimension."""
-    jb = pl.program_id(3)
+def _flash_bwd_kernel(qo_ref, ko_ref, k_ref, v_ref, q_ref, do_ref, lse_ref,
+                      d_ref, dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
+                      scale: float, causal: bool, block_q: int, block_k: int,
+                      kv_len: int, window: Optional[int] = None,
+                      band_i0=None):
+    """The whole backward of one (q, kv) tile: p is recomputed from lse
+    ONCE and feeds all three gradients,
+
+        dv_j += p_ij^T dO_i
+        dk_j += scale * ds_ij^T q_i      ds_ij = p_ij * (dO_i . v_j - D_i)
+        dq_i += scale * ds_ij k_j
+
+    five products a live tile.  Grid (B, H, nk, nq) — or (B, H, nk,
+    n_band) on the banded window path: kv block major, q block minor.
+    dk/dv carry in VMEM scratch across the minor q axis for one kv block.
+    dq carries across BOTH minor axes: its output block is every q row of
+    one (batch, head), resident in VMEM from the head's first grid step
+    (zeroed there) to its last (written back once, when the block index
+    moves on), and each tile adds into its q block's rows.  A q block
+    meets its kv blocks in ascending j and a kv block its q blocks in
+    ascending i, so all three sums run in the order of a kernel that
+    computed one of them alone."""
+    j = pl.program_id(2)
+    ib = pl.program_id(3)  # band position when band_i0, else q block
     nb = pl.num_programs(3)
 
-    @pl.when(jb == 0)
-    def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
-
-    i = pl.program_id(2)
-    j = band_j0(i) + jb if band_j0 is not None else jb
-    # Fully-masked blocks contribute p == 0 everywhere, so dq is
-    # unchanged — skip all three matmuls.
-    live = _block_live(qo_ref, ko_ref, i, j, block_q, block_k, kv_len,
-                       causal, window)
-    full = _block_full(qo_ref, ko_ref, i, j, block_q, block_k, kv_len,
-                       causal, window)
-
-    def _update(masked):
-        q = q_ref[0, 0]  # [block_q, D]
-        do = do_ref[0, 0]
-        k = k_ref[0, 0]  # [block_k, D]
-        v = v_ref[0, 0]
-        lse = jnp.max(lse_ref[0, 0], axis=1, keepdims=True)  # [block_q, 1]
-        dvec = jnp.max(d_ref[0, 0], axis=1, keepdims=True)
-
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if scale != 1.0:  # statically elided under Config.flash_prescale
-            s = s * scale
-        if masked:
-            s = jnp.where(_valid_mask(qo_ref, ko_ref, i, j, block_q,
-                                      block_k, kv_len, causal, window),
-                          s, NEG_INF)
-        p = jnp.exp(s - lse)  # masked / fully-masked rows (lse=+1e30): 0
-
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)  # [block_q, block_k]
-        ds = p * (dp - dvec)
-        dqk = jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dq_acc[:] = dq_acc[:] + (scale * dqk if scale != 1.0 else dqk)
-
-    @pl.when(jnp.logical_and(live, full))
-    def _update_full():
-        _update(masked=False)
-
-    @pl.when(jnp.logical_and(live, jnp.logical_not(full)))
-    def _update_partial():
-        _update(masked=True)
-
-    @pl.when(jb == nb - 1)
-    def _finalize():
-        dq_ref[0, 0] = dq_acc[:].astype(dq_ref.dtype)
-
-
-def _flash_bwd_dkv_kernel(qo_ref, ko_ref, k_ref, v_ref, q_ref, do_ref,
-                          lse_ref, d_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
-                          scale: float, causal: bool, block_q: int,
-                          block_k: int, kv_len: int,
-                          window: Optional[int] = None, band_i0=None):
-    """dk_j = scale * sum_i ds_ij^T q_i;  dv_j = sum_i p_ij^T dO_i.
-    Grid (B, H, nk, nq) — or (B, H, nk, n_band) on the banded window
-    path: the q dimension is minor so the dk/dv accumulators carry
-    across it for one kv block."""
-    ib = pl.program_id(3)
-    nb = pl.num_programs(3)
+    @pl.when(jnp.logical_and(j == 0, ib == 0))
+    def _init_head():
+        dq_ref[...] = jnp.zeros_like(dq_ref)
 
     @pl.when(ib == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    j = pl.program_id(2)
     i = band_i0(j) + ib if band_i0 is not None else ib
     # For this kv block, q blocks entirely in its past (causal)
-    # contribute p == 0 — skip all four matmuls.  (Padded keys inside a
+    # contribute p == 0 — skip all five matmuls.  (Padded keys inside a
     # live block are excluded by _valid_mask, not here.)
     live = _block_live(qo_ref, ko_ref, i, j, block_q, block_k, kv_len,
                        causal, window)
@@ -465,19 +444,25 @@ def _flash_bwd_dkv_kernel(qo_ref, ko_ref, k_ref, v_ref, q_ref, do_ref,
             s = jnp.where(_valid_mask(qo_ref, ko_ref, i, j, block_q,
                                       block_k, kv_len, causal, window),
                           s, NEG_INF)
-        p = jnp.exp(s - lse)  # [block_q, block_k]
+        p = jnp.exp(s - lse)  # masked / fully-masked rows (lse=+1e30): 0
 
         dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            preferred_element_type=jnp.float32)  # [block_q, block_k]
         ds = p * (dp - dvec)
         dkq = jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dk_acc[:] = dk_acc[:] + (scale * dkq if scale != 1.0 else dkq)
+        dqk = jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+        dq_ref[0, 0, rows, :] = dq_ref[0, 0, rows, :] + (
+            scale * dqk if scale != 1.0 else dqk)
 
     @pl.when(jnp.logical_and(live, full))
     def _update_full():
@@ -643,6 +628,15 @@ def _stat_lanes(x, Tqp):
     return jnp.broadcast_to(x[..., None], (*x.shape, _STAT_LANES))
 
 
+def _q_span_blocks(nq: int, block_q: int, d: int, dq_bytes: int) -> int:
+    """q blocks ONE backward call keeps resident: all ``nq`` when both
+    pipeline buffers of their float32 dq fit ``dq_bytes``, else the even
+    split into the fewest spans that do."""
+    fit = max(1, dq_bytes // (2 * block_q * d * 4))
+    spans = -(-nq // fit)
+    return -(-nq // spans)
+
+
 def flash_attention_bwd(q, k, v, do, lse, dvec, *, causal: bool,
                         scale: float, q_offset=0, kv_offset=0,
                         block_q: int = 128, block_k: int = 128,
@@ -655,7 +649,25 @@ def flash_attention_bwd(q, k, v, do, lse, dvec, *, causal: bool,
     rowsum).  Serves both the single-device VJP and each step of the ring
     backward in parallel/sequence.py, where the kv shard (and its offset)
     rotates.
+
+    ONE ``pallas_call`` (:func:`_flash_bwd_kernel`, identity
+    ``flash.dkv``) on the grid (B, H, kv block, q block): dk/dv carry
+    across the minor q axis, dq across both minor axes for one head, so
+    both are ``"arbitrary"``.  The q rows whose dq one call keeps in VMEM
+    follow from the shape (:func:`_q_span_blocks`): every row up to about
+    64k of 128 lanes, beyond that the same kernel over spans of the q
+    axis with dk/dv summed, as the ring backward sums them across shards.
     """
+    return _flash_bwd(q, k, v, do, lse, dvec, causal=causal, scale=scale,
+                      q_offset=q_offset, kv_offset=kv_offset,
+                      block_q=block_q, block_k=block_k, window=window,
+                      interpret=interpret, dq_bytes=_DQ_RESIDENT_BYTES)
+
+
+def _flash_bwd(q, k, v, do, lse, dvec, *, causal, scale, q_offset,
+               kv_offset, block_q, block_k, window, interpret, dq_bytes):
+    """:func:`flash_attention_bwd` with the resident dq's VMEM budget as
+    an argument (tests force several q spans with a small one)."""
     B, Tq, H, D = q.shape
     Tkv, Hkv = k.shape[1], k.shape[2]
     group = _gqa_group(H, Hkv)
@@ -675,7 +687,7 @@ def flash_attention_bwd(q, k, v, do, lse, dvec, *, causal: bool,
         kt = jnp.pad(kt, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
         vt = jnp.pad(vt, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
     Tqp, Tkvp = qt.shape[2], kt.shape[2]
-    nq, nk = Tqp // block_q, Tkvp // block_k
+    nk = Tkvp // block_k
     lse_l = _stat_lanes(lse, Tqp)
     # dvec's padding value is irrelevant (padded rows have p == 0, so
     # ds == p * (dp - dvec) == 0); _stat_lanes' +1e30 never produces nan.
@@ -684,70 +696,64 @@ def flash_attention_bwd(q, k, v, do, lse, dvec, *, causal: bool,
     if interpret is None:
         interpret = ring._interpret_mode()
 
-    # Banded grids for static offsets + window — see flash_attention.
-    band_j0, grid_nk = _band_setup(
-        window, causal, q_offset, kv_offset, span_block=block_q,
-        step_block=block_k, n_total=nk, start_fn=_kv_band_start,
-        block_q=block_q, block_k=block_k, nk=nk)
-    band_i0, grid_nq = _band_setup(
-        window, causal, q_offset, kv_offset, span_block=block_k,
-        step_block=block_q, n_total=nq, start_fn=_q_band_start,
-        block_q=block_q, block_k=block_k, nq=nq)
-
-    qo = jnp.asarray(q_offset, jnp.int32).reshape(1)
     ko = jnp.asarray(kv_offset, jnp.int32).reshape(1)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    qb = pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0))
-    kb = pl.BlockSpec((1, 1, block_k, D), _banded_minor_map(band_j0, group))
-    sb = pl.BlockSpec((1, 1, block_q, _STAT_LANES),
-                      lambda b, h, i, j: (b, h, i, 0))
-
-    dq_kernel = functools.partial(
-        _flash_bwd_dq_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, kv_len=Tkv, window=window, band_j0=band_j0)
-    dq = pl.pallas_call(
-        dq_kernel,
-        out_shape=jax.ShapeDtypeStruct(qt.shape, jnp.float32),
-        grid=(B, H, nq, grid_nk),
-        in_specs=[smem, smem, qb, qb, sb, sb, kb, kb],
-        out_specs=qb,
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        interpret=interpret,
-        compiler_params=_flash_params(interpret),
-        metadata=ring.kernel_identity("flash.dq"),
-    )(qo, ko, qt, dot_, lse_l, d_l, kt, vt)
-
-    # dkv grid puts the q-block dimension minor; index maps swap i and j
-    # relative to the dq call (grid = (B, H, nk, nq)).  GQA: k/v INPUTS
-    # are fetched at the group's kv head (h // group), but the kernel
-    # emits PER-Q-HEAD dk/dv partials (out at full H) — writing
-    # Hkv-headed outs directly would let each group member's finalize
-    # overwrite the last (out blocks are written, not accumulated).  The
-    # group-sum afterwards is exactly autodiff's transpose of the
-    # jnp.repeat head broadcast.
-    kv_in_map2 = lambda b, h, j, i: (b, h // group, j, 0)  # noqa: E731
-    kb2 = pl.BlockSpec((1, 1, block_k, D), kv_in_map2)
-    dout2 = pl.BlockSpec((1, 1, block_k, D),
-                         lambda b, h, j, i: (b, h, j, 0))
-    q_map2 = _banded_minor_map(band_i0)
-    qb2 = pl.BlockSpec((1, 1, block_q, D), q_map2)
-    sb2 = pl.BlockSpec((1, 1, block_q, _STAT_LANES), q_map2)
-    dkv_kernel = functools.partial(
-        _flash_bwd_dkv_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, kv_len=Tkv, window=window, band_i0=band_i0)
+    # GQA: k/v INPUTS are fetched at the group's kv head (h // group),
+    # but the kernel emits PER-Q-HEAD dk/dv partials (out at full H) —
+    # writing Hkv-headed outs directly would let each group member's
+    # finalize overwrite the last (out blocks are written, not
+    # accumulated).  The group-sum afterwards is exactly autodiff's
+    # transpose of the jnp.repeat head broadcast.
+    kb = pl.BlockSpec((1, 1, block_k, D),
+                      lambda b, h, j, i: (b, h // group, j, 0))
+    dkv_out = pl.BlockSpec((1, 1, block_k, D),
+                           lambda b, h, j, i: (b, h, j, 0))
     dkv_shape = jax.ShapeDtypeStruct((B, H, Tkvp, D), jnp.float32)
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
-        out_shape=(dkv_shape, dkv_shape),
-        grid=(B, H, nk, grid_nq),
-        in_specs=[smem, smem, kb2, kb2, qb2, qb2, sb2, sb2],
-        out_specs=(dout2, dout2),
-        scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
-                        pltpu.VMEM((block_k, D), jnp.float32)],
-        interpret=interpret,
-        compiler_params=_flash_params(interpret),
-        metadata=ring.kernel_identity("flash.dkv"),
-    )(qo, ko, kt, vt, qt, dot_, lse_l, d_l)
+
+    def one_span(r0, r1):
+        """The kernel over q rows [r0, r1): their dq, and their share of
+        every dk/dv."""
+        nq = (r1 - r0) // block_q
+        qo_s = q_offset + r0
+        # Banded grid for static offsets + window — see flash_attention.
+        band_i0, grid_nq = _band_setup(
+            window, causal, qo_s, kv_offset, span_block=block_k,
+            step_block=block_q, n_total=nq, start_fn=_q_band_start,
+            block_q=block_q, block_k=block_k, nq=nq)
+        q_map = _banded_minor_map(band_i0)
+        qb = pl.BlockSpec((1, 1, block_q, D), q_map)
+        sb = pl.BlockSpec((1, 1, block_q, _STAT_LANES), q_map)
+        # Every q row of the head, whatever (j, i): resident, see the
+        # kernel.
+        dq_out = pl.BlockSpec((1, 1, r1 - r0, D),
+                              lambda b, h, j, i: (b, h, 0, 0))
+        kernel = functools.partial(
+            _flash_bwd_kernel, scale=scale, causal=causal, block_q=block_q,
+            block_k=block_k, kv_len=Tkv, window=window, band_i0=band_i0)
+        return pl.pallas_call(
+            kernel,
+            out_shape=(jax.ShapeDtypeStruct((B, H, r1 - r0, D), jnp.float32),
+                       dkv_shape, dkv_shape),
+            grid=(B, H, nk, grid_nq),
+            in_specs=[smem, smem, kb, kb, qb, qb, sb, sb],
+            out_specs=(dq_out, dkv_out, dkv_out),
+            scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
+                            pltpu.VMEM((block_k, D), jnp.float32)],
+            interpret=interpret,
+            compiler_params=_flash_params(interpret, _BWD_SEMANTICS,
+                                          vmem_limit_bytes=_BWD_VMEM_LIMIT),
+            metadata=ring.kernel_identity("flash.dkv"),
+        )(jnp.asarray(qo_s, jnp.int32).reshape(1), ko, kt, vt,
+          qt[:, :, r0:r1], dot_[:, :, r0:r1], lse_l[:, :, r0:r1],
+          d_l[:, :, r0:r1])
+
+    span = _q_span_blocks(Tqp // block_q, block_q, D, dq_bytes) * block_q
+    parts = [one_span(r0, min(r0 + span, Tqp)) for r0 in range(0, Tqp, span)]
+    dq, dk, dv = parts[0]
+    if len(parts) > 1:
+        dq = jnp.concatenate([p[0] for p in parts], axis=2)
+        dk = sum((p[1] for p in parts[1:]), dk)
+        dv = sum((p[2] for p in parts[1:]), dv)
     if group > 1:
         dk = dk.reshape(B, Hkv, group, Tkvp, D).sum(axis=2)
         dv = dv.reshape(B, Hkv, group, Tkvp, D).sum(axis=2)

@@ -119,7 +119,7 @@ def local_kernel_params(interpret):
 
 def kernel_identity(name: str):
     """``metadata=`` of every ``pallas_call`` in ``ops/``: the kernel's
-    identity, ``<family>.<role>`` (``flash.dq``, ``ring.allreduce.chunked``).
+    identity, ``<family>.<role>`` (``flash.dkv``, ``ring.allreduce.chunked``).
 
     Pallas carries it into the custom call's
     ``frontend_attributes={kernel_metadata={"tm_kernel":"<name>"}}``, which
