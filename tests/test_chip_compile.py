@@ -238,6 +238,34 @@ def test_expert_row_kernels_compile_at_the_cells_shapes(one_chip, call):
     assert not XENT_NAME.match(name) and not FLASH_NAME.match(name)
 
 
+@pytest.mark.parametrize("slots", [5, 20])
+@pytest.mark.parametrize("call", ["gather", "combine"])
+def test_expert_row_kernels_compile_at_a_decode_steps_rows(one_chip, call,
+                                                           slots):
+    # imoe-16b-serve-conv-sat's pooled decode step: a row a slot of 2048
+    # bf16, six routes each.  The buffer is smaller than one block of 256,
+    # so the block is the buffer: 120 rows, or 30, which is no multiple of
+    # the 8-row tile.
+    from torchmpi_tpu.ops import moe
+
+    width, routes = 2048, 6 * slots
+    assert moe.block_rows(routes) == routes
+    order = _sds((routes,), jnp.int32, one_chip)
+    n_live = _sds((), jnp.int32, one_chip)
+    if call == "gather":
+        compiled = _compile(moe.rows_from_tokens,
+                            _sds((slots, width), jnp.bfloat16, one_chip),
+                            order, n_live, kernels=1)
+    else:
+        compiled = _compile(
+            lambda y, o, n, w: moe.tokens_from_rows(
+                y, o, n, slots, weight=w, round_to=jnp.bfloat16),
+            _sds((routes, width), jnp.float32, one_chip), order, n_live,
+            _sds((routes,), jnp.float32, one_chip), kernels=1)
+    ((_, ident),) = _kernels(compiled)
+    assert ident == ("moe.combine" if call == "combine" else "moe.gather")
+
+
 def test_smallthinker_step_compiles_at_the_cells_sizes(chip):
     # The whole train step of the benchmark's st-21b-ep4-t8k
     # (chipbench/configs/smallthinker-21b-a3b.json) at its real sizes, as

@@ -65,6 +65,41 @@ ST_JOINS = ["dispatch_ms_per_step.tok", "xent_roofline_pct.tok",
             "device_idle_pct.tok"]
 ST_STAYS_OUT = ["mfu_pct.tok", "flash_roofline_pct.tok",
                 "mlp_ms_per_step.tok"]
+# what PR 30 added, in order: the served cells' metrics (``.srv`` moves
+# ``out_tokens_per_s_chip``, ``.lat`` moves ``itl_ms_p99``)
+SAT, R80 = "sc2-3b-serve-sat", "sc2-3b-serve-r80"
+SRV_METRICS = [
+    "served_tokens_per_s.srv", "batch_occupancy_pct.srv",
+    "prefill_share_pct.srv", "decode_step_ms.srv", "prefill_ms_per_ktok.srv",
+    "cache_tokens_used_over_reserved.srv", "device_idle_pct.srv",
+    "generator_late_ms_p95.srv", "itl_ms_p50.srv", "itl_ms_p99.srv",
+    "ttft_ms_p50.srv", "mfu_pct.srv", "decode_hbm_roofline_pct.srv"]
+LAT_METRICS = [
+    "prefill_ms_per_ktok.lat", "prefill_share_pct.lat", "decode_step_ms.lat",
+    "itl_ms_mean.lat", "itl_ms_p50.lat", "batch_occupancy_pct.lat",
+    "device_idle_pct.lat", "generator_late_ms_p95.lat", "ttft_ms_p50.lat",
+    "ttft_ms_p90.lat", "queue_wait_ms_p90.lat"]
+# what PR 31 added, in order: metric -> (reader, layer, source, unit, better)
+IMOE = "imoe-16b-serve-conv-sat"
+IMOE_METRICS = {
+    "decode_moe_hbm_roofline_pct.srv": (
+        "serve_moe_decode_roofline", "serving engine", "device_trace", "%",
+        "higher"),
+    "moe_experts_ms_per_decode_step.srv": (
+        "serve_scopes_in_module", "expert layer", "device_trace", "ms",
+        "lower"),
+    "moe_permute_ms_per_decode_step.srv": (
+        "serve_scopes_in_module", "expert layer", "device_trace", "ms",
+        "lower"),
+    "latent_attn_ms_per_decode_step.srv": (
+        "serve_scopes_in_module", "serving engine", "device_trace", "ms",
+        "lower"),
+    "moe_experts_touched_share.srv": (
+        "moe_touched_share", "expert layer", "program_counter", "1",
+        "higher"),
+}
+# a dense model's count of a step's bytes: not the sparse cell's
+IMOE_STAYS_OUT = ["decode_hbm_roofline_pct.srv"]
 
 with open(os.path.join(TESTDATA, "expected_names.json")) as _f:
     WANT = json.load(_f)
@@ -125,7 +160,7 @@ def test_live_share_of_rows_moved_is_a_ratio_of_the_programs_counters():
         "name": LIVE_SHARE, "unit": "1", "better": "higher",
         "source": "program_counter", "layer": "expert layer",
         "moves": "tokens_per_s_chip", "workloads": [ST]}
-    assert MANIFEST["per_layer"][-1] == entry      # appended, nothing moved
+    assert MANIFEST["per_layer"][30] == entry   # where PR 27 appended it
     spec = harness.load_json(MANIFEST, "layer_metrics", LIVE_SHARE)
     assert spec == {"reader": "counter_ratio", "args": {
         "numerator": "tm_moe_routes_held_total",
@@ -158,13 +193,91 @@ def test_expert_cell_joins_the_lists_whose_readers_are_right_for_it():
     cell = harness.by_name(MANIFEST["workloads"], ST, "workload")
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "smallthinker-21b-a3b", "b1-t8192", 1)
-    assert [w["name"] for w in MANIFEST["workloads"]][-1] == ST
+    assert [w["name"] for w in MANIFEST["workloads"]][4] == ST
     assert sum(w["chips"] == 4 for w in MANIFEST["workloads"]) == 1
+
+
+@pytest.mark.parametrize("metric", sorted(IMOE_METRICS))
+def test_sparse_served_cell_metric_resolves_to_a_file_and_a_reader(metric):
+    reader, layer, source, unit, better = IMOE_METRICS[metric]
+    entry = harness.by_name(MANIFEST["per_layer"], metric, "metric")
+    assert entry == {
+        "name": metric, "unit": unit, "better": better, "source": source,
+        "layer": layer, "moves": "out_tokens_per_s_chip",
+        "workloads": [IMOE]}
+    spec = harness.load_json(MANIFEST, "layer_metrics", metric)
+    assert spec["reader"] == reader
+    assert callable(harness.load_module(MANIFEST, "readers", reader).read)
+    mine = harness.by_name(harness.resolve(MANIFEST, IMOE).per_layer, metric,
+                           "metric")
+    assert mine["args"] == spec["args"]
+    for pattern in ("module", "op_name", "name", "not_name"):
+        re.compile(spec["args"].get(pattern, ""))
+    if source == "device_trace":     # the pooled decode step's program
+        assert spec["args"]["module"] == "jit__slot_step_jit"
+
+
+def test_sparse_served_cell_joins_the_lists_whose_readers_are_right_for_it():
+    """PR 31's cell: the ``.srv`` metrics of PR 30 whose readers are right
+    for it unchanged, its own five, and not the dense model's count of a
+    decode step's bytes; one four-chip cell of eight."""
+    mine = {m["name"] for m in harness.resolve(MANIFEST, IMOE).per_layer}
+    joined = set(SRV_METRICS) - set(IMOE_STAYS_OUT)
+    assert mine == joined | set(IMOE_METRICS)
+    for metric in joined:
+        assert harness.by_name(MANIFEST["per_layer"], metric,
+                               "metric")["workloads"] == [SAT, IMOE]
+    for metric in IMOE_STAYS_OUT:
+        assert harness.by_name(MANIFEST["per_layer"], metric,
+                               "metric")["workloads"] == [SAT]
+    for metric in LAT_METRICS:
+        assert harness.by_name(MANIFEST["per_layer"], metric,
+                               "metric")["workloads"] == [R80]
+    cell = harness.resolve(MANIFEST, IMOE)
+    assert {m["name"] for m in cell.end_to_end} == {
+        "out_tokens_per_s_chip", "setup_s"}
+    assert harness.by_name(MANIFEST["end_to_end"], "out_tokens_per_s_chip",
+                           "metric")["workloads"] == [SAT, IMOE]
+    entry = harness.by_name(MANIFEST["workloads"], IMOE, "workload")
+    assert (entry["config"], entry["traffic"], entry["chips"]) == (
+        "instella-moe-16b-a3b-serve", "open-conv-sat", 1)
+    assert cell.config["runner"] == "serve_open_loop_experts"
+    assert cell.config["reduced"] == harness.by_name(
+        MANIFEST["configs"], "instella-moe-16b-a3b-serve",
+        "config")["reduced"] == ["num_hidden_layers",
+                                 "num_nextn_predict_layers"]
+    names = [w["name"] for w in MANIFEST["workloads"]]
+    assert names[5:] == [R80, SAT, IMOE] and len(names) == 8
+    assert sum(w["chips"] == 4 for w in MANIFEST["workloads"]) == 1
+
+
+def test_sparse_served_readers_give_no_number_without_the_programs_names():
+    """A program without the counters (the parent) gives no number and no
+    error; with them, the share of the experts a step touched."""
+    from torchmpi_tpu import obs
+    share = harness.load_module(MANIFEST, "readers", "moe_touched_share")
+    roof = harness.load_module(MANIFEST, "readers",
+                               "serve_moe_decode_roofline")
+    cell = harness.resolve(MANIFEST, IMOE)
+    ctx = {"cell": cell, "platform": "tpu", "traced": {"steps": 0}}
+    obs.reset()
+    assert share.read(ctx, experts="n_routed_experts") is None
+    assert roof.touched_per_step() is None
+    assert roof.read(ctx, module="jit__slot_step_jit") is None
+    for layer in ("a", "b"):
+        obs.registry().counter_inc("tm_moe_experts_touched_total", 96,
+                                   layer=layer)
+    obs.registry().counter_inc("tm_moe_decode_steps_total", 2, replica="r")
+    assert share.read(ctx, experts="n_routed_experts") == 96 / (64 * 2)
+    assert roof.touched_per_step() == 96.0
+    obs.reset()
 
 
 def test_benchmark_json_only_gained_entries_at_the_end():
     """What the benchmark had (PR 23, then PR 24) is still there, first
-    and unchanged in order; PR 26's metrics follow it, then PR 27's."""
+    and unchanged in order; PR 26's metrics follow it, then PR 27's, PR
+    30's and PR 31's, each where its PR appended it: the next PR appends
+    after them and adds its own slice here."""
     names = [m["name"] for m in MANIFEST["per_layer"]]
     assert set(names[10:22]) == set(NEW_METRICS)
     assert names[:22] == [
@@ -177,11 +290,22 @@ def test_benchmark_json_only_gained_entries_at_the_end():
         "fwd_ms_per_step.img", "fwd_ms_per_step.tok", "bwd_ms_per_step.img",
         "bwd_ms_per_step.tok", "mlp_ms_per_step.tok",
         "attn_proj_ms_per_step.tok", "step_span_ms.img", "step_span_ms.tok"]
-    assert names[22:] == list(ST_METRICS) + [LIVE_SHARE]
+    assert names[22:31] == list(ST_METRICS) + [LIVE_SHARE]
+    assert names[31:55] == SRV_METRICS + LAT_METRICS
+    assert names[55:60] == list(IMOE_METRICS)
     layers = {m["layer"] for m in MANIFEST["per_layer"][:10]}
     assert {m["layer"] for m in MANIFEST["per_layer"][10:22]} <= layers
-    assert {m["layer"] for m in MANIFEST["per_layer"][22:]} <= layers | {
+    assert {m["layer"] for m in MANIFEST["per_layer"][22:31]} <= layers | {
         "expert layer"}
+    assert {m["layer"] for m in MANIFEST["per_layer"][31:60]} <= layers | {
+        "expert layer", "serving scheduler", "serving engine",
+        "serving slot pool"}
+    assert [c["name"] for c in MANIFEST["configs"]] == [
+        "resnet50", "starcoder2-3b", "smallthinker-21b-a3b",
+        "starcoder2-3b-serve", "instella-moe-16b-a3b-serve"]
+    assert [m["name"] for m in MANIFEST["end_to_end"]] == [
+        "images_per_s_chip", "tokens_per_s_chip", "step_ms_p90",
+        "itl_ms_p99", "out_tokens_per_s_chip", "setup_s"]
 
 
 # --------------------------------------------------- file -> metadata, sums
@@ -437,3 +561,27 @@ def test_traced_rehearsal_of_the_expert_cell_reads_its_counters():
     assert max(check["router_rel_err"]) < 1e-5
     assert {"step_span_ms.tok", "dispatch_ms_per_step.tok"} <= set(
         out["metrics"])
+
+
+def test_traced_rehearsal_of_the_sparse_served_cell_comes_out_correct():
+    """``run.py --rehearse`` as the driver calls it, through
+    ``serving.Server``: correct against the plain reference; the program's
+    counter needs no device plane and is there on the CPU, the device's
+    times and the roofline are left out."""
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    p = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "chipbench", "run.py"),
+         "--workload", IMOE, "--seed", "2931000011", "--seconds", "2",
+         "--trace", "1", "--rehearse"],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-2000:]
+    out = json.loads(p.stdout.strip().splitlines()[-1])
+    assert out["correct"] is True and out["rehearsal"] is True
+    assert out["failed"] == 0 and out["attempted"] > 50
+    assert out["compared"]["logit_gap"][0] <= 0.05
+    assert 0 < out["metrics"]["moe_experts_touched_share.srv"]["value"] <= 1
+    device_only = {m for m, v in IMOE_METRICS.items()
+                   if v[2] == "device_trace"}
+    assert not device_only & set(out["metrics"])
+    assert {"batch_occupancy_pct.srv", "prefill_share_pct.srv",
+            "itl_ms_p50.srv"} <= set(out["metrics"])

@@ -107,7 +107,7 @@ GATED_FIELD_FAMILIES = (
 # Registry methods whose first argument is a metric name (obs/__init__
 # is the only emitter, but the scan covers the whole package).
 _EMIT_FUNCS = ("counter_inc", "hist_observe", "counter_handle",
-               "hist_handle")
+               "hist_handle", "gauge_set")
 
 # Doc tokens that look like metrics but are not registry metric names
 # (reviewed by hand; keep this list short and commented).

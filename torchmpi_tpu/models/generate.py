@@ -613,28 +613,38 @@ def slot_prefill(dmodel, params, prompt, *, true_len=None,
 @partial(jax.jit, static_argnums=(0,))
 def _slot_step_jit(dmodel, params, cache, tokens, positions, seeds,
                    idxs, temps, top_ks, top_ps):
+    from ..parallel.expert import decode_counts
+
     logits, updated = dmodel.apply(
         {"params": params, "cache": cache}, tokens[:, None],
-        pos_offset=positions, mutable=["cache"])
+        pos_offset=positions, mutable=["cache", "moe"])
     nxt = _sample_rows(logits[:, 0], _sample_keys(seeds, idxs), temps,
                        top_ks, top_ps, tokens.dtype)
-    return updated["cache"], nxt
+    # a live slot writes at its prompt's length or later, an idle one at 0
+    return (updated["cache"], nxt,
+            decode_counts(updated.get("moe", {}), positions > 0))
 
 
 def slot_decode_step(dmodel, params, cache, tokens, positions,
-                     sampling=None):
+                     sampling=None, counted: bool = False):
     """One decode tick over the whole slot pool: ``tokens`` [S] are each
     slot's pending token, ``positions`` [S] its absolute write index
-    (inactive slots pass any valid filler — their outputs are ignored
-    and their cache rows are fully overwritten on the next admission).
-    Returns ``(new_cache, next_tokens [S])``.  One compiled executable
-    serves the entire trace — admission, retirement, and greedy/sampled
-    mixes never retrace (the sampling knobs are [S] operands)."""
+    (inactive slots pass 0 and any token: their outputs are ignored,
+    their cache rows are fully overwritten on the next admission, and the
+    expert layers' counts leave them out).
+    Returns ``(new_cache, next_tokens [S])``, and with ``counted`` a third
+    value: what the step's expert layers did
+    (``parallel.expert.decode_counts``: [layers, 2] int32, ready when the
+    tokens are), None for a model without expert layers.  One compiled
+    executable serves the entire trace — admission, retirement, and
+    greedy/sampled mixes never retrace (the sampling knobs are [S]
+    operands)."""
     tokens = jnp.asarray(tokens)
     if sampling is None:
         sampling = _greedy_sampling(tokens.shape[0])
-    return _slot_step_jit(dmodel, params, cache, tokens,
-                          jnp.asarray(positions), *sampling)
+    out = _slot_step_jit(dmodel, params, cache, tokens,
+                         jnp.asarray(positions), *sampling)
+    return out if counted else out[:2]
 
 
 @partial(jax.jit, static_argnums=(0,))

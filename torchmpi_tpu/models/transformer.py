@@ -22,11 +22,14 @@ float32 parameters and softmax statistics.
 
 from __future__ import annotations
 
+import functools
+import math
 from typing import Optional, Tuple, Union
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ..parallel import expert as eplib
@@ -307,6 +310,174 @@ class SPAttention(nn.Module):
         return nn.Dense(E, dtype=self.dtype, use_bias=bias, name="out")(o)
 
 
+def yarn_rope(dim: int, base: float, yarn=None):
+    """RoPE frequencies of ``dim // 2`` rotary pairs and the two YaRN
+    factors, as ``deepseek_v3`` computes them: ``yarn`` is ``(factor,
+    original_max_position, beta_fast, beta_slow, mscale, mscale_all_dim)``
+    (None: plain RoPE).  Pairs that turn more than ``beta_fast`` times over
+    the original positions keep their frequency, those that turn less than
+    ``beta_slow`` times are divided by ``factor``, a linear ramp between.
+    Returns ``(inv_freq [dim // 2] float32, the factor on cos and sin, the
+    factor m on the softmax scale: scores are scaled by m squared)``."""
+    freqs = float(base) ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if yarn is None:
+        return np.float32(1.0 / freqs), 1.0, 1.0
+    factor, original, fast, slow, mscale, mscale_all = yarn
+
+    def turns_at(rotations):        # the pair that turns so often
+        return (dim * math.log(original / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    def m(scale):
+        return 0.1 * scale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+    low = max(math.floor(turns_at(fast)), 0)
+    high = min(math.ceil(turns_at(slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / ((high - low) or 0.001),
+                   0, 1)
+    inv = ramp / (factor * freqs) + (1 - ramp) / freqs
+    return np.float32(inv), m(mscale) / m(mscale_all), m(mscale_all)
+
+
+def apply_rope_pairs(x, pos, inv_freq, factor: float = 1.0):
+    """RoPE over INTERLEAVED pairs: features ``(2i, 2i + 1)`` of ``x``
+    ([B, T, H, D]) turn by ``pos * inv_freq[i]``; ``pos`` [T] or [B, T];
+    ``factor`` scales cos and sin (YaRN's)."""
+    ang = pos.astype(jnp.float32)[..., None] * inv_freq
+    cos = (jnp.cos(ang) * factor)[..., None, :]
+    sin = (jnp.sin(ang) * factor)[..., None, :]
+    x1, x2 = x[..., 0::2], x[..., 1::2]
+    return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                     axis=-1).reshape(x.shape).astype(x.dtype)
+
+
+class LatentAttention(nn.Module):
+    """Attention over a low-rank LATENT of the keys and values
+    (``deepseek_v3``'s, without a query latent), no bias anywhere:
+
+        q = x W_q                       [T, H, D], D = nope + rope
+        [c_raw, k_r] = x W_kva          rank + rope;  c = RMSNorm(c_raw)
+        [k_n, v] = c W_kvb              [T, H, nope + v_dim]
+
+    ``k_r`` is ONE rotary key for all heads; RoPE (interleaved pairs, YaRN
+    frequencies) turns it and the last ``rope`` features of every query
+    head.  Scores are ``(q_n . k_n + q_r . k_r) * D**-0.5 * m**2``, causal
+    over the whole context.  With ``gate`` the heads' output is multiplied
+    by ``sigmoid(x W_g)`` before the output projection.
+
+    ``decode=True`` keeps a latent cache: ``c`` [B, max_len, rank] and the
+    turned ``k_rope`` [B, max_len, rope], float32, no heads.  A block of
+    prompt tokens on a fresh cache (scalar ``pos_offset``, T > 1) expands
+    ``k_n`` and ``v`` per head and attends within the block
+    (``latent_expand``); every other step attends ABSORBED over the cache
+    (``latent_absorb``): ``q_lat = q_n W_uk^T``, scores over ``c`` and
+    ``k_rope``, ``o = (P c) W_uv``, with ``W_kvb = [W_uk, W_uv]``: a key or
+    a value per head never exists.  Positions, the per-row slot form and
+    the cache's write indices follow :class:`SPAttention`."""
+
+    num_heads: int
+    head_dim: int                 # nope + rope: a query's and a key's width
+    rope_dim: int
+    v_dim: int
+    rank: int
+    dtype: jnp.dtype = jnp.float32
+    decode: bool = False
+    max_len: int = 0
+    rope_base: float = 10000.0
+    yarn: Optional[Tuple[float, ...]] = None
+    norm_eps: float = 1e-6
+    gate: bool = False
+
+    @nn.compact
+    def __call__(self, x, pos_offset=0):  # x: [B, T, E]
+        B, T, E = x.shape
+        H, D, dr, dv, r = (self.num_heads, self.head_dim, self.rope_dim,
+                           self.v_dim, self.rank)
+        dn = D - dr
+        inv_freq, cs, m = yarn_rope(dr, self.rope_base, self.yarn)
+        scale = D ** -0.5 * m * m
+
+        def dense(features, name):
+            return nn.DenseGeneral(features, axis=-1, dtype=self.dtype,
+                                   use_bias=False, name=name)
+
+        w_kvb = self.param("kv_b", nn.initializers.lecun_normal(),
+                           (r, H, dn + dv), jnp.float32)
+        po = jnp.asarray(pos_offset)
+        per_row = self.decode and po.ndim == 1
+        if self.decode:
+            if self.max_len <= 0:
+                raise ValueError("decode=True needs max_len > 0")
+            cc = self.variable("cache", "c", jnp.zeros,
+                               (B, self.max_len, r), jnp.float32)
+            ckr = self.variable("cache", "k_rope", jnp.zeros,
+                                (B, self.max_len, dr), jnp.float32)
+            idx = self.variable("cache", "idx",
+                                lambda: jnp.zeros((), jnp.int32))
+            # the write indices go through THE clamp (see SPAttention)
+            start = clamp_slot_positions(idx.value, self.max_len, T)
+            starts = (clamp_slot_positions(po.astype(jnp.int32),
+                                           self.max_len, T)
+                      if per_row else None)  # [B]
+            rpos = (starts[:, None] + jnp.arange(T) if per_row
+                    else start + jnp.arange(T))
+        else:
+            rpos = pos_offset + jnp.arange(T)
+        q = dense((H, D), "q")(x).astype(jnp.float32)
+        q_n = q[..., :dn]
+        q_r = apply_rope_pairs(q[..., dn:], rpos, inv_freq, cs)
+        with jax.named_scope("latent_down"):
+            kva = dense(r + dr, "kv_a")(x).astype(jnp.float32)
+            c = nn.RMSNorm(epsilon=self.norm_eps, dtype=jnp.float32,
+                           name="kv_norm")(kva[..., :r])
+            k_r = apply_rope_pairs(kva[:, :, None, r:], rpos, inv_freq,
+                                   cs)[:, :, 0]
+        if self.decode:
+            if per_row:
+                row_upd = jax.vmap(
+                    lambda cache, u, s: lax.dynamic_update_slice(
+                        cache, u, (s, 0)))
+                cc.value = row_upd(cc.value, c, starts)
+                ckr.value = row_upd(ckr.value, k_r, starts)
+            else:
+                cc.value = lax.dynamic_update_slice(cc.value, c,
+                                                    (0, start, 0))
+                ckr.value = lax.dynamic_update_slice(ckr.value, k_r,
+                                                     (0, start, 0))
+                idx.value = start + T
+        if not self.decode or (T > 1 and not per_row):
+            # training, or a block of prompt tokens on a fresh cache:
+            # keys and values per head, attention within the block
+            with jax.named_scope("latent_expand"):
+                kv = jnp.einsum("btr,rhd->bthd", c.astype(self.dtype),
+                                w_kvb.astype(self.dtype)
+                                ).astype(jnp.float32)
+            k = jnp.concatenate(
+                [kv[..., :dn],
+                 jnp.broadcast_to(k_r[:, :, None], (B, T, H, dr))], axis=-1)
+            o = seqlib.reference_attention(
+                jnp.concatenate([q_n, q_r], axis=-1), k, kv[..., dn:],
+                causal=True, scale=scale)
+        else:
+            with jax.named_scope("latent_absorb"):
+                w = w_kvb.astype(jnp.float32)
+                q_lat = jnp.einsum("bthd,rhd->bthr", q_n, w[..., :dn])
+                s = (jnp.einsum("bthr,bkr->bhtk", q_lat, cc.value)
+                     + jnp.einsum("bthd,bkd->bhtk", q_r, ckr.value)) * scale
+                q_pos = rpos if per_row else rpos[None]       # [B | 1, T]
+                mask = jnp.arange(self.max_len) <= q_pos[..., None]
+                p = jax.nn.softmax(jnp.where(mask[:, None], s, -jnp.inf),
+                                   axis=-1)
+                o_lat = jnp.einsum("bhtk,bkr->bthr", p, cc.value)
+                o = jnp.einsum("bthr,rhd->bthd", o_lat, w[..., dn:])
+        o = o.reshape(B, T, H * dv)
+        if self.gate:
+            with jax.named_scope("attn_gate"):
+                o = o * jax.nn.sigmoid(
+                    dense(H * dv, "gate")(x).astype(jnp.float32))
+        return dense(E, "out")(o.astype(self.dtype))
+
+
 class MoEMLP(nn.Module):
     """Expert-parallel MLP: tokens routed over ``expert_axis`` with the
     all-to-all dispatch of parallel/expert.py.
@@ -371,18 +542,26 @@ class MoEMLP(nn.Module):
 
 
 class ExpertFFN(nn.Module):
-    """Top-k gated (ReGLU) expert feed-forward whose weights exist only for
-    the experts ``held`` = ``(first, count)`` of ``n_experts`` (None: all):
-    one chip's share of an expert-parallel layer, routed over all experts
-    by a float32 router, dropless (``parallel/expert.held_experts``).  What
-    the experts held elsewhere would add is left out; under an expert axis
-    the exchange that brings it in wraps this module.
+    """Top-k gated expert feed-forward whose weights exist only for the
+    experts ``held`` = ``(first, count)`` of ``n_experts`` (None: all): one
+    chip's share of an expert-parallel layer, routed over all experts by a
+    float32 router, dropless (``parallel/expert.held_experts``).  What the
+    experts held elsewhere would add is left out; under an expert axis the
+    exchange that brings it in wraps this module.
 
     ``router_in`` is what the router reads: ``Block`` hands it the
-    attention's normed input, the block's pre-attention state.  The
-    counters ``routes_held``, ``rows_computed`` and ``rows_moved``, the
-    chosen ``experts`` and the float32 ``router_logits`` are sown to the
-    ``moe`` collection (``mutable=["moe"]``), as ``MoEMLP`` sows its loss.
+    attention's normed input or the feed-forward's own (``Block.
+    router_reads``).  ``gate`` names the rule that turns the router's
+    logits into the chosen experts and their weights (``"softmax"``:
+    ``parallel/expert.softmax_gate``; ``"sigmoid"``: ``sigmoid_gate`` with
+    the selection-only ``router_bias`` and ``route_scale``), ``act`` the experts'
+    gate activation (``"relu"``: ReGLU, ``"silu"``: SwiGLU).
+    ``shared_width`` > 0 adds a gated feed-forward of that width that
+    every token goes through (the shared experts as one; scope
+    ``shared_experts``).  The counters ``routes_held``, ``rows_computed``
+    and ``rows_moved``, the chosen ``experts`` and the float32
+    ``router_logits`` are sown to the ``moe`` collection
+    (``mutable=["moe"]``), as ``MoEMLP`` sows its loss.
     """
 
     n_experts: int
@@ -390,6 +569,10 @@ class ExpertFFN(nn.Module):
     width: int
     held: Optional[Tuple[int, int]] = None
     dtype: jnp.dtype = jnp.float32
+    act: str = "relu"
+    gate: str = "softmax"
+    route_scale: float = 1.0
+    shared_width: int = 0
 
     @nn.compact
     def __call__(self, u, router_in):  # both [B, T, E]
@@ -406,9 +589,30 @@ class ExpertFFN(nn.Module):
         with jax.named_scope("route"):
             logits = jnp.dot(router_in.reshape(B * T, E).astype(jnp.float32),
                              router, precision=lax.Precision.HIGHEST)
+        if self.gate == "sigmoid":
+            bias = self.param("router_bias", nn.initializers.zeros,
+                              (self.n_experts,), jnp.float32)
+            gate = functools.partial(
+                eplib.sigmoid_gate, bias=bias.astype(jnp.float32),
+                scale=self.route_scale)
+        elif self.gate == "softmax":
+            gate = eplib.softmax_gate
+        else:
+            raise ValueError(f"unknown gate {self.gate!r}")
+        x = u.reshape(B * T, E).astype(self.dtype)
         out, stats = eplib.held_experts(
-            u.reshape(B * T, E).astype(self.dtype), logits, self.k, first,
-            w_gate, w_up, w_down)
+            x, logits, self.k, first, w_gate, w_up, w_down, gate=gate,
+            act=getattr(jax.nn, self.act))
+        if self.shared_width:
+            with jax.named_scope("shared_experts"):
+                def dense(features, name):
+                    return nn.Dense(features, dtype=self.dtype,
+                                    use_bias=False, name=name)
+
+                hidden = (getattr(jax.nn, self.act)(
+                    dense(self.shared_width, "shared_gate")(x))
+                    * dense(self.shared_width, "shared_up")(x))
+                out = out + dense(E, "shared_down")(hidden)
         if not self.is_initializing():
             for name, value in {**stats, "router_logits": logits}.items():
                 self.sow("moe", name, value)
@@ -446,35 +650,92 @@ class Block(nn.Module):
     use_bias: bool = True
     # n_experts > 0: the feed-forward is an ExpertFFN (top-``moe_k`` of
     # ``n_experts``, ``experts_held`` of them here, each ``expert_width``
-    # wide) whose router reads the attention's input.
+    # wide) whose router reads the attention's input, or the feed-forward's
+    # own with ``router_reads="ffn_input"``.
     n_experts: int = 0
     experts_held: Optional[Tuple[int, int]] = None
     expert_width: int = 0
+    expert_act: str = "relu"
+    expert_gate: str = "softmax"
+    route_scale: float = 1.0
+    shared_width: int = 0
+    router_reads: str = "attention_input"
+    # kv_rank > 0: latent attention (see LatentAttention); ``head_dim`` is
+    # then a query's width, ``rope_dim`` of it rotary.
+    kv_rank: int = 0
+    rope_dim: int = 0
+    v_dim: int = 0
+    yarn: Optional[Tuple[float, ...]] = None
+    attn_gate: bool = False
+    # The dense feed-forward: "gelu" (two matrices, ``mlp_ratio`` wide) or
+    # "swiglu" (gated, three matrices, ``mlp_width`` wide).
+    mlp: str = "gelu"
+    mlp_width: int = 0
+    # FarSkip wiring: each sub-block reads the stream as it stood BEFORE
+    # the previous sub-block's output was added.  The block then takes and
+    # returns the pair (stream, stream one sub-block back).
+    farskip: bool = False
+
+    def _attention(self):
+        if self.kv_rank:
+            if self.attn_impl not in ("local", "flash") or (
+                    self.attn_impl != "local" and not self.decode):
+                raise ValueError(
+                    f"latent attention runs attn_impl='local' (got "
+                    f"{self.attn_impl!r}): ops/flash.py has no separate qk "
+                    f"and v head sizes")
+            return LatentAttention(
+                self.num_heads, self.head_dim, self.rope_dim, self.v_dim,
+                self.kv_rank, dtype=self.dtype, decode=self.decode,
+                max_len=self.max_len, rope_base=self.rope_base,
+                yarn=self.yarn, norm_eps=self.norm_eps, gate=self.attn_gate)
+        return SPAttention(self.num_heads, self.head_dim, self.attn_impl,
+                           self.seq_axis, self.dtype, decode=self.decode,
+                           max_len=self.max_len, window=self.window,
+                           num_kv_heads=self.num_kv_heads,
+                           rope=self.rope, rope_base=self.rope_base,
+                           use_bias=self.use_bias)
 
     @nn.compact
-    def __call__(self, x, pos_offset=0):
+    def __call__(self, x, pos_offset=0, lag=None):
+        # (no helper method calls a submodule here: flax would put the
+        # method's name into every operation's path, ``/Block_n/Dense_n/``,
+        # which the benchmark's readers find operations by)
+        if self.router_reads not in ("attention_input", "ffn_input"):
+            raise ValueError(f"unknown router_reads {self.router_reads!r}")
         E = x.shape[-1]
-        a = _norm(self.norm, self.norm_eps)(x)
-        x = x + SPAttention(self.num_heads, self.head_dim, self.attn_impl,
-                            self.seq_axis, self.dtype, decode=self.decode,
-                            max_len=self.max_len, window=self.window,
-                            num_kv_heads=self.num_kv_heads,
-                            rope=self.rope, rope_base=self.rope_base,
-                            use_bias=self.use_bias)(a, pos_offset)
-        h = _norm(self.norm, self.norm_eps)(x)
+        a = _norm(self.norm, self.norm_eps)(lag if self.farskip else x)
+        mid = x + self._attention()(a, pos_offset)
+        h = _norm(self.norm, self.norm_eps)(x if self.farskip else mid)
+
+        def dense(features):
+            return nn.Dense(features, dtype=self.dtype,
+                            use_bias=self.use_bias)
+
         if self.n_experts:
-            return x + ExpertFFN(self.n_experts, self.moe_k,
-                                 self.expert_width, self.experts_held,
-                                 dtype=self.dtype)(h, a)
-        if self.moe_axis is not None:
-            return x + MoEMLP(self.moe_experts_per_device, self.mlp_ratio,
-                              self.moe_axis,
-                              capacity_factor=self.moe_capacity_factor,
-                              k=self.moe_k, dtype=self.dtype)(h)
-        h = nn.Dense(E * self.mlp_ratio, dtype=self.dtype,
-                     use_bias=self.use_bias)(h)
-        h = nn.gelu(h)
-        return x + nn.Dense(E, dtype=self.dtype, use_bias=self.use_bias)(h)
+            h = ExpertFFN(
+                self.n_experts, self.moe_k, self.expert_width,
+                self.experts_held, dtype=self.dtype, act=self.expert_act,
+                gate=self.expert_gate, route_scale=self.route_scale,
+                shared_width=self.shared_width)(
+                    h, h if self.router_reads == "ffn_input" else a)
+        elif self.moe_axis is not None:
+            h = MoEMLP(self.moe_experts_per_device, self.mlp_ratio,
+                       self.moe_axis,
+                       capacity_factor=self.moe_capacity_factor,
+                       k=self.moe_k, dtype=self.dtype)(h)
+        # (a module is named when it is made: in the order it is used)
+        elif self.mlp == "swiglu":
+            gate = dense(self.mlp_width)(h)
+            hidden = jax.nn.silu(gate) * dense(self.mlp_width)(h)
+            h = dense(E)(hidden)
+        elif self.mlp == "gelu":
+            hidden = nn.gelu(dense(E * self.mlp_ratio)(h))
+            h = dense(E)(hidden)
+        else:
+            raise ValueError(f"unknown mlp {self.mlp!r}")
+        out = mid + h
+        return (out, mid) if self.farskip else out
 
 
 class TransformerLM(nn.Module):
@@ -521,6 +782,28 @@ class TransformerLM(nn.Module):
     n_experts: int = 0
     experts_held: Optional[Tuple[int, int]] = None
     expert_width: int = 0
+    # The expert layer's rules (see ExpertFFN): the experts' activation,
+    # the gate with its scale, the shared experts' width, what the router
+    # reads; and how many LEADING layers keep a dense feed-forward.
+    expert_act: str = "relu"
+    expert_gate: str = "softmax"
+    route_scale: float = 1.0
+    shared_width: int = 0
+    router_reads: str = "attention_input"
+    dense_layers: int = 0
+    # Latent attention (see LatentAttention): ``kv_rank`` > 0 turns it on,
+    # ``head_dim`` is then a query's width with ``rope_dim`` of it rotary,
+    # ``v_dim`` a value's; ``yarn`` the six YaRN numbers (``yarn_rope``).
+    kv_rank: int = 0
+    rope_dim: int = 0
+    v_dim: int = 0
+    yarn: Optional[Tuple[float, ...]] = None
+    attn_gate: bool = False
+    # The dense feed-forward ("gelu" | "swiglu" of ``mlp_width``) and the
+    # FarSkip residual wiring (see Block).
+    mlp: str = "gelu"
+    mlp_width: int = 0
+    farskip: bool = False
 
     @nn.compact
     def __call__(self, tokens, pos_offset=0, return_prehead: bool = False):
@@ -544,9 +827,11 @@ class TransformerLM(nn.Module):
             if layout is not None and len(layout) != self.depth:
                 raise ValueError(f"{name} has {len(layout)} entries for "
                                  f"{self.depth} layers")
+        lag = x
         for i in range(self.depth):
             windowed = self.window_layout is None or self.window_layout[i]
             rotated = self.rope_layout is None or self.rope_layout[i]
+            sparse = i >= self.dense_layers
             x = Block(self.num_heads, self.head_dim,
                       attn_impl=self.attn_impl, seq_axis=self.seq_axis,
                       moe_axis=self.moe_axis,
@@ -559,9 +844,21 @@ class TransformerLM(nn.Module):
                       rope=self.pos_emb == "rope" and bool(rotated),
                       rope_base=self.rope_base, norm=self.norm,
                       norm_eps=self.norm_eps, use_bias=self.use_bias,
-                      n_experts=self.n_experts,
+                      n_experts=self.n_experts if sparse else 0,
                       experts_held=self.experts_held,
-                      expert_width=self.expert_width)(x, pos_offset)
+                      expert_width=self.expert_width,
+                      expert_act=self.expert_act,
+                      expert_gate=self.expert_gate,
+                      route_scale=self.route_scale,
+                      shared_width=self.shared_width,
+                      router_reads=self.router_reads,
+                      kv_rank=self.kv_rank, rope_dim=self.rope_dim,
+                      v_dim=self.v_dim, yarn=self.yarn,
+                      attn_gate=self.attn_gate, mlp=self.mlp,
+                      mlp_width=self.mlp_width,
+                      farskip=self.farskip)(x, pos_offset, lag)
+            if self.farskip:
+                x, lag = x
         x = _norm(self.norm, self.norm_eps)(x)
         # Bias-free explicit unembedding (standard for LMs) so callers can
         # feed (pre-head activations, head matrix) to the fused

@@ -1,4 +1,4 @@
-"""Metrics registry: counters + log2-bucketed histograms.
+"""Metrics registry: counters, gauges + log2-bucketed histograms.
 
 The in-memory store behind ``torchmpi_tpu.obs`` (docs/OBSERVABILITY.md).
 Deliberately dependency-free (no jax, no numpy): the registry must be
@@ -55,12 +55,13 @@ class _Hist:
 
 
 class Registry:
-    """Counter + histogram store with JSONL/Prometheus exposition."""
+    """Counter, gauge + histogram store with JSONL/Prometheus exposition."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
         self._counters: Dict[Tuple[str, LabelKey], float] = {}
         self._hists: Dict[Tuple[str, LabelKey], _Hist] = {}
+        self._gauges: Dict[Tuple[str, LabelKey], float] = {}
 
     # -- mutation ----------------------------------------------------------
 
@@ -68,6 +69,11 @@ class Registry:
         key = (name, _label_key(labels))
         with self._lock:
             self._counters[key] = self._counters.get(key, 0) + value
+
+    def gauge_set(self, name: str, value: float, **labels) -> None:
+        """A value that is SET, not summed (a size fixed at construction)."""
+        with self._lock:
+            self._gauges[(name, _label_key(labels))] = value
 
     def hist_observe(self, name: str, value: float, **labels) -> None:
         key = (name, _label_key(labels))
@@ -111,12 +117,17 @@ class Registry:
         with self._lock:
             self._counters.clear()
             self._hists.clear()
+            self._gauges.clear()
 
     # -- reads -------------------------------------------------------------
 
     def counter(self, name: str, **labels) -> float:
         """Current value of one counter series (0 if never incremented)."""
         return self._counters.get((name, _label_key(labels)), 0)
+
+    def gauge(self, name: str, **labels) -> Optional[float]:
+        """Current value of one gauge series (None if never set)."""
+        return self._gauges.get((name, _label_key(labels)))
 
     def counter_total(self, name: str) -> float:
         """Sum of a counter across every label combination."""
@@ -127,7 +138,8 @@ class Registry:
     def names(self) -> List[str]:
         with self._lock:
             return sorted({n for n, _ in self._counters}
-                          | {n for n, _ in self._hists})
+                          | {n for n, _ in self._hists}
+                          | {n for n, _ in self._gauges})
 
     def snapshot(self, best_effort: bool = False) -> List[dict]:
         """Every series as a JSON-ready record (the JSONL dump body and
@@ -145,6 +157,9 @@ class Registry:
             out: List[dict] = []
             for (name, lk), v in sorted(self._counters.items()):
                 out.append({"kind": "counter", "name": name,
+                            "labels": dict(lk), "value": v})
+            for (name, lk), v in sorted(self._gauges.items()):
+                out.append({"kind": "gauge", "name": name,
                             "labels": dict(lk), "value": v})
             for (name, lk), h in sorted(self._hists.items()):
                 out.append({"kind": "hist", "name": name,
@@ -174,10 +189,10 @@ def prometheus_lines(records: List[dict]) -> Iterator[str]:
     seen_type = set()
     for rec in records:
         name, labels = rec.get("name"), rec.get("labels", {})
-        if rec.get("kind") == "counter":
+        if rec.get("kind") in ("counter", "gauge"):
             if name not in seen_type:
                 seen_type.add(name)
-                yield f"# TYPE {name} counter"
+                yield f"# TYPE {name} {rec['kind']}"
             yield f"{name}{_prom_labels(labels)} {_prom_num(rec['value'])}"
         elif rec.get("kind") == "hist":
             if name not in seen_type:

@@ -216,10 +216,11 @@ def _combine_bwd(dtype, res, g):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def _held_part(u, probs, order, sizes, w_gate, w_up, w_down):
+def _held_part(act, u, probs, order, sizes, w_gate, w_up, w_down):
     """``sum_j probs[j, t] * f_e(u[t])`` over token t's live routes:
     ``order`` sorts the rank-major routes ``[k * T]`` by expert, the live
-    ones (``sizes`` of them an expert held) first."""
+    ones (``sizes`` of them an expert held) first; ``act`` is the
+    activation on the experts' gate product."""
     n_live = sizes.sum()
     with jax.named_scope("dispatch"):
         rows = _dispatch(u, order, n_live)
@@ -228,7 +229,7 @@ def _held_part(u, probs, order, sizes, w_gate, w_up, w_down):
             return lax.ragged_dot(x, w.astype(x.dtype), sizes,
                                   preferred_element_type=jnp.float32)
 
-        hidden = jax.nn.relu(product(rows, w_gate)) * product(rows, w_up)
+        hidden = act(product(rows, w_gate)) * product(rows, w_up)
         y = product(hidden.astype(u.dtype), w_down)
     with jax.named_scope("combine"):
         # the cast of ``y`` to the rows' type rides on the kernel, and its
@@ -236,23 +237,44 @@ def _held_part(u, probs, order, sizes, w_gate, w_up, w_down):
         return _combine(u.dtype, y, probs, order, n_live)
 
 
+def softmax_gate(logits, k: int):
+    """Top-k of the router's logits, weighted by the softmax over the k
+    chosen (SmallThinker's gate).  ``logits`` [T, n_experts] float32 ->
+    ``(experts [T, k] int32, weights [T, k] float32)``."""
+    scores, experts = lax.top_k(logits.astype(jnp.float32), k)
+    return experts, jax.nn.softmax(scores, axis=-1)
+
+
+def sigmoid_gate(logits, k: int, bias, scale: float):
+    """``deepseek_v3``'s gate without groups: scores are the logits'
+    sigmoids, the chosen are the top-k of ``scores + bias`` (the bias only
+    SELECTS), the weights the chosen scores over their sum, times
+    ``scale``.  Same shapes as :func:`softmax_gate`."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, experts = lax.top_k(scores + bias, k)
+    chosen = jnp.take_along_axis(scores, experts, axis=-1)
+    return experts, chosen / (chosen.sum(-1, keepdims=True) + 1e-20) * scale
+
+
 def held_experts(u, router_logits, k: int, first: int, w_gate, w_up,
-                 w_down):
-    """The held experts' part of a top-k gated (ReGLU) expert layer.
+                 w_down, *, gate=softmax_gate, act=jax.nn.relu):
+    """The held experts' part of a top-k gated expert layer.
 
     u: [T, D] tokens; router_logits: [T, n_experts] over ALL experts;
-    ``w_gate``, ``w_up`` [count, D, F] and ``w_down`` [count, F, D] are the
-    weights of the experts ``[first, first + count)``, the only ones that
-    exist here.  Returns ``(out, stats)`` with
+    ``gate(router_logits, k)`` gives the chosen experts and their weights
+    (:func:`softmax_gate`, or :func:`sigmoid_gate` with its bias and scale
+    bound); ``w_gate``, ``w_up`` [count, D, F] and ``w_down`` [count, F, D]
+    are the weights of the experts ``[first, first + count)``, the only
+    ones that exist here.  Returns ``(out, stats)`` with
 
-        out[t] = sum_{j: e_j held} p_j * w_down[e_j] (relu(w_gate[e_j] u_t)
+        out[t] = sum_{j: e_j held} p_j * w_down[e_j] (act(w_gate[e_j] u_t)
                                                       * (w_up[e_j] u_t))
 
-    where ``e`` are token t's top-k experts and ``p`` the softmax of their
-    k logits, normalised over all k whether held or not.  What the other
-    experts would add is left out: under an expert axis this is what
-    :func:`moe_layer`'s exchange would wrap, and nothing here stands in
-    for it.
+    where ``e`` are token t's chosen experts and ``p`` their weights, the
+    gate's over all k whether held or not (``act``: relu for ReGLU experts,
+    silu for SwiGLU).  What the other experts would add is left out: under
+    an expert axis this is what :func:`moe_layer`'s exchange would wrap,
+    and nothing here stands in for it.
 
     Dropless: the routes are sorted by expert into a buffer of ``T * k``
     rows, the worst case, so no imbalance drops a route.  The routes to a
@@ -274,13 +296,15 @@ def held_experts(u, router_logits, k: int, first: int, w_gate, w_up,
     depends on its own row alone, the weight gradients' contraction over
     rows stops at ``sizes`` (shown on the chip with NaN there, PERF.md),
     and both combines stop at ``n_live``.  With every expert held every
-    row is live and the same code moves them all.
+    row is live and the same code moves them all.  The buffer may be
+    smaller than one block (a pooled decode step routes a row a slot):
+    the block is then the buffer.
 
-    Router top-k and softmax run in float32, the products in ``u``'s type
-    with float32 accumulation.  The sort runs once, outside the part that
-    the backward pass recomputes (its integers are the residuals); the
-    buffers are recomputed, not kept: at the worst case they are
-    ``n_experts / count`` times what the routes need.
+    The gate runs in float32, the products in ``u``'s type with float32
+    accumulation.  The sort runs once, outside the part that the backward
+    pass recomputes (its integers are the residuals); the buffers are
+    recomputed, not kept: at the worst case they are ``n_experts / count``
+    times what the routes need.
 
     ``stats``: ``routes_held`` (routes to a held expert), ``rows_computed``
     (rows the grouped products are told to compute), ``rows_moved`` (rows
@@ -293,8 +317,7 @@ def held_experts(u, router_logits, k: int, first: int, w_gate, w_up,
             f"held experts [{first}, {first + count}) with k={k} do not "
             f"fit a router over {n_experts}")
     with jax.named_scope("route"):
-        scores, experts = lax.top_k(router_logits.astype(jnp.float32), k)
-        probs = jax.nn.softmax(scores, axis=-1)
+        experts, weights = gate(router_logits, k)
         held = (experts >= first) & (experts < first + count)
         group = jnp.where(held, experts - first, count)
     with jax.named_scope("dispatch"):
@@ -303,14 +326,34 @@ def held_experts(u, router_logits, k: int, first: int, w_gate, w_up,
         key = group.T.reshape(-1)
         order = jnp.argsort(key, stable=True)        # held first, by expert
         sizes = (key[:, None] == jnp.arange(count)).sum(0, dtype=jnp.int32)
-    out = jax.checkpoint(_held_part)(u, probs.T, order, sizes, w_gate, w_up,
-                                     w_down)
+    out = jax.checkpoint(_held_part, static_argnums=(0,))(
+        act, u, weights.T, order, sizes, w_gate, w_up, w_down)
     rows = sizes.sum()
     block = moe.block_rows(key.shape[0])
     return out.astype(u.dtype), {
         "routes_held": held.sum(dtype=jnp.int32), "rows_computed": rows,
         "rows_moved": (rows + block - 1) // block * block,
         "experts": experts}
+
+
+def decode_counts(moe_collection, live):
+    """What a pooled decode step's expert layers did, from the ``moe``
+    collection the step sowed (one row a slot): ``[layers, 2]`` int32, a
+    layer (in the order of the modules' paths) the experts that at least
+    one LIVE row chose and the live rows' routes; ``live`` [rows] bool
+    leaves the idle slots' rows out.  None without an expert layer."""
+    from flax.traverse_util import flatten_dict
+
+    flat = flatten_dict(moe_collection)
+    rows = []
+    for path in sorted(p for p in flat if p[-1] == "experts"):
+        chosen, = flat[path]                     # [rows, k]: one call
+        n_experts = flat[path[:-1] + ("router_logits",)][0].shape[-1]
+        touched = jnp.zeros((n_experts,), jnp.int32).at[
+            jnp.where(live[:, None], chosen, n_experts)].max(1, mode="drop")
+        rows.append(jnp.stack([touched.sum(), live.sum(dtype=jnp.int32)
+                               * chosen.shape[-1]]))
+    return jnp.stack(rows) if rows else None
 
 
 def record_counters(moe_collection) -> None:

@@ -14,6 +14,14 @@ telemetry (TTFT / inter-token latency histograms, queue-depth and
 slot-occupancy gauges) rides the obs registry as ``tm_serving_*`` when
 telemetry is on.
 
+The pool carries whatever the decode model declares as its ``cache``
+collection, a row a slot: per-head keys and values (``SPAttention``;
+61,440 B a token for a 30-layer model of 2 kv heads of 128 in float32)
+or a latent and one rotary key (``LatentAttention``; 19,584 B a token for
+nine layers of rank 512 + 32), read as ``ReplicaEngine.
+cache_bytes_per_token``.  It reserves ``slot_tokens`` positions for every
+layer alike, whatever its kind.
+
 Decode is per-request greedy OR sampled (temperature / top-k / top-p /
 seed on each :class:`Request`), bitwise-reproducible given (seed,
 prompt) — which is also what keeps re-routing token-exact.  Prefill

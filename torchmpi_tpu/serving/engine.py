@@ -38,11 +38,14 @@ Work accounting: ``stats`` counts executable invocations and
 draft forwards at the proposer's ``unit_weight``) — the noise-immune
 clock ``benchmarks/serving_bench.py`` compares schedules on.
 
-The engine is time-free and telemetry-free on purpose (the one
-exception: the prefill-compile counter above, which is a property of
-the engine's own jit keying): the scheduler owns the clock, the SLO
-histograms, and the fault hooks, so the engine stays a pure slot/cache
-mechanism that tests can drive tick by tick.
+The engine is time-free and telemetry-free on purpose (the
+exceptions are properties of the engine's own programs: the
+prefill-compile counter above, the gauge of what a cached token costs,
+and, for a model with expert layers, what the pooled step itself
+counted: experts touched and routes, read with the tokens): the
+scheduler owns the clock, the SLO histograms, and the fault hooks, so
+the engine stays a pure slot/cache mechanism that tests can drive tick
+by tick.
 """
 
 from __future__ import annotations
@@ -165,6 +168,25 @@ class ReplicaEngine:
                              shapes)
         self._cache = (jax.device_put(cache, device)
                        if device is not None else cache)
+        from .. import obs
+        from flax.traverse_util import flatten_dict
+
+        #: What a cached token costs, summed over the layers' cache leaves
+        #: (per-head keys and values, or a latent and its rotary key).
+        self.cache_bytes_per_token = sum(
+            int(np.prod(s.shape[2:])) * s.dtype.itemsize
+            for s in jax.tree.leaves(shapes) if len(s.shape) >= 2)
+        obs.registry().gauge_set("tm_serving_cache_bytes_per_token",
+                                 self.cache_bytes_per_token, replica=name)
+        # The expert layers (by their router's path, the order in which
+        # the decode step counts them), each with its two counters' handles.
+        count = obs.registry().counter_handle
+        self._expert_counters = [
+            (count("tm_moe_experts_touched_total", layer="/".join(path[:-1])),
+             count("tm_moe_decode_routes_total", layer="/".join(path[:-1])))
+            for path in sorted(flatten_dict(params))
+            if path[-1] == "router"]
+        self._expert_steps = count("tm_moe_decode_steps_total", replica=name)
 
     def _init_serving(self, cfg, name, slots, st, *, sample,
                       prefill_bucket, spec_k, draft,
@@ -323,10 +345,18 @@ class ReplicaEngine:
                             sampling=sampling)
 
     def _backend_step(self, toks: np.ndarray, pos: np.ndarray, sampling):
-        self._cache, nxt = slot_decode_step(
+        self._cache, nxt, counts = slot_decode_step(
             self.dmodel, self.params, self._cache, toks, pos,
-            sampling=sampling)
-        return np.asarray(nxt)
+            sampling=sampling, counted=True)
+        # ONE blocking read: the counts are ready when the tokens are
+        nxt, counts = jax.device_get((nxt, counts))
+        if counts is not None:
+            self._expert_steps()
+            for (touched, routes), row in zip(self._expert_counters,
+                                              counts.tolist()):
+                touched(row[0])
+                routes(row[1])
+        return nxt
 
     def _backend_verify(self, toks: np.ndarray, pos: np.ndarray,
                         sampling):
