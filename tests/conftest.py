@@ -94,3 +94,27 @@ def hier_runtime():
     mesh = mpi.init(mpi.Config(dcn_size=2))
     yield mesh
     mpi.stop()
+
+
+@pytest.fixture()
+def chip_rule(monkeypatch):
+    """Call it to make a ``decode=True`` prompt block attend as on the chip:
+    through the flash forward kernel, interpreted here
+    (``models.transformer.prefill_runs_flash``; the layer and the serving
+    engine both look the rule up on that module).  The jitted programs are
+    keyed by the model and the shapes, not by the rule, so what was traced
+    under one answer must not serve the other: the caches go, at the switch
+    and after the test."""
+    from torchmpi_tpu.models import transformer
+
+    rule = transformer.prefill_runs_flash
+
+    def on():
+        monkeypatch.setattr(
+            transformer, "prefill_runs_flash",
+            lambda T, per_row, platform=None: rule(T, per_row, "tpu"))
+        jax.clear_caches()
+
+    yield on
+    monkeypatch.undo()
+    jax.clear_caches()

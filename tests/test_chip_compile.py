@@ -266,6 +266,94 @@ def test_expert_row_kernels_compile_at_a_decode_steps_rows(one_chip, call,
     assert ident == ("moe.combine" if call == "combine" else "moe.gather")
 
 
+def _harness():
+    """``chipbench/harness.py``: the benchmark's files sit beside ``tests/``."""
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    from chipbench import harness
+
+    return harness
+
+
+def _served_starcoder2(one_chip, layers=None):
+    """``starcoder2-3b-serve`` as the served cells build it (its widths, its
+    slots, bfloat16 weights; ``layers`` cuts the depth), as shapes on the
+    described chip: -> (decode model, params, slots, sampling operands)."""
+    harness = _harness()
+    cell = harness.resolve(harness.load_manifest(), "sc2-3b-serve-sat")
+    if layers is not None:
+        cell.config["num_hidden_layers"] = layers
+    model = harness.build_model(cell)
+    srv = cell.config["serving"]
+    dmodel = model.clone(decode=True, max_len=srv["slot_tokens"])
+    params = jax.tree.map(
+        lambda a: _sds(a.shape, jnp.bfloat16, one_chip),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                       jnp.zeros((1, 8), jnp.int32))["params"])
+
+    def sampling(rows):
+        return tuple(_sds((rows,), dt, one_chip) for dt in (
+            jnp.uint32, jnp.int32, jnp.float32, jnp.int32, jnp.float32))
+
+    return dmodel, params, srv["slots"], sampling
+
+
+@pytest.mark.parametrize("bucket", [64, 4096])
+def test_served_prefill_compiles_with_the_flash_kernel(one_chip, bucket):
+    # sc2-3b-serve-*'s prefill program at the smallest bucket (less than one
+    # block of the kernel: q and k padded inside it) and the largest, two
+    # layers of the thirty: 24 q / 2 kv heads of 128 in float32, window
+    # 4096.  On the chip a prompt's attention is ops/flash's forward kernel
+    # (models/transformer.prefill_runs_flash), and no [heads, T, T] array is
+    # left in the program.
+    from torchmpi_tpu.models.generate import _slot_prefill_jit
+
+    layers = 2
+    dmodel, params, _, sampling = _served_starcoder2(one_chip, layers)
+    compiled = _slot_prefill_jit.lower(
+        dmodel, params, _sds((1, bucket), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip), *sampling(1)).compile()
+    found = _kernels(compiled)
+    assert [ident for _, ident in found] == ["flash.fwd"] * layers
+    # traced once and called by every layer (transformer._prompt_attention),
+    # so named after that call and not after the layer: neither train
+    # roofline metric's pattern finds them
+    assert not any(FLASH_NAME.match(name) or XENT_NAME.match(name)
+                   for name, _ in found), found
+    assert not re.search(r"\[(?:\d+,)+%d,%d\]" % (bucket, bucket),
+                         compiled.as_text())
+    ma = compiled.memory_analysis()
+    # what the dense form needs for its scores alone at 4096: 1.6 GB
+    assert ma.temp_size_in_bytes < 0.6e9
+
+
+def test_served_decode_step_lowers_to_the_parents_text(one_chip):
+    # PR 32 changed what a PROMPT's attention runs and nothing a decode
+    # step runs: jit__slot_step_jit of starcoder2-3b-serve (30 layers, 5
+    # slots of 4608) lowers for the described chip to the text it had
+    # before (sha256 as PERF.md section 6 has it since PR 31).  A change
+    # that means to touch the step replaces the digest and says so there.
+    import hashlib
+
+    from torchmpi_tpu.models.generate import _slot_step_jit
+
+    dmodel, params, slots, sampling = _served_starcoder2(one_chip)
+    cache = jax.tree.map(
+        lambda a: _sds(a.shape, a.dtype, one_chip),
+        jax.eval_shape(lambda: dmodel.init(
+            jax.random.PRNGKey(0), jnp.zeros((slots, 1), jnp.int32),
+            pos_offset=jnp.zeros((slots,), jnp.int32)))["cache"])
+    text = _slot_step_jit.lower(
+        dmodel, params, cache, _sds((slots,), jnp.int32, one_chip),
+        _sds((slots,), jnp.int32, one_chip), *sampling(slots)).as_text()
+    assert "tpu_custom_call" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "132fe59526c5893ed71cbb5cd4bd2aa512c6c2511b22c729279ccf2caa2b5afa")
+
+
 def test_smallthinker_step_compiles_at_the_cells_sizes(chip):
     # The whole train step of the benchmark's st-21b-ep4-t8k
     # (chipbench/configs/smallthinker-21b-a3b.json) at its real sizes, as
@@ -275,13 +363,7 @@ def test_smallthinker_step_compiles_at_the_cells_sizes(chip):
     # grouped-matmul kernels of its own), and a 37984-row vocabulary slice,
     # no multiple of fused xent's tile.  It must fit what the chip's
     # allocator gives (bytes_limit 16,909,336,064 on a v5e; PERF.md).
-    import os
-    import sys
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    sys.path.insert(0, root)
-    from chipbench import harness
-
+    harness = _harness()
     manifest = harness.load_manifest()
     cell = harness.resolve(manifest, "st-21b-ep4-t8k")
     mesh = Mesh(np.asarray(chip.devices[:1]).reshape((1, 1)), mpi.WORLD_AXES)

@@ -660,3 +660,192 @@ def test_filter_logits_rows_matches_static_and_sentinels():
         logits, jnp.zeros((4,), jnp.float32),
         jnp.zeros((4,), jnp.int32), jnp.full((4,), 2.0, jnp.float32)))
     np.testing.assert_array_equal(noop, np.asarray(logits))
+
+
+# ---------------------------------------------------------------------------
+# A decode=True prompt block through the flash forward kernel
+# (models/transformer.prefill_runs_flash decides; the CPU keeps dense scores
+# unless a test calls conftest's chip_rule: the kernel is then interpreted)
+# ---------------------------------------------------------------------------
+
+from torchmpi_tpu.models import transformer  # noqa: E402
+from torchmpi_tpu.models.generate import (  # noqa: E402
+    slot_decode_step, slot_prefill, slot_write)
+
+
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+@pytest.mark.parametrize("T", [1, 2, 64, 4096])
+@pytest.mark.parametrize("per_row", [False, True])
+def test_prefill_runs_flash_rule(platform, T, per_row):
+    # platform x T x per_row: a prompt block on a fresh cache, on the chip,
+    # and nothing else.  Per-row T > 1 is the speculative verify step and
+    # the prefix-hit extend; T == 1 is the decode step.
+    want = platform == "tpu" and T > 1 and not per_row
+    assert transformer.prefill_runs_flash(T, per_row, platform) is want
+
+
+def test_prefill_runs_flash_reads_the_kernels_own_platform():
+    # With no platform given the rule asks what every kernel of ops/ asks:
+    # here the CPU (dense), and the chip wherever real lowering is forced,
+    # as tests/test_chip_compile.py forces it for a described device.
+    from torchmpi_tpu.ops import ring
+
+    assert not transformer.prefill_runs_flash(64, False)
+    ring.set_interpret(False)
+    try:
+        assert transformer.prefill_runs_flash(64, False)
+        assert not transformer.prefill_runs_flash(64, True)
+        assert not transformer.prefill_runs_flash(1, False)
+    finally:
+        ring.set_interpret(None)
+    assert not transformer.prefill_runs_flash(64, False)
+
+
+@pytest.fixture(scope="module")
+def gqa_window_lm():
+    # 4 q / 2 kv heads, a window smaller than the prompts below; sizes no
+    # other test of this process uses, so no other test's trace is met
+    model = TransformerLM(vocab=61, embed=32, depth=2, num_heads=4,
+                          num_kv_heads=2, head_dim=8, max_len=1040,
+                          window=24, pos_emb="rope")
+    params = jax.jit(model.init)(jax.random.PRNGKey(3),
+                                 jnp.zeros((1, 4), jnp.int32))["params"]
+    return model, params
+
+
+def _padded_prompt(bucket, true_len, seed=0):
+    prompt = np.zeros((1, bucket), np.int32)
+    prompt[0, :true_len] = np.random.RandomState(seed).randint(
+        0, 61, size=true_len)
+    return prompt
+
+
+def _prefill(dmodel):
+    """The layer's own prefill call: (params, prompt) -> (logits, cache)."""
+    return lambda p, x: dmodel.apply({"params": p}, x, pos_offset=0,
+                                     mutable=["cache"])
+
+
+def _count_kernels(fn, *args):
+    """Kernel calls a run of ``fn`` makes.  Interpreted, a kernel leaves no
+    custom call behind, so the calls are counted in the jaxpr, every call
+    site of a shared inner jaxpr for itself (the layers share one trace of
+    the kernel, ``transformer._prompt_attention``)."""
+    def calls(jaxpr):
+        n = 0
+        for eqn in jaxpr.eqns:
+            n += eqn.primitive.name == "pallas_call"
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                n += calls(sub)
+        return n
+
+    return calls(jax.make_jaxpr(fn)(*args).jaxpr)
+
+
+# 64: less than the kernel's smallest block (128), so q and k are padded
+# inside it; 1024: two blocks of 512 each way, no padding.
+@pytest.mark.parametrize("bucket,true_len", [(64, 41), (1024, 900)])
+def test_slot_prefill_through_the_kernel_matches_dense(
+        chip_rule, gqa_window_lm, bucket, true_len):
+    model, params = gqa_window_lm
+    dmodel = model.clone(decode=True)
+    prompt = _padded_prompt(bucket, true_len)
+    dense_logits, _ = jax.jit(_prefill(dmodel))(params, prompt)
+    dense_cache, dense_first = slot_prefill(dmodel, params, prompt,
+                                            true_len=true_len)
+    assert _count_kernels(_prefill(dmodel), params, prompt) == 0
+    chip_rule()
+    assert _count_kernels(_prefill(dmodel), params, prompt) == 2  # a layer
+    # ... which share ONE trace of the kernel: a second layer costs no
+    # second lowering to Mosaic (set-up time on the chip)
+    shared = [eqn.params["jaxpr"] for eqn in jax.make_jaxpr(
+        _prefill(dmodel))(params, prompt).jaxpr.eqns
+        if eqn.params.get("name") == "_prompt_attention"]
+    assert len(shared) == 2 and shared[0] is shared[1]
+    logits, _ = jax.jit(_prefill(dmodel))(params, prompt)
+    cache, first = slot_prefill(dmodel, params, prompt, true_len=true_len)
+    np.testing.assert_array_equal(np.asarray(first),
+                                  np.asarray(dense_first))
+    # the real positions' logits (a pad's are never read)
+    np.testing.assert_allclose(
+        np.asarray(logits, np.float32)[:, :true_len],
+        np.asarray(dense_logits, np.float32)[:, :true_len],
+        rtol=2e-5, atol=2e-5)
+    # the cache is written by the same code either way: the first layer's
+    # rows are the same bits, the second's differ by the first's o
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(cache),
+                            jax.tree.leaves(dense_cache)):
+        if a.ndim:   # k and v, [1, max_len, 2, 8]; idx is a scalar
+            a, b = a[:, :true_len], b[:, :true_len]
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5,
+                                   atol=2e-5, err_msg=str(path))
+    layer0 = cache["Block_0"]["SPAttention_0"]
+    np.testing.assert_array_equal(
+        np.asarray(layer0["k"]),
+        np.asarray(dense_cache["Block_0"]["SPAttention_0"]["k"]))
+
+
+def _served_tokens(dmodel, params, prompt, true_len, steps):
+    """slot_prefill, the write into row 1 of a two-row pool, then the
+    pooled per-row step: -> (the tokens, the prompt's cache, the pool)."""
+    one, first = slot_prefill(dmodel, params, prompt, true_len=true_len)
+    pool = jax.tree.map(
+        lambda s: jnp.zeros((2,) + s.shape[1:], s.dtype)
+        if getattr(s, "ndim", 0) >= 1 else s, one)
+    pool = slot_write(pool, one, 1)
+    toks, pos = [int(np.asarray(first)[0])], true_len
+    for _ in range(steps - 1):
+        pool, nxt = slot_decode_step(
+            dmodel, params, pool, np.asarray([0, toks[-1]], np.int32),
+            np.asarray([0, pos], np.int32))
+        toks.append(int(np.asarray(nxt)[1]))
+        pos += 1
+    return toks, one, pool
+
+
+def test_kernel_prefill_then_the_untouched_steps_serve_the_dense_tokens(
+        gqa_window_lm, chip_rule):
+    # A prompt prefilled through the kernel, written to a pool row and
+    # decoded through the pooled per-row step, gives the tokens the dense
+    # prefill gives.  The step and the per-row extend hold no kernel.
+    from torchmpi_tpu.models.generate import (
+        _greedy_sampling, _slot_extend_jit, _slot_prefill_jit,
+        _slot_step_jit)
+
+    model, params = gqa_window_lm
+    dmodel = model.clone(decode=True, max_len=96)
+    true_len, steps = 41, 6
+    prompt = _padded_prompt(64, true_len, seed=4)
+    dense_toks, _, _ = _served_tokens(dmodel, params, prompt, true_len,
+                                      steps)
+    chip_rule()
+    toks, one, pool = _served_tokens(dmodel, params, prompt, true_len,
+                                     steps)
+    assert toks == dense_toks
+    assert _count_kernels(
+        lambda *a: _slot_prefill_jit(dmodel, *a), params, prompt,
+        jnp.asarray(true_len, jnp.int32), *_greedy_sampling(1)) == 2
+    assert _count_kernels(
+        lambda *a: _slot_step_jit(dmodel, *a), params, pool,
+        jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32),
+        *_greedy_sampling(2)) == 0
+    # the prefix-hit extend: a suffix at a per-row depth, T > 1
+    assert _count_kernels(
+        lambda *a: _slot_extend_jit(dmodel, *a), params, one,
+        jnp.zeros((1, 8), jnp.int32), jnp.asarray([16], jnp.int32),
+        jnp.asarray(8, jnp.int32), *_greedy_sampling(1)) == 0
+
+
+def test_generate_prefills_through_the_kernel_to_the_dense_tokens(
+        gqa_window_lm, chip_rule):
+    # generate()'s one full-prompt pass (a scalar offset, T > 1, a batch of
+    # two) is the same layer code: the same tokens, the scan behind it
+    # untouched.
+    model, params = gqa_window_lm
+    model = model.clone(max_len=64)
+    prompt = np.random.RandomState(6).randint(
+        0, 61, size=(2, 37)).astype(np.int32)
+    dense = np.asarray(generate(model, params, prompt, steps=5))
+    chip_rule()
+    np.testing.assert_array_equal(
+        np.asarray(generate(model, params, prompt, steps=5)), dense)

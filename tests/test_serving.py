@@ -782,3 +782,106 @@ def test_mid_speculation_kill_discards_draft_state(lm, tmp_path):
 # superseded by the static H1 import-discipline rule —
 # torchmpi_tpu/analysis/hostcheck.py, tests/test_hostcheck.py;
 # runtime anchors live in test_obs.py / test_faults.py.)
+
+
+# ---------------------------------------------------------------------------
+# The prefill through the flash forward kernel: the engine's side
+# (models/transformer.prefill_runs_flash; tests/test_generate.py has the
+# layer's side).  On the CPU the dense form runs unless a test calls
+# conftest's chip_rule.
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def gqa_lm():
+    # 4 q / 2 kv heads and a window smaller than the prompts; sizes no
+    # other test of this process uses (programs are keyed by the model)
+    model = TransformerLM(vocab=43, embed=32, depth=2, num_heads=4,
+                          num_kv_heads=2, head_dim=8, max_len=96,
+                          window=12, pos_emb="rope")
+    params = jax.jit(model.init)(jax.random.PRNGKey(2),
+                                 jnp.zeros((1, 4), jnp.int32))["params"]
+    return model, params
+
+
+def _kernel_reqs():
+    rng = np.random.RandomState(47)
+    shared = rng.randint(0, 43, size=(16,))
+    prompts = [np.concatenate([shared, rng.randint(0, 43, size=(n,))])
+               .astype(np.int32) for n in (3, 21, 5, 40)]
+    return [serving.Request(f"k{i}", p, max_new=5, arrival_s=0.001 * i)
+            for i, p in enumerate(prompts)]
+
+
+def _serve(model, params, **kw):
+    reqs = _kernel_reqs()
+    srv = serving.Server(model, params, replicas=1, slots=2,
+                         slot_tokens=96, prefill_bucket=32, **kw)
+    assert len(srv.run_trace(reqs, tick_seconds=0.001)) == len(reqs)
+    return {r.rid: r.tokens for r in reqs}, srv.router.replicas[0]
+
+
+def test_kernel_prefill_serves_the_dense_tokens_and_is_counted(
+        gqa_lm, chip_rule, tmp_path):
+    from torchmpi_tpu import obs
+
+    model, params = gqa_lm
+    dense_toks, dense_eng = _serve(model, params)
+    # padded: 19 -> 32, 37 -> 64, 21 -> 32, 56 -> 64
+    assert dense_eng.stats["prefill_tokens"] == 192
+    assert dense_eng.stats["prefill_kernel_tokens"] == 0   # the CPU: dense
+
+    chip_rule()
+    mpi.stop()
+    mpi.init(mpi.Config(dcn_size=1, obs="metrics",
+                        obs_dir=str(tmp_path / "obs")))
+    try:
+        obs.reset()
+        toks, eng = _serve(model, params)
+        assert toks == dense_toks
+        assert (eng.stats["prefill_kernel_tokens"]
+                == eng.stats["prefill_tokens"] == 192)
+        assert obs.registry().counter_total(
+            "tm_serving_prefill_kernel_tokens_total") == 192
+        # the count only rises, by the padded length of each admission
+        eng.admit(serving.Request("more", np.arange(9, dtype=np.int32),
+                                  max_new=2))
+        assert eng.stats["prefill_kernel_tokens"] == 192 + 32
+    finally:
+        mpi.stop()
+
+
+def test_prefix_hit_extend_stays_dense_and_uncounted(gqa_lm, chip_rule):
+    # A prefix hit's extend (a suffix at a per-row depth, T > 1) attends
+    # against the CACHE: dense on every platform, never counted, and the
+    # tokens are the full kernel prefill's.
+    model, params = gqa_lm
+    chip_rule()
+    miss_toks, _ = _serve(model, params)
+    toks, eng = _serve(model, params, prefix_cache=16, prefix_block=8)
+    assert toks == miss_toks
+    assert eng.stats["prefix_hits"] == 3
+    assert eng.stats["prefill_tokens"] > 32
+    assert eng.stats["prefill_kernel_tokens"] == 32   # the one miss
+
+
+def test_latent_and_tensor_parallel_prefills_count_no_kernel_tokens(
+        monkeypatch):
+    # Their prefills are their own (LatentAttention expands keys and values
+    # per head; tp_generate._block_prefill is dense): the rule is
+    # SPAttention's and the engine asks it for no other layer.
+    import types
+
+    from torchmpi_tpu.models import transformer
+    from torchmpi_tpu.serving.engine import ReplicaEngine
+    from torchmpi_tpu.serving.tp_engine import TPReplicaEngine
+
+    monkeypatch.setattr(transformer, "prefill_runs_flash",
+                        lambda T, per_row, platform=None: True)
+    dense = TransformerLM(vocab=43, embed=32, depth=1, num_heads=4,
+                          head_dim=8, max_len=32, pos_emb="rope")
+    latent = dense.clone(kv_rank=16, rope_dim=4, v_dim=8)
+    holds = lambda dmodel: types.SimpleNamespace(dmodel=dmodel)  # noqa: E731
+    assert ReplicaEngine._prefill_runs_flash(holds(dense), 64)
+    assert not ReplicaEngine._prefill_runs_flash(holds(latent), 64)
+    assert not TPReplicaEngine._prefill_runs_flash(holds(None), 64)

@@ -65,6 +65,58 @@ def apply_rope(x, pos, *, base: float = 10000.0):
                             x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
 
 
+def prefill_runs_flash(T: int, per_row: bool,
+                       platform: Optional[str] = None) -> bool:
+    """Which attention a ``decode=True`` call of :class:`SPAttention` runs
+    for its NEW tokens: the flash forward kernel (``ops/flash``; True) or
+    dense scores (False).  THE one rule: the layer decides by it at trace
+    time and ``serving/engine.py`` counts by it
+    (``stats["prefill_kernel_tokens"]``).  Not an ``attn_impl``, a
+    ``Config`` field or an environment variable: what the code can observe.
+
+    - ``T`` (static) and ``per_row``: only a prompt block on a FRESH cache
+      (a scalar offset and ``T > 1``: ``generate``'s one-pass prefill,
+      ``slot_prefill``) attends within itself, which is what the kernel
+      computes.  A single token, the speculative verify step ``[S, K+1]``
+      and the prefix-hit ``slot_extend`` (both per-row) attend against the
+      CACHE and stay dense.
+    - ``platform``: ``"tpu"`` where a Pallas kernel is compiled for the
+      chip, ``"cpu"`` where it would run in the interpreter (None: what
+      ``ops/ring._interpret_mode`` decides for every kernel of the
+      library).  The interpreter is correct but slow, so the CPU keeps the
+      dense form.  On the chip every ``T > 1`` takes the kernel, the
+      smallest buckets too: a 30-layer prefill of 64 to 512 tokens takes
+      the same time either way, within a millisecond, and from 1024 up the
+      kernel wins (PERF.md section 6, PR 32), so there is no threshold.
+    """
+    if platform is None:
+        from ..ops import ring
+
+        platform = "cpu" if ring._interpret_mode() else "tpu"
+    return platform == "tpu" and T > 1 and not per_row
+
+
+@functools.partial(jax.jit, static_argnames=("window", "block_q", "block_k",
+                                             "interpret"))
+def _prompt_attention(q, k, v, *, window, block_q, block_k, interpret):
+    """The prompt block's kernel call, traced ONCE a program: every layer
+    hands it the same shapes, and a jitted function met again inside a trace
+    is a call of the jaxpr it already has.  Without this each of a model's
+    layers traces the kernel and lowers it to Mosaic anew, which a process
+    pays before it can even look its program up in the compile cache: on
+    the chip 26 s of a WARM set-up for four 30-layer programs, every one of
+    them a cache hit (PERF.md section 6, PR 32).  The compiler inlines the
+    calls: the program is the same.  What ``flash_attention`` would read
+    from the runtime while tracing (the blocks, interpret mode) is an
+    argument here, so that it is part of what the trace is keyed by
+    (``Config.flash_prescale``, off by default, is still read inside)."""
+    from ..ops.flash import flash_attention
+
+    return flash_attention(q, k, v, causal=True, window=window,
+                           block_q=block_q, block_k=block_k,
+                           interpret=interpret)
+
+
 class SPAttention(nn.Module):
     num_heads: int
     head_dim: int
@@ -147,10 +199,12 @@ class SPAttention(nn.Module):
             # cache cannot serve one new global token a step).
             ulysses = (self.attn_impl in ("ulysses", "ulysses_flash")
                        and self.seq_axis is not None)
-            # "flash" is accepted as an alias of "local" here: decode
-            # attends against the cache with the einsum below either
-            # way (the train-time kernel never runs in decode), so a
-            # flash-trained model serves without rebinding attn_impl.
+            # "flash" and "local" are ONE thing here, so a flash-trained
+            # model serves without rebinding attn_impl: a token attends
+            # against the cache with the einsum below, a prompt block
+            # within itself, through the flash forward kernel or dense
+            # scores as prefill_runs_flash says (the platform and the
+            # static T decide, not attn_impl).
             if self.attn_impl not in ("local", "flash") and not ulysses:
                 raise ValueError(
                     f"decode=True supports attn_impl='local'/'flash' (or "
@@ -224,13 +278,33 @@ class SPAttention(nn.Module):
                 # FLOPs/memory).  Assumes start == 0, which is the only
                 # way the scalar-offset serving path produces T > 1;
                 # chunked prefill with history would need the
-                # cache-prefix form.  Per-row T > 1 (the speculative
-                # verify step: [S, K+1] tokens at per-slot depths) takes
+                # cache-prefix form (this kernel with a q_offset).
+                # Per-row T > 1 (the speculative verify step: [S, K+1]
+                # tokens at per-slot depths; the prefix-hit extend) takes
                 # the cache-masked branch below instead — its k/v were
                 # just written at rows' own offsets, and the per-row
                 # causal mask bounds each query at its own depth.
-                o = seqlib.reference_attention(q, k, v, causal=True,
-                                               window=self.window)
+                #
+                # Two producers of the same o.  On the chip the flash
+                # forward kernel, float32 at its door as in training:
+                # the kv heads are not repeated and no [H, T, T] score
+                # array exists (1.6 GB a layer at 24 heads and 4096
+                # tokens).  A right-padded prompt needs nothing more:
+                # the mask is causal, so a real token never sees a pad.
+                # Where the kernel would be interpreted (the CPU), dense
+                # scores.
+                if prefill_runs_flash(T, per_row):
+                    from .. import runtime
+                    from ..ops import ring
+
+                    block_q, block_k = runtime.resolve_blocks(
+                        None, None, "flash_block_q", "flash_block_k")
+                    o = _prompt_attention(
+                        q, k, v, window=self.window, block_q=block_q,
+                        block_k=block_k, interpret=ring._interpret_mode())
+                else:
+                    o = seqlib.reference_attention(q, k, v, causal=True,
+                                                   window=self.window)
             else:
                 # Steady-state single-token step: query the filled cache.
                 # Causal mask over the cache: query t attends to cache

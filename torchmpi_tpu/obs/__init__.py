@@ -590,7 +590,10 @@ def record_serving(event: str, n: int = 1, *, replica: str = "") -> None:
     healed replica returned to the dispatch rotation) |
     ``prefill_compiles`` (a prompt length the engine had not prefilled
     before — one new XLA specialization; O(buckets) with bucketed
-    prefill, O(distinct lengths) without) | ``spec_drafted`` /
+    prefill, O(distinct lengths) without) | ``prefill_kernel_tokens``
+    (padded prompt tokens whose prefill program attended through the
+    flash forward kernel, ``models.transformer.prefill_runs_flash``) |
+    ``spec_drafted`` /
     ``spec_accepted`` (speculative-decode draft tokens proposed /
     accepted — the live acceptance rate) | ``prefix_hits`` /
     ``prefix_misses`` / ``prefix_tokens_saved`` /

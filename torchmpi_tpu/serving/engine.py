@@ -40,8 +40,12 @@ clock ``benchmarks/serving_bench.py`` compares schedules on.
 
 The engine is time-free and telemetry-free on purpose (the
 exceptions are properties of the engine's own programs: the
-prefill-compile counter above, the gauge of what a cached token costs,
-and, for a model with expert layers, what the pooled step itself
+prefill-compile counter above, the padded tokens whose prefill program
+attended through the flash forward kernel
+(``stats["prefill_kernel_tokens"]`` beside ``stats["prefill_tokens"]``,
+by the layer's own rule, ``models.transformer.prefill_runs_flash``), the
+gauge of what a cached token costs, and, for a model with expert layers,
+what the pooled step itself
 counted: experts touched and routes, read with the tokens): the
 scheduler owns the clock, the SLO histograms, and the fault hooks, so
 the engine stays a pure slot/cache mechanism that tests can drive tick
@@ -62,6 +66,7 @@ from .. import runtime
 from ..models.generate import slot_cache_slice, slot_cache_write, \
     slot_decode_step, slot_extend, slot_prefill, slot_verify_step, \
     slot_write
+from ..models import transformer
 from .prefix_cache import PrefixCache
 from .slots import SlotPool
 
@@ -233,6 +238,7 @@ class ReplicaEngine:
         self.stats = {"prefills": 0, "steps": 0, "prefill_compiles": 0,
                       "spec_steps": 0, "spec_drafted": 0,
                       "spec_accepted": 0, "prefill_tokens": 0,
+                      "prefill_kernel_tokens": 0,
                       "prefix_hits": 0, "prefix_misses": 0}
         #: Work units spent (prefill/pooled forward = 1 each, draft
         #: forwards at the proposer's weight) — the scheduler's
@@ -344,6 +350,18 @@ class ReplicaEngine:
                             jnp.asarray(prompt), true_len=true_len,
                             sampling=sampling)
 
+    def _prefill_runs_flash(self, padded_len: int) -> bool:
+        """Whether :meth:`_backend_prefill`'s program attends through the
+        flash forward kernel at this padded length: the layer's own rule
+        (``models.transformer.prefill_runs_flash``), for a model whose
+        attention layer is the one that asks it (latent attention expands
+        its own prefill)."""
+        # Looked up on the module at call time, as the layer does: tests
+        # put another rule there.
+        return (not getattr(self.dmodel, "kv_rank", 0)
+                and transformer.prefill_runs_flash(padded_len,
+                                                   per_row=False))
+
     def _backend_step(self, toks: np.ndarray, pos: np.ndarray, sampling):
         self._cache, nxt, counts = slot_decode_step(
             self.dmodel, self.params, self._cache, toks, pos,
@@ -450,6 +468,15 @@ class ReplicaEngine:
                     padded, true_len, samp)
                 if self._prefix is not None:
                     self.stats["prefix_misses"] += 1
+                if self._prefill_runs_flash(padded.shape[1]):
+                    # Of prefill_tokens, those whose program ran the
+                    # kernel (the extend above never does).
+                    n_padded = int(padded.shape[1])
+                    self.stats["prefill_kernel_tokens"] += n_padded
+                    mod = _obs()
+                    if mod is not None:
+                        mod.record_serving("prefill_kernel_tokens",
+                                           n_padded, replica=self.name)
             self.stats["prefill_tokens"] += int(padded.shape[1])
             self._cache = slot_write(self._cache, one_cache, slot)
             tok = int(np.asarray(first)[0])

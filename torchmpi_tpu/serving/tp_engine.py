@@ -110,6 +110,9 @@ class TPReplicaEngine(ReplicaEngine):
                                t_max=self.pool.slot_tokens,
                                true_len=true_len, sampling=sampling)
 
+    def _prefill_runs_flash(self, padded_len):
+        return False  # tp_generate._block_prefill: dense scores
+
     def _backend_step(self, toks, pos, sampling):
         self._cache, nxt = tp_slot_decode(
             self.params, self._cache,
