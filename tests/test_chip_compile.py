@@ -282,8 +282,12 @@ def _served_starcoder2(one_chip, layers=None):
     """``starcoder2-3b-serve`` as the served cells build it (its widths, its
     slots, bfloat16 weights; ``layers`` cuts the depth), as shapes on the
     described chip: -> (decode model, params, slots, sampling operands)."""
+    return _served(one_chip, "sc2-3b-serve-sat", layers)
+
+
+def _served(one_chip, workload, layers=None):
     harness = _harness()
-    cell = harness.resolve(harness.load_manifest(), "sc2-3b-serve-sat")
+    cell = harness.resolve(harness.load_manifest(), workload)
     if layers is not None:
         cell.config["num_hidden_layers"] = layers
     model = harness.build_model(cell)
@@ -341,17 +345,67 @@ def test_served_decode_step_lowers_to_the_parents_text(one_chip):
     from torchmpi_tpu.models.generate import _slot_step_jit
 
     dmodel, params, slots, sampling = _served_starcoder2(one_chip)
-    cache = jax.tree.map(
-        lambda a: _sds(a.shape, a.dtype, one_chip),
-        jax.eval_shape(lambda: dmodel.init(
-            jax.random.PRNGKey(0), jnp.zeros((slots, 1), jnp.int32),
-            pos_offset=jnp.zeros((slots,), jnp.int32)))["cache"])
+    cache = _pool_cache(dmodel, slots, one_chip)
     text = _slot_step_jit.lower(
         dmodel, params, cache, _sds((slots,), jnp.int32, one_chip),
         _sds((slots,), jnp.int32, one_chip), *sampling(slots)).as_text()
     assert "tpu_custom_call" not in text
     assert hashlib.sha256(text.encode()).hexdigest() == (
         "132fe59526c5893ed71cbb5cd4bd2aa512c6c2511b22c729279ccf2caa2b5afa")
+
+
+def _pool_cache(dmodel, slots, one_chip):
+    return jax.tree.map(
+        lambda a: _sds(a.shape, a.dtype, one_chip),
+        jax.eval_shape(lambda: dmodel.init(
+            jax.random.PRNGKey(0), jnp.zeros((slots, 1), jnp.int32),
+            pos_offset=jnp.zeros((slots,), jnp.int32)))["cache"])
+
+
+@pytest.mark.parametrize("program", ["prefill64", "prefill1024", "step"])
+def test_served_hybrid_compiles_at_the_cells_sizes(one_chip, program):
+    # nm3s-120b-serve-chat-sat's programs at the published widths
+    # (chipbench/configs/nemotron3-super-120b-a12b-serve.json): 5 Mamba-2
+    # mixers of 128 heads x 64 with a state of 128 (a prompt in chunks of
+    # 128, a pooled step ONE recurrent update a slot), 5 latent expert
+    # layers with 128 of 512 relu2 experts held, top-22, one GQA layer whose
+    # prompt runs the flash forward kernel; 9.3 GB of bfloat16 weights.  The
+    # smallest and the largest prefill bucket of the cell's traffic and the
+    # pooled step at the file's slots fit the chip; the step's cache has the
+    # two state leaves a mixer and no token axis on them.
+    from torchmpi_tpu.models.generate import (STATE_LEAVES, _slot_prefill_jit,
+                                              _slot_step_jit)
+
+    dmodel, params, slots, sampling = _served(one_chip,
+                                              "nm3s-120b-serve-chat-sat")
+    if program == "step":
+        cache = _pool_cache(dmodel, slots, one_chip)
+        state = [a for path, a in jax.tree_util.tree_leaves_with_path(cache)
+                 if path[-1].key in STATE_LEAVES]
+        assert sorted(a.shape for a in state) == sorted(
+            [(slots, 128, 64, 128), (slots, 3, 10240)] * 5)
+        compiled = _slot_step_jit.lower(
+            dmodel, params, cache, _sds((slots,), jnp.int32, one_chip),
+            _sds((slots,), jnp.int32, one_chip), *sampling(slots)).compile()
+        attention = []
+    else:
+        bucket = int(program[len("prefill"):])
+        compiled = _slot_prefill_jit.lower(
+            dmodel, params, _sds((1, bucket), jnp.int32, one_chip),
+            _sds((), jnp.int32, one_chip), *sampling(1)).compile()
+        attention = ["flash.fwd"]
+    # the library's kernels: the one attention layer's prompt, and the live
+    # rows' gather and combine of the five expert layers; the compiler's
+    # own grouped-matmul kernels for the experts' TWO products a layer
+    # (1,408 sorted rows a step over 128 experts: grouped, not dense)
+    text = compiled.as_text()
+    assert sorted(ident for _, ident in KERNEL.findall(text)) == sorted(
+        attention + ["moe.gather", "moe.combine"] * 5)
+    assert len(re.findall(r"^\s*(?:ROOT )?%ragged-dot-none\S* = ", text,
+                          re.M)) == 2 * 5
+    ma = compiled.memory_analysis()
+    assert (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes) < 16_909_336_064
 
 
 def test_smallthinker_step_compiles_at_the_cells_sizes(chip):

@@ -100,6 +100,37 @@ IMOE_METRICS = {
 }
 # a dense model's count of a step's bytes: not the sparse cell's
 IMOE_STAYS_OUT = ["decode_hbm_roofline_pct.srv"]
+# what PR 33 added, in order: metric -> (reader, layer, source, unit, better)
+NM3 = "nm3s-120b-serve-chat-sat"
+NM3_CONFIG = "nemotron3-super-120b-a12b-serve"
+NM3_METRICS = {
+    "ssm_ms_per_decode_step.srv": (
+        "serve_scopes_in_module", "state-space layers", "device_trace", "ms",
+        "lower"),
+    "attn_ms_per_decode_step.srv": (
+        "serve_scopes_in_module", "serving engine", "device_trace", "ms",
+        "lower"),
+    "moe_latent_ms_per_decode_step.srv": (
+        "serve_scopes_in_module", "expert layer", "device_trace", "ms",
+        "lower"),
+    "ssm_scan_ms_per_prefill.srv": (
+        "serve_scopes_in_module_per", "state-space layers", "device_trace",
+        "ms", "lower"),
+    "moe_routes_held_share.srv": (
+        "counter_ratio", "expert layer", "program_counter", "1", "higher"),
+    "decode_hybrid_hbm_roofline_pct.srv": (
+        "serve_hybrid_decode_roofline", "serving engine", "device_trace",
+        "%", "higher"),
+}
+# PR 31's metrics whose readers are right for the hybrid as they stand ...
+NM3_JOINS_OF_IMOE = ["moe_experts_ms_per_decode_step.srv",
+                     "moe_permute_ms_per_decode_step.srv",
+                     "moe_experts_touched_share.srv"]
+# ... and the three it stays out of: a dense model's and a latent-cache
+# model's counts of a step's bytes, and latent attention's scope
+NM3_STAYS_OUT = {"decode_hbm_roofline_pct.srv": [SAT],
+                 "decode_moe_hbm_roofline_pct.srv": [IMOE],
+                 "latent_attn_ms_per_decode_step.srv": [IMOE]}
 
 with open(os.path.join(TESTDATA, "expected_names.json")) as _f:
     WANT = json.load(_f)
@@ -204,7 +235,8 @@ def test_sparse_served_cell_metric_resolves_to_a_file_and_a_reader(metric):
     assert entry == {
         "name": metric, "unit": unit, "better": better, "source": source,
         "layer": layer, "moves": "out_tokens_per_s_chip",
-        "workloads": [IMOE]}
+        "workloads": entry["workloads"]}
+    assert entry["workloads"][0] == IMOE
     spec = harness.load_json(MANIFEST, "layer_metrics", metric)
     assert spec["reader"] == reader
     assert callable(harness.load_module(MANIFEST, "readers", reader).read)
@@ -226,7 +258,7 @@ def test_sparse_served_cell_joins_the_lists_whose_readers_are_right_for_it():
     assert mine == joined | set(IMOE_METRICS)
     for metric in joined:
         assert harness.by_name(MANIFEST["per_layer"], metric,
-                               "metric")["workloads"] == [SAT, IMOE]
+                               "metric")["workloads"][:2] == [SAT, IMOE]
     for metric in IMOE_STAYS_OUT:
         assert harness.by_name(MANIFEST["per_layer"], metric,
                                "metric")["workloads"] == [SAT]
@@ -237,7 +269,7 @@ def test_sparse_served_cell_joins_the_lists_whose_readers_are_right_for_it():
     assert {m["name"] for m in cell.end_to_end} == {
         "out_tokens_per_s_chip", "setup_s"}
     assert harness.by_name(MANIFEST["end_to_end"], "out_tokens_per_s_chip",
-                           "metric")["workloads"] == [SAT, IMOE]
+                           "metric")["workloads"][:2] == [SAT, IMOE]
     entry = harness.by_name(MANIFEST["workloads"], IMOE, "workload")
     assert (entry["config"], entry["traffic"], entry["chips"]) == (
         "instella-moe-16b-a3b-serve", "open-conv-sat", 1)
@@ -247,8 +279,106 @@ def test_sparse_served_cell_joins_the_lists_whose_readers_are_right_for_it():
         "config")["reduced"] == ["num_hidden_layers",
                                  "num_nextn_predict_layers"]
     names = [w["name"] for w in MANIFEST["workloads"]]
-    assert names[5:] == [R80, SAT, IMOE] and len(names) == 8
-    assert sum(w["chips"] == 4 for w in MANIFEST["workloads"]) == 1
+    assert names[5:8] == [R80, SAT, IMOE]
+    assert sum(w["chips"] == 4 for w in MANIFEST["workloads"][:8]) == 1
+
+
+@pytest.mark.parametrize("metric", sorted(NM3_METRICS))
+def test_hybrid_served_cell_metric_resolves_to_a_file_and_a_reader(metric):
+    reader, layer, source, unit, better = NM3_METRICS[metric]
+    entry = harness.by_name(MANIFEST["per_layer"], metric, "metric")
+    assert entry == {
+        "name": metric, "unit": unit, "better": better, "source": source,
+        "layer": layer, "moves": "out_tokens_per_s_chip",
+        "workloads": entry["workloads"]}
+    assert entry["workloads"][0] == NM3
+    spec = harness.load_json(MANIFEST, "layer_metrics", metric)
+    assert spec["reader"] == reader
+    assert callable(harness.load_module(MANIFEST, "readers", reader).read)
+    mine = harness.by_name(harness.resolve(MANIFEST, NM3).per_layer, metric,
+                           "metric")
+    assert mine["args"] == spec["args"]
+    for pattern in ("module", "op_name", "name", "not_name"):
+        re.compile(spec["args"].get(pattern, ""))
+    if source == "device_trace":     # one of the served path's two programs
+        assert spec["args"]["module"] == (
+            "jit__slot_prefill_jit" if "prefill" in metric
+            else "jit__slot_step_jit")
+
+
+def test_hybrid_served_cell_joins_the_lists_whose_readers_are_right_for_it():
+    """PR 33's cell: the twelve generic ``.srv`` metrics of PR 30, three of
+    PR 31's, its own six, and not the three whose counts or scopes are
+    another model's; nine cells, one of them on four chips; its
+    configuration the sixth, with the six keys it cut."""
+    mine = {m["name"] for m in harness.resolve(MANIFEST, NM3).per_layer}
+    joined = (set(SRV_METRICS) - set(IMOE_STAYS_OUT)) | set(
+        NM3_JOINS_OF_IMOE)
+    assert mine == joined | set(NM3_METRICS)
+    for metric in joined:
+        assert harness.by_name(MANIFEST["per_layer"], metric,
+                               "metric")["workloads"][-1] == NM3
+    for metric, cells in NM3_STAYS_OUT.items():
+        assert harness.by_name(MANIFEST["per_layer"], metric,
+                               "metric")["workloads"][:len(cells)] == cells
+        assert metric not in mine
+    cell = harness.resolve(MANIFEST, NM3)
+    assert {m["name"] for m in cell.end_to_end} == {
+        "out_tokens_per_s_chip", "setup_s"}
+    assert harness.by_name(MANIFEST["end_to_end"], "out_tokens_per_s_chip",
+                           "metric")["workloads"][:3] == [SAT, IMOE, NM3]
+    entry = harness.by_name(MANIFEST["workloads"], NM3, "workload")
+    assert (entry["config"], entry["traffic"], entry["chips"]) == (
+        NM3_CONFIG, "open-chat-sat", 1)
+    assert cell.config["runner"] == "serve_open_loop_hybrid"
+    assert cell.config["reduced"] == harness.by_name(
+        MANIFEST["configs"], NM3_CONFIG, "config")["reduced"] == [
+            "num_hidden_layers", "hybrid_override_pattern",
+            "n_routed_experts", "vocab_size", "num_nextn_predict_layers",
+            "mtp_hybrid_override_pattern"]
+    # the share: 128 of the router's 512 held, a quarter of the vocabulary
+    assert (cell.config["n_routed_experts"], cell.config["router_width"],
+            cell.config["experts_held"]) == (128, 512, [0, 128])
+    assert cell.config["published"]["n_routed_experts"] == 512
+    assert cell.config["vocab_size"] * 4 == cell.config["published"][
+        "vocab_size"]
+    # the traffic's ids come from the slice, its lengths fit a slot
+    srv, traffic = cell.config["serving"], cell.traffic
+    assert (traffic["prompt_tokens"]["max"] + traffic["answer_tokens"]["max"]
+            <= srv["slot_tokens"])
+    names = [w["name"] for w in MANIFEST["workloads"]]
+    assert names[8:9] == [NM3]
+    assert sum(w["chips"] == 4 for w in MANIFEST["workloads"][:9]) == 1
+
+
+def test_hybrid_served_readers_give_no_number_without_the_programs_names():
+    """A program without the scopes or the counters (the parent) gives no
+    number and no error; with the counters, the live slots a step."""
+    from torchmpi_tpu import obs
+    per = harness.load_module(MANIFEST, "readers",
+                              "serve_scopes_in_module_per")
+    roof = harness.load_module(MANIFEST, "readers",
+                               "serve_hybrid_decode_roofline")
+    cell = harness.resolve(MANIFEST, NM3)
+    scan = harness.load_json(MANIFEST, "layer_metrics",
+                             "ssm_scan_ms_per_prefill.srv")["args"]
+    obs.reset()
+    # no prefill in the traced stretch; no device plane; no counters
+    ctx = {"cell": cell, "platform": "tpu", "traced": {"steps": 5}}
+    assert per.read(ctx, **scan) is None
+    ctx["traced"]["prefills"] = 3
+    ctx["trace"] = xplane.Trace({}, {}, (0, 1))
+    assert per.read(ctx, **scan) is None
+    assert roof.live_slots_per_step(22) is None
+    assert roof.read(ctx, module="jit__slot_step_jit") is None
+    for layer in ("a", "b"):
+        obs.registry().counter_inc("tm_moe_decode_routes_total", 22 * 60,
+                                   layer=layer)
+    obs.registry().counter_inc("tm_moe_decode_steps_total", 2, replica="r")
+    assert roof.live_slots_per_step(22) == 30.0
+    # ... and still no number without the held experts' counter
+    assert roof.read(ctx, module="jit__slot_step_jit") is None
+    obs.reset()
 
 
 def test_sparse_served_readers_give_no_number_without_the_programs_names():
@@ -276,8 +406,8 @@ def test_sparse_served_readers_give_no_number_without_the_programs_names():
 def test_benchmark_json_only_gained_entries_at_the_end():
     """What the benchmark had (PR 23, then PR 24) is still there, first
     and unchanged in order; PR 26's metrics follow it, then PR 27's, PR
-    30's and PR 31's, each where its PR appended it: the next PR appends
-    after them and adds its own slice here."""
+    30's, PR 31's and PR 33's, each where its PR appended it: the next PR
+    appends after them and adds its own slice here."""
     names = [m["name"] for m in MANIFEST["per_layer"]]
     assert set(names[10:22]) == set(NEW_METRICS)
     assert names[:22] == [
@@ -293,6 +423,7 @@ def test_benchmark_json_only_gained_entries_at_the_end():
     assert names[22:31] == list(ST_METRICS) + [LIVE_SHARE]
     assert names[31:55] == SRV_METRICS + LAT_METRICS
     assert names[55:60] == list(IMOE_METRICS)
+    assert names[60:66] == list(NM3_METRICS)
     layers = {m["layer"] for m in MANIFEST["per_layer"][:10]}
     assert {m["layer"] for m in MANIFEST["per_layer"][10:22]} <= layers
     assert {m["layer"] for m in MANIFEST["per_layer"][22:31]} <= layers | {
@@ -300,9 +431,11 @@ def test_benchmark_json_only_gained_entries_at_the_end():
     assert {m["layer"] for m in MANIFEST["per_layer"][31:60]} <= layers | {
         "expert layer", "serving scheduler", "serving engine",
         "serving slot pool"}
-    assert [c["name"] for c in MANIFEST["configs"]] == [
+    assert {m["layer"] for m in MANIFEST["per_layer"][60:66]} == {
+        "state-space layers", "expert layer", "serving engine"}
+    assert [c["name"] for c in MANIFEST["configs"]][:6] == [
         "resnet50", "starcoder2-3b", "smallthinker-21b-a3b",
-        "starcoder2-3b-serve", "instella-moe-16b-a3b-serve"]
+        "starcoder2-3b-serve", "instella-moe-16b-a3b-serve", NM3_CONFIG]
     assert [m["name"] for m in MANIFEST["end_to_end"]] == [
         "images_per_s_chip", "tokens_per_s_chip", "step_ms_p90",
         "itl_ms_p99", "out_tokens_per_s_chip", "setup_s"]
@@ -581,6 +714,34 @@ def test_traced_rehearsal_of_the_sparse_served_cell_comes_out_correct():
     assert out["compared"]["logit_gap"][0] <= 0.05
     assert 0 < out["metrics"]["moe_experts_touched_share.srv"]["value"] <= 1
     device_only = {m for m, v in IMOE_METRICS.items()
+                   if v[2] == "device_trace"}
+    assert not device_only & set(out["metrics"])
+    assert {"batch_occupancy_pct.srv", "prefill_share_pct.srv",
+            "itl_ms_p50.srv"} <= set(out["metrics"])
+
+
+def test_traced_rehearsal_of_the_hybrid_served_cell_comes_out_correct():
+    """``run.py --rehearse`` as the driver calls it: bucketed prefill with
+    its true length, the state's slot write and the pooled recurrent step,
+    correct against the plain reference; the program's counters need no
+    device plane and are there on the CPU, the device's times and the
+    roofline are left out."""
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    p = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "chipbench", "run.py"),
+         "--workload", NM3, "--seed", "3300000011", "--seconds", "2",
+         "--trace", "1", "--rehearse"],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-2000:]
+    out = json.loads(p.stdout.strip().splitlines()[-1])
+    assert out["correct"] is True and out["rehearsal"] is True
+    assert out["failed"] == 0 and out["attempted"] > 50
+    assert out["compared"]["logit_gap"][0] <= 0.05
+    assert out["compared"]["off_the_top_share"][0] == 0.0
+    # 16 of the router's 64 held: a quarter of the routes, more or less
+    assert 0.1 < out["metrics"]["moe_routes_held_share.srv"]["value"] < 0.5
+    assert 0 < out["metrics"]["moe_experts_touched_share.srv"]["value"] <= 1
+    device_only = {m for m, v in NM3_METRICS.items()
                    if v[2] == "device_trace"}
     assert not device_only & set(out["metrics"])
     assert {"batch_occupancy_pct.srv", "prefill_share_pct.srv",

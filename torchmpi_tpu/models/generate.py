@@ -572,19 +572,34 @@ def _greedy_sampling(n):
             jnp.full((n,), 2.0, jnp.float32))
 
 
+#: Cache leaves with NO token axis: one value a slot, whatever the slot's
+#: depth (``transformer.Mamba2Mixer``'s recurrent state and the last inputs
+#: of its convolution).  ``slot_write`` carries them as they are; the
+#: engine books them a slot, not a token, and the fragment primitives
+#: below, which cut every leaf along axis 1, are not for a model that has
+#: them (``serving.ReplicaEngine`` refuses the prefix cache there).
+STATE_LEAVES = ("ssm_state", "conv_state")
+
+
 @partial(jax.jit, static_argnums=(0,))
 def _slot_prefill_jit(dmodel, params, prompt, true_len, seeds, idxs,
                       temps, top_ks, top_ps):
+    # A model with state leaves is told the true length: what a recurrent
+    # state holds after the bucket's padding is not what it held after the
+    # prompt (the other models' programs are what they were).
+    told = ({"true_len": true_len}
+            if getattr(dmodel, "layer_pattern", None) else {})
     (xs, head), updated = dmodel.apply(
         {"params": params}, prompt, pos_offset=0, return_prehead=True,
-        mutable=["cache"])
+        mutable=["cache"], **told)
     # The TRUE last position, not -1: with bucketed prefill the prompt
     # is right-padded, and the pad positions' logits must never be
     # sampled.  (Causality makes the real positions' activations
     # independent of the padding, so the sliced logits are bitwise the
     # unpadded ones; the pad positions' k/v land in the cache but every
     # later query is depth-masked below them until the decode steps
-    # overwrite them in order.)
+    # overwrite them in order.  A recurrent state is the exception, told
+    # above.)
     x_last = lax.dynamic_slice_in_dim(
         xs, clamp_slot_positions(true_len - 1, xs.shape[1]), 1,
         axis=1)[:, 0]
@@ -622,7 +637,8 @@ def _slot_step_jit(dmodel, params, cache, tokens, positions, seeds,
                        top_ks, top_ps, tokens.dtype)
     # a live slot writes at its prompt's length or later, an idle one at 0
     return (updated["cache"], nxt,
-            decode_counts(updated.get("moe", {}), positions > 0))
+            decode_counts(updated.get("moe", {}), positions > 0,
+                          getattr(dmodel, "experts_held", None)))
 
 
 def slot_decode_step(dmodel, params, cache, tokens, positions,
@@ -634,7 +650,7 @@ def slot_decode_step(dmodel, params, cache, tokens, positions,
     expert layers' counts leave them out).
     Returns ``(new_cache, next_tokens [S])``, and with ``counted`` a third
     value: what the step's expert layers did
-    (``parallel.expert.decode_counts``: [layers, 2] int32, ready when the
+    (``parallel.expert.decode_counts``: [layers, 3] int32, ready when the
     tokens are), None for a model without expert layers.  One compiled
     executable serves the entire trace — admission, retirement, and
     greedy/sampled mixes never retrace (the sampling knobs are [S]

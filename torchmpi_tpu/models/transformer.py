@@ -552,6 +552,181 @@ class LatentAttention(nn.Module):
         return dense(E, "out")(o.astype(self.dtype))
 
 
+def ssd_chunked(x, dt, a, b, c, chunk: int):
+    """The state-space-duality form of Mamba-2's recurrence (arXiv:2405.21060,
+    section 6), from a ZERO state, a head ``h`` of group ``h // (H / G)``:
+
+        S_t = exp(dt_t a_h) S_(t-1) + dt_t x_t (outer) b_t;   y_t = S_t c_t
+
+    ``x`` [B, T, H, P], ``dt`` [B, T, H] (0 at a position leaves the state
+    as it was: that is how a padded position is told), ``a`` [H] negative,
+    ``b`` and ``c`` [B, T, G, N]; all float32, and every decay is an
+    ``exp`` of a float32 difference of cumulative sums.  T is padded to a
+    multiple of ``chunk`` with such dead positions.  Within a chunk the
+    outputs are a masked ``[chunk, chunk]`` product of ``c b^T`` with the
+    decays, a chunk's own end state is a product of the same kind, and the
+    states pass from chunk to chunk by a scan over ``T / chunk`` steps.
+    Heads lead and (position, position) are the minor axes, which is what
+    the chip's tiles want.  Returns ``(y [B, T, H, P], the state after the
+    last position [B, H, P, N])``."""
+    B, T, H, P = x.shape
+    G, N = b.shape[-2:]
+    R, Q = H // G, chunk
+    pad = -T % Q
+    if pad:
+        x, dt, b, c = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
+                       for v in (x, dt, b, c))
+    nc = (T + pad) // Q
+    xg = x.reshape(B, nc, Q, G, R, P).transpose(0, 1, 3, 4, 2, 5)
+    dtg = dt.reshape(B, nc, Q, G, R).transpose(0, 1, 3, 4, 2)  # [B,nc,G,R,Q]
+    bg = b.reshape(B, nc, Q, G, N).transpose(0, 1, 3, 2, 4)    # [B,nc,G,Q,N]
+    cg = c.reshape(B, nc, Q, G, N).transpose(0, 1, 3, 2, 4)
+    cum = jnp.cumsum(dtg * a.reshape(G, R, 1), axis=-1)        # inclusive
+    causal = jnp.arange(Q)[:, None] >= jnp.arange(Q)[None, :]
+    # decay from j to i inside a chunk (i >= j), times what j puts in
+    seg = jnp.where(causal, cum[..., :, None] - cum[..., None, :], -jnp.inf)
+    cb = jnp.einsum("bcgin,bcgjn->bcgij", cg, bg)
+    w = cb[:, :, :, None] * jnp.exp(seg) * dtg[..., None, :]
+    y = jnp.einsum("bcgrij,bcgrjp->bcgrip", w, xg)
+    # a chunk's own end state, and the scan that passes states on
+    to_end = jnp.exp(cum[..., -1:] - cum) * dtg                # [B,nc,G,R,Q]
+    own = jnp.einsum("bcgrjp,bcgjn->bcgrpn", xg * to_end[..., None], bg)
+    through = jnp.exp(cum[..., -1])                            # [B,nc,G,R]
+
+    def pass_on(state, chunk_of):
+        mine, decay = chunk_of
+        return decay[..., None, None] * state + mine, state
+
+    final, before = lax.scan(
+        pass_on, jnp.zeros((B, G, R, P, N), jnp.float32),
+        (own.swapaxes(0, 1), through.swapaxes(0, 1)))
+    y = y + (jnp.einsum("bcgin,bcgrpn->bcgrip", cg, before.swapaxes(0, 1))
+             * jnp.exp(cum)[..., None])
+    y = y.transpose(0, 1, 4, 2, 3, 5).reshape(B, T + pad, H, P)[:, :T]
+    return y, final.reshape(B, H, P, N)
+
+
+class Mamba2Mixer(nn.Module):
+    """A Mamba-2 mixer (``nemotron_h``'s ``M`` layer), no bias on either
+    projection:
+
+        [z, xBC, dt] = u W_in          widths H P, H P + 2 G N, H
+        xBC <- silu(conv(xBC))         causal, depthwise, ``conv_width`` taps
+                                       and a bias; split x, b, c
+        dt <- softplus(dt + dt_bias);  a = -exp(A_log)
+        S_t = exp(dt_t a) S_(t-1) + dt_t x_t (outer) b_t
+        y_t = S_t c_t + D x_t          a head, state S [P, N], zero at first
+        y <- RMSNorm_grouped(y * silu(z))   the gate, THEN the norm over
+                                            groups of H P / G channels
+        out = y W_out
+
+    Everything between the two projections is float32.  A block of tokens
+    runs :func:`ssd_chunked`.  ``decode=True`` keeps what the recurrence
+    carries in the ``cache`` collection, and NEITHER leaf has a token axis
+    (``models.generate.STATE_LEAVES``): ``ssm_state`` [B, H, P, N] and
+    ``conv_state`` [B, conv_width - 1, H P + 2 G N], the last inputs of the
+    convolution.  A prompt block (T > 1, a scalar ``pos_offset``: a FRESH
+    cache, as :class:`SPAttention` assumes) starts from zero and leaves the
+    state after its last LIVE token: with ``true_len`` (traced; None: all
+    of it) the positions from there on get ``dt = 0``, which changes
+    nothing (``exp(0 a) = 1``, ``0 x b = 0``), and the convolution's state
+    is the last live rows.  One token (T == 1) is ONE recurrent update of
+    every row; no position enters.  A block at per-row depths (speculation's
+    verify step, the prefix cache's extend) would need a state that can be
+    un-updated or copied by fragment: refused here and by the engine."""
+
+    num_heads: int
+    head_dim: int
+    state: int
+    groups: int
+    conv_width: int = 4
+    chunk: int = 128
+    norm_eps: float = 1e-5
+    dtype: jnp.dtype = jnp.float32
+    decode: bool = False
+
+    @nn.compact
+    def __call__(self, u, pos_offset=0, true_len=None):  # u: [B, T, E]
+        B, T, E = u.shape
+        H, P, N, G, K = (self.num_heads, self.head_dim, self.state,
+                         self.groups, self.conv_width)
+        inner, wide = H * P, H * P + 2 * G * N
+        f32 = jnp.float32
+
+        def param(name, init, shape):
+            return self.param(name, init, shape, f32).astype(f32)
+
+        with jax.named_scope("ssm_in_proj"):
+            proj = nn.Dense(inner + wide + H, dtype=self.dtype,
+                            use_bias=False, name="in_proj")(u)
+        z, xbc, dt = (proj[..., :inner], proj[..., inner:inner + wide],
+                      proj[..., inner + wide:])
+        taps = param("conv_kernel", nn.initializers.lecun_normal(), (K, wide))
+        conv_bias = param("conv_bias", nn.initializers.zeros, (wide,))
+        dt = jax.nn.softplus(dt.astype(f32) + param(
+            "dt_bias", nn.initializers.zeros, (H,)))
+        a = -jnp.exp(param("A_log", nn.initializers.zeros, (H,)))
+        skip = param("D", nn.initializers.ones, (H,))
+        scale = param("norm_scale", nn.initializers.ones, (inner,))
+        xbc = xbc.astype(f32)
+        if self.decode:
+            if T > 1 and jnp.ndim(pos_offset) == 1:
+                raise ValueError(
+                    "a Mamba2Mixer cannot take a block of tokens at per-row "
+                    "depths (speculative verify, prefix-cache extend): its "
+                    "state cannot be un-updated")
+            ssm = self.variable("cache", "ssm_state", jnp.zeros,
+                                (B, H, P, N), f32)
+            conv = self.variable("cache", "conv_state", jnp.zeros,
+                                 (B, K - 1, wide), f32)
+        step = self.decode and T == 1
+        with jax.named_scope("ssm_conv"):
+            # tap k reads the input K - 1 - k positions back
+            if step:
+                rows = jnp.concatenate([conv.value, xbc], axis=1)  # [B, K]
+                conv.value = rows[:, 1:]
+                xbc = (rows * taps).sum(1, keepdims=True)
+            else:
+                rows = jnp.pad(xbc, ((0, 0), (K - 1, 0), (0, 0)))
+                if self.decode:
+                    live = T if true_len is None else true_len
+                    conv.value = lax.dynamic_slice_in_dim(
+                        rows, clamp_slot_positions(live, T + K - 1, K - 1),
+                        K - 1, axis=1)
+                xbc = sum(rows[:, k:k + T] * taps[k] for k in range(K))
+            xbc = jax.nn.silu(xbc + conv_bias)
+        x = xbc[..., :inner].reshape(B, T, H, P)
+        b = xbc[..., inner:inner + G * N].reshape(B, T, G, N)
+        c = xbc[..., inner + G * N:].reshape(B, T, G, N)
+        if step:
+            with jax.named_scope("ssm_step"):
+                xg = x.reshape(B, G, H // G, P)
+                dtg = dt.reshape(B, G, H // G)
+                state = (jnp.exp(dtg * a.reshape(G, -1))[..., None, None]
+                         * ssm.value.reshape(B, G, H // G, P, N)
+                         + (dtg[..., None] * xg)[..., None]
+                         * b.reshape(B, G, 1, 1, N))
+                ssm.value = state.reshape(B, H, P, N)
+                y = jnp.einsum("bgrpn,bgn->bgrp", state,
+                               c.reshape(B, G, N)).reshape(B, T, H, P)
+        else:
+            with jax.named_scope("ssm_scan"):
+                if true_len is not None:
+                    dt = jnp.where(jnp.arange(T)[:, None] < true_len, dt, 0.0)
+                y, state = ssd_chunked(x, dt, a, b, c, self.chunk)
+                if self.decode:
+                    ssm.value = state
+        with jax.named_scope("ssm_gate_norm"):
+            y = ((y + skip[:, None] * x).reshape(B, T, inner)
+                 * jax.nn.silu(z.astype(f32))).reshape(B, T, G, inner // G)
+            y = y * lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
+                              + self.norm_eps)
+            y = y.reshape(B, T, inner) * scale
+        with jax.named_scope("ssm_out_proj"):
+            return nn.Dense(E, dtype=self.dtype, use_bias=False,
+                            name="out_proj")(y.astype(self.dtype))
+
+
 class MoEMLP(nn.Module):
     """Expert-parallel MLP: tokens routed over ``expert_axis`` with the
     all-to-all dispatch of parallel/expert.py.
@@ -615,6 +790,11 @@ class MoEMLP(nn.Module):
         return out.reshape(B, T, E).astype(self.dtype)
 
 
+def relu2(x):
+    """``relu(x) ** 2``: ``nemotron_h``'s feed-forward activation."""
+    return jnp.square(jax.nn.relu(x))
+
+
 class ExpertFFN(nn.Module):
     """Top-k gated expert feed-forward whose weights exist only for the
     experts ``held`` = ``(first, count)`` of ``n_experts`` (None: all): one
@@ -629,10 +809,18 @@ class ExpertFFN(nn.Module):
     logits into the chosen experts and their weights (``"softmax"``:
     ``parallel/expert.softmax_gate``; ``"sigmoid"``: ``sigmoid_gate`` with
     the selection-only ``router_bias`` and ``route_scale``), ``act`` the experts'
-    gate activation (``"relu"``: ReGLU, ``"silu"``: SwiGLU).
-    ``shared_width`` > 0 adds a gated feed-forward of that width that
-    every token goes through (the shared experts as one; scope
-    ``shared_experts``).  The counters ``routes_held``, ``rows_computed``
+    activation: ``"relu"`` (ReGLU) and ``"silu"`` (SwiGLU) on the gate
+    product of a three-matrix expert, ``"relu2"`` (``relu(.) ** 2``) on the
+    up product of a NON-GATED one, which has two matrices and no
+    ``w_gate``.  ``latent_width`` > 0 puts the routed part into a latent:
+    ``latent_in`` [E -> latent] before the experts, which are that wide at
+    their doors, ``latent_out`` [latent -> E] after their weighted sum
+    (scopes ``moe_latent_in``, ``moe_latent_out``; plain maps, no bias);
+    the router still reads ``router_in``.
+    ``shared_width`` > 0 adds a feed-forward of that width, gated as the
+    experts are or not, on the full hidden, that every token goes through
+    (the shared experts as one; scope ``shared_experts``).  The counters
+    ``routes_held``, ``rows_computed``
     and ``rows_moved``, the chosen ``experts`` and the float32
     ``router_logits`` are sown to the ``moe`` collection
     (``mutable=["moe"]``), as ``MoEMLP`` sows its loss.
@@ -647,19 +835,29 @@ class ExpertFFN(nn.Module):
     gate: str = "softmax"
     route_scale: float = 1.0
     shared_width: int = 0
+    latent_width: int = 0
 
     @nn.compact
     def __call__(self, u, router_in):  # both [B, T, E]
         B, T, E = u.shape
         first, count = self.held or (0, self.n_experts)
+        gated = self.act != "relu2"
+        act = relu2 if self.act == "relu2" else getattr(jax.nn, self.act)
+        door = self.latent_width or E       # an expert's input and output
         init = nn.initializers.lecun_normal(batch_axis=(0,))
         router = self.param("router", nn.initializers.lecun_normal(),
                             (E, self.n_experts), jnp.float32)
-        w_gate = self.param("w_gate", init, (count, E, self.width),
+        w_gate = (self.param("w_gate", init, (count, door, self.width),
+                             jnp.float32) if gated else None)
+        w_up = self.param("w_up", init, (count, door, self.width),
+                          jnp.float32)
+        w_down = self.param("w_down", init, (count, self.width, door),
                             jnp.float32)
-        w_up = self.param("w_up", init, (count, E, self.width), jnp.float32)
-        w_down = self.param("w_down", init, (count, self.width, E),
-                            jnp.float32)
+
+        def dense(features, name):
+            return nn.Dense(features, dtype=self.dtype, use_bias=False,
+                            name=name)
+
         with jax.named_scope("route"):
             logits = jnp.dot(router_in.reshape(B * T, E).astype(jnp.float32),
                              router, precision=lax.Precision.HIGHEST)
@@ -674,18 +872,23 @@ class ExpertFFN(nn.Module):
         else:
             raise ValueError(f"unknown gate {self.gate!r}")
         x = u.reshape(B * T, E).astype(self.dtype)
+        routed_in = x
+        if self.latent_width:
+            with jax.named_scope("moe_latent_in"):
+                routed_in = dense(self.latent_width, "latent_in")(x)
         out, stats = eplib.held_experts(
-            x, logits, self.k, first, w_gate, w_up, w_down, gate=gate,
-            act=getattr(jax.nn, self.act))
+            routed_in, logits, self.k, first, w_gate, w_up, w_down,
+            gate=gate, act=act)
+        if self.latent_width:
+            with jax.named_scope("moe_latent_out"):
+                out = dense(E, "latent_out")(out)
         if self.shared_width:
             with jax.named_scope("shared_experts"):
-                def dense(features, name):
-                    return nn.Dense(features, dtype=self.dtype,
-                                    use_bias=False, name=name)
-
-                hidden = (getattr(jax.nn, self.act)(
-                    dense(self.shared_width, "shared_gate")(x))
-                    * dense(self.shared_width, "shared_up")(x))
+                if gated:
+                    hidden = (act(dense(self.shared_width, "shared_gate")(x))
+                              * dense(self.shared_width, "shared_up")(x))
+                else:
+                    hidden = act(dense(self.shared_width, "shared_up")(x))
                 out = out + dense(E, "shared_down")(hidden)
         if not self.is_initializing():
             for name, value in {**stats, "router_logits": logits}.items():
@@ -749,6 +952,14 @@ class Block(nn.Module):
     # the previous sub-block's output was added.  The block then takes and
     # returns the pair (stream, stream one sub-block back).
     farskip: bool = False
+    # ``kind`` set: the layer is ONE sub-block under one norm with a
+    # residual (``nemotron_h``'s letters): "M" a Mamba2Mixer of the ``ssm``
+    # sizes (heads, head_dim, state, groups, conv_width, chunk), "*" the
+    # attention above, "E" the ExpertFFN (``expert_latent`` wide at its
+    # experts' doors).  None: attention THEN a feed-forward, as ever.
+    kind: Optional[str] = None
+    ssm: Optional[Tuple[int, ...]] = None
+    expert_latent: int = 0
 
     def _attention(self):
         if self.kv_rank:
@@ -771,13 +982,34 @@ class Block(nn.Module):
                            use_bias=self.use_bias)
 
     @nn.compact
-    def __call__(self, x, pos_offset=0, lag=None):
+    def __call__(self, x, pos_offset=0, lag=None, true_len=None):
         # (no helper method calls a submodule here: flax would put the
         # method's name into every operation's path, ``/Block_n/Dense_n/``,
         # which the benchmark's readers find operations by)
         if self.router_reads not in ("attention_input", "ffn_input"):
             raise ValueError(f"unknown router_reads {self.router_reads!r}")
         E = x.shape[-1]
+        if self.kind is not None:
+            if self.farskip:
+                raise ValueError("farskip wires attention-then-feed-forward "
+                                 "blocks, not a layer pattern")
+            a = _norm(self.norm, self.norm_eps)(x)
+            if self.kind == "M":
+                h = Mamba2Mixer(*self.ssm, norm_eps=self.norm_eps,
+                                dtype=self.dtype, decode=self.decode)(
+                                    a, pos_offset, true_len)
+            elif self.kind == "*":
+                h = self._attention()(a, pos_offset)
+            elif self.kind == "E":
+                h = ExpertFFN(
+                    self.n_experts, self.moe_k, self.expert_width,
+                    self.experts_held, dtype=self.dtype, act=self.expert_act,
+                    gate=self.expert_gate, route_scale=self.route_scale,
+                    shared_width=self.shared_width,
+                    latent_width=self.expert_latent)(a, a)
+            else:
+                raise ValueError(f"unknown layer kind {self.kind!r} (M, *, E)")
+            return x + h
         a = _norm(self.norm, self.norm_eps)(lag if self.farskip else x)
         mid = x + self._attention()(a, pos_offset)
         h = _norm(self.norm, self.norm_eps)(x if self.farskip else mid)
@@ -791,7 +1023,8 @@ class Block(nn.Module):
                 self.n_experts, self.moe_k, self.expert_width,
                 self.experts_held, dtype=self.dtype, act=self.expert_act,
                 gate=self.expert_gate, route_scale=self.route_scale,
-                shared_width=self.shared_width)(
+                shared_width=self.shared_width,
+                latent_width=self.expert_latent)(
                     h, h if self.router_reads == "ffn_input" else a)
         elif self.moe_axis is not None:
             h = MoEMLP(self.moe_experts_per_device, self.mlp_ratio,
@@ -878,10 +1111,28 @@ class TransformerLM(nn.Module):
     mlp: str = "gelu"
     mlp_width: int = 0
     farskip: bool = False
+    # A hybrid's layers (see Block.kind), one letter a layer, ``depth`` of
+    # them: "M" a Mamba-2 mixer of the ``ssm_*`` sizes, "*" attention, "E"
+    # the expert layer; each ONE sub-block under one norm.  None: every
+    # layer is attention then a feed-forward.  ``expert_latent`` > 0: the
+    # routed experts work in a latent of that width (see ExpertFFN).
+    # ``pos_emb="none"``: no position table and no rotation (the mixers
+    # carry the order).
+    layer_pattern: Optional[str] = None
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    expert_latent: int = 0
 
     @nn.compact
-    def __call__(self, tokens, pos_offset=0, return_prehead: bool = False):
-        # tokens: [B, T_local] int32
+    def __call__(self, tokens, pos_offset=0, return_prehead: bool = False,
+                 true_len=None):
+        # tokens: [B, T_local] int32; ``true_len`` (traced): how many of a
+        # right-padded prompt's positions are real, for the layers that
+        # carry a state past the block (see Mamba2Mixer)
         B, T = tokens.shape
         x = nn.Embed(self.vocab, self.embed, dtype=self.dtype)(tokens)
         if self.pos_emb == "learned":
@@ -894,9 +1145,9 @@ class TransformerLM(nn.Module):
                 x = x + table(po[:, None] + jnp.arange(T)[None])
             else:
                 x = x + table(pos_offset + jnp.arange(T))[None]
-        elif self.pos_emb != "rope":
+        elif self.pos_emb not in ("rope", "none"):
             raise ValueError(f"unknown pos_emb {self.pos_emb!r}")
-        for name in ("window_layout", "rope_layout"):
+        for name in ("window_layout", "rope_layout", "layer_pattern"):
             layout = getattr(self, name)
             if layout is not None and len(layout) != self.depth:
                 raise ValueError(f"{name} has {len(layout)} entries for "
@@ -929,8 +1180,12 @@ class TransformerLM(nn.Module):
                       kv_rank=self.kv_rank, rope_dim=self.rope_dim,
                       v_dim=self.v_dim, yarn=self.yarn,
                       attn_gate=self.attn_gate, mlp=self.mlp,
-                      mlp_width=self.mlp_width,
-                      farskip=self.farskip)(x, pos_offset, lag)
+                      mlp_width=self.mlp_width, farskip=self.farskip,
+                      kind=self.layer_pattern and self.layer_pattern[i],
+                      ssm=(self.ssm_heads, self.ssm_head_dim, self.ssm_state,
+                           self.ssm_groups, self.ssm_conv, self.ssm_chunk),
+                      expert_latent=self.expert_latent)(
+                          x, pos_offset, lag, true_len)
             if self.farskip:
                 x, lag = x
         x = _norm(self.norm, self.norm_eps)(x)

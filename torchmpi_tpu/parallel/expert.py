@@ -220,7 +220,8 @@ def _held_part(act, u, probs, order, sizes, w_gate, w_up, w_down):
     """``sum_j probs[j, t] * f_e(u[t])`` over token t's live routes:
     ``order`` sorts the rank-major routes ``[k * T]`` by expert, the live
     ones (``sizes`` of them an expert held) first; ``act`` is the
-    activation on the experts' gate product."""
+    activation on the experts' gate product, or, without a ``w_gate``, on
+    the up product itself."""
     n_live = sizes.sum()
     with jax.named_scope("dispatch"):
         rows = _dispatch(u, order, n_live)
@@ -229,7 +230,8 @@ def _held_part(act, u, probs, order, sizes, w_gate, w_up, w_down):
             return lax.ragged_dot(x, w.astype(x.dtype), sizes,
                                   preferred_element_type=jnp.float32)
 
-        hidden = act(product(rows, w_gate)) * product(rows, w_up)
+        hidden = (act(product(rows, w_up)) if w_gate is None
+                  else act(product(rows, w_gate)) * product(rows, w_up))
         y = product(hidden.astype(u.dtype), w_down)
     with jax.named_scope("combine"):
         # the cast of ``y`` to the rows' type rides on the kernel, and its
@@ -272,7 +274,9 @@ def held_experts(u, router_logits, k: int, first: int, w_gate, w_up,
 
     where ``e`` are token t's chosen experts and ``p`` their weights, the
     gate's over all k whether held or not (``act``: relu for ReGLU experts,
-    silu for SwiGLU).  What the other experts would add is left out: under
+    silu for SwiGLU).  A NON-GATED expert has two matrices: with ``w_gate``
+    None it is ``w_down[e] act(w_up[e] u_t)`` (``nemotron_h``: ``act`` the
+    squared relu).  What the other experts would add is left out: under
     an expert axis this is what :func:`moe_layer`'s exchange would wrap,
     and nothing here stands in for it.
 
@@ -311,7 +315,7 @@ def held_experts(u, router_logits, k: int, first: int, w_gate, w_up,
     of the buffer the dispatch wrote: ``n_live`` rounded up to its block)
     and ``experts`` ([T, k] chosen experts), all computed on the device.
     """
-    n_experts, count = router_logits.shape[-1], w_gate.shape[0]
+    n_experts, count = router_logits.shape[-1], w_up.shape[0]
     if not (0 <= first and first + count <= n_experts and 1 <= k <= n_experts):
         raise ValueError(
             f"held experts [{first}, {first + count}) with k={k} do not "
@@ -336,12 +340,15 @@ def held_experts(u, router_logits, k: int, first: int, w_gate, w_up,
         "experts": experts}
 
 
-def decode_counts(moe_collection, live):
+def decode_counts(moe_collection, live, held=None):
     """What a pooled decode step's expert layers did, from the ``moe``
-    collection the step sowed (one row a slot): ``[layers, 2]`` int32, a
-    layer (in the order of the modules' paths) the experts that at least
-    one LIVE row chose and the live rows' routes; ``live`` [rows] bool
-    leaves the idle slots' rows out.  None without an expert layer."""
+    collection the step sowed (one row a slot): ``[layers, 3]`` int32, a
+    layer (in the order of the modules' paths) the HELD experts that at
+    least one LIVE row chose (their weights are what the step must read),
+    the live rows' routes, and those of them that went to a held expert;
+    ``live`` [rows] bool leaves the idle slots' rows out, ``held`` is the
+    layers' ``(first, count)`` (None: every expert is held, and the third
+    number is the second).  None without an expert layer."""
     from flax.traverse_util import flatten_dict
 
     flat = flatten_dict(moe_collection)
@@ -349,10 +356,15 @@ def decode_counts(moe_collection, live):
     for path in sorted(p for p in flat if p[-1] == "experts"):
         chosen, = flat[path]                     # [rows, k]: one call
         n_experts = flat[path[:-1] + ("router_logits",)][0].shape[-1]
+        mine = live[:, None]
+        routes = routes_held = live.sum(dtype=jnp.int32) * chosen.shape[-1]
+        if held is not None:
+            first, count = held
+            mine = mine & (chosen >= first) & (chosen < first + count)
+            routes_held = mine.sum(dtype=jnp.int32)
         touched = jnp.zeros((n_experts,), jnp.int32).at[
-            jnp.where(live[:, None], chosen, n_experts)].max(1, mode="drop")
-        rows.append(jnp.stack([touched.sum(), live.sum(dtype=jnp.int32)
-                               * chosen.shape[-1]]))
+            jnp.where(mine, chosen, n_experts)].max(1, mode="drop")
+        rows.append(jnp.stack([touched.sum(), routes, routes_held]))
     return jnp.stack(rows) if rows else None
 
 

@@ -20,7 +20,12 @@ collection, a row a slot: per-head keys and values (``SPAttention``;
 or a latent and one rotary key (``LatentAttention``; 19,584 B a token for
 nine layers of rank 512 + 32), read as ``ReplicaEngine.
 cache_bytes_per_token``.  It reserves ``slot_tokens`` positions for every
-layer alike, whatever its kind.
+layer alike, whatever its kind.  A state-space layer's leaves have NO token
+axis (``Mamba2Mixer``: a recurrent state and the last inputs of its
+convolution, one value a slot; 21,585,920 B a slot for five mixers of 128
+heads x 64 x a state of 128), booked apart as ``ReplicaEngine.
+state_bytes_per_slot``; a model that has them is refused the prefix cache
+and speculation (``docs/SERVING.md``).
 
 Decode is per-request greedy OR sampled (temperature / top-k / top-p /
 seed on each :class:`Request`), bitwise-reproducible given (seed,

@@ -44,9 +44,9 @@ prefill-compile counter above, the padded tokens whose prefill program
 attended through the flash forward kernel
 (``stats["prefill_kernel_tokens"]`` beside ``stats["prefill_tokens"]``,
 by the layer's own rule, ``models.transformer.prefill_runs_flash``), the
-gauge of what a cached token costs, and, for a model with expert layers,
-what the pooled step itself
-counted: experts touched and routes, read with the tokens): the
+gauges of what a cached token and a slot's recurrent state cost, and, for
+a model with expert layers, what the pooled step itself
+counted: held experts touched, routes and routes held, read with the tokens): the
 scheduler owns the clock, the SLO histograms, and the fault hooks, so
 the engine stays a pure slot/cache mechanism that tests can drive tick
 by tick.
@@ -63,9 +63,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import runtime
-from ..models.generate import slot_cache_slice, slot_cache_write, \
-    slot_decode_step, slot_extend, slot_prefill, slot_verify_step, \
-    slot_write
+from ..models.generate import STATE_LEAVES, slot_cache_slice, \
+    slot_cache_write, slot_decode_step, slot_extend, slot_prefill, \
+    slot_verify_step, slot_write
 from ..models import transformer
 from .prefix_cache import PrefixCache
 from .slots import SlotPool
@@ -176,19 +176,42 @@ class ReplicaEngine:
         from .. import obs
         from flax.traverse_util import flatten_dict
 
-        #: What a cached token costs, summed over the layers' cache leaves
-        #: (per-head keys and values, or a latent and its rotary key).
+        # The pool books two kinds of cache leaf apart.  A leaf with a token
+        # axis costs a slot its DEPTH (per-head keys and values, or a latent
+        # and its rotary key); a state leaf (``STATE_LEAVES``: a recurrent
+        # layer's) costs every slot the same, whatever its depth.
+        leaves = {path: s for path, s in flatten_dict(shapes).items()
+                  if len(s.shape) >= 2}
+        state = sorted(p for p in leaves if p[-1] in STATE_LEAVES)
+        #: What a cached token costs, summed over the leaves with a token
+        #: axis, and what a slot's state costs, summed over the others.
         self.cache_bytes_per_token = sum(
             int(np.prod(s.shape[2:])) * s.dtype.itemsize
-            for s in jax.tree.leaves(shapes) if len(s.shape) >= 2)
+            for p, s in leaves.items() if p[-1] not in STATE_LEAVES)
+        self.state_bytes_per_slot = sum(
+            int(np.prod(leaves[p].shape[1:])) * leaves[p].dtype.itemsize
+            for p in state)
         obs.registry().gauge_set("tm_serving_cache_bytes_per_token",
                                  self.cache_bytes_per_token, replica=name)
+        obs.registry().gauge_set("tm_serving_state_bytes_per_slot",
+                                 self.state_bytes_per_slot, replica=name)
+        if state and (self._prefix is not None or self._spec_k > 0):
+            # a state cannot be cut into fragments by token, nor un-updated
+            # after a rejected draft: both need snapshots (ROADMAP B5)
+            asked = ("the prefix cache (prefix_cache > 0)"
+                     if self._prefix is not None
+                     else "speculation (spec_k > 0)")
+            raise ValueError(
+                f"{name}: the model keeps a per-slot recurrent state "
+                f"({'/'.join(state[0])}, {len(state)} such leaves), which "
+                f"{asked} cannot serve yet: set it to 0")
         # The expert layers (by their router's path, the order in which
-        # the decode step counts them), each with its two counters' handles.
+        # the decode step counts them), each with its three counters' handles.
         count = obs.registry().counter_handle
         self._expert_counters = [
-            (count("tm_moe_experts_touched_total", layer="/".join(path[:-1])),
-             count("tm_moe_decode_routes_total", layer="/".join(path[:-1])))
+            tuple(count(f"tm_moe_{what}_total", layer="/".join(path[:-1]))
+                  for what in ("experts_touched", "decode_routes",
+                               "decode_routes_held"))
             for path in sorted(flatten_dict(params))
             if path[-1] == "router"]
         self._expert_steps = count("tm_moe_decode_steps_total", replica=name)
@@ -370,10 +393,9 @@ class ReplicaEngine:
         nxt, counts = jax.device_get((nxt, counts))
         if counts is not None:
             self._expert_steps()
-            for (touched, routes), row in zip(self._expert_counters,
-                                              counts.tolist()):
-                touched(row[0])
-                routes(row[1])
+            for handles, row in zip(self._expert_counters, counts.tolist()):
+                for add, value in zip(handles, row):
+                    add(value)
         return nxt
 
     def _backend_verify(self, toks: np.ndarray, pos: np.ndarray,
