@@ -41,13 +41,15 @@ _params = _zeros_like_tree(jax.eval_shape(
     lambda: _model.init(jax.random.PRNGKey(0),
                         jnp.zeros((1, 4), jnp.int32)))["params"])
 
-# Zero pool cache from the decode model's cache spec — the same
-# construction ReplicaEngine uses (serving/engine.py), so the sweep
-# traces exactly the operand shapes the serving loop feeds.
-_pool_cache = _zeros_like_tree(jax.eval_shape(
+# The pool cache's SHAPES from the decode model's cache spec — the
+# construction ReplicaEngine starts from (serving/engine.py), so the
+# sweep traces exactly the operand shapes the serving loop feeds.  No
+# arrays: the pooled programs consume the pool they are handed, and three
+# targets below share this one.
+_pool_cache = jax.eval_shape(
     lambda: _dmodel.init(
         jax.random.PRNGKey(0), jnp.zeros((_SLOTS, 1), jnp.int32),
-        pos_offset=jnp.zeros((_SLOTS,), jnp.int32)))["cache"])
+        pos_offset=jnp.zeros((_SLOTS,), jnp.int32)))["cache"]
 _one_cache = _zeros_like_tree(jax.eval_shape(
     lambda: _dmodel.init(
         jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32),

@@ -335,11 +335,12 @@ def test_served_prefill_compiles_with_the_flash_kernel(one_chip, bucket):
 
 
 def test_served_decode_step_lowers_to_the_parents_text(one_chip):
-    # PR 32 changed what a PROMPT's attention runs and nothing a decode
-    # step runs: jit__slot_step_jit of starcoder2-3b-serve (30 layers, 5
-    # slots of 4608) lowers for the described chip to the text it had
-    # before (sha256 as PERF.md section 6 has it since PR 31).  A change
-    # that means to touch the step replaces the digest and says so there.
+    # jit__slot_step_jit of starcoder2-3b-serve (30 layers, 5 slots of
+    # 4608) lowers for the described chip to a pinned text (sha256 as
+    # PERF.md section 6 has it).  PR 32 and PR 33 left it at 132fe595...;
+    # PR 34 meant to touch it and did: the pool is donated (every cache leaf
+    # of the entry carries tf.aliasing_output), nothing else.  A change that
+    # means to touch the step replaces the digest and says so there.
     import hashlib
 
     from torchmpi_tpu.models.generate import _slot_step_jit
@@ -350,8 +351,9 @@ def test_served_decode_step_lowers_to_the_parents_text(one_chip):
         dmodel, params, cache, _sds((slots,), jnp.int32, one_chip),
         _sds((slots,), jnp.int32, one_chip), *sampling(slots)).as_text()
     assert "tpu_custom_call" not in text
+    assert text.count("tf.aliasing_output") == len(jax.tree.leaves(cache))
     assert hashlib.sha256(text.encode()).hexdigest() == (
-        "132fe59526c5893ed71cbb5cd4bd2aa512c6c2511b22c729279ccf2caa2b5afa")
+        "a179e72a9929a0a8e3704730b8c54deebbff8634572d00c1d9d499e5faff1de9")
 
 
 def _pool_cache(dmodel, slots, one_chip):
@@ -362,8 +364,67 @@ def _pool_cache(dmodel, slots, one_chip):
             pos_offset=jnp.zeros((slots,), jnp.int32)))["cache"])
 
 
+@pytest.fixture(scope="module")
+def compiled_steps():
+    """The served cells' pooled steps as compiled for the described chip, by
+    cell: a step of a whole model takes half a minute, and two tests read
+    one."""
+    return {}
+
+
+def _compiled_step(steps, one_chip, workload):
+    """``jit__slot_step_jit`` of a served cell at its file's slots ->
+    (the pool's shapes, the executable), compiled once a module."""
+    from torchmpi_tpu.models.generate import _slot_step_jit
+
+    if workload not in steps:
+        dmodel, params, slots, sampling = _served(one_chip, workload)
+        cache = _pool_cache(dmodel, slots, one_chip)
+        steps[workload] = cache, _slot_step_jit.lower(
+            dmodel, params, cache, _sds((slots,), jnp.int32, one_chip),
+            _sds((slots,), jnp.int32, one_chip), *sampling(slots)).compile()
+    return steps[workload]
+
+
+@pytest.mark.parametrize("workload", [
+    "sc2-3b-serve-sat", "imoe-16b-serve-conv-sat",
+    "nm3s-120b-serve-chat-sat"])
+def test_served_decode_step_updates_the_pool_in_place(
+        one_chip, compiled_steps, workload):
+    # The pooled step of each served configuration at its file's slots: the
+    # pool is DONATED (PR 34), so the executable aliases every byte of it
+    # to its result, and no instruction copies a whole leaf with a token
+    # axis to a leaf of the same type: the parent's text held 2 such copies
+    # (starcoder2: f32[5,4608,2,128]), 9 (instella: f32[21,3072,512], 132 MB
+    # each, 3.6 ms of a 28.3 ms step on the chip) and 2 (nemotron:
+    # f32[68,2048,2,128]).  What stays and is not the pool's copy: the
+    # attention's READ of a leaf as bfloat16 in another layout (a `copy` to
+    # bf16), the compiler's own round trip of a leaf through fast memory
+    # around the per-row write (`copy-start`: 58 of starcoder2's 60 leaves
+    # at the parent, 44 now), and the convolution taps, [slots, 3, 10240],
+    # which shift by one and so cannot be written over what is still read.
+    from torchmpi_tpu.models.generate import STATE_LEAVES
+
+    cache, compiled = _compiled_step(compiled_steps, one_chip, workload)
+    leaves = jax.tree_util.tree_leaves_with_path(cache)
+    pool_bytes = sum(a.size * a.dtype.itemsize for _, a in leaves)
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= pool_bytes > 1.2e9
+    assert ma.output_size_in_bytes - ma.alias_size_in_bytes < 1e6
+    names = {"float32": "f32", "bfloat16": "bf16"}
+    token_leaves = {
+        "%s[%s]" % (names[a.dtype.name], ",".join(map(str, a.shape)))
+        for path, a in leaves
+        if a.ndim and path[-1].key not in STATE_LEAVES}
+    assert token_leaves
+    copied = re.findall(r"^\s*(?:ROOT )?%copy[\w.]* = (\w+\[[\d,]*\])\S* "
+                        r"copy\(", compiled.as_text(), re.M)
+    assert copied and not token_leaves & set(copied)
+
+
 @pytest.mark.parametrize("program", ["prefill64", "prefill1024", "step"])
-def test_served_hybrid_compiles_at_the_cells_sizes(one_chip, program):
+def test_served_hybrid_compiles_at_the_cells_sizes(
+        one_chip, compiled_steps, program):
     # nm3s-120b-serve-chat-sat's programs at the published widths
     # (chipbench/configs/nemotron3-super-120b-a12b-serve.json): 5 Mamba-2
     # mixers of 128 heads x 64 with a state of 128 (a prompt in chunks of
@@ -373,22 +434,20 @@ def test_served_hybrid_compiles_at_the_cells_sizes(one_chip, program):
     # smallest and the largest prefill bucket of the cell's traffic and the
     # pooled step at the file's slots fit the chip; the step's cache has the
     # two state leaves a mixer and no token axis on them.
-    from torchmpi_tpu.models.generate import (STATE_LEAVES, _slot_prefill_jit,
-                                              _slot_step_jit)
+    from torchmpi_tpu.models.generate import STATE_LEAVES, _slot_prefill_jit
 
-    dmodel, params, slots, sampling = _served(one_chip,
-                                              "nm3s-120b-serve-chat-sat")
     if program == "step":
-        cache = _pool_cache(dmodel, slots, one_chip)
+        cache, compiled = _compiled_step(compiled_steps, one_chip,
+                                         "nm3s-120b-serve-chat-sat")
         state = [a for path, a in jax.tree_util.tree_leaves_with_path(cache)
                  if path[-1].key in STATE_LEAVES]
+        slots = state[0].shape[0]
         assert sorted(a.shape for a in state) == sorted(
             [(slots, 128, 64, 128), (slots, 3, 10240)] * 5)
-        compiled = _slot_step_jit.lower(
-            dmodel, params, cache, _sds((slots,), jnp.int32, one_chip),
-            _sds((slots,), jnp.int32, one_chip), *sampling(slots)).compile()
         attention = []
     else:
+        dmodel, params, _, sampling = _served(one_chip,
+                                              "nm3s-120b-serve-chat-sat")
         bucket = int(program[len("prefill"):])
         compiled = _slot_prefill_jit.lower(
             dmodel, params, _sds((1, bucket), jnp.int32, one_chip),

@@ -785,13 +785,20 @@ def test_slot_prefill_through_the_kernel_matches_dense(
         np.asarray(dense_cache["Block_0"]["SPAttention_0"]["k"]))
 
 
+def _pool_like(one, slots):
+    """A zero pool of ``slots`` rows for a one-row cache; it shares no leaf
+    with the row (the scalar ``idx`` neither: the pool is consumed by the
+    programs it is handed to, the row is not)."""
+    return jax.tree.map(
+        lambda s: jnp.zeros((slots,) + s.shape[1:] if s.ndim else (),
+                            s.dtype), one)
+
+
 def _served_tokens(dmodel, params, prompt, true_len, steps):
     """slot_prefill, the write into row 1 of a two-row pool, then the
     pooled per-row step: -> (the tokens, the prompt's cache, the pool)."""
     one, first = slot_prefill(dmodel, params, prompt, true_len=true_len)
-    pool = jax.tree.map(
-        lambda s: jnp.zeros((2,) + s.shape[1:], s.dtype)
-        if getattr(s, "ndim", 0) >= 1 else s, one)
+    pool = _pool_like(one, 2)
     pool = slot_write(pool, one, 1)
     toks, pos = [int(np.asarray(first)[0])], true_len
     for _ in range(steps - 1):
@@ -849,3 +856,102 @@ def test_generate_prefills_through_the_kernel_to_the_dense_tokens(
     chip_rule()
     np.testing.assert_array_equal(
         np.asarray(generate(model, params, prompt, steps=5)), dense)
+
+
+# ---------------------------------------------------------------------------
+# Who owns a cache (PR 34): the pooled programs consume the pool they are
+# handed (donate_argnums: one generation alive, updated in place); a row is
+# never consumed.
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def one_row(gqa_window_lm):
+    model, params = gqa_window_lm
+    dmodel = model.clone(decode=True, max_len=48)
+    one, first = slot_prefill(dmodel, params, _padded_prompt(16, 11, seed=8),
+                              true_len=11)
+    return dmodel, params, one, int(np.asarray(first)[0])
+
+
+def _deleted(tree):
+    return [leaf.is_deleted() for leaf in jax.tree.leaves(tree)]
+
+
+@pytest.mark.parametrize("program", ["write", "step", "verify"])
+def test_pooled_programs_consume_the_pool_and_leave_the_row(one_row,
+                                                            program):
+    from torchmpi_tpu.models.generate import slot_verify_step
+
+    dmodel, params, one, first = one_row
+    kept = jax.tree.map(np.asarray, one)
+    pool = slot_write(_pool_like(one, 2), one, 1)
+    went_in = pool
+    if program == "write":
+        pool = slot_write(pool, one, 0)
+    elif program == "step":
+        pool, _ = slot_decode_step(
+            dmodel, params, pool, np.asarray([0, first], np.int32),
+            np.asarray([0, 11], np.int32))
+    else:
+        pool, _ = slot_verify_step(
+            dmodel, params, pool, np.asarray([[0, 0], [first, 3]], np.int32),
+            np.asarray([0, 11], np.int32))
+    # every leaf of the pool that went in is gone, the scalar idx too ...
+    assert all(_deleted(went_in))
+    with pytest.raises((RuntimeError, ValueError), match="deleted"):
+        slot_write(went_in, one, 0)
+    # ... what came back is whole, and the row is as it was
+    assert not any(_deleted(pool)) and not any(_deleted(one))
+    for a, b in zip(jax.tree.leaves(one), jax.tree.leaves(kept)):
+        np.testing.assert_array_equal(np.asarray(a), b)
+    row1 = jax.tree.map(lambda p: p[1:2] if p.ndim else p, pool)
+    for a, b in zip(jax.tree.leaves(row1), jax.tree.leaves(kept)):
+        if b.ndim:   # the prompt's positions: no program here rewrites them
+            np.testing.assert_array_equal(np.asarray(a)[:, :11], b[:, :11])
+
+
+def test_donated_step_serves_the_tokens_of_an_undonated_one(one_row):
+    # The same arithmetic writes the same positions: a pool stepped through
+    # the donating program and a pool stepped through the SAME function
+    # jitted without donation hold the same bits and give the same tokens.
+    from torchmpi_tpu.models.generate import _greedy_sampling, _slot_step_jit
+
+    dmodel, params, one, first = one_row
+    plain = jax.jit(_slot_step_jit.__wrapped__, static_argnums=(0,))
+    donated = slot_write(_pool_like(one, 2), one, 1)
+    copied = jax.tree.map(jnp.copy, donated)
+    tok_a = tok_b = first
+    for pos in range(11, 17):
+        args = (jnp.asarray([0, pos], jnp.int32),) + _greedy_sampling(2)
+        donated, nxt_a, _ = _slot_step_jit(
+            dmodel, params, donated, jnp.asarray([0, tok_a], jnp.int32),
+            *args)
+        before = copied
+        copied, nxt_b, _ = plain(
+            dmodel, params, copied, jnp.asarray([0, tok_b], jnp.int32),
+            *args)
+        assert not any(_deleted(before))     # the control copies, as PR 33
+        tok_a, tok_b = int(nxt_a[1]), int(nxt_b[1])
+        assert tok_a == tok_b
+    for a, b in zip(jax.tree.leaves(donated), jax.tree.leaves(copied)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("program", ["cache_write", "extend"])
+def test_row_programs_leave_the_row_they_are_given(one_row, program):
+    # ReplicaEngine._row_zero is ONE template for every admission: the row
+    # programs hand back a new row and leave theirs alone.
+    from torchmpi_tpu.models.generate import (
+        slot_cache_slice, slot_cache_write, slot_extend)
+
+    dmodel, params, one, _ = one_row
+    zero = jax.tree.map(jnp.zeros_like, one)
+    if program == "cache_write":
+        out = slot_cache_write(zero, slot_cache_slice(one, 0, 8), 0)
+    else:
+        out, _ = slot_extend(dmodel, params, zero,
+                             np.zeros((1, 4), np.int32), pos_offset=[8])
+    assert not any(_deleted(zero)) and not any(_deleted(out))
+    assert not any(np.asarray(leaf).any() for leaf in jax.tree.leaves(zero))
+    assert any(np.asarray(leaf).any() for leaf in jax.tree.leaves(out))

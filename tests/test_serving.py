@@ -677,6 +677,9 @@ def test_tp_sharded_server_matches_tp_oracle():
     eng = srv2.router.replicas[0]
     assert eng.stats["spec_steps"] > 0
     assert eng.stats["prefill_compiles"] == 1  # one 8-bucket
+    # the sharded pool is consumed by every step, verify and slot write
+    for e in srv.router.replicas + [eng]:
+        assert e.stats["pool_donated"] == e.stats["pool_calls"] > 0
 
     # The planner keys one decision plan per (replica, mesh) topology.
     from torchmpi_tpu import planner
@@ -885,3 +888,176 @@ def test_latent_and_tensor_parallel_prefills_count_no_kernel_tokens(
     assert ReplicaEngine._prefill_runs_flash(holds(dense), 64)
     assert not ReplicaEngine._prefill_runs_flash(holds(latent), 64)
     assert not TPReplicaEngine._prefill_runs_flash(holds(None), 64)
+
+
+# ---------------------------------------------------------------------------
+# The pool is donated (PR 34): every program that takes it takes it over,
+# the engine holds the one reference, and a failure leaves it serving or dead
+# ---------------------------------------------------------------------------
+
+
+def _alive(tree):
+    return not any(leaf.is_deleted() for leaf in jax.tree.leaves(tree))
+
+
+def _pool_trace(lm, kind):
+    """One finished trace of ``kind`` -> (requests, their prompts, engine)."""
+    model, params = lm
+    prompts = _prompts(6, seed=61)
+    kw = {}
+    if kind == "bucketed":
+        kw = dict(prefill_bucket=8)
+    elif kind == "spec_ngram":
+        kw = dict(spec_k=3)
+    elif kind == "spec_model":
+        draft_model = TransformerLM(vocab=VOCAB, embed=16, depth=1,
+                                    num_heads=2, head_dim=8, max_len=32,
+                                    pos_emb="rope")
+        draft_params = draft_model.init(
+            jax.random.PRNGKey(9), jnp.zeros((1, 4), jnp.int32))["params"]
+        kw = dict(spec_k=2,
+                  draft=serving.ModelDraft(draft_model, draft_params))
+    elif kind == "prefix":
+        prompts = np.concatenate(
+            [np.tile(_prompts(1, tp=16, seed=62), (6, 1)), prompts], axis=1)
+        kw = dict(prefix_cache=16, prefix_block=8)
+    reqs = [serving.Request(f"d{i}", prompts[i], max_new=7,
+                            arrival_s=0.001 * i) for i in range(6)]
+    return reqs, prompts, _run_server(model, params, reqs, **kw)
+
+
+@pytest.mark.parametrize(
+    "kind", ["plain", "bucketed", "spec_ngram", "spec_model", "prefix"])
+def test_every_pooled_call_takes_the_pool_over(lm, kind):
+    model, params = lm
+    reqs, prompts, eng = _pool_trace(lm, kind)
+    # the served tokens are what they were before the pool was donated
+    for i, req in enumerate(reqs):
+        assert req.tokens == _offline(model, params, prompts[i], 7).tolist()
+    # steps, verifies and slot writes: each handed the pool over, each got
+    # it back, and the one left is alive
+    assert eng.stats["pool_calls"] == \
+        eng.stats["prefills"] + eng.stats["steps"] > 0
+    assert eng.stats["pool_donated"] == eng.stats["pool_calls"]
+    assert _alive(eng._cache)
+    if kind == "prefix":
+        # ONE zero row served every hit's assembly and is still there
+        assert eng.stats["prefix_hits"] == 5 and _alive(eng._row_zero)
+    if kind == "spec_model":   # the draft's own pool, rebound the same way
+        assert _alive(eng._draft._cache)
+
+
+def test_pool_counters_are_mirrored_in_the_registry(lm, tmp_path):
+    mpi.stop()
+    mpi.init(mpi.Config(dcn_size=1, obs="metrics", obs_dir=str(tmp_path)))
+    try:
+        from torchmpi_tpu import obs
+
+        obs.reset()
+        _, _, eng = _pool_trace(lm, "spec_ngram")
+        reg = obs.registry()
+        assert reg.counter("tm_serving_pool_calls_total",
+                           replica=eng.name) == eng.stats["pool_calls"] > 0
+        assert reg.counter("tm_serving_pool_donated_total",
+                           replica=eng.name) == eng.stats["pool_donated"]
+    finally:
+        mpi.stop()
+
+
+def test_a_backend_that_declines_the_donation_reads_zero(lm, monkeypatch):
+    # the share is a measurement, not a constant: with the step's program
+    # jitted WITHOUT donation the pool that went in is still there
+    import importlib
+
+    gen = importlib.import_module("torchmpi_tpu.models.generate")
+    model, params = lm
+    monkeypatch.setattr(gen, "_slot_step_jit", jax.jit(
+        gen._slot_step_jit.__wrapped__, static_argnums=(0,)))
+    engine = serving.ReplicaEngine(model, params, slots=2, slot_tokens=32)
+    prompt = _prompts(1, seed=63)[0]
+    req = serving.Request("x", prompt, max_new=5)
+    engine.admit(req)
+    while engine.active:
+        engine.step()
+    assert engine.stats["pool_calls"] == 5           # 1 write, 4 steps
+    assert engine.stats["pool_donated"] == 1         # the write alone
+
+
+def _drive(engine, req):
+    """Admit ``req`` and step the engine dry -> the request's tokens."""
+    sess, done = engine.admit(req)
+    while not done and engine.active:
+        _, finished = engine.step()
+        done = sess in finished
+    return sess.emitted
+
+
+def test_prefill_that_raises_leaves_the_engine_serving(lm, monkeypatch):
+    import torchmpi_tpu.serving.engine as eng_mod
+
+    model, params = lm
+    prompts = _prompts(3, seed=64)
+    engine = serving.ReplicaEngine(model, params, slots=2, slot_tokens=32)
+    first, _ = engine.admit(serving.Request("a", prompts[0], max_new=6))
+    engine.step()
+    calls = engine.stats["pool_calls"]
+    with monkeypatch.context() as m:
+        m.setattr(eng_mod, "slot_prefill",
+                  lambda *a, **k: (_ for _ in ()).throw(
+                      RuntimeError("exploded")))
+        with pytest.raises(RuntimeError, match="exploded"):
+            engine.admit(serving.Request("b", prompts[1], max_new=6))
+    # the prefill failed BEFORE the pool was handed to anything
+    assert not engine.dead and engine.pool.free_count == 1
+    assert engine.stats["pool_calls"] == calls
+    assert _drive(engine, serving.Request("c", prompts[2], max_new=6)) == \
+        _offline(model, params, prompts[2], 6).tolist()
+    assert first.emitted == _offline(model, params, prompts[0], 6).tolist()
+
+
+@pytest.mark.parametrize("program", ["slot_decode_step", "slot_write"])
+@pytest.mark.parametrize("when", ["at_dispatch", "after_it_took_the_pool"])
+def test_pooled_program_that_raises(lm, monkeypatch, program, when):
+    # A program that fails at dispatch has consumed nothing: the engine
+    # keeps its pool and serves on.  One that fails after it took the pool
+    # leaves nothing to decode from: the replica reads as dead, it does not
+    # decode from a deleted buffer.  Either way an admission's slot is freed.
+    import torchmpi_tpu.serving.engine as eng_mod
+
+    model, params = lm
+    prompts = _prompts(2, seed=65)
+    engine = serving.ReplicaEngine(model, params, slots=2, slot_tokens=32)
+    first, _ = engine.admit(serving.Request("a", prompts[0], max_new=6))
+    calls = engine.stats["pool_calls"]
+
+    def fails(*args, **kwargs):
+        pool = args[0] if program == "slot_write" else args[2]
+        if when == "after_it_took_the_pool":
+            for leaf in jax.tree.leaves(pool):
+                leaf.delete()
+        raise RuntimeError("exploded")
+
+    with monkeypatch.context() as m:
+        m.setattr(eng_mod, program, fails)
+        with pytest.raises(RuntimeError, match="exploded"):
+            if program == "slot_write":
+                engine.admit(serving.Request("b", prompts[1], max_new=6))
+            else:
+                engine.step()
+    assert engine.stats["pool_calls"] == calls      # a finished call counts
+    assert engine.pool.free_count == 1              # "a" holds the other
+    if when == "at_dispatch":
+        assert not engine.dead
+        while engine.active:
+            engine.step()
+        assert first.emitted == _offline(model, params, prompts[0],
+                                         6).tolist()
+        assert _drive(engine, serving.Request("b", prompts[1], max_new=6)) \
+            == _offline(model, params, prompts[1], 6).tolist()
+    else:
+        assert engine.dead and not engine.has_capacity()
+        with pytest.raises(RuntimeError, match="is dead"):
+            engine.step()
+        with pytest.raises(RuntimeError, match="is dead"):
+            engine.admit(serving.Request("c", prompts[1], max_new=6))
+        assert [s.request.rid for s in engine.drain()] == ["a"]

@@ -446,6 +446,12 @@ def test_tp_prefix_bitwise():
     assert eng.stats["prefix_hits"] > 0
     for node in eng._prefix._nodes:
         assert eng.pool.block_refcount(node.bid) == 1
+    # tp_slot_decode consumes the cache it is given: the pool every step,
+    # and in the extend the row ASSEMBLED for the hit, never the one zero
+    # template behind every assembly
+    assert eng.stats["pool_donated"] == eng.stats["pool_calls"] > 0
+    assert not any(leaf.is_deleted()
+                   for leaf in jax.tree.leaves(eng._row_zero))
 
 
 # ---------------------------------------------------------------------------

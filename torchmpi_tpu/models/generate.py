@@ -521,7 +521,7 @@ def generate_parallel(model, params, prompt, steps: int, *, mesh,
 #   — the logits are sliced at the true last position, so padded and
 #   unpadded prefill emit bitwise-identical tokens while the compile
 #   count drops from O(distinct lengths) to O(buckets);
-# - :func:`slot_write`      — copy that request's cache rows into pool
+# - :func:`slot_write`      — write that request's cache rows into pool
 #   row ``slot`` (admission);
 # - :func:`slot_decode_step` — ONE [S, 1] decode tick advancing every
 #   active slot at its own depth (per-row ``pos_offset`` — see
@@ -533,6 +533,17 @@ def generate_parallel(model, params, prompt, steps: int, *, mesh,
 #   tokens at per-row depths, returning what the model samples at EVERY
 #   position — the accept/reject scan over those samples is host-side
 #   (serving/engine.py) and distribution-exact by construction.
+#
+# Ownership: a POOL handed to :func:`slot_write`, :func:`slot_decode_step`
+# or :func:`slot_verify_step` is CONSUMED (``donate_argnums``: the program
+# writes its positions into the pool's own buffers, where an input it did
+# not own would first be copied whole, every step).  Use what comes back
+# and hold no second reference: the arrays that went in are deleted.  A
+# ROW is not consumed: ``slot_write`` leaves ``one_cache`` alone (the
+# engine cuts prefix fragments from it after the write), and the row
+# programs (:func:`slot_prefill`, :func:`slot_extend`,
+# :func:`slot_cache_write`) hand back a new row, because the engine's zero
+# row is one template shared by every admission.
 #
 # Sampling: each primitive takes a ``sampling`` operand tuple
 # ``(seeds, idxs, temps, top_ks, top_ps)`` ([R] arrays) routed through
@@ -625,7 +636,7 @@ def slot_prefill(dmodel, params, prompt, *, true_len=None,
                              jnp.asarray(true_len, jnp.int32), *sampling)
 
 
-@partial(jax.jit, static_argnums=(0,))
+@partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
 def _slot_step_jit(dmodel, params, cache, tokens, positions, seeds,
                    idxs, temps, top_ks, top_ps):
     from ..parallel.expert import decode_counts
@@ -643,8 +654,10 @@ def _slot_step_jit(dmodel, params, cache, tokens, positions, seeds,
 
 def slot_decode_step(dmodel, params, cache, tokens, positions,
                      sampling=None, counted: bool = False):
-    """One decode tick over the whole slot pool: ``tokens`` [S] are each
-    slot's pending token, ``positions`` [S] its absolute write index
+    """One decode tick over the whole slot pool; consumes the pool it is
+    given (``cache`` is deleted: use the one that comes back).  ``tokens``
+    [S] are each slot's pending token, ``positions`` [S] its absolute
+    write index
     (inactive slots pass 0 and any token: their outputs are ignored,
     their cache rows are fully overwritten on the next admission, and the
     expert layers' counts leave them out).
@@ -663,7 +676,7 @@ def slot_decode_step(dmodel, params, cache, tokens, positions,
     return out if counted else out[:2]
 
 
-@partial(jax.jit, static_argnums=(0,))
+@partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
 def _slot_verify_jit(dmodel, params, cache, tokens, positions, seeds,
                      idxs, temps, top_ks, top_ps):
     logits, updated = dmodel.apply(
@@ -686,8 +699,9 @@ def _slot_verify_jit(dmodel, params, cache, tokens, positions, seeds,
 
 def slot_verify_step(dmodel, params, cache, tokens, positions,
                      sampling=None):
-    """The speculative-decoding verify forward: ``tokens`` [S, K+1] is
-    each slot's pending token followed by its K draft tokens,
+    """The speculative-decoding verify forward; consumes the pool it is
+    given (``cache`` is deleted: use the one that comes back).  ``tokens``
+    [S, K+1] is each slot's pending token followed by its K draft tokens,
     ``positions`` [S] each slot's write index.  One forward writes all
     K+1 k/v entries at per-row depths and returns the model's sample at
     EVERY position ([S, K+1]) — sample j is the token the sequential
@@ -705,7 +719,7 @@ def slot_verify_step(dmodel, params, cache, tokens, positions,
                             jnp.asarray(positions), *sampling)
 
 
-@jax.jit
+@partial(jax.jit, donate_argnums=(0,))
 def _slot_write_jit(pool_cache, one_cache, slot):
     pooled = [p for p in jax.tree.leaves(pool_cache)
               if getattr(p, "ndim", 0) >= 1]
@@ -723,8 +737,10 @@ def _slot_write_jit(pool_cache, one_cache, slot):
 
 
 def slot_write(pool_cache, one_cache, slot: int):
-    """Copy a :func:`slot_prefill` cache (leading dim 1) into row
-    ``slot`` of the pool cache (leading dim = slot count)."""
+    """Write a :func:`slot_prefill` cache (leading dim 1) into row
+    ``slot`` of the pool cache (leading dim = slot count); consumes the
+    pool it is given (``pool_cache`` is deleted: use the one that comes
+    back) and leaves ``one_cache`` as it was."""
     return _slot_write_jit(pool_cache, one_cache,
                            jnp.asarray(slot, jnp.int32))
 

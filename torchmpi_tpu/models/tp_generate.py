@@ -407,7 +407,9 @@ def _tp_fn(mesh, axis, num_heads, steps, depth, top_k, top_p, eos_id):
 # keeps local.  Sampling flows through the SAME ``_sample_rows`` /
 # ``_sample_keys`` as the dense pool (replicated math inside shard_map,
 # identical keys), which is what makes a dense replica and a TP replica
-# emit bitwise-identical streams for the same (seed, prompt).
+# emit bitwise-identical streams for the same (seed, prompt).  One
+# ownership contract too: a cache handed to :func:`tp_slot_decode` is
+# CONSUMED, as ``generate.slot_decode_step`` consumes its pool.
 # ---------------------------------------------------------------------------
 
 
@@ -478,10 +480,11 @@ def _tp_slot_step_fn(mesh, axis, num_heads, depth):
 
     body = partial(_tp_slot_step_body, axis=axis, num_heads=num_heads)
     cs = _tp_cache_specs(depth, axis)
+    # the cache is donated, as generate's pooled programs donate theirs
     return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(_tp_specs(depth, axis), cs) +
         (P(),) * 7,
-        out_specs=(cs, P()), check_vma=False))
+        out_specs=(cs, P()), check_vma=False), donate_argnums=(1,))
 
 
 def tp_slot_prefill(params, prompt, *, mesh, axis, num_heads, t_max,
@@ -504,7 +507,9 @@ def tp_slot_prefill(params, prompt, *, mesh, axis, num_heads, t_max,
 
 def tp_slot_decode(params, cache, tokens, positions, *, mesh, axis,
                    num_heads, sampling=None):
-    """One pooled decode/verify forward over the TP mesh: ``tokens``
+    """One pooled decode/verify forward over the TP mesh; consumes the
+    cache it is given (``cache`` is deleted: use the one that comes
+    back), the contract of ``generate.slot_decode_step``.  ``tokens``
     [S, T] (T = 1 for the continuous-batching tick, K+1 for the
     speculative verify), ``positions`` [S] per-slot write depths.
     Returns ``(new_cache, samples [S, T])`` — one compiled executable

@@ -593,6 +593,8 @@ def record_serving(event: str, n: int = 1, *, replica: str = "") -> None:
     prefill, O(distinct lengths) without) | ``prefill_kernel_tokens``
     (padded prompt tokens whose prefill program attended through the
     flash forward kernel, ``models.transformer.prefill_runs_flash``) |
+    ``pool_calls`` / ``pool_donated`` (calls of a program that takes the
+    slot pool, and those after which the pool that went in was gone) |
     ``spec_drafted`` /
     ``spec_accepted`` (speculative-decode draft tokens proposed /
     accepted — the live acceptance rate) | ``prefix_hits`` /

@@ -44,7 +44,9 @@ prefill-compile counter above, the padded tokens whose prefill program
 attended through the flash forward kernel
 (``stats["prefill_kernel_tokens"]`` beside ``stats["prefill_tokens"]``,
 by the layer's own rule, ``models.transformer.prefill_runs_flash``), the
-gauges of what a cached token and a slot's recurrent state cost, and, for
+gauges of what a cached token and a slot's recurrent state cost, how many
+of the programs that were handed the pool took it over
+(``stats["pool_donated"]`` of ``stats["pool_calls"]``), and, for
 a model with expert layers, what the pooled step itself
 counted: held experts touched, routes and routes held, read with the tokens): the
 scheduler owns the clock, the SLO histograms, and the fault hooks, so
@@ -77,6 +79,13 @@ class RequestRejected(ValueError):
     or its sampling knobs are invalid).  A dedicated type so the
     scheduler can reject exactly this case and keep serving — any
     other exception out of admission is a real bug and stays loud."""
+
+
+def _gone(pool) -> bool:
+    """Whether a program took ``pool`` over: its first pooled leaf is
+    deleted (an attribute of the array; the device is not asked)."""
+    return next(leaf for leaf in jax.tree.leaves(pool)
+                if getattr(leaf, "ndim", 0) >= 1).is_deleted()
 
 
 def _obs():
@@ -262,7 +271,8 @@ class ReplicaEngine:
                       "spec_steps": 0, "spec_drafted": 0,
                       "spec_accepted": 0, "prefill_tokens": 0,
                       "prefill_kernel_tokens": 0,
-                      "prefix_hits": 0, "prefix_misses": 0}
+                      "prefix_hits": 0, "prefix_misses": 0,
+                      "pool_calls": 0, "pool_donated": 0}
         #: Work units spent (prefill/pooled forward = 1 each, draft
         #: forwards at the proposer's weight) — the scheduler's
         #: ``unit_seconds`` virtual clock advances by the delta.
@@ -385,12 +395,46 @@ class ReplicaEngine:
                 and transformer.prefill_runs_flash(padded_len,
                                                    per_row=False))
 
+    def _pooled(self, program):
+        """Run one program that CONSUMES the pool (a decode step, a verify,
+        a slot write): ``program(pool)`` hands back the new pool first, and
+        what follows it is returned.  The engine holds the only reference,
+        so the pool that went in is gone when the call returns
+        (``stats["pool_donated"]`` counts that; a backend that declines the
+        donation leaves it behind).  A program that raises before it is
+        dispatched has consumed nothing and the engine serves on; one that
+        raises after it took the pool leaves nothing to decode from, and
+        the replica reads as dead."""
+        pool, self._cache = self._cache, None
+        try:
+            out = program(pool)
+        except BaseException:
+            if _gone(pool):
+                self.dead = True
+            else:
+                self._cache = pool
+            raise
+        self._cache = out[0]
+        donated = int(_gone(pool))
+        self.stats["pool_calls"] += 1
+        self.stats["pool_donated"] += donated
+        mod = _obs()
+        if mod is not None:
+            mod.record_serving("pool_calls", replica=self.name)
+            mod.record_serving("pool_donated", donated, replica=self.name)
+        return out[1:]
+
     def _backend_step(self, toks: np.ndarray, pos: np.ndarray, sampling):
-        self._cache, nxt, counts = slot_decode_step(
-            self.dmodel, self.params, self._cache, toks, pos,
-            sampling=sampling, counted=True)
-        # ONE blocking read: the counts are ready when the tokens are
-        nxt, counts = jax.device_get((nxt, counts))
+        def program(pool):
+            pool, nxt, counts = slot_decode_step(
+                self.dmodel, self.params, pool, toks, pos,
+                sampling=sampling, counted=True)
+            # ONE blocking read: the counts are ready when the tokens are
+            # (inside the call, so a step that fails on the device fails
+            # here, with the pool it took)
+            return (pool,) + jax.device_get((nxt, counts))
+
+        nxt, counts = self._pooled(program)
         if counts is not None:
             self._expert_steps()
             for handles, row in zip(self._expert_counters, counts.tolist()):
@@ -400,10 +444,13 @@ class ReplicaEngine:
 
     def _backend_verify(self, toks: np.ndarray, pos: np.ndarray,
                         sampling):
-        self._cache, out = slot_verify_step(
-            self.dmodel, self.params, self._cache, toks, pos,
-            sampling=sampling)
-        return np.asarray(out)
+        def program(pool):
+            pool, out = slot_verify_step(
+                self.dmodel, self.params, pool, toks, pos,
+                sampling=sampling)
+            return pool, np.asarray(out)
+
+        return self._pooled(program)[0]
 
     def _row_template(self):
         """Fresh single-row zero cache — the canvas prefix-cache
@@ -500,7 +547,8 @@ class ReplicaEngine:
                         mod.record_serving("prefill_kernel_tokens",
                                            n_padded, replica=self.name)
             self.stats["prefill_tokens"] += int(padded.shape[1])
-            self._cache = slot_write(self._cache, one_cache, slot)
+            self._pooled(
+                lambda pool: (slot_write(pool, one_cache, slot),))
             tok = int(np.asarray(first)[0])
             full_chain: List[Any] = []
             n_new = n_evicted = 0

@@ -114,18 +114,18 @@ class TPReplicaEngine(ReplicaEngine):
         return False  # tp_generate._block_prefill: dense scores
 
     def _backend_step(self, toks, pos, sampling):
-        self._cache, nxt = tp_slot_decode(
-            self.params, self._cache,
-            np.asarray(toks, np.int32)[:, None], pos,
-            mesh=self.mesh, axis=self.axis, num_heads=self.num_heads,
-            sampling=sampling)
-        return np.asarray(nxt)[:, 0]
+        return self._backend_verify(
+            np.asarray(toks, np.int32)[:, None], pos, sampling)[:, 0]
 
     def _backend_verify(self, toks, pos, sampling):
-        self._cache, out = tp_slot_decode(
-            self.params, self._cache, toks, pos, mesh=self.mesh,
-            axis=self.axis, num_heads=self.num_heads, sampling=sampling)
-        return np.asarray(out)
+        def program(pool):
+            pool, out = tp_slot_decode(
+                self.params, pool, toks, pos, mesh=self.mesh,
+                axis=self.axis, num_heads=self.num_heads,
+                sampling=sampling)
+            return pool, np.asarray(out)
+
+        return self._pooled(program)[0]
 
     def _row_template(self):
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -146,6 +146,9 @@ class TPReplicaEngine(ReplicaEngine):
         # by -(true_len - 1): the TRUE last suffix position then
         # samples with exactly the request's global token index, and
         # the (discarded) earlier positions' keys don't matter.
+        # tp_slot_decode consumes the row it is given: that is the row
+        # assembled for this hit (at least one fragment was written onto
+        # it), never the shared zero template.
         seeds, idxs, temps, tks, tps = sampling
         shifted = (seeds, idxs - jnp.int32(true_len - 1), temps, tks,
                    tps)
