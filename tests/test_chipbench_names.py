@@ -9,6 +9,7 @@ them.  No test imports the chip's library; the rehearsal runs the command
 as the driver does, in a process of its own.
 """
 
+import gzip
 import json
 import os
 import re
@@ -132,8 +133,69 @@ NM3_STAYS_OUT = {"decode_hbm_roofline_pct.srv": [SAT],
                  "decode_moe_hbm_roofline_pct.srv": [IMOE],
                  "latent_attn_ms_per_decode_step.srv": [IMOE]}
 
+# what PR 35 added, in order: the program's own ``tm.serve.*`` spans, read
+# by ``program_span`` (mean ms) and ``serve_idle_by_span`` (the idle share
+# under them): metric -> (reader, the reader's ``span``, layer)
+SATS = [SAT, IMOE, NM3]
+ENGINE, SCHEDULER = "serving engine", "serving scheduler"
+SPAN_METRICS = {
+    "decode_span_ms.srv": ("program_span", "tm.serve.step", ENGINE),
+    "admit_span_ms.srv": ("program_span", "tm.serve.admit", ENGINE),
+    "decode_span_ms.lat": ("program_span", "tm.serve.step", ENGINE),
+    "admit_span_ms.lat": ("program_span", "tm.serve.admit", ENGINE),
+    "idle_step_operands_pct.srv": (
+        "serve_idle_by_span", r"^tm\.serve\.step\.(operands|draft)$", ENGINE),
+    "idle_step_dispatch_pct.srv": (
+        "serve_idle_by_span", r"^tm\.serve\.step\.dispatch$", ENGINE),
+    "idle_step_read_pct.srv": (
+        "serve_idle_by_span", r"^tm\.serve\.step\.read$", ENGINE),
+    "idle_step_book_pct.srv": (
+        "serve_idle_by_span", r"^tm\.serve\.step\.book$", ENGINE),
+    "idle_admit_operands_pct.srv": (
+        "serve_idle_by_span", r"^tm\.serve\.admit\.operands$", ENGINE),
+    "idle_admit_dispatch_pct.srv": (
+        "serve_idle_by_span", r"^tm\.serve\.admit\.(prefill|slot_write)$",
+        ENGINE),
+    "idle_admit_read_pct.srv": (
+        "serve_idle_by_span", r"^tm\.serve\.admit\.read$", ENGINE),
+    "idle_tick_self_pct.srv": (
+        "serve_idle_by_span",
+        r"^tm\.serve\.(tick|step|admit|admit\.book|gate)$", SCHEDULER),
+    "idle_outside_tick_pct.srv": (
+        "serve_idle_by_span", r"^outside$", SCHEDULER),
+    "idle_in_step_pct.lat": (
+        "serve_idle_by_span", r"^tm\.serve\.step", ENGINE),
+    "idle_in_admit_pct.lat": (
+        "serve_idle_by_span", r"^tm\.serve\.admit", ENGINE),
+    "idle_outside_tick_pct.lat": (
+        "serve_idle_by_span", r"^outside$", SCHEDULER),
+}
+SPAN_SRV = {m for m in SPAN_METRICS if m.endswith(".srv")}
+IDLE_SRV = [m for m, v in SPAN_METRICS.items()
+            if v[0] == "serve_idle_by_span" and m.endswith(".srv")]
+IDLE = harness.load_module(MANIFEST, "readers", "serve_idle_by_span")
+
 with open(os.path.join(TESTDATA, "expected_names.json")) as _f:
     WANT = json.load(_f)
+
+
+with open(os.path.join(TESTDATA, "expected_served.json")) as _f:
+    SERVED = json.load(_f)
+
+
+@pytest.fixture(scope="module")
+def recorded_served(tmp_path_factory):
+    """Three recorded ticks of ``sc2-3b-serve-sat`` with one admission in
+    the middle one, cut from PR 35's traced chip run: (the file, its Trace
+    and module executions as the served runner loads them)."""
+    from chipbench import xplane_serve
+
+    path = os.path.join(TESTDATA, SERVED["file"])
+    raw = tmp_path_factory.mktemp("served") / "ticks.xplane.pb"
+    with gzip.open(path, "rb") as f:
+        raw.write_bytes(f.read())
+    trace, modules = xplane_serve.load(str(raw))
+    return path, trace, modules
 
 
 @pytest.fixture(scope="module")
@@ -255,7 +317,7 @@ def test_sparse_served_cell_joins_the_lists_whose_readers_are_right_for_it():
     decode step's bytes; one four-chip cell of eight."""
     mine = {m["name"] for m in harness.resolve(MANIFEST, IMOE).per_layer}
     joined = set(SRV_METRICS) - set(IMOE_STAYS_OUT)
-    assert mine == joined | set(IMOE_METRICS)
+    assert mine == joined | set(IMOE_METRICS) | SPAN_SRV
     for metric in joined:
         assert harness.by_name(MANIFEST["per_layer"], metric,
                                "metric")["workloads"][:2] == [SAT, IMOE]
@@ -314,7 +376,7 @@ def test_hybrid_served_cell_joins_the_lists_whose_readers_are_right_for_it():
     mine = {m["name"] for m in harness.resolve(MANIFEST, NM3).per_layer}
     joined = (set(SRV_METRICS) - set(IMOE_STAYS_OUT)) | set(
         NM3_JOINS_OF_IMOE)
-    assert mine == joined | set(NM3_METRICS)
+    assert mine == joined | set(NM3_METRICS) | SPAN_SRV
     for metric in joined:
         assert harness.by_name(MANIFEST["per_layer"], metric,
                                "metric")["workloads"][-1] == NM3
@@ -403,11 +465,53 @@ def test_sparse_served_readers_give_no_number_without_the_programs_names():
     obs.reset()
 
 
+@pytest.mark.parametrize("metric", list(SPAN_METRICS))
+def test_span_metric_resolves_to_a_file_and_a_reader(metric):
+    reader, span, layer = SPAN_METRICS[metric]
+    lat = metric.endswith(".lat")
+    entry = harness.by_name(MANIFEST["per_layer"], metric, "metric")
+    assert entry == {
+        "name": metric, "unit": "ms" if "_span_ms" in metric else "%",
+        "better": "lower", "source": "program_span", "layer": layer,
+        "moves": "itl_ms_p99" if lat else "out_tokens_per_s_chip",
+        "workloads": [R80] if lat else SATS}
+    spec = harness.load_json(MANIFEST, "layer_metrics", metric)
+    assert spec == {"reader": reader, "args": {"span": span}}
+    assert callable(harness.load_module(MANIFEST, "readers", reader).read)
+    for cell in entry["workloads"]:
+        mine = harness.by_name(harness.resolve(MANIFEST, cell).per_layer,
+                               metric, "metric")
+        assert (mine["reader"], mine["args"]) == (reader, spec["args"])
+
+
+def test_the_idle_rules_name_every_program_span_once():
+    """The nine ``.srv`` rules are a partition of the program's span names
+    and ``outside`` (so their shares add up to the idle share); the three
+    ``.lat`` ones a coarser cut that leaves the tick's self time out."""
+    from torchmpi_tpu.serving.engine import SPANS
+
+    names = list(SPANS) + [IDLE.OUTSIDE]
+    assert len(IDLE_SRV) == 9
+    for name in names:
+        assert sum(bool(re.search(SPAN_METRICS[m][1], name))
+                   for m in IDLE_SRV) == 1, name
+    lat = [m for m in SPAN_METRICS if m.startswith("idle") and
+           m.endswith(".lat")]
+    left_out = [n for n in names
+                if not any(re.search(SPAN_METRICS[m][1], n) for m in lat)]
+    assert left_out == ["tm.serve.gate", "tm.serve.tick"]
+    assert all(sum(bool(re.search(SPAN_METRICS[m][1], n)) for m in lat) <= 1
+               for n in names)
+    # the two spans the ``*_span_ms`` metrics read are the program's
+    assert {v[1] for v in SPAN_METRICS.values()
+            if v[0] == "program_span"} <= set(SPANS)
+
+
 def test_benchmark_json_only_gained_entries_at_the_end():
     """What the benchmark had (PR 23, then PR 24) is still there, first
     and unchanged in order; PR 26's metrics follow it, then PR 27's, PR
-    30's, PR 31's and PR 33's, each where its PR appended it: the next PR
-    appends after them and adds its own slice here."""
+    30's, PR 31's, PR 33's and PR 35's, each where its PR appended it: the
+    next PR appends after them and adds its own slice here."""
     names = [m["name"] for m in MANIFEST["per_layer"]]
     assert set(names[10:22]) == set(NEW_METRICS)
     assert names[:22] == [
@@ -424,6 +528,7 @@ def test_benchmark_json_only_gained_entries_at_the_end():
     assert names[31:55] == SRV_METRICS + LAT_METRICS
     assert names[55:60] == list(IMOE_METRICS)
     assert names[60:66] == list(NM3_METRICS)
+    assert names[66:82] == list(SPAN_METRICS)
     layers = {m["layer"] for m in MANIFEST["per_layer"][:10]}
     assert {m["layer"] for m in MANIFEST["per_layer"][10:22]} <= layers
     assert {m["layer"] for m in MANIFEST["per_layer"][22:31]} <= layers | {
@@ -433,6 +538,8 @@ def test_benchmark_json_only_gained_entries_at_the_end():
         "serving slot pool"}
     assert {m["layer"] for m in MANIFEST["per_layer"][60:66]} == {
         "state-space layers", "expert layer", "serving engine"}
+    assert {m["layer"] for m in MANIFEST["per_layer"][66:82]} == {
+        ENGINE, SCHEDULER}
     assert [c["name"] for c in MANIFEST["configs"]][:6] == [
         "resnet50", "starcoder2-3b", "smallthinker-21b-a3b",
         "starcoder2-3b-serve", "instella-moe-16b-a3b-serve", NM3_CONFIG]
@@ -520,6 +627,61 @@ def test_program_span_reads_the_recorded_host_plane(recorded):
                            "tm.step.throttle") == []
 
 
+def test_idle_by_span_reads_the_recorded_ticks(recorded_served, monkeypatch):
+    path, trace, modules = recorded_served
+    assert list(trace.window) == SERVED["window_ns"]
+    assert {d: len(es) for d, es in trace.devices.items()} == {
+        SERVED["device"]: SERVED["device_events"]}
+    assert [e.name.split("(")[0] for e in modules[SERVED["device"]]] == \
+        SERVED["modules"]
+    spans = IDLE.program_spans(path, SPAN.HOST_PLANE)
+    counts = {}
+    for _, _, name in spans:
+        counts[name] = counts.get(name, 0) + 1
+    assert counts == SERVED["spans"]
+    shares, rows = IDLE.split(trace, spans)
+    assert shares == pytest.approx(SERVED["idle_pct_by_span"], rel=1e-9)
+    assert {r["span"]: r["n"] for r in rows if r["n"]} == SERVED["spans"]
+    # the reader as the runner calls it, the profile being the fixture
+    monkeypatch.setattr(SCOPES, "raw_trace", lambda ctx: path)
+    ctx = {"cell": harness.resolve(MANIFEST, SAT), "trace": trace}
+    got = {m: IDLE.read(ctx, span=v[1]) for m, v in SPAN_METRICS.items()
+           if v[0] == "serve_idle_by_span"}
+    assert got == pytest.approx(SERVED["metrics"], rel=1e-9)
+    # the partition: the nine shares ARE the idle share of the same trace
+    busy = harness.load_module(MANIFEST, "readers", "xplane_busy")
+    assert busy.read(ctx) == pytest.approx(SERVED["device_idle_pct"])
+    assert sum(got[m] for m in IDLE_SRV) == pytest.approx(
+        SERVED["device_idle_pct"], rel=1e-12)
+    # on the chip the read owns most of a step's idle time, not the loop
+    assert got["idle_step_read_pct.srv"] > got["idle_step_operands_pct.srv"] \
+        > got["idle_step_dispatch_pct.srv"] > got["idle_outside_tick_pct.srv"]
+
+
+def test_program_span_reads_the_recorded_ticks(recorded_served):
+    path, trace, modules = recorded_served
+    stats = {name: [st for _, _, st in SPAN.host_spans(
+        path, f"tm.serve.{name}")] for name in (
+            "tick", "step", "admit", "gate", "admit.prefill")}
+    assert [st["tick"] for st in stats["tick"]] == SERVED["tick_nums"]
+    assert stats["step"] == SERVED["step_stats"]
+    assert stats["admit"] == SERVED["admit_stats"]
+    assert stats["admit.prefill"] == SERVED["prefill_stats"]
+    # a request's path: its gate, then the admission that carries its rid
+    assert stats["gate"] == SERVED["gate_stats"] == [
+        {"rid": stats["admit"][0]["rid"]}]
+    decode, admit = (
+        SPAN.span_ms(SPAN.host_spans(path, name), trace.window)
+        for name in ("tm.serve.step", "tm.serve.admit"))
+    assert decode == pytest.approx(SERVED["decode_span_ms"], rel=1e-9)
+    assert admit == pytest.approx(SERVED["admit_span_ms"], rel=1e-9)
+    # the span is the step as the caller sees it: never below its device time
+    module_ms = harness.load_module(MANIFEST, "readers", "serve_module_ms")
+    ctx = {"modules": modules, "traced": {"steps": 3}}
+    device_ms = module_ms.read(ctx, module="jit__slot_step_jit", per="steps")
+    assert 12.0 < device_ms < decode < device_ms + 6.0
+
+
 # ------------------------------------------------- events -> numbers, by hand
 
 E = xplane.Event
@@ -599,6 +761,101 @@ def test_program_span_on_hand_made_spans():
     assert SPAN.span_ms(spans, (5 * ms, 30 * ms)) == pytest.approx(1.5)
     assert SPAN.span_ms(spans, (40 * ms, 50 * ms)) is None
     assert SPAN.span_ms([], (0, 50 * ms)) is None
+
+
+# the program's spans of two ticks (ms): an admission and a step, a request
+# entering between the ticks, a step alone
+HAND_SPANS = [
+    (0, 40, "tm.serve.tick"),
+    (1, 18, "tm.serve.admit"),
+    (2, 4, "tm.serve.admit.operands"), (4, 8, "tm.serve.admit.prefill"),
+    (8, 9, "tm.serve.admit.slot_write"), (9, 16, "tm.serve.admit.read"),
+    (16, 17.5, "tm.serve.admit.book"),
+    (19, 39, "tm.serve.step"),
+    (20, 22, "tm.serve.step.operands"), (22, 25, "tm.serve.step.dispatch"),
+    (25, 37, "tm.serve.step.read"), (37, 38, "tm.serve.step.book"),
+    (44, 45, "tm.serve.gate"),
+    (50, 70, "tm.serve.tick"),
+    (51, 69, "tm.serve.step"),
+    (52, 54, "tm.serve.step.operands"), (54, 56, "tm.serve.step.dispatch"),
+    (56, 67, "tm.serve.step.read"), (67, 68, "tm.serve.step.book")]
+HAND_WINDOW_MS = 80
+# chip 0 runs the prefill, then the two steps; its four gaps straddle up to
+# twelve spans each.  Chip 1 idles 4 ms, three spans deep in the first read
+HAND_BUSY = {"/device:TPU:0": [(6, 15), (26, 36), (57.5, 66)],
+             "/device:TPU:1": [(0, 30), (34, 80)]}
+HAND_IDLE_MS = {        # chip 0's 52.5 idle ms by the innermost span
+    "tm.serve.tick": 5, "tm.serve.admit": 1.5,
+    "tm.serve.admit.operands": 2, "tm.serve.admit.prefill": 2,
+    "tm.serve.admit.read": 1, "tm.serve.admit.book": 1.5,
+    "tm.serve.step": 4, "tm.serve.step.operands": 4,
+    "tm.serve.step.dispatch": 5, "tm.serve.step.read": 4.5,
+    "tm.serve.step.book": 2, "tm.serve.gate": 1, "outside": 19}
+
+
+def hand_served(chips):
+    ms = 1_000_000
+    spans = [(s * ms, t * ms, name) for s, t, name in HAND_SPANS]
+    devices = {d: [E("%op", s * ms, t * ms, {}) for s, t in busy]
+               for d, busy in list(HAND_BUSY.items())[:chips]}
+    return xplane.Trace(devices, {}, (0, HAND_WINDOW_MS * ms)), spans
+
+
+@pytest.mark.parametrize("chips", [1, 2])
+def test_idle_by_span_on_hand_made_events(chips, monkeypatch):
+    trace, spans = hand_served(chips)
+    want = dict(HAND_IDLE_MS)
+    if chips == 2:
+        want["tm.serve.step.read"] += 4
+    idle = IDLE.idle_ns_by_span(trace.devices, trace.window, spans)
+    assert {k: v / 1e6 for k, v in idle.items()} == want
+    assert "tm.serve.admit.slot_write" not in idle      # never idle there
+    # the innermost span: each instant once, in order, none left open
+    cover = IDLE.innermost(spans)
+    assert all(a[1] <= b[0] for a, b in zip(cover, cover[1:]))
+    assert sum(t - s for s, t, _ in cover) == (40 + 1 + 20) * 1_000_000
+    # the reader, as the runner calls it: a share of the window, a chip
+    monkeypatch.setattr(SCOPES, "raw_trace", lambda ctx: "hand.xplane.pb")
+    monkeypatch.setattr(IDLE, "program_spans", lambda path, plane: spans)
+    ctx = {"cell": harness.resolve(MANIFEST, SAT), "trace": trace}
+    got = {m: IDLE.read(ctx, span=SPAN_METRICS[m][1]) for m in IDLE_SRV}
+    scale = 100.0 / (HAND_WINDOW_MS * chips)
+    assert got["idle_step_read_pct.srv"] == pytest.approx(
+        want["tm.serve.step.read"] * scale)
+    assert got["idle_step_operands_pct.srv"] == pytest.approx(4 * scale)
+    assert got["idle_admit_dispatch_pct.srv"] == pytest.approx(2 * scale)
+    assert got["idle_tick_self_pct.srv"] == pytest.approx(
+        (5 + 1.5 + 1.5 + 4 + 1) * scale)
+    assert got["idle_outside_tick_pct.srv"] == pytest.approx(19 * scale)
+    # the nine shares are the idle share, to the last digit that matters
+    busy = harness.load_module(MANIFEST, "readers", "xplane_busy")
+    assert sum(got.values()) == pytest.approx(busy.read(ctx), rel=1e-12)
+    # ``-r80``'s coarser cut leaves the tick's and the gate's self time out
+    lat = {m: IDLE.read(ctx, span=SPAN_METRICS[m][1])
+           for m in ("idle_in_step_pct.lat", "idle_in_admit_pct.lat",
+                     "idle_outside_tick_pct.lat")}
+    assert sum(lat.values()) == pytest.approx(busy.read(ctx) - 6 * scale)
+
+
+def test_idle_by_span_gives_no_number_without_a_plane_or_the_spans(
+        monkeypatch):
+    """A rehearsal on the CPU has no device plane, the parent's profile no
+    ``tm.serve.*`` span: no number from either, and no error."""
+    trace, spans = hand_served(1)
+    ctx = {"cell": harness.resolve(MANIFEST, SAT),
+           "trace": xplane.Trace({}, {}, trace.window)}
+    monkeypatch.setattr(
+        SCOPES, "raw_trace",
+        lambda ctx: pytest.fail("no device plane: no file is looked for"))
+    assert IDLE.read(ctx, span="^outside$") is None
+    # the recorded train step: a device plane and a host plane, no tm.serve
+    path = os.path.join(TESTDATA, WANT["file"])
+    assert IDLE.program_spans(path, SPAN.HOST_PLANE) == []
+    monkeypatch.setattr(SCOPES, "raw_trace", lambda ctx: path)
+    ctx["trace"] = xplane.load(path)
+    for metric in IDLE_SRV:
+        assert IDLE.read(ctx, span=SPAN_METRICS[metric][1]) is None
+    assert IDLE.innermost([]) == []
 
 
 def test_wire_reader_on_a_hand_made_message():
@@ -696,6 +953,17 @@ def test_traced_rehearsal_of_the_expert_cell_reads_its_counters():
         out["metrics"])
 
 
+def served_spans_are_read_and_the_idle_split_left_out(out):
+    """The program's ``tm.serve.*`` spans need a host plane only: their
+    means are there on the CPU, an admission longer than the prefill it
+    holds would be alone; the idle split needs a device plane."""
+    assert SPAN_SRV & set(out["metrics"]) == {"decode_span_ms.srv",
+                                              "admit_span_ms.srv"}
+    assert out["metrics"]["decode_span_ms.srv"]["value"] > 0
+    assert out["metrics"]["admit_span_ms.srv"]["value"] > 0
+    assert out["checks"]["compiles_in_window"] == []
+
+
 def test_traced_rehearsal_of_the_sparse_served_cell_comes_out_correct():
     """``run.py --rehearse`` as the driver calls it, through
     ``serving.Server``: correct against the plain reference; the program's
@@ -718,6 +986,7 @@ def test_traced_rehearsal_of_the_sparse_served_cell_comes_out_correct():
     assert not device_only & set(out["metrics"])
     assert {"batch_occupancy_pct.srv", "prefill_share_pct.srv",
             "itl_ms_p50.srv"} <= set(out["metrics"])
+    served_spans_are_read_and_the_idle_split_left_out(out)
 
 
 def test_traced_rehearsal_of_the_hybrid_served_cell_comes_out_correct():
@@ -746,3 +1015,4 @@ def test_traced_rehearsal_of_the_hybrid_served_cell_comes_out_correct():
     assert not device_only & set(out["metrics"])
     assert {"batch_occupancy_pct.srv", "prefill_share_pct.srv",
             "itl_ms_p50.srv"} <= set(out["metrics"])
+    served_spans_are_read_and_the_idle_split_left_out(out)

@@ -13,6 +13,7 @@ the off-by-default import discipline (a non-serving session never has
 analysis/obs/faults).
 """
 
+import collections
 import importlib.util
 import json
 import os
@@ -28,6 +29,7 @@ import jax.numpy as jnp
 import torchmpi_tpu as mpi
 from torchmpi_tpu import serving
 from torchmpi_tpu.models import TransformerLM, generate
+from torchmpi_tpu.serving.engine import SPANS
 from torchmpi_tpu.serving.slots import SlotPool
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -631,7 +633,7 @@ def test_bucketed_prefill_compile_count_and_bitwise(lm):
 # ---------------------------------------------------------------------------
 
 
-def test_tp_sharded_server_matches_tp_oracle():
+def test_tp_sharded_server_matches_tp_oracle(tmp_path):
     """``Server.sharded`` carves disjoint TP meshes per replica; every
     stream must equal the offline ``tp_generate`` oracle — and spec +
     bucketed prefill compose with the sharded backend bitwise."""
@@ -658,11 +660,28 @@ def test_tp_sharded_server_matches_tp_oracle():
                             arrival_s=0.001 * i) for i in range(6)]
     srv = serving.Server.sharded(tparams, tp=2, num_heads=4,
                                  slot_tokens=32, replicas=2, slots=2)
-    done = srv.run_trace(reqs, tick_seconds=0.001)
+    done = []
+    spans = _traced(tmp_path, lambda: done.extend(
+        srv.run_trace(reqs, tick_seconds=0.001)))
     assert len(done) == 6
     assert {r.replica for r in reqs} == {"tp0", "tp1"}
     for i, req in enumerate(reqs):
         assert req.tokens == oracle[req.rid], i
+    # a mesh-slice replica names its phases as the dense one does: the
+    # dispatch and the read sit in the one seam both backends go through
+    assert {s.name for s in spans} == set(SPANS) - {"tm.serve.step.draft"}
+    step_spans = _named(spans, "tm.serve.step")
+    assert {s.stats["replica"] for s in step_spans} == {"tp0", "tp1"}
+    for s in step_spans:
+        assert [k.name for k in _children(spans, s)] == [
+            f"tm.serve.step.{k}" for k in ("operands", "dispatch", "read",
+                                           "book")]
+    admits = _named(spans, "tm.serve.admit")
+    assert sorted(a.stats["rid"] for a in admits) == sorted(oracle)
+    for a in admits:
+        kids = _children(spans, a)
+        assert [k.name for k in kids] == ADMIT_CHILDREN
+        assert kids[1].stats == {"padded_tokens": 5, "kernel": 0}
 
     # Speculation + bucketing over the SAME sharded stack: bitwise.
     reqs2 = [serving.Request(f"t{i}", prompts[i], max_new=lens[i])
@@ -900,6 +919,16 @@ def _alive(tree):
     return not any(leaf.is_deleted() for leaf in jax.tree.leaves(tree))
 
 
+def _model_draft():
+    """A small same-vocabulary LM as the speculative proposer."""
+    draft_model = TransformerLM(vocab=VOCAB, embed=16, depth=1,
+                                num_heads=2, head_dim=8, max_len=32,
+                                pos_emb="rope")
+    draft_params = draft_model.init(
+        jax.random.PRNGKey(9), jnp.zeros((1, 4), jnp.int32))["params"]
+    return serving.ModelDraft(draft_model, draft_params)
+
+
 def _pool_trace(lm, kind):
     """One finished trace of ``kind`` -> (requests, their prompts, engine)."""
     model, params = lm
@@ -910,13 +939,7 @@ def _pool_trace(lm, kind):
     elif kind == "spec_ngram":
         kw = dict(spec_k=3)
     elif kind == "spec_model":
-        draft_model = TransformerLM(vocab=VOCAB, embed=16, depth=1,
-                                    num_heads=2, head_dim=8, max_len=32,
-                                    pos_emb="rope")
-        draft_params = draft_model.init(
-            jax.random.PRNGKey(9), jnp.zeros((1, 4), jnp.int32))["params"]
-        kw = dict(spec_k=2,
-                  draft=serving.ModelDraft(draft_model, draft_params))
+        kw = dict(spec_k=2, draft=_model_draft())
     elif kind == "prefix":
         prompts = np.concatenate(
             [np.tile(_prompts(1, tp=16, seed=62), (6, 1)), prompts], axis=1)
@@ -1061,3 +1084,247 @@ def test_pooled_program_that_raises(lm, monkeypatch, program, when):
         with pytest.raises(RuntimeError, match="is dead"):
             engine.admit(serving.Request("c", prompts[1], max_new=6))
         assert [s.request.rid for s in engine.drain()] == ["a"]
+
+
+# ---------------------------------------------------------------------------
+# The tier's own spans on the profiler's clock (``tm.serve.*``)
+# ---------------------------------------------------------------------------
+
+ADMIT_CHILDREN = [s for s in SPANS if s.startswith("tm.serve.admit.")]
+Span = collections.namedtuple("Span", "name start end stats")
+
+
+def _traced(trace_dir, body):
+    """Run ``body`` under ``jax.profiler`` -> the host plane's
+    ``tm.serve.*`` events in order of their start (a parent before its
+    first child), as ``[Span]``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=options)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
+    spans = [Span(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                  dict(e.stats))
+             for plane in ProfileData.from_file(path).planes
+             if plane.name == "/host:CPU"
+             for line in plane.lines for e in line.events
+             if e.name.startswith("tm.serve.")]
+    assert {s.name for s in spans} <= set(SPANS)
+    return sorted(spans, key=lambda s: (s.start, -s.end))
+
+
+def _named(spans, name):
+    return [s for s in spans if s.name == name]
+
+
+def _inside(spans, parent):
+    """The spans that lie inside ``parent``, itself left out."""
+    return [s for s in spans if s is not parent
+            and parent.start <= s.start and s.end <= parent.end]
+
+
+def _children(spans, parent):
+    """Those inside ``parent`` that no other span inside it holds."""
+    inside = _inside(spans, parent)
+    return [s for s in inside
+            if not any(s in _inside(inside, other) for other in inside)]
+
+
+def _in_order(spans):
+    return all(a.end <= b.start for a, b in zip(spans, spans[1:]))
+
+
+def _span_server(lm, **kw):
+    """A one-replica server whose programs are compiled: what it is traced
+    serving afterwards is the steady loop."""
+    model, params = lm
+    kw = {"slots": 2, "slot_tokens": 32, "prefill_bucket": 8, **kw}
+    srv = serving.Server(model, params, replicas=1, **kw)
+    warm = [serving.Request(f"warm{i}", p, max_new=3)
+            for i, p in enumerate(_prompts(2, seed=70))]
+    srv.run_trace(warm, tick_seconds=0.001)
+    return srv, srv.router.replicas[0]
+
+
+def test_a_served_trace_writes_the_span_tree(lm, tmp_path):
+    model, params = lm
+    srv, eng = _span_server(lm)
+    prompts = _prompts(5, seed=71)
+    lens = [4, 1, 6, 3, 5]          # "s1" is done at its admission
+    reqs = [serving.Request(f"s{i}", prompts[i], max_new=lens[i],
+                            arrival_s=0.002 * i) for i in range(5)]
+    ticks, steps = srv._n_ticks, eng.stats["steps"]
+    spans = _traced(tmp_path, lambda: srv.run_trace(reqs,
+                                                    tick_seconds=0.001))
+    for i, req in enumerate(reqs):      # the tokens are what they were
+        assert req.tokens == _offline(model, params, prompts[i],
+                                      lens[i]).tolist()
+    # one tick a call of _tick, counted by the server from its first on
+    tick_spans = _named(spans, "tm.serve.tick")
+    assert [t.stats["tick"] for t in tick_spans] == list(
+        range(ticks, srv._n_ticks))
+    assert len(tick_spans) > 5 and _in_order(tick_spans)
+    assert tick_spans[0].stats["pending"] == 1      # "s0" was queued
+    # one admission a request, with its rid, the slot it got, the replica
+    admits = _named(spans, "tm.serve.admit")
+    assert [a.stats["rid"] for a in admits] == [f"s{i}" for i in range(5)]
+    assert all(a.stats["slot"] in (0, 1) and a.stats["replica"] == eng.name
+               and a.stats["prompt_tokens"] == 5 for a in admits)
+    padded, _ = eng._pad_prompt(prompts[:1])
+    for a in admits:
+        kids = _children(spans, a)
+        assert [k.name for k in kids] == ADMIT_CHILDREN and _in_order(kids)
+        assert _inside(spans, a) == kids
+        assert kids[1].stats == {"padded_tokens": padded.shape[1],
+                                 "kernel": 0}
+    # one step a pooled step, counted by the engine, with its live sessions
+    step_spans = _named(spans, "tm.serve.step")
+    assert [s.stats["step"] for s in step_spans] == list(
+        range(steps, eng.stats["steps"]))
+    assert all(s.stats["live"] in (1, 2) and s.stats["spec"] == 0
+               and s.stats["replica"] == eng.name for s in step_spans)
+    assert max(s.stats["live"] for s in step_spans) == 2
+    for s in step_spans:
+        kids = _children(spans, s)
+        assert [k.name for k in kids] == [
+            f"tm.serve.step.{k}" for k in ("operands", "dispatch", "read",
+                                           "book")] and _in_order(kids)
+        assert _inside(spans, s) == kids
+    # a tick holds its admissions and then its one step, nothing else
+    for t in tick_spans:
+        kids = _children(spans, t)
+        assert [k.name for k in kids] == \
+            ["tm.serve.admit"] * (len(kids) - 1) + ["tm.serve.step"] \
+            or {k.name for k in kids} <= {"tm.serve.admit"}
+    assert sum(len(_children(spans, t)) for t in tick_spans) == \
+        len(admits) + len(step_spans)
+    # a request's path: its gate, then the admission of the same rid
+    gates = _named(spans, "tm.serve.gate")
+    assert [g.stats["rid"] for g in gates] == [f"s{i}" for i in range(5)]
+    for g, a in zip(gates, admits):
+        assert g.end <= a.start and not _inside(spans, g)
+    assert {s.name for s in spans} == set(SPANS) - {"tm.serve.step.draft"}
+
+
+def test_a_tick_with_no_live_session_writes_no_step_span(lm, tmp_path):
+    srv, eng = _span_server(lm)
+    reqs = [serving.Request(f"o{i}", p, max_new=1)
+            for i, p in enumerate(_prompts(3, seed=72))]
+    steps = eng.stats["steps"]
+    spans = _traced(tmp_path, lambda: srv.run_trace(reqs,
+                                                    tick_seconds=0.001))
+    assert [len(r.tokens) for r in reqs] == [1, 1, 1]
+    assert eng.stats["steps"] == steps
+    assert len(_named(spans, "tm.serve.admit")) == 3
+    assert not _named(spans, "tm.serve.step")
+    assert not [s for s in spans if s.name.startswith("tm.serve.step.")]
+
+
+def test_a_request_that_gets_no_slot_opens_no_admit_span(lm, tmp_path):
+    model, params = lm
+    engine = serving.ReplicaEngine(model, params, slots=1, slot_tokens=16)
+    prompts = _prompts(3, seed=73)
+    out = {}
+
+    def body():
+        out["first"] = engine.admit(
+            serving.Request("fits", prompts[0], max_new=4))
+        # the pool is full: raced, the caller retries next tick
+        out["raced"] = engine.admit(
+            serving.Request("raced", prompts[1], max_new=4))
+        with pytest.raises(serving.RequestRejected):
+            engine.admit(serving.Request("big", prompts[2], max_new=12))
+
+    spans = _traced(tmp_path, body)
+    assert out["first"] is not None and out["raced"] is None
+    assert [a.stats["rid"] for a in _named(spans, "tm.serve.admit")] == [
+        "fits"]
+    assert {s.name for s in spans} == {"tm.serve.admit", *ADMIT_CHILDREN}
+
+
+def test_a_prefill_that_raises_closes_its_spans(lm, monkeypatch, tmp_path):
+    import torchmpi_tpu.serving.engine as eng_mod
+
+    model, params = lm
+    prompts = _prompts(2, seed=74)
+    engine = serving.ReplicaEngine(model, params, slots=1, slot_tokens=32)
+    out = {}
+
+    def body():
+        with monkeypatch.context() as m:
+            m.setattr(eng_mod, "slot_prefill",
+                      lambda *a, **k: (_ for _ in ()).throw(
+                          RuntimeError("exploded")))
+            with pytest.raises(RuntimeError, match="exploded"):
+                engine.admit(serving.Request("bad", prompts[0], max_new=4))
+        out["free"] = engine.pool.free_count
+        out["tokens"] = _drive(engine, serving.Request("good", prompts[1],
+                                                       max_new=4))
+
+    spans = _traced(tmp_path, body)
+    # the slot came back and the engine serves on
+    assert out["free"] == 1 and not engine.dead
+    assert out["tokens"] == _offline(model, params, prompts[1], 4).tolist()
+    bad, good = _named(spans, "tm.serve.admit")
+    assert (bad.stats["rid"], good.stats["rid"]) == ("bad", "good")
+    # the failed admission's spans are closed where it failed ...
+    assert [k.name for k in _inside(spans, bad)] == ADMIT_CHILDREN[:2]
+    assert bad.end <= good.start
+    # ... and the next one writes all five
+    assert [k.name for k in _children(spans, good)] == ADMIT_CHILDREN
+
+
+@pytest.mark.parametrize("draft", ["ngram", "model"])
+def test_a_speculative_step_writes_spec_and_its_draft_span(lm, tmp_path,
+                                                           draft):
+    model, params = lm
+    kw = {"draft": _model_draft()} if draft == "model" else {}
+    srv, eng = _span_server(lm, spec_k=3, **kw)
+    prompts = _prompts(3, seed=75)
+    reqs = [serving.Request(f"k{i}", prompts[i], max_new=9)
+            for i in range(3)]
+    spans = _traced(tmp_path, lambda: srv.run_trace(reqs,
+                                                    tick_seconds=0.001))
+    for i, req in enumerate(reqs):
+        assert req.tokens == _offline(model, params, prompts[i], 9).tolist()
+    step_spans = _named(spans, "tm.serve.step")
+    assert step_spans and all(s.stats["spec"] == 1 for s in step_spans)
+    for s in step_spans:
+        kids = _children(spans, s)
+        assert [k.name for k in kids] == [
+            f"tm.serve.step.{k}" for k in (
+                "operands", "draft", "operands", "dispatch", "read",
+                "book")] and _in_order(kids)
+        assert 0 <= kids[1].stats["k"] <= 3
+    assert max(_named(spans, "tm.serve.step.draft"),
+               key=lambda d: d.stats["k"]).stats["k"] == 3
+    assert {s.name for s in spans} == set(SPANS)
+
+
+def test_the_span_names_are_one_list_held_by_the_code_and_the_docs():
+    """``engine.SPANS`` is THE list: every ``tm.serve.*`` literal under
+    ``serving/`` and every one in docs/OBSERVABILITY.md is on it, each at
+    least once (a parent before its children)."""
+    import glob
+    import re
+
+    token = re.compile(r"tm\.serve(?:\.[a-z_]+)+")
+    code = set()
+    for path in glob.glob(os.path.join(_REPO, "torchmpi_tpu", "serving",
+                                       "*.py")):
+        with open(path) as f:
+            code |= set(token.findall(f.read()))
+    with open(os.path.join(_REPO, "docs", "OBSERVABILITY.md")) as f:
+        documented = set(token.findall(f.read()))
+    assert code == documented == set(SPANS)
+    assert len(set(SPANS)) == len(SPANS) == 14
+    for i, name in enumerate(SPANS):
+        parent = name.rsplit(".", 1)[0]
+        assert parent == "tm.serve" or parent in SPANS[:i]

@@ -66,15 +66,6 @@ def test_bus_bandwidth_formula():
     assert metrics.allreduce_bus_bandwidth(100, 1, 1.0) == 0.0
 
 
-def test_annotate_inside_jit():
-    @jax.jit
-    def f(x):
-        with tracing.annotate("torchmpi_tpu.test_span"):
-            return x * 2
-
-    np.testing.assert_allclose(np.asarray(f(jnp.ones(3))), 2.0)
-
-
 def test_trace_nested_degrades_to_noop(tmp_path):
     """jax allows one profiler trace per process: a trace() inside
     another must degrade to a no-op span (and a failed start must not

@@ -52,12 +52,16 @@ counted: held experts touched, routes and routes held, read with the tokens): th
 scheduler owns the clock, the SLO histograms, and the fault hooks, so
 the engine stays a pure slot/cache mechanism that tests can drive tick
 by tick.
+
+On the profiler's clock the engine names its own phases (:data:`SPANS`:
+``tm.serve.admit`` and ``tm.serve.step`` with their children, each a
+``jax.profiler.TraceAnnotation``, a flag test when no profiler is
+attached; docs/OBSERVABILITY.md, "What a profile shows").
 """
 
 from __future__ import annotations
 
 import dataclasses
-import sys
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -69,8 +73,22 @@ from ..models.generate import STATE_LEAVES, slot_cache_slice, \
     slot_cache_write, slot_decode_step, slot_extend, slot_prefill, \
     slot_verify_step, slot_write
 from ..models import transformer
+from ..utils.telemetry import emit
 from .prefix_cache import PrefixCache
 from .slots import SlotPool
+
+#: The serving tier's spans on the profiler's clock, parents before
+#: children: THE list the tests and docs/OBSERVABILITY.md are held to.
+#: ``gate`` and ``tick`` are the scheduler's, the rest the engine's; a
+#: span's stats are given when it opens (``rid``, ``slot``, ``step``, ...).
+SPANS = (
+    "tm.serve.gate", "tm.serve.tick",
+    "tm.serve.admit", "tm.serve.admit.operands", "tm.serve.admit.prefill",
+    "tm.serve.admit.slot_write", "tm.serve.admit.read",
+    "tm.serve.admit.book",
+    "tm.serve.step", "tm.serve.step.operands", "tm.serve.step.draft",
+    "tm.serve.step.dispatch", "tm.serve.step.read", "tm.serve.step.book")
+span = jax.profiler.TraceAnnotation
 
 
 class RequestRejected(ValueError):
@@ -86,16 +104,6 @@ def _gone(pool) -> bool:
     deleted (an attribute of the array; the device is not asked)."""
     return next(leaf for leaf in jax.tree.leaves(pool)
                 if getattr(leaf, "ndim", 0) >= 1).is_deleted()
-
-
-def _obs():
-    mod = sys.modules.get("torchmpi_tpu.obs")
-    try:
-        if mod is not None and mod.active():
-            return mod
-    except Exception:  # noqa: BLE001 — telemetry never fails a tick
-        pass
-    return None
 
 
 @dataclasses.dataclass
@@ -347,9 +355,7 @@ class ReplicaEngine:
             return
         self._prefill_lens.add(key)
         self.stats["prefill_compiles"] += 1
-        mod = _obs()
-        if mod is not None:
-            mod.record_serving("prefill_compiles", replica=self.name)
+        emit("record_serving", "prefill_compiles", replica=self.name)
 
     def _sampling_arrays(self, sessions: Dict[int, Session]):
         """[S] operand arrays for the pooled forwards.  ``idxs`` is
@@ -395,10 +401,16 @@ class ReplicaEngine:
                 and transformer.prefill_runs_flash(padded_len,
                                                    per_row=False))
 
-    def _pooled(self, program):
+    def _pooled(self, program, read=False):
         """Run one program that CONSUMES the pool (a decode step, a verify,
         a slot write): ``program(pool)`` hands back the new pool first, and
-        what follows it is returned.  The engine holds the only reference,
+        what follows it is returned.  With ``read`` (a step, a verify) that
+        is fetched to the host in ONE blocking read, inside the call, so a
+        step that fails on the device fails here, with the pool it took;
+        the step's two phases are named here for every backend:
+        ``tm.serve.step.dispatch`` the call into the program (``jit``'s
+        dispatch, the enqueue), ``tm.serve.step.read`` the read the device
+        runs the step under.  The engine holds the only reference,
         so the pool that went in is gone when the call returns
         (``stats["pool_donated"]`` counts that; a backend that declines the
         donation leaves it behind).  A program that raises before it is
@@ -407,7 +419,13 @@ class ReplicaEngine:
         the replica reads as dead."""
         pool, self._cache = self._cache, None
         try:
-            out = program(pool)
+            if read:
+                with span("tm.serve.step.dispatch"):
+                    out = program(pool)
+                with span("tm.serve.step.read"):
+                    out = out[:1] + jax.device_get(out[1:])
+            else:
+                out = program(pool)
         except BaseException:
             if _gone(pool):
                 self.dead = True
@@ -418,23 +436,16 @@ class ReplicaEngine:
         donated = int(_gone(pool))
         self.stats["pool_calls"] += 1
         self.stats["pool_donated"] += donated
-        mod = _obs()
-        if mod is not None:
-            mod.record_serving("pool_calls", replica=self.name)
-            mod.record_serving("pool_donated", donated, replica=self.name)
+        emit("record_serving", "pool_calls", replica=self.name)
+        emit("record_serving", "pool_donated", donated, replica=self.name)
         return out[1:]
 
     def _backend_step(self, toks: np.ndarray, pos: np.ndarray, sampling):
-        def program(pool):
-            pool, nxt, counts = slot_decode_step(
+        # the counts are ready when the tokens are: one read fetches both
+        nxt, counts = self._pooled(
+            lambda pool: slot_decode_step(
                 self.dmodel, self.params, pool, toks, pos,
-                sampling=sampling, counted=True)
-            # ONE blocking read: the counts are ready when the tokens are
-            # (inside the call, so a step that fails on the device fails
-            # here, with the pool it took)
-            return (pool,) + jax.device_get((nxt, counts))
-
-        nxt, counts = self._pooled(program)
+                sampling=sampling, counted=True), read=True)
         if counts is not None:
             self._expert_steps()
             for handles, row in zip(self._expert_counters, counts.tolist()):
@@ -444,13 +455,10 @@ class ReplicaEngine:
 
     def _backend_verify(self, toks: np.ndarray, pos: np.ndarray,
                         sampling):
-        def program(pool):
-            pool, out = slot_verify_step(
+        return self._pooled(
+            lambda pool: slot_verify_step(
                 self.dmodel, self.params, pool, toks, pos,
-                sampling=sampling)
-            return pool, np.asarray(out)
-
-        return self._pooled(program)[0]
+                sampling=sampling), read=True)[0]
 
     def _row_template(self):
         """Fresh single-row zero cache — the canvas prefix-cache
@@ -498,112 +506,129 @@ class ReplicaEngine:
         slot = self.pool.alloc()
         if slot is None:
             return None
+        with span("tm.serve.admit", rid=str(request.rid), slot=slot,
+                  replica=self.name, prompt_tokens=int(prompt.shape[1])):
+            return self._admit_into(slot, request, sampling, prompt,
+                                    prev.size)
+
+    def _admit_into(self, slot: int, request, sampling, prompt: np.ndarray,
+                    idx: int) -> Tuple[Session, bool]:
+        """:meth:`admit` from the moment the slot is known: five phases,
+        each its own span, in this order."""
+        booked = False      # past every fallible op: the slot is a session's
         try:
-            self.stats["prefills"] += 1
-            self.units += 1.0
-            samp = tuple(jnp.asarray(np.asarray([v], d)) for v, d in
-                         zip((sampling[3], prev.size, sampling[0],
-                              sampling[1], sampling[2]),
-                             (np.uint32, np.int32, np.float32, np.int32,
-                              np.float32)))
-            chain = (self._prefix.match(prompt[0])
-                     if self._prefix is not None else [])
+            with span("tm.serve.admit.operands"):
+                self.stats["prefills"] += 1
+                self.units += 1.0
+                samp = tuple(jnp.asarray(np.asarray([v], d)) for v, d in
+                             zip((sampling[3], idx, sampling[0],
+                                  sampling[1], sampling[2]),
+                                 (np.uint32, np.int32, np.float32, np.int32,
+                                  np.float32)))
+                chain = (self._prefix.match(prompt[0])
+                         if self._prefix is not None else [])
+                if chain:
+                    # Cache hit: assemble the matched fragments onto a
+                    # fresh row and run the forward over ONLY the unshared
+                    # suffix.  The sampling operand (idx = the request's
+                    # global token index) is untouched by the hit, so the
+                    # fold_in schedule — and therefore every emitted token
+                    # — is bitwise the miss path's.
+                    B = self._prefix.block_tokens
+                    depth = B * len(chain)
+                    if self._row_zero is None:
+                        self._row_zero = self._row_template()
+                    row = self._row_zero
+                    for i, node in enumerate(chain):
+                        row = slot_cache_write(row, node.frag, i * B)
+                    padded, true_len = self._pad_prompt(
+                        prompt[:, depth:],
+                        cap=self.pool.slot_tokens - depth)
+                    self._count_prefill_compile(("ext", padded.shape[1]))
+                else:
+                    depth = 0
+                    padded, true_len = self._pad_prompt(prompt)
+                    self._count_prefill_compile(padded.shape[1])
+                n_padded = int(padded.shape[1])
+                # the extend forward never runs the kernel
+                kernel = not chain and self._prefill_runs_flash(n_padded)
+            with span("tm.serve.admit.prefill", padded_tokens=n_padded,
+                      kernel=int(kernel)):
+                if chain:
+                    one_cache, first = self._backend_extend(
+                        row, padded, depth, true_len, samp)
+                else:
+                    one_cache, first = self._backend_prefill(
+                        padded, true_len, samp)
             if chain:
-                # Cache hit: assemble the matched fragments onto a
-                # fresh row and run the forward over ONLY the unshared
-                # suffix.  The sampling operand (idx = the request's
-                # global token index) is untouched by the hit, so the
-                # fold_in schedule — and therefore every emitted token
-                # — is bitwise the miss path's.
-                B = self._prefix.block_tokens
-                depth = B * len(chain)
-                if self._row_zero is None:
-                    self._row_zero = self._row_template()
-                row = self._row_zero
-                for i, node in enumerate(chain):
-                    row = slot_cache_write(row, node.frag, i * B)
-                padded, true_len = self._pad_prompt(
-                    prompt[:, depth:],
-                    cap=self.pool.slot_tokens - depth)
-                self._count_prefill_compile(("ext", padded.shape[1]))
-                one_cache, first = self._backend_extend(
-                    row, padded, depth, true_len, samp)
                 self.stats["prefix_hits"] += 1
-            else:
-                depth = 0
-                padded, true_len = self._pad_prompt(prompt)
-                self._count_prefill_compile(padded.shape[1])
-                one_cache, first = self._backend_prefill(
-                    padded, true_len, samp)
+            elif self._prefix is not None:
+                self.stats["prefix_misses"] += 1
+            if kernel:
+                # Of prefill_tokens, those whose program ran the kernel.
+                self.stats["prefill_kernel_tokens"] += n_padded
+                emit("record_serving", "prefill_kernel_tokens", n_padded,
+                     replica=self.name)
+            self.stats["prefill_tokens"] += n_padded
+            with span("tm.serve.admit.slot_write"):
+                self._pooled(
+                    lambda pool: (slot_write(pool, one_cache, slot),))
+            with span("tm.serve.admit.read"):
+                # the blocking read: the device runs the prefill under it
+                tok = int(np.asarray(first)[0])
+            with span("tm.serve.admit.book"):
+                full_chain: List[Any] = []
                 if self._prefix is not None:
-                    self.stats["prefix_misses"] += 1
-                if self._prefill_runs_flash(padded.shape[1]):
-                    # Of prefill_tokens, those whose program ran the
-                    # kernel (the extend above never does).
-                    n_padded = int(padded.shape[1])
-                    self.stats["prefill_kernel_tokens"] += n_padded
-                    mod = _obs()
-                    if mod is not None:
-                        mod.record_serving("prefill_kernel_tokens",
-                                           n_padded, replica=self.name)
-            self.stats["prefill_tokens"] += int(padded.shape[1])
-            self._pooled(
-                lambda pool: (slot_write(pool, one_cache, slot),))
-            tok = int(np.asarray(first)[0])
-            full_chain: List[Any] = []
-            n_new = n_evicted = 0
-            if self._prefix is not None:
-                # Cache every full block of the TRUE prompt from the
-                # row we just computed (one_cache covers the assembled
-                # depth + the suffix, so slicing works for matched and
-                # new blocks alike; insert only materializes the new
-                # ones), then pin the whole chain for this session's
-                # lifetime — eviction can never touch a block a live
-                # slot was built from.
-                B = self._prefix.block_tokens
-                full_chain, n_new, n_evicted = self._prefix.insert(
-                    prompt[0], prompt.shape[1],
-                    lambda i: slot_cache_slice(one_cache, i * B, B))
-                self._prefix.pin(full_chain)
-                mod = _obs()
-                if mod is not None:
+                    # Cache every full block of the TRUE prompt from the
+                    # row we just computed (one_cache covers the assembled
+                    # depth + the suffix, so slicing works for matched and
+                    # new blocks alike; insert only materializes the new
+                    # ones), then pin the whole chain for this session's
+                    # lifetime — eviction can never touch a block a live
+                    # slot was built from.
+                    B = self._prefix.block_tokens
+                    full_chain, n_new, n_evicted = self._prefix.insert(
+                        prompt[0], prompt.shape[1],
+                        lambda i: slot_cache_slice(one_cache, i * B, B))
+                    self._prefix.pin(full_chain)
                     if chain:
-                        mod.record_serving("prefix_hits",
-                                           replica=self.name)
-                        mod.record_serving("prefix_tokens_saved", depth,
-                                           replica=self.name)
-                        mod.record_serving(
-                            "prefix_bytes_saved",
-                            sum(n.nbytes for n in chain),
-                            replica=self.name)
+                        emit("record_serving", "prefix_hits",
+                             replica=self.name)
+                        emit("record_serving", "prefix_tokens_saved", depth,
+                             replica=self.name)
+                        emit("record_serving", "prefix_bytes_saved",
+                             sum(n.nbytes for n in chain),
+                             replica=self.name)
                     else:
-                        mod.record_serving("prefix_misses",
-                                           replica=self.name)
+                        emit("record_serving", "prefix_misses",
+                             replica=self.name)
                     if n_new:
-                        mod.record_serving("prefix_inserted", n_new,
-                                           replica=self.name)
+                        emit("record_serving", "prefix_inserted", n_new,
+                             replica=self.name)
                     if n_evicted:
-                        mod.record_serving("prefix_evicted", n_evicted,
-                                           replica=self.name)
+                        emit("record_serving", "prefix_evicted", n_evicted,
+                             replica=self.name)
+                booked = True
+                sess = Session(request=request, slot=slot, last_tok=tok,
+                               pos_next=prompt.shape[1], emitted=[tok],
+                               sampling=sampling, last_emit=1,
+                               prefix_chain=full_chain)
+                if self._finished(sess):
+                    self.pool.free(slot)
+                    self._retire_prefix(sess)
+                    return sess, True
+                self._sessions[slot] = sess
+                if self._draft is not None:
+                    self.units += self._draft.admit(slot, sess)
+                return sess, False
         except BaseException:
             # A failed prefill must not leak the block: after `slots`
             # leaks the pool would be silently full forever.  (Prefix
             # pins are taken LAST, after every fallible op, so there is
             # never a pinned chain to unwind here.)
-            self.pool.free(slot)
+            if not booked:
+                self.pool.free(slot)
             raise
-        sess = Session(request=request, slot=slot, last_tok=tok,
-                       pos_next=prompt.shape[1], emitted=[tok],
-                       sampling=sampling, last_emit=1,
-                       prefix_chain=full_chain)
-        if self._finished(sess):
-            self.pool.free(slot)
-            self._retire_prefix(sess)
-            return sess, True
-        self._sessions[slot] = sess
-        if self._draft is not None:
-            self.units += self._draft.admit(slot, sess)
-        return sess, False
 
     def step(self) -> Tuple[List[Session], List[Session]]:
         """One decode tick over every in-flight slot; returns
@@ -615,32 +640,39 @@ class ReplicaEngine:
             raise RuntimeError(f"{self.name} is dead")
         if not self._sessions:
             return [], []
-        if self._draft is not None:
-            return self._spec_step()
-        self.stats["steps"] += 1
-        self.units += 1.0
-        S = self.pool.n_slots
-        toks = np.zeros((S,), np.int32)
-        pos = np.zeros((S,), np.int32)
-        for slot, sess in self._sessions.items():
-            toks[slot] = sess.last_tok
-            pos[slot] = sess.pos_next
-        nxt = self._backend_step(toks, pos,
-                                 self._sampling_arrays(self._sessions))
-        advanced, finished = [], []
-        for slot in list(self._sessions):
-            sess = self._sessions[slot]
-            sess.last_tok = int(nxt[slot])
-            sess.pos_next += 1
-            sess.emitted.append(sess.last_tok)
-            sess.last_emit = 1
-            advanced.append(sess)
-            if self._finished(sess):
-                del self._sessions[slot]
-                self.pool.free(slot)
-                self._retire_prefix(sess)
-                finished.append(sess)
-        return advanced, finished
+        spec = self._draft is not None
+        with span("tm.serve.step", step=self.stats["steps"],
+                  live=len(self._sessions), replica=self.name,
+                  spec=int(spec)):
+            return self._spec_step() if spec else self._plain_step()
+
+    def _plain_step(self) -> Tuple[List[Session], List[Session]]:
+        with span("tm.serve.step.operands"):
+            self.stats["steps"] += 1
+            self.units += 1.0
+            S = self.pool.n_slots
+            toks = np.zeros((S,), np.int32)
+            pos = np.zeros((S,), np.int32)
+            for slot, sess in self._sessions.items():
+                toks[slot] = sess.last_tok
+                pos[slot] = sess.pos_next
+            samp = self._sampling_arrays(self._sessions)
+        nxt = self._backend_step(toks, pos, samp)
+        with span("tm.serve.step.book"):
+            advanced, finished = [], []
+            for slot in list(self._sessions):
+                sess = self._sessions[slot]
+                sess.last_tok = int(nxt[slot])
+                sess.pos_next += 1
+                sess.emitted.append(sess.last_tok)
+                sess.last_emit = 1
+                advanced.append(sess)
+                if self._finished(sess):
+                    del self._sessions[slot]
+                    self.pool.free(slot)
+                    self._retire_prefix(sess)
+                    finished.append(sess)
+            return advanced, finished
 
     def _spec_step(self) -> Tuple[List[Session], List[Session]]:
         """Draft K, verify in ONE [S, K+1] forward, accept while the
@@ -648,76 +680,78 @@ class ReplicaEngine:
         conditions only on accepted tokens, so the emitted stream is
         bitwise the non-speculative one at the same (seed, prompt) —
         drafting moves SPEED, never content."""
-        sessions = dict(self._sessions)
-        # The [S, K+1] verify writes K+1 cache positions per row at its
-        # own offset; a row near the end of its slot block has less
-        # room than that, and an out-of-range dynamic_update_slice
-        # CLAMPS the start index — silent corruption.  Clamp K to the
-        # tick's tightest room instead (>= 0: an in-flight session
-        # always has 1 free position for its next token).
-        room = min(self.pool.slot_tokens - s.pos_next
-                   for s in sessions.values())
-        K = min(self._spec_k, max(0, room - 1))
-        # Sampling arrays BEFORE drafting: idxs must index the first
-        # token this tick emits.
-        samp = self._sampling_arrays(sessions)
-        drafts, draft_units = self._draft.propose(sessions, K)
-        S = self.pool.n_slots
-        toks = np.zeros((S, K + 1), np.int32)
-        pos = np.zeros((S,), np.int32)
-        for slot, sess in sessions.items():
-            d = list(drafts.get(slot, []))[:K]
-            toks[slot, 0] = sess.last_tok
-            if d:
-                toks[slot, 1:1 + len(d)] = d
-            pos[slot] = sess.pos_next
-        self.stats["steps"] += 1
-        self.stats["spec_steps"] += 1
-        self.units += 1.0 + float(draft_units)
+        with span("tm.serve.step.operands"):
+            sessions = dict(self._sessions)
+            # The [S, K+1] verify writes K+1 cache positions per row at its
+            # own offset; a row near the end of its slot block has less
+            # room than that, and an out-of-range dynamic_update_slice
+            # CLAMPS the start index — silent corruption.  Clamp K to the
+            # tick's tightest room instead (>= 0: an in-flight session
+            # always has 1 free position for its next token).
+            room = min(self.pool.slot_tokens - s.pos_next
+                       for s in sessions.values())
+            K = min(self._spec_k, max(0, room - 1))
+            # Sampling arrays BEFORE drafting: idxs must index the first
+            # token this tick emits.
+            samp = self._sampling_arrays(sessions)
+        with span("tm.serve.step.draft", k=K):
+            drafts, draft_units = self._draft.propose(sessions, K)
+        with span("tm.serve.step.operands"):
+            S = self.pool.n_slots
+            toks = np.zeros((S, K + 1), np.int32)
+            pos = np.zeros((S,), np.int32)
+            for slot, sess in sessions.items():
+                d = list(drafts.get(slot, []))[:K]
+                toks[slot, 0] = sess.last_tok
+                if d:
+                    toks[slot, 1:1 + len(d)] = d
+                pos[slot] = sess.pos_next
+            self.stats["steps"] += 1
+            self.stats["spec_steps"] += 1
+            self.units += 1.0 + float(draft_units)
         out = self._backend_verify(toks, pos, samp)
-        advanced, finished = [], []
-        tick_drafted = tick_accepted = 0
-        for slot, sess in sessions.items():
-            d = list(drafts.get(slot, []))[:K]
-            row = out[slot]
-            m = 0
-            for j in range(len(d) + 1):
-                t = int(row[j])
-                sess.last_tok = t
-                sess.emitted.append(t)
-                m += 1
+        with span("tm.serve.step.book"):
+            advanced, finished = [], []
+            tick_drafted = tick_accepted = 0
+            for slot, sess in sessions.items():
+                d = list(drafts.get(slot, []))[:K]
+                row = out[slot]
+                m = 0
+                for j in range(len(d) + 1):
+                    t = int(row[j])
+                    sess.last_tok = t
+                    sess.emitted.append(t)
+                    m += 1
+                    if self._finished(sess):
+                        break
+                    if j < len(d) and t != d[j]:
+                        # Mismatch: t IS the corrected token (sampled from
+                        # the accepted prefix); the remaining samples
+                        # conditioned on the wrong draft and are dropped.
+                        break
+                sess.pos_next += m
+                sess.last_emit = m
+                tick_drafted += len(d)
+                tick_accepted += sum(1 for j in range(min(m, len(d)))
+                                     if int(row[j]) == d[j])
+                advanced.append(sess)
                 if self._finished(sess):
-                    break
-                if j < len(d) and t != d[j]:
-                    # Mismatch: t IS the corrected token (sampled from
-                    # the accepted prefix); the remaining samples
-                    # conditioned on the wrong draft and are dropped.
-                    break
-            sess.pos_next += m
-            sess.last_emit = m
-            tick_drafted += len(d)
-            tick_accepted += sum(1 for j in range(min(m, len(d)))
-                                 if int(row[j]) == d[j])
-            advanced.append(sess)
-            if self._finished(sess):
-                del self._sessions[slot]
-                self.pool.free(slot)
-                self._retire_prefix(sess)
-                self._draft.free(slot)
-                finished.append(sess)
-            else:
-                self._draft.observe(slot, sess)
-        self.stats["spec_drafted"] += tick_drafted
-        self.stats["spec_accepted"] += tick_accepted
-        mod = _obs()
-        if mod is not None:
+                    del self._sessions[slot]
+                    self.pool.free(slot)
+                    self._retire_prefix(sess)
+                    self._draft.free(slot)
+                    finished.append(sess)
+                else:
+                    self._draft.observe(slot, sess)
+            self.stats["spec_drafted"] += tick_drafted
+            self.stats["spec_accepted"] += tick_accepted
             if tick_drafted:
-                mod.record_serving("spec_drafted", tick_drafted,
-                                   replica=self.name)
+                emit("record_serving", "spec_drafted", tick_drafted,
+                     replica=self.name)
             if tick_accepted:
-                mod.record_serving("spec_accepted", tick_accepted,
-                                   replica=self.name)
-        return advanced, finished
+                emit("record_serving", "spec_accepted", tick_accepted,
+                     replica=self.name)
+            return advanced, finished
 
     def drain(self) -> List[Session]:
         """Mark this replica dead and hand its in-flight sessions back

@@ -29,6 +29,7 @@ from __future__ import annotations
 import sys
 from typing import List, Optional
 
+from ..utils.telemetry import emit
 from .engine import ReplicaEngine
 
 
@@ -85,12 +86,7 @@ class Router:
         if not replica.dead or getattr(replica, "retired", False):
             return
         replica.dead = False
-        mod = sys.modules.get("torchmpi_tpu.obs")
-        try:
-            if mod is not None and mod.active():
-                mod.record_serving("readmitted", replica=replica.name)
-        except Exception:  # noqa: BLE001 — telemetry never fails this
-            pass
+        emit("record_serving", "readmitted", replica=replica.name)
 
     def decide(self, replica: ReplicaEngine) -> str:
         if replica.dead:

@@ -31,6 +31,12 @@ SLO observability rides the obs registry when telemetry is active
 latency histograms (microseconds) per replica, queue-depth and
 slot-occupancy gauges per tick, request/token/completion counters.
 ``scripts/obs_tool.py slo`` turns the dumps into p50/p95/p99 tables.
+
+On the profiler's clock, and always (a flag test when no profiler is
+attached): ``tm.serve.gate`` a request entering the program and
+``tm.serve.tick`` a scheduler tick, with the engine's ``tm.serve.admit``
+/ ``tm.serve.step`` trees inside it (``engine.SPANS``;
+docs/OBSERVABILITY.md, "What a profile shows").
 """
 
 from __future__ import annotations
@@ -45,7 +51,8 @@ import jax
 import numpy as np
 
 from .. import runtime
-from .engine import ReplicaEngine, RequestRejected, Session
+from ..utils.telemetry import active as obs_active, emit
+from .engine import ReplicaEngine, RequestRejected, Session, span
 from .fleet import AdmissionController, AdmissionRejected, \
     FleetController
 from .router import Router
@@ -99,18 +106,6 @@ class Request:
         return self.finish_s - self.arrival_s
 
 
-def _obs():
-    """The obs module when telemetry is active (sys.modules lookup —
-    serving must not import the telemetry it reports to)."""
-    mod = sys.modules.get("torchmpi_tpu.obs")
-    try:
-        if mod is not None and mod.active():
-            return mod
-    except Exception:  # noqa: BLE001 — telemetry never fails a tick
-        pass
-    return None
-
-
 def _is_fault(e: BaseException) -> bool:
     """Is ``e`` a fault-layer error?  Checked via sys.modules: if the
     fault layer was never armed, the classes do not exist and no
@@ -136,6 +131,8 @@ class Server:
     # loop with the gate and the autoscaler disarmed.
     _admission = None
     _fleet = None
+    #: Ticks this server has run (``tm.serve.tick``'s ``tick`` stat).
+    _n_ticks = 0
 
     def __init__(self, model, params, *, replicas: Optional[int] = None,
                  slots: Optional[int] = None,
@@ -356,14 +353,17 @@ class Server:
             if self._fleet is not None:
                 event = self._fleet.tick(len(pending), pending)
                 if event is not None:
-                    mod = _obs()
-                    if mod is not None:
-                        mod.record_serving(event)
+                    emit("record_serving", event)
         raise RuntimeError(f"trace did not drain in {max_ticks} ticks")
 
     # -- one tick ----------------------------------------------------------
 
     def _tick(self, pending: deque):
+        n, self._n_ticks = self._n_ticks, self._n_ticks + 1
+        with span("tm.serve.tick", tick=n, pending=len(pending)):
+            return self._tick_body(pending)
+
+    def _tick_body(self, pending: deque):
         admitted: List[Session] = []
         finished: List[Session] = []
         stepped: List[Session] = []
@@ -386,9 +386,7 @@ class Server:
                 # is a real bug and stays loud.
                 req.error = str(e)
                 rejected.append(req)
-                mod = _obs()
-                if mod is not None:
-                    mod.record_serving("rejected", replica=eng.name)
+                emit("record_serving", "rejected", replica=eng.name)
                 continue
             if res is None:  # raced a full pool; retry next tick
                 pending.appendleft(req)
@@ -432,31 +430,26 @@ class Server:
         is a shed — a dropped admission RPC and an SLO rejection look
         identical to the client), then the SLO admission controller.
         Returns the shed reason, or None to admit into the queue."""
-        if runtime.effective_config().faults != "off":
-            from .. import faults
+        with span("tm.serve.gate", rid=str(req.rid)):
+            if runtime.effective_config().faults != "off":
+                from .. import faults
 
-            try:
-                faults.fire("serving.admit", peer=req.rid)
-            except BaseException as e:  # noqa: BLE001 — shed, not crash
-                if not _is_fault(e):
-                    raise
-                mod = _obs()
-                if mod is not None:
-                    mod.record_serving("shed")
-                return (f"request {req.rid!r} shed (fault at "
-                        f"serving.admit): {e}")
-        if self._admission is not None:
-            try:
-                self._admission.check(req.rid, depth)
-            except AdmissionRejected as e:
-                mod = _obs()
-                if mod is not None:
-                    mod.record_serving("shed")
-                return str(e)
-            mod = _obs()
-            if mod is not None:
-                mod.record_serving("admitted")
-        return None
+                try:
+                    faults.fire("serving.admit", peer=req.rid)
+                except BaseException as e:  # noqa: BLE001 — shed, not crash
+                    if not _is_fault(e):
+                        raise
+                    emit("record_serving", "shed")
+                    return (f"request {req.rid!r} shed (fault at "
+                            f"serving.admit): {e}")
+            if self._admission is not None:
+                try:
+                    self._admission.check(req.rid, depth)
+                except AdmissionRejected as e:
+                    emit("record_serving", "shed")
+                    return str(e)
+                emit("record_serving", "admitted")
+            return None
 
     def _handle_failure(self, eng: ReplicaEngine, e: BaseException,
                         pending: deque) -> bool:
@@ -479,10 +472,9 @@ class Server:
         (they already waited once) for re-prefill elsewhere."""
         sessions = eng.drain()
         eng.dead = True
-        mod = _obs()
-        if mod is not None and sessions:
-            mod.record_serving("rerouted", len(sessions),
-                               replica=eng.name)
+        if sessions:
+            emit("record_serving", "rerouted", len(sessions),
+                 replica=eng.name)
         for sess in reversed(sessions):
             req = sess.request
             req.tokens.extend(sess.emitted)
@@ -493,7 +485,7 @@ class Server:
 
     def _record_tick(self, pending, admitted, stepped, finished,
                      completed, clock: float, elapsed: float) -> None:
-        mod = _obs()
+        on = obs_active()
         for sess in admitted:
             req = sess.request
             if req.ttft_s is None:
@@ -503,19 +495,18 @@ class Server:
                     # telemetry — admission control must work with obs
                     # off.
                     self._admission.observe(req.ttft_s)
-                if mod is not None:
-                    mod.record_serving("requests", replica=req.replica)
-                    mod.record_serving_latency("ttft", req.ttft_s,
-                                               replica=req.replica)
-            elif mod is not None:
+                emit("record_serving", "requests", replica=req.replica)
+                emit("record_serving_latency", "ttft", req.ttft_s,
+                     replica=req.replica)
+            elif on:
                 # Re-admission after a re-route: the WHOLE stall since
                 # the session's last token (drain + queue wait +
                 # re-prefill) is one long inter-token latency, not a
                 # second TTFT — that is the SLO impact of the kill.
                 since = (req.last_token_s if req.last_token_s is not None
                          else clock - elapsed)
-                mod.record_serving_latency("itl", clock - since,
-                                           replica=req.replica)
+                emit("record_serving_latency", "itl", clock - since,
+                     replica=req.replica)
             req.last_token_s = clock
         for sess in finished:
             req = sess.request
@@ -523,9 +514,8 @@ class Server:
             sess.emitted = []
             req.finish_s = clock
             completed.append(req)
-            if mod is not None:
-                mod.record_serving("completed", replica=req.replica)
-        if mod is None:
+            emit("record_serving", "completed", replica=req.replica)
+        if not on:
             return
         for sess in stepped:
             req = sess.request
@@ -543,8 +533,8 @@ class Server:
                      else clock - elapsed)
             m = max(1, sess.last_emit)
             for _ in range(m):
-                mod.record_serving_latency("itl", (clock - since) / m,
-                                           replica=req.replica)
+                emit("record_serving_latency", "itl", (clock - since) / m,
+                     replica=req.replica)
             req.last_token_s = clock
         n_tok = len(admitted) + sum(s.last_emit for s in stepped)
         if n_tok:
@@ -556,11 +546,11 @@ class Server:
                 by_rep[sess.request.replica] = \
                     by_rep.get(sess.request.replica, 0) + sess.last_emit
             for rep, n in by_rep.items():
-                mod.record_serving("tokens", n, replica=rep)
-        mod.record_serving_depth(len(pending))
+                emit("record_serving", "tokens", n, replica=rep)
+        emit("record_serving_depth", len(pending))
         for eng in self.router.live():
-            mod.record_serving_occupancy(eng.pool.occupancy_pct(),
-                                         replica=eng.name)
+            emit("record_serving_occupancy", eng.pool.occupancy_pct(),
+                 replica=eng.name)
         # Tick boundary: the serving-side attribution window edge
         # (obs_tool attribute; docs/OBSERVABILITY.md).
-        mod.record_step("serving_tick")
+        emit("record_step", "serving_tick")

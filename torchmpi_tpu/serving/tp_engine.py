@@ -118,14 +118,11 @@ class TPReplicaEngine(ReplicaEngine):
             np.asarray(toks, np.int32)[:, None], pos, sampling)[:, 0]
 
     def _backend_verify(self, toks, pos, sampling):
-        def program(pool):
-            pool, out = tp_slot_decode(
+        return self._pooled(
+            lambda pool: tp_slot_decode(
                 self.params, pool, toks, pos, mesh=self.mesh,
                 axis=self.axis, num_heads=self.num_heads,
-                sampling=sampling)
-            return pool, np.asarray(out)
-
-        return self._pooled(program)[0]
+                sampling=sampling), read=True)[0]
 
     def _row_template(self):
         from jax.sharding import NamedSharding, PartitionSpec as P

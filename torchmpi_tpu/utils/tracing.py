@@ -1,8 +1,12 @@
-"""Tracing/profiling hooks (SURVEY.md §6.1).
+"""Profiler capture (SURVEY.md §6.1).
 
-The reference had nothing built-in (external MPI profilers only); here each
-collective / train step can be annotated so ``jax.profiler`` traces show
-named spans, and a whole-program trace dumps perfetto-compatible files.
+The reference had nothing built-in (external MPI profilers only); here
+:func:`trace` captures a ``jax.profiler`` trace around a region of the
+program and leaves perfetto-compatible files.  What the capture shows by
+name is written where the work is, not here: ``tm.step`` (the train
+step's dispatch), the ``tm.serve.*`` tree (the serving tier's phases),
+the Pallas kernels' ``tm_kernel`` identities and the models'
+``jax.named_scope``s (docs/OBSERVABILITY.md, "What a profile shows").
 """
 
 from __future__ import annotations
@@ -12,13 +16,6 @@ import os
 from typing import Iterator
 
 import jax
-
-
-@contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Named scope visible in XLA/profiler traces (works inside jit)."""
-    with jax.named_scope(name):
-        yield
 
 
 @contextlib.contextmanager
