@@ -339,8 +339,10 @@ def test_served_decode_step_lowers_to_the_parents_text(one_chip):
     # 4608) lowers for the described chip to a pinned text (sha256 as
     # PERF.md section 6 has it).  PR 32 and PR 33 left it at 132fe595...;
     # PR 34 meant to touch it and did: the pool is donated (every cache leaf
-    # of the entry carries tf.aliasing_output), nothing else.  A change that
-    # means to touch the step replaces the digest and says so there.
+    # of the entry carries tf.aliasing_output), nothing else: a179e72a...;
+    # PR 36 meant to touch it and did: the sampling tail is one `case` of
+    # two regions under the scope `sample_rows`, nothing else.  A change
+    # that means to touch the step replaces the digest and says so there.
     import hashlib
 
     from torchmpi_tpu.models.generate import _slot_step_jit
@@ -353,7 +355,7 @@ def test_served_decode_step_lowers_to_the_parents_text(one_chip):
     assert "tpu_custom_call" not in text
     assert text.count("tf.aliasing_output") == len(jax.tree.leaves(cache))
     assert hashlib.sha256(text.encode()).hexdigest() == (
-        "a179e72a9929a0a8e3704730b8c54deebbff8634572d00c1d9d499e5faff1de9")
+        "f6b412bd3e16ffcf64999b7cc5319637e855fbd9152a4cdcd1a6bea808b21133")
 
 
 def _pool_cache(dmodel, slots, one_chip):
@@ -420,6 +422,38 @@ def test_served_decode_step_updates_the_pool_in_place(
     copied = re.findall(r"^\s*(?:ROOT )?%copy[\w.]* = (\w+\[[\d,]*\])\S* "
                         r"copy\(", compiled.as_text(), re.M)
     assert copied and not token_leaves & set(copied)
+
+
+@pytest.mark.parametrize("workload", [
+    "sc2-3b-serve-sat", "imoe-16b-serve-conv-sat",
+    "nm3s-120b-serve-chat-sat"])
+def test_served_decode_step_sorts_the_vocabulary_in_one_branch_only(
+        one_chip, compiled_steps, workload):
+    # The sampling tail of each served configuration's pooled step, as the
+    # chip's compiler leaves it (PR 36): ONE `conditional` under the scope
+    # `sample_rows` with two branch computations (a select, both sides
+    # run, would leave none), the first an argmax with no `sort` and no
+    # random bits, and every sort over [slots, vocab] of the whole program
+    # in the second.  The parent's text held that sort in the entry
+    # computation: 3.6 ms of a 24.8 ms step at [21, 128896].
+    harness = _harness()
+    cell = harness.resolve(harness.load_manifest(), workload)
+    rows = "[%d,%d]" % (cell.config["serving"]["slots"],
+                        cell.config["vocab_size"])
+    text = _compiled_step(compiled_steps, one_chip, workload)[1].as_text()
+    bodies = dict(re.findall(
+        r"^(?:ENTRY )?(%[\w.\-]+) \(.*?\{\n(.*?)^\}", text, re.M | re.S))
+    (tail,) = re.findall(
+        r" conditional\(.*branch_computations=\{([^}]*)\}.*"
+        r"op_name=\"[^\"]*/sample_rows/", text)
+    greedy, drawn = (bodies[b] for b in tail.split(", "))
+    assert "branch_0_fun/reduce" in greedy          # the argmax
+    assert " sort(" not in greedy and "rng-bit-generator" not in greedy
+    assert "_gumbel" not in greedy and "_gumbel" in drawn
+    sorts = {name: len(re.findall(r"%s\S* sort\(" % re.escape(rows), body))
+             for name, body in bodies.items()}
+    assert sorts.pop(tail.split(", ")[1]) == 1, sorts
+    assert not any(sorts.values()), sorts
 
 
 @pytest.mark.parametrize("program", ["prefill64", "prefill1024", "step"])

@@ -171,6 +171,9 @@ SPAN_METRICS = {
         "serve_idle_by_span", r"^outside$", SCHEDULER),
 }
 SPAN_SRV = {m for m in SPAN_METRICS if m.endswith(".srv")}
+# what PR 36 added: the sampling tail's device time a decode step, by the
+# scope the program gives it (``generate._sample_rows``)
+SAMPLE_MS = "sample_ms_per_decode_step.srv"
 IDLE_SRV = [m for m, v in SPAN_METRICS.items()
             if v[0] == "serve_idle_by_span" and m.endswith(".srv")]
 IDLE = harness.load_module(MANIFEST, "readers", "serve_idle_by_span")
@@ -317,7 +320,7 @@ def test_sparse_served_cell_joins_the_lists_whose_readers_are_right_for_it():
     decode step's bytes; one four-chip cell of eight."""
     mine = {m["name"] for m in harness.resolve(MANIFEST, IMOE).per_layer}
     joined = set(SRV_METRICS) - set(IMOE_STAYS_OUT)
-    assert mine == joined | set(IMOE_METRICS) | SPAN_SRV
+    assert mine == joined | set(IMOE_METRICS) | SPAN_SRV | {SAMPLE_MS}
     for metric in joined:
         assert harness.by_name(MANIFEST["per_layer"], metric,
                                "metric")["workloads"][:2] == [SAT, IMOE]
@@ -376,7 +379,7 @@ def test_hybrid_served_cell_joins_the_lists_whose_readers_are_right_for_it():
     mine = {m["name"] for m in harness.resolve(MANIFEST, NM3).per_layer}
     joined = (set(SRV_METRICS) - set(IMOE_STAYS_OUT)) | set(
         NM3_JOINS_OF_IMOE)
-    assert mine == joined | set(NM3_METRICS) | SPAN_SRV
+    assert mine == joined | set(NM3_METRICS) | SPAN_SRV | {SAMPLE_MS}
     for metric in joined:
         assert harness.by_name(MANIFEST["per_layer"], metric,
                                "metric")["workloads"][-1] == NM3
@@ -484,6 +487,56 @@ def test_span_metric_resolves_to_a_file_and_a_reader(metric):
         assert (mine["reader"], mine["args"]) == (reader, spec["args"])
 
 
+def test_sample_metric_resolves_to_a_file_and_the_pooled_steps_scope():
+    entry = harness.by_name(MANIFEST["per_layer"], SAMPLE_MS, "metric")
+    assert entry == {
+        "name": SAMPLE_MS, "unit": "ms", "better": "lower",
+        "source": "device_trace", "layer": ENGINE,
+        "moves": "out_tokens_per_s_chip", "workloads": SATS}
+    spec = harness.load_json(MANIFEST, "layer_metrics", SAMPLE_MS)
+    assert spec == {"reader": "serve_scopes_in_module", "args": {
+        "module": "jit__slot_step_jit", "op_name": "/sample_rows/"}}
+    for cell in SATS:
+        mine = harness.by_name(harness.resolve(MANIFEST, cell).per_layer,
+                               SAMPLE_MS, "metric")
+        assert (mine["reader"], mine["args"]) == (spec["reader"],
+                                                  spec["args"])
+    # the name the reader looks for is the one the program gives: the scope
+    # of generate._sample_rows, on every operation of the tail, the cond
+    # and its two branches among them
+    import jax
+    import jax.numpy as jnp
+    from torchmpi_tpu.models.generate import _sample_keys, _sample_rows
+
+    rows = jnp.zeros((3,), jnp.int32)
+    text = jax.jit(_sample_rows, static_argnums=5).lower(
+        jnp.zeros((3, 16)), _sample_keys(rows.astype(jnp.uint32), rows),
+        rows.astype(jnp.float32), rows, rows + 2.0,
+        jnp.int32).as_text(debug_info=True)
+    named = re.findall(r'loc\("(jit\(_sample_rows\)/[^"]*)"', text)
+    assert named and all(re.search(spec["args"]["op_name"], n + "/")
+                         for n in named), named
+    assert any(n.endswith("sample_rows/cond") for n in named)
+
+
+def test_sample_metric_reads_no_number_where_the_scope_is_not(
+        recorded_served, monkeypatch):
+    """The recorded ticks are PR 35's: they predate the scope (and their
+    operations' own stats were dropped), so the reader finds the pooled
+    step's executions, nothing under the name, and gives no number and no
+    error: what a traced run of the parent reads."""
+    path, trace, modules = recorded_served
+    monkeypatch.setattr(SCOPES, "raw_trace", lambda ctx: path)
+    read = harness.load_module(MANIFEST, "readers",
+                               "serve_scopes_in_module").read
+    args = harness.load_json(MANIFEST, "layer_metrics", SAMPLE_MS)["args"]
+    ctx = {"cell": harness.resolve(MANIFEST, SAT), "trace": trace,
+           "modules": modules, "traced": {"steps": 3}}
+    assert read(ctx, **args) is None
+    # the same executions do hold the parent's tail, by instruction name
+    assert read(ctx, module=args["module"], name=r"^%sort\.") > 0.1
+
+
 def test_the_idle_rules_name_every_program_span_once():
     """The nine ``.srv`` rules are a partition of the program's span names
     and ``outside`` (so their shares add up to the idle share); the three
@@ -510,8 +563,8 @@ def test_the_idle_rules_name_every_program_span_once():
 def test_benchmark_json_only_gained_entries_at_the_end():
     """What the benchmark had (PR 23, then PR 24) is still there, first
     and unchanged in order; PR 26's metrics follow it, then PR 27's, PR
-    30's, PR 31's, PR 33's and PR 35's, each where its PR appended it: the
-    next PR appends after them and adds its own slice here."""
+    30's, PR 31's, PR 33's, PR 35's and PR 36's, each where its PR appended
+    it: the next PR appends after them and adds its own slice here."""
     names = [m["name"] for m in MANIFEST["per_layer"]]
     assert set(names[10:22]) == set(NEW_METRICS)
     assert names[:22] == [
@@ -529,6 +582,7 @@ def test_benchmark_json_only_gained_entries_at_the_end():
     assert names[55:60] == list(IMOE_METRICS)
     assert names[60:66] == list(NM3_METRICS)
     assert names[66:82] == list(SPAN_METRICS)
+    assert names[82:] == [SAMPLE_MS]
     layers = {m["layer"] for m in MANIFEST["per_layer"][:10]}
     assert {m["layer"] for m in MANIFEST["per_layer"][10:22]} <= layers
     assert {m["layer"] for m in MANIFEST["per_layer"][22:31]} <= layers | {
@@ -540,6 +594,7 @@ def test_benchmark_json_only_gained_entries_at_the_end():
         "state-space layers", "expert layer", "serving engine"}
     assert {m["layer"] for m in MANIFEST["per_layer"][66:82]} == {
         ENGINE, SCHEDULER}
+    assert MANIFEST["per_layer"][82]["layer"] == ENGINE
     assert [c["name"] for c in MANIFEST["configs"]][:6] == [
         "resnet50", "starcoder2-3b", "smallthinker-21b-a3b",
         "starcoder2-3b-serve", "instella-moe-16b-a3b-serve", NM3_CONFIG]

@@ -663,6 +663,121 @@ def test_filter_logits_rows_matches_static_and_sentinels():
 
 
 # ---------------------------------------------------------------------------
+# _sample_rows: the tail does what its rows ask (one lax.cond on the
+# device), bitwise the always-filter form it replaced
+# ---------------------------------------------------------------------------
+
+
+def _sample_rows_always_filter(logits, keys, temps, top_ks, top_ps, dtype):
+    """The form every pooled program ran until PR 36, kept here as the
+    reference: filter every row, draw every row, then pick by row."""
+    from torchmpi_tpu.models.generate import _filter_logits_rows
+
+    logits = _filter_logits_rows(logits.astype(jnp.float32), temps,
+                                 top_ks, top_ps)
+    drawn = jax.vmap(jax.random.categorical)(
+        keys, logits / jnp.maximum(temps, 1e-6)[:, None])
+    return jnp.where(temps > 0.0, drawn,
+                     jnp.argmax(logits, axis=-1)).astype(dtype)
+
+
+# (temperature, top_k, top_p) of the rows a mix cycles through
+_GREEDY, _GREEDY_KNOBS = (0.0, 0, 2.0), (0.0, 3, 0.6)
+_TEMP, _TOP_K, _TOP_P, _K_AND_P = (0.8, 0, 2.0), (1.3, 5, 2.0), \
+    (0.7, 0, 0.9), (1.0, 7, 0.8)
+_SAMPLE_MIXES = {
+    # name: (rows, logits dtype, the knobs cycled over the rows, branch)
+    "all_greedy": (5, jnp.float32, [_GREEDY], 0),
+    "greedy_with_knobs_set": (5, jnp.float32, [_GREEDY_KNOBS, _GREEDY], 0),
+    "temperature_only": (5, jnp.float32, [_TEMP, (1.5, 0, 2.0)], 1),
+    "greedy_and_temperature": (5, jnp.float32, [_GREEDY, _TEMP], 1),
+    "greedy_knobs_and_temperature": (5, jnp.float32,
+                                     [_GREEDY_KNOBS, _TEMP], 1),
+    "greedy_filtered_and_temperature": (5, jnp.float32,
+                                        [_GREEDY, _TOP_K, _TEMP, _TOP_P], 1),
+    "every_row_filtered": (5, jnp.float32, [_TOP_K, _TOP_P, _K_AND_P], 1),
+    "bfloat16_greedy": (5, jnp.bfloat16, [_GREEDY], 0),
+    "bfloat16_mixed": (5, jnp.bfloat16,
+                       [_GREEDY, _TEMP, _K_AND_P, _GREEDY_KNOBS], 1),
+    "one_row_greedy": (1, jnp.float32, [_GREEDY], 0),
+    "one_row_temperature": (1, jnp.float32, [_TEMP], 1),
+    "one_row_filtered": (1, jnp.float32, [_K_AND_P], 1),
+    "verify_rows_greedy": (4 * 3, jnp.float32, [_GREEDY], 0),
+    "verify_rows_mixed": (4 * 3, jnp.float32,
+                          [_GREEDY, _GREEDY, _GREEDY, _TEMP, _TEMP, _TEMP,
+                           _TOP_P, _TOP_P, _TOP_P], 1),
+}
+
+
+def _sample_mix(name):
+    from torchmpi_tpu.models.generate import _sample_keys
+
+    R, dtype, knobs, branch = _SAMPLE_MIXES[name]
+    rng = np.random.RandomState(len(name) + R)
+    # a coarse, clipped grid: every row holds ties, its maximum among them
+    logits = np.clip(np.round(rng.randn(R, 257) * 2.0) / 2.0, -2.0, 2.0)
+    rows = [knobs[i % len(knobs)] for i in range(R)]
+    temps, top_ks, top_ps = (jnp.asarray(col, dt) for col, dt in zip(
+        zip(*rows), (jnp.float32, jnp.int32, jnp.float32)))
+    keys = _sample_keys(jnp.arange(R, dtype=jnp.uint32) + 11,
+                        jnp.arange(R, dtype=jnp.int32) * 3)
+    return (jnp.asarray(logits, dtype), keys, temps, top_ks, top_ps), branch
+
+
+@pytest.mark.parametrize("mix", sorted(_SAMPLE_MIXES))
+def test_sample_rows_is_bitwise_the_always_filter_form(mix):
+    from torchmpi_tpu.models.generate import _sample_rows
+
+    operands, _ = _sample_mix(mix)
+    got = jax.jit(_sample_rows, static_argnums=5)(*operands, jnp.int32)
+    want = jax.jit(_sample_rows_always_filter, static_argnums=5)(
+        *operands, jnp.int32)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    top = np.asarray(operands[0].astype(jnp.float32))
+    assert ((top == top.max(-1, keepdims=True)).sum(-1) > 1).all()  # ties
+
+
+def _primitives(jaxpr, found=None):
+    """Names of every primitive of ``jaxpr``, sub-jaxprs included."""
+    found = set() if found is None else found
+    for eqn in jaxpr.eqns:
+        found.add(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _primitives(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("mix", ["all_greedy", "greedy_and_temperature",
+                                 "every_row_filtered"])
+def test_sample_rows_is_one_cond_and_each_branch_holds_its_own_work(mix):
+    """One ``cond`` at the top; the sort, the running sum and the random
+    bits only in its last branch; and the index the operands give is the
+    branch the mix asks for."""
+    from torchmpi_tpu.models.generate import _sample_rows
+
+    operands, branch = _sample_mix(mix)
+    jaxpr = jax.make_jaxpr(_sample_rows, static_argnums=5)(
+        *operands, jnp.int32).jaxpr
+    conds = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
+    assert len(conds) == 1
+    outside = {e.primitive.name for e in jaxpr.eqns} - {"cond"}
+    heavy = {"sort", "cumsum", "random_bits", "argmax", "exp"}
+    assert not outside & heavy, outside & heavy
+    greedy, drawn = (
+        _primitives(b.jaxpr) for b in conds[0].params["branches"])
+    assert "argmax" in greedy and not greedy & (heavy - {"argmax"})
+    assert {"sort", "cumsum", "random_bits"} <= drawn
+    # the predicate, evaluated: everything the top level computes before
+    # the cond is the index
+    index = jax.core.eval_jaxpr(
+        jaxpr.replace(outvars=[conds[0].invars[0]],
+                      eqns=jaxpr.eqns[:jaxpr.eqns.index(conds[0])]),
+        [], *operands)[0]
+    assert int(index) == branch
+
+
+# ---------------------------------------------------------------------------
 # A decode=True prompt block through the flash forward kernel
 # (models/transformer.prefill_runs_flash decides; the CPU keeps dense scores
 # unless a test calls conftest's chip_rule: the kernel is then interpreted)

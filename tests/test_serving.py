@@ -29,7 +29,7 @@ import jax.numpy as jnp
 import torchmpi_tpu as mpi
 from torchmpi_tpu import serving
 from torchmpi_tpu.models import TransformerLM, generate
-from torchmpi_tpu.serving.engine import SPANS
+from torchmpi_tpu.serving.engine import SAMPLE_BRANCHES, SPANS
 from torchmpi_tpu.serving.slots import SlotPool
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -500,6 +500,89 @@ def test_invalid_sampling_rejected(lm):
 
 
 # ---------------------------------------------------------------------------
+# The sampling tail does what a pool's rows ask: the engine counts which
+# branch of generate._sample_rows each pooled step's sessions ask for
+# ---------------------------------------------------------------------------
+
+_SAMPLED = {"temperature": dict(temperature=0.9, seed=31),
+            "filtered": dict(temperature=0.9, top_k=12, top_p=0.9, seed=31)}
+
+
+def _branch_counts(engine):
+    return [engine.stats[b] for b in SAMPLE_BRANCHES]
+
+
+@pytest.mark.parametrize("kind", ["plain", "bucketed", "spec_ngram"])
+def test_a_greedy_trace_counts_every_step_under_the_argmax(lm, kind):
+    model, params = lm
+    reqs, prompts, eng = _pool_trace(lm, kind)
+    assert _branch_counts(eng) == [eng.stats["steps"], 0]
+    assert eng.stats["steps"] > 0
+    for i, req in enumerate(reqs):
+        assert req.tokens == _offline(model, params, prompts[i], 7).tolist()
+
+
+@pytest.mark.parametrize("knobs", sorted(_SAMPLED))
+def test_a_sampled_request_moves_the_steps_it_is_live_for(lm, knobs):
+    """Two greedy sessions decode throughout; one sampled request is
+    admitted after two steps and retires four steps later: exactly the
+    steps it is live for leave ``sample_argmax``, the greedy streams stay
+    the offline ones, and the sampled stream is what the request emits
+    alone in a pool (where every step draws)."""
+    model, params = lm
+    prompts = _prompts(3, seed=27)
+    sampled = dict(max_new=5, **_SAMPLED[knobs])
+    alone = serving.ReplicaEngine(model, params, slots=1, slot_tokens=32)
+    want = _drive(alone, serving.Request("s", prompts[2], **sampled))
+    assert _branch_counts(alone) == [0, 4] and alone.stats["steps"] == 4
+
+    engine = serving.ReplicaEngine(model, params, slots=3, slot_tokens=32)
+    greedy = [engine.admit(serving.Request(f"g{i}", prompts[i],
+                                           max_new=12))[0]
+              for i in range(2)]
+    engine.step(), engine.step()
+    assert _branch_counts(engine) == [2, 0]
+    sess, _ = engine.admit(serving.Request("s", prompts[2], **sampled))
+    finished = []
+    while sess not in finished:
+        finished = engine.step()[1]
+    assert _branch_counts(engine) == [2, 4]
+    while engine.active:
+        engine.step()
+    assert _branch_counts(engine) == [11 - 4, 4]
+    assert engine.stats["steps"] == 11
+    assert sess.emitted == want
+    assert want != _offline(model, params, prompts[2], 5).tolist()
+    for i, g in enumerate(greedy):
+        assert g.emitted == _offline(model, params, prompts[i], 12).tolist()
+
+
+def test_branch_counters_are_mirrored_in_the_registry(lm, tmp_path):
+    model, params = lm
+    mpi.stop()
+    mpi.init(mpi.Config(dcn_size=1, obs="metrics", obs_dir=str(tmp_path)))
+    try:
+        from torchmpi_tpu import obs
+
+        obs.reset()
+        prompts = _prompts(3, seed=28)
+        reqs = [serving.Request("g", prompts[0], max_new=9),
+                serving.Request("d", prompts[1], max_new=3,
+                                **_SAMPLED["temperature"]),
+                serving.Request("f", prompts[2], max_new=6, arrival_s=0.004,
+                                **_SAMPLED["filtered"])]
+        eng = _run_server(model, params, reqs)
+        reg = obs.registry()
+        counts = _branch_counts(eng)
+        assert all(counts) and sum(counts) == eng.stats["steps"]
+        for branch, n in zip(SAMPLE_BRANCHES, counts):
+            assert reg.counter(f"tm_serving_{branch}_total",
+                               replica=eng.name) == n
+    finally:
+        mpi.stop()
+
+
+# ---------------------------------------------------------------------------
 # Speculative decoding: bitwise the plain stream, cheaper per token
 # ---------------------------------------------------------------------------
 
@@ -709,6 +792,44 @@ def test_tp_sharded_server_matches_tp_oracle(tmp_path):
                                                   ("model",))
         assert p1.extra["devices"] == 2
         assert p1.extra["axes"] == ("model",)
+
+
+def test_tp_engine_takes_the_branch_its_rows_ask_for():
+    """The branch under ``shard_map`` (the predicate replicated): a greedy
+    stream beside a sampled one is the offline oracle's, the sampled one
+    is what it is alone in a pool, and the steps are counted by branch."""
+    import importlib
+
+    tpg = importlib.import_module("torchmpi_tpu.models.tp_generate")
+    from jax.sharding import Mesh
+
+    V = 64
+    tparams = tpg.init_tp_lm(jax.random.PRNGKey(5), vocab=V, embed=32,
+                             depth=2, num_heads=4, head_dim=8)
+    prompts = np.random.RandomState(14).randint(
+        0, V, size=(2, 5)).astype(np.int32)
+    mesh = Mesh(np.asarray(jax.devices()[:2]), ("model",))
+    oracle = np.asarray(tpg.tp_generate(
+        tparams, prompts[0].reshape(1, -1), steps=9, mesh=mesh,
+        axis="model", num_heads=4))[0, 5:].tolist()
+
+    def engine(slots):
+        return serving.TPReplicaEngine(tparams, mesh=mesh, num_heads=4,
+                                       slots=slots, slot_tokens=32)
+
+    sampled = dict(max_new=4, **_SAMPLED["filtered"])
+    alone = engine(1)
+    want = _drive(alone, serving.Request("s", prompts[1], **sampled))
+    assert _branch_counts(alone) == [0, 3]
+
+    eng = engine(2)
+    greedy, _ = eng.admit(serving.Request("g", prompts[0], max_new=9))
+    eng.step()
+    got = _drive(eng, serving.Request("s", prompts[1], **sampled))
+    while eng.active:
+        eng.step()
+    assert _branch_counts(eng) == [5, 3]
+    assert got == want and greedy.emitted == oracle
 
 
 def test_tp_engine_requires_explicit_slot_tokens():

@@ -131,16 +131,34 @@ def _filter_logits_rows(logits, temps, top_ks, top_ps):
 def _sample_rows(logits, keys, temps, top_ks, top_ps, dtype):
     """Per-row filtered sampling over [R, V] logits with [R] knob
     arrays and [R] per-row keys (:func:`_sample_keys`): greedy rows
-    (temp <= 0) take the argmax of the (no-op-filtered) logits, sampled
-    rows a categorical draw at their own temperature.  The serving
-    engines route every emitted token — prefill first-token, [S, 1]
-    decode, [S, K+1] speculative verify — through this one function."""
-    logits = _filter_logits_rows(logits.astype(jnp.float32), temps,
-                                 top_ks, top_ps)
-    drawn = jax.vmap(jax.random.categorical)(
-        keys, logits / jnp.maximum(temps, 1e-6)[:, None])
-    return jnp.where(temps > 0.0, drawn,
-                     jnp.argmax(logits, axis=-1)).astype(dtype)
+    (temp <= 0) take the argmax of the logits, sampled rows a
+    categorical draw at their own temperature over their own support.
+    The serving engines route every emitted token — prefill first-token,
+    [S, 1] decode, [S, K+1] speculative verify — through this one
+    function.
+
+    The tail does what its rows ask, decided ON THE DEVICE from the
+    operands (one executable, nothing for the host to choose): a
+    ``lax.cond`` on whether any row samples.  None does: the argmax,
+    and no sort, softmax, running sum or draw over the vocabulary.  A
+    greedy row's token never depended on its ``top_k`` / ``top_p``: the
+    k-filter keeps the maximum, the nucleus rule the first sorted entry,
+    ties their order, so this is bitwise what the other branch gives
+    such rows.  One does: :func:`_filter_logits_rows`, then the draw."""
+
+    def argmax(logits):
+        return jnp.argmax(logits.astype(jnp.float32), axis=-1)
+
+    def filter_then_draw(logits):
+        logits = _filter_logits_rows(logits.astype(jnp.float32), temps,
+                                     top_ks, top_ps)
+        drawn = jax.vmap(jax.random.categorical)(
+            keys, logits / jnp.maximum(temps, 1e-6)[:, None])
+        return jnp.where(temps > 0.0, drawn, jnp.argmax(logits, axis=-1))
+
+    with jax.named_scope("sample_rows"):
+        return lax.cond(jnp.any(temps > 0.0), filter_then_draw, argmax,
+                        logits).astype(dtype)
 
 
 def _generate_scan(model, params, prompt, steps, temperature, rng,

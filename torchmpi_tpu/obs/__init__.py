@@ -595,6 +595,9 @@ def record_serving(event: str, n: int = 1, *, replica: str = "") -> None:
     flash forward kernel, ``models.transformer.prefill_runs_flash``) |
     ``pool_calls`` / ``pool_donated`` (calls of a program that takes the
     slot pool, and those after which the pool that went in was gone) |
+    ``sample_argmax`` / ``sample_draw`` (pooled steps by the branch of
+    ``models.generate._sample_rows`` their rows asked for: no row
+    samples, the argmax | some row samples, the filter and the draw) |
     ``spec_drafted`` /
     ``spec_accepted`` (speculative-decode draft tokens proposed /
     accepted — the live acceptance rate) | ``prefix_hits`` /

@@ -46,7 +46,10 @@ attended through the flash forward kernel
 by the layer's own rule, ``models.transformer.prefill_runs_flash``), the
 gauges of what a cached token and a slot's recurrent state cost, how many
 of the programs that were handed the pool took it over
-(``stats["pool_donated"]`` of ``stats["pool_calls"]``), and, for
+(``stats["pool_donated"]`` of ``stats["pool_calls"]``), which branch
+of the sampling tail each pooled step's sessions ask for
+(:data:`SAMPLE_BRANCHES`: ``stats["sample_argmax"]`` +
+``stats["sample_draw"]`` = ``stats["steps"]``), and, for
 a model with expert layers, what the pooled step itself
 counted: held experts touched, routes and routes held, read with the tokens): the
 scheduler owns the clock, the SLO histograms, and the fault hooks, so
@@ -89,6 +92,11 @@ SPANS = (
     "tm.serve.step", "tm.serve.step.operands", "tm.serve.step.draft",
     "tm.serve.step.dispatch", "tm.serve.step.read", "tm.serve.step.book")
 span = jax.profiler.TraceAnnotation
+
+#: What a pooled step's rows ask of the sampling tail, by the branch
+#: ``models.generate._sample_rows`` takes for them on the device: no row
+#: samples (the argmax) | some row samples (the filter, then the draw).
+SAMPLE_BRANCHES = ("sample_argmax", "sample_draw")
 
 
 class RequestRejected(ValueError):
@@ -280,7 +288,8 @@ class ReplicaEngine:
                       "spec_accepted": 0, "prefill_tokens": 0,
                       "prefill_kernel_tokens": 0,
                       "prefix_hits": 0, "prefix_misses": 0,
-                      "pool_calls": 0, "pool_donated": 0}
+                      "pool_calls": 0, "pool_donated": 0,
+                      **dict.fromkeys(SAMPLE_BRANCHES, 0)}
         #: Work units spent (prefill/pooled forward = 1 each, draft
         #: forwards at the proposer's weight) — the scheduler's
         #: ``unit_seconds`` virtual clock advances by the delta.
@@ -646,9 +655,19 @@ class ReplicaEngine:
                   spec=int(spec)):
             return self._spec_step() if spec else self._plain_step()
 
+    def _count_step(self, sessions: Dict[int, Session]) -> None:
+        """One more pooled step, under the branch of the sampling tail
+        its rows ask for (:data:`SAMPLE_BRANCHES`): the program's own
+        rule, any temperature above zero, on the host's copy of it."""
+        self.stats["steps"] += 1
+        branch = SAMPLE_BRANCHES[any(s.sampling[0] > 0.0
+                                     for s in sessions.values())]
+        self.stats[branch] += 1
+        emit("record_serving", branch, replica=self.name)
+
     def _plain_step(self) -> Tuple[List[Session], List[Session]]:
         with span("tm.serve.step.operands"):
-            self.stats["steps"] += 1
+            self._count_step(self._sessions)
             self.units += 1.0
             S = self.pool.n_slots
             toks = np.zeros((S,), np.int32)
@@ -706,7 +725,7 @@ class ReplicaEngine:
                 if d:
                     toks[slot, 1:1 + len(d)] = d
                 pos[slot] = sess.pos_next
-            self.stats["steps"] += 1
+            self._count_step(sessions)
             self.stats["spec_steps"] += 1
             self.units += 1.0 + float(draft_units)
         out = self._backend_verify(toks, pos, samp)
