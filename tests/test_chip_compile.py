@@ -390,7 +390,7 @@ def _compiled_step(steps, one_chip, workload):
 
 @pytest.mark.parametrize("workload", [
     "sc2-3b-serve-sat", "imoe-16b-serve-conv-sat",
-    "nm3s-120b-serve-chat-sat"])
+    "nm3s-120b-serve-chat-sat", "jamba2-3b-serve-reason-sat"])
 def test_served_decode_step_updates_the_pool_in_place(
         one_chip, compiled_steps, workload):
     # The pooled step of each served configuration at its file's slots: the
@@ -426,7 +426,7 @@ def test_served_decode_step_updates_the_pool_in_place(
 
 @pytest.mark.parametrize("workload", [
     "sc2-3b-serve-sat", "imoe-16b-serve-conv-sat",
-    "nm3s-120b-serve-chat-sat"])
+    "nm3s-120b-serve-chat-sat", "jamba2-3b-serve-reason-sat"])
 def test_served_decode_step_sorts_the_vocabulary_in_one_branch_only(
         one_chip, compiled_steps, workload):
     # The sampling tail of each served configuration's pooled step, as the
@@ -499,6 +499,61 @@ def test_served_hybrid_compiles_at_the_cells_sizes(
     ma = compiled.memory_analysis()
     assert (ma.argument_size_in_bytes + ma.output_size_in_bytes
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes) < 16_909_336_064
+
+
+@pytest.mark.parametrize("program", ["prefill64", "prefill1024", "step"])
+def test_served_jamba_compiles_at_the_cells_sizes(
+        one_chip, compiled_steps, program):
+    # jamba2-3b-serve-reason-sat's programs at the published widths
+    # (chipbench/configs/jamba2-3b-serve.json), the WHOLE model: 26 Mamba-1
+    # mixers of 5120 channels x 16 states (a prompt's scan in pieces, a
+    # pooled step ONE recurrent update a slot), two attention layers with
+    # one key/value head whose prompt runs the flash forward kernel, 28
+    # SwiGLU feed-forwards of 8192, a tied head: 3,029,337,472 parameters,
+    # 6.06 GB in bfloat16 and no `head` among them.  The smallest and the
+    # largest prefill bucket of the cell's traffic and the pooled step at
+    # the file's slots fit the chip; the step's cache has the two state
+    # leaves a mixer, channels-minor, no token axis on them; a slot's bytes
+    # are the configuration's count; and a 1024-token prompt's scan holds
+    # nothing of [T, 16, 5120] (335 MB a layer): the prefill's temporaries
+    # stay under 0.6 GB.
+    from chipbench import flops_jamba_serve as counts
+    from torchmpi_tpu.models.generate import STATE_LEAVES, _slot_prefill_jit
+
+    harness = _harness()
+    workload = "jamba2-3b-serve-reason-sat"
+    cfg = harness.resolve(harness.load_manifest(), workload).config
+    sizes = {k: cfg[k] for k in cfg["flops"]["sizes"]}
+    dmodel, params, slots, sampling = _served(one_chip, workload)
+    assert "head" not in params
+    assert sum(a.size for a in jax.tree.leaves(params)) == counts.parameters(
+        **sizes) == 3_029_337_472
+    if program == "step":
+        cache, compiled = _compiled_step(compiled_steps, one_chip, workload)
+        leaves = jax.tree_util.tree_leaves_with_path(cache)
+        state = [a for path, a in leaves if path[-1].key in STATE_LEAVES]
+        assert sorted(a.shape for a in state) == sorted(
+            [(slots, 16, 5120), (slots, 3, 5120)] * 26)
+        assert sum(a.size * a.dtype.itemsize for a in state) == (
+            slots * counts.state_bytes_per_slot(**sizes)) == (
+                slots * 10_117_120)
+        tokens = [a for path, a in leaves
+                  if a.ndim and path[-1].key not in STATE_LEAVES]
+        assert sorted(a.shape for a in tokens) == [
+            (slots, cfg["serving"]["slot_tokens"], 1, 128)] * 4
+        attention, limit = [], 1.0e9
+    else:
+        bucket = int(program[len("prefill"):])
+        compiled = _slot_prefill_jit.lower(
+            dmodel, params, _sds((1, bucket), jnp.int32, one_chip),
+            _sds((), jnp.int32, one_chip), *sampling(1)).compile()
+        attention, limit = ["flash.fwd"] * 2, 0.6e9
+    text = compiled.as_text()
+    assert sorted(ident for _, ident in KERNEL.findall(text)) == attention
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < limit
+    assert (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes) < 15e9
 
 
 def test_smallthinker_step_compiles_at_the_cells_sizes(chip):

@@ -133,10 +133,51 @@ NM3_STAYS_OUT = {"decode_hbm_roofline_pct.srv": [SAT],
                  "decode_moe_hbm_roofline_pct.srv": [IMOE],
                  "latent_attn_ms_per_decode_step.srv": [IMOE]}
 
+# what PR 37 added, in order: metric -> (reader, layer, source, unit, better)
+JAMBA = "jamba2-3b-serve-reason-sat"
+JAMBA_CONFIG = "jamba2-3b-serve"
+JAMBA_METRICS = {
+    "mamba_ms_per_decode_step.srv": (
+        "serve_scopes_in_module", "state-space layers", "device_trace", "ms",
+        "lower"),
+    "mamba_state_ms_per_decode_step.srv": (
+        "serve_scopes_in_module", "state-space layers", "device_trace", "ms",
+        "lower"),
+    "mamba_state_hbm_roofline_pct.srv": (
+        "serve_state_roofline", "state-space layers", "device_trace", "%",
+        "higher"),
+    "decode_ssm_hbm_roofline_pct.srv": (
+        "serve_state_roofline", "state-space layers", "device_trace", "%",
+        "higher"),
+    "mamba_scan_ms_per_prefill.srv": (
+        "serve_scopes_in_module_per", "state-space layers", "device_trace",
+        "ms", "lower"),
+    "mlp_ms_per_decode_step.srv": (
+        "serve_scopes_in_module", "model step", "device_trace", "ms",
+        "lower"),
+}
+# PR 33's metric whose reader is right for it as it stands (the scope of
+# ``SPAttention``) ...
+JAMBA_JOINS_OF_NM3 = ["attn_ms_per_decode_step.srv"]
+# ... and what it stays out of: the other models' counts of a step's bytes,
+# latent attention's and Mamba-2's scopes, everything of an expert layer
+JAMBA_STAYS_OUT = {
+    "decode_hbm_roofline_pct.srv": [SAT],
+    "decode_moe_hbm_roofline_pct.srv": [IMOE],
+    "decode_hybrid_hbm_roofline_pct.srv": [NM3],
+    "latent_attn_ms_per_decode_step.srv": [IMOE],
+    "ssm_ms_per_decode_step.srv": [NM3],
+    "ssm_scan_ms_per_prefill.srv": [NM3],
+    "moe_experts_ms_per_decode_step.srv": [IMOE, NM3],
+    "moe_permute_ms_per_decode_step.srv": [IMOE, NM3],
+    "moe_experts_touched_share.srv": [IMOE, NM3],
+    "moe_latent_ms_per_decode_step.srv": [NM3],
+    "moe_routes_held_share.srv": [NM3]}
+
 # what PR 35 added, in order: the program's own ``tm.serve.*`` spans, read
 # by ``program_span`` (mean ms) and ``serve_idle_by_span`` (the idle share
 # under them): metric -> (reader, the reader's ``span``, layer)
-SATS = [SAT, IMOE, NM3]
+SATS = [SAT, IMOE, NM3, JAMBA]
 ENGINE, SCHEDULER = "serving engine", "serving scheduler"
 SPAN_METRICS = {
     "decode_span_ms.srv": ("program_span", "tm.serve.step", ENGINE),
@@ -381,8 +422,8 @@ def test_hybrid_served_cell_joins_the_lists_whose_readers_are_right_for_it():
         NM3_JOINS_OF_IMOE)
     assert mine == joined | set(NM3_METRICS) | SPAN_SRV | {SAMPLE_MS}
     for metric in joined:
-        assert harness.by_name(MANIFEST["per_layer"], metric,
-                               "metric")["workloads"][-1] == NM3
+        assert NM3 in harness.by_name(MANIFEST["per_layer"], metric,
+                                      "metric")["workloads"]
     for metric, cells in NM3_STAYS_OUT.items():
         assert harness.by_name(MANIFEST["per_layer"], metric,
                                "metric")["workloads"][:len(cells)] == cells
@@ -414,6 +455,131 @@ def test_hybrid_served_cell_joins_the_lists_whose_readers_are_right_for_it():
     names = [w["name"] for w in MANIFEST["workloads"]]
     assert names[8:9] == [NM3]
     assert sum(w["chips"] == 4 for w in MANIFEST["workloads"][:9]) == 1
+
+
+@pytest.mark.parametrize("metric", sorted(JAMBA_METRICS))
+def test_state_served_cell_metric_resolves_to_a_file_and_a_reader(metric):
+    reader, layer, source, unit, better = JAMBA_METRICS[metric]
+    entry = harness.by_name(MANIFEST["per_layer"], metric, "metric")
+    assert entry == {
+        "name": metric, "unit": unit, "better": better, "source": source,
+        "layer": layer, "moves": "out_tokens_per_s_chip",
+        "workloads": entry["workloads"]}
+    assert entry["workloads"][0] == JAMBA
+    spec = harness.load_json(MANIFEST, "layer_metrics", metric)
+    assert spec["reader"] == reader
+    assert callable(harness.load_module(MANIFEST, "readers", reader).read)
+    mine = harness.by_name(harness.resolve(MANIFEST, JAMBA).per_layer,
+                           metric, "metric")
+    assert mine["args"] == spec["args"]
+    for pattern in ("module", "op_name", "name", "not_name"):
+        re.compile(spec["args"].get(pattern, ""))
+    assert spec["args"]["module"] == (
+        "jit__slot_prefill_jit" if "prefill" in metric
+        else "jit__slot_step_jit")
+
+
+def test_state_served_cell_joins_the_lists_whose_readers_are_right_for_it():
+    """PR 37's cell: the generic ``.srv`` metrics of PR 30, PR 35 and PR 36,
+    one of PR 33's, its own six, and nothing of another model's counts,
+    scopes or expert layers; ten cells, one of them on four chips; its
+    configuration the seventh, with nothing cut."""
+    mine = {m["name"] for m in harness.resolve(MANIFEST, JAMBA).per_layer}
+    joined = (set(SRV_METRICS) - set(IMOE_STAYS_OUT)) | set(
+        JAMBA_JOINS_OF_NM3) | SPAN_SRV | {SAMPLE_MS}
+    assert mine == joined | set(JAMBA_METRICS)
+    # pinned by name and by "still there, in order", not by the tail: the
+    # next served cell appends after these and trips nothing (ROADMAP C0)
+    for metric in joined:
+        cells = harness.by_name(MANIFEST["per_layer"], metric,
+                                "metric")["workloads"]
+        assert JAMBA in cells and (
+            NM3 not in cells or cells.index(NM3) < cells.index(JAMBA))
+    for metric, cells in JAMBA_STAYS_OUT.items():
+        assert harness.by_name(MANIFEST["per_layer"], metric,
+                               "metric")["workloads"][:len(cells)] == cells
+        assert metric not in mine
+    cell = harness.resolve(MANIFEST, JAMBA)
+    assert {m["name"] for m in cell.end_to_end} == {
+        "out_tokens_per_s_chip", "setup_s"}
+    assert harness.by_name(MANIFEST["end_to_end"], "out_tokens_per_s_chip",
+                           "metric")["workloads"][:4] == SATS
+    entry = harness.by_name(MANIFEST["workloads"], JAMBA, "workload")
+    assert (entry["config"], entry["traffic"], entry["chips"]) == (
+        JAMBA_CONFIG, "open-reason-sat", 1)
+    assert 1 <= len(entry["why"]) <= 200
+    config = harness.by_name(MANIFEST["configs"], JAMBA_CONFIG, "config")
+    assert config["reduced"] == cell.config["reduced"] == []
+    assert 1 <= len(config["why"]) <= 200
+    assert cell.config["runner"] == "serve_open_loop_jamba"
+    # the whole model as published: every key of the catalog's config, the
+    # layers' order from the period and the offset, the parameters counted
+    assert (cell.config["num_hidden_layers"], cell.config["hidden_size"],
+            cell.config["intermediate_size"], cell.config["vocab_size"],
+            cell.config["mamba_d_state"], cell.config["mamba_dt_rank"],
+            cell.config["num_key_value_heads"],
+            cell.config["tie_word_embeddings"]) == (
+                28, 2560, 8192, 65536, 16, 160, 1, True)
+    assert cell.config["layer_pattern"] == "".join(
+        "a" if i % cell.config["attn_layer_period"]
+        == cell.config["attn_layer_offset"] else "m" for i in range(28))
+    from chipbench import flops_jamba_serve as counts
+
+    sizes = {k: cell.config[k] for k in cell.config["flops"]["sizes"]}
+    assert counts.parameters(**sizes) == 3_029_337_472
+    assert counts.state_bytes_per_slot(**sizes) == 10_117_120
+    assert "3,029,337,472" in cell.config["parameters"]
+    # the traffic's lengths fit a slot; decode-heavy: answers over prompts
+    srv, traffic = cell.config["serving"], cell.traffic
+    assert (traffic["prompt_tokens"]["max"] + traffic["answer_tokens"]["max"]
+            == srv["slot_tokens"])
+    assert traffic["answer_tokens"]["median"] == 4 * traffic[
+        "prompt_tokens"]["median"]
+    names = [w["name"] for w in MANIFEST["workloads"]]
+    assert names[9:10] == [JAMBA]
+    assert sum(w["chips"] == 4 for w in MANIFEST["workloads"][:10]) == 1
+
+
+def test_state_roofline_reader_counts_the_work_from_the_windows_live_slots(
+        monkeypatch):
+    """No live slots among the window's numbers (no decode step in it),
+    no traced step or no chip: no number and no error.  With them: the
+    recurrent updates' bytes over the scope's time, the whole step's bytes
+    over the program's time, both over the chip's bandwidth."""
+    from chipbench import flops, flops_jamba_serve as counts
+
+    roof = harness.load_module(MANIFEST, "readers", "serve_state_roofline")
+    cell = harness.resolve(MANIFEST, JAMBA)
+    args = {m: harness.load_json(MANIFEST, "layer_metrics", m)["args"]
+            for m in ("mamba_state_hbm_roofline_pct.srv",
+                      "decode_ssm_hbm_roofline_pct.srv")}
+    ctx = {"cell": cell, "platform": "tpu", "kind": "TPU v5 lite",
+           "traced": {"steps": 5, "live_tokens_per_step": 400 * 700.0},
+           "serve": {}, "trace": xplane.Trace({}, {}, (0, 1))}
+    for spec in args.values():
+        assert roof.read(ctx, **spec) is None               # no live slots
+    ctx["serve"]["live_slots_per_step"] = 400.0
+    for spec in args.values():
+        assert roof.read(ctx, **spec) is None               # nothing traced
+        assert roof.read({**ctx, "platform": "cpu"}, **spec) is None
+    # 10 ms under the scope, 40 ms a step
+    monkeypatch.setattr(harness.load_module(
+        MANIFEST, "readers", "serve_scopes_in_module"), "read",
+        lambda ctx, module, op_name=None: 10.0)
+    monkeypatch.setattr(harness.load_module(
+        MANIFEST, "readers", "serve_module_ms"), "read",
+        lambda ctx, module, per: 40.0)
+    hbm = flops.peak_for("TPU v5 lite")["hbm_bytes_per_s"]
+    sizes = {k: cell.config[k] for k in cell.config["flops"]["sizes"]}
+    state = 2 * 400 * 26 * 16 * 5120 * 4
+    assert roof.read(ctx, **args["mamba_state_hbm_roofline_pct.srv"]) == (
+        pytest.approx(100 * 1e3 * state / hbm / 10.0))
+    whole = 2 * 3_029_337_472 + 2 * 400 * 10_117_120 + 4 * 512 * 400 * 700
+    assert counts.decode_step_bytes(400, 400 * 700.0, **sizes) == whole
+    assert roof.read(ctx, **args["decode_ssm_hbm_roofline_pct.srv"]) == (
+        pytest.approx(100 * 1e3 * whole / hbm / 40.0))
+    with pytest.raises(ValueError, match="unknown work"):
+        roof.read(ctx, module="jit__slot_step_jit", what="weights")
 
 
 def test_hybrid_served_readers_give_no_number_without_the_programs_names():
@@ -477,7 +643,8 @@ def test_span_metric_resolves_to_a_file_and_a_reader(metric):
         "name": metric, "unit": "ms" if "_span_ms" in metric else "%",
         "better": "lower", "source": "program_span", "layer": layer,
         "moves": "itl_ms_p99" if lat else "out_tokens_per_s_chip",
-        "workloads": [R80] if lat else SATS}
+        "workloads": entry["workloads"]}
+    assert entry["workloads"][:4] == ([R80] if lat else SATS)
     spec = harness.load_json(MANIFEST, "layer_metrics", metric)
     assert spec == {"reader": reader, "args": {"span": span}}
     assert callable(harness.load_module(MANIFEST, "readers", reader).read)
@@ -492,7 +659,8 @@ def test_sample_metric_resolves_to_a_file_and_the_pooled_steps_scope():
     assert entry == {
         "name": SAMPLE_MS, "unit": "ms", "better": "lower",
         "source": "device_trace", "layer": ENGINE,
-        "moves": "out_tokens_per_s_chip", "workloads": SATS}
+        "moves": "out_tokens_per_s_chip", "workloads": entry["workloads"]}
+    assert entry["workloads"][:4] == SATS
     spec = harness.load_json(MANIFEST, "layer_metrics", SAMPLE_MS)
     assert spec == {"reader": "serve_scopes_in_module", "args": {
         "module": "jit__slot_step_jit", "op_name": "/sample_rows/"}}
@@ -563,8 +731,8 @@ def test_the_idle_rules_name_every_program_span_once():
 def test_benchmark_json_only_gained_entries_at_the_end():
     """What the benchmark had (PR 23, then PR 24) is still there, first
     and unchanged in order; PR 26's metrics follow it, then PR 27's, PR
-    30's, PR 31's, PR 33's, PR 35's and PR 36's, each where its PR appended
-    it: the next PR appends after them and adds its own slice here."""
+    30's, PR 31's, PR 33's, PR 35's, PR 36's and PR 37's, each where its PR
+    appended it: the next PR appends after them and adds its own slice here."""
     names = [m["name"] for m in MANIFEST["per_layer"]]
     assert set(names[10:22]) == set(NEW_METRICS)
     assert names[:22] == [
@@ -582,7 +750,8 @@ def test_benchmark_json_only_gained_entries_at_the_end():
     assert names[55:60] == list(IMOE_METRICS)
     assert names[60:66] == list(NM3_METRICS)
     assert names[66:82] == list(SPAN_METRICS)
-    assert names[82:] == [SAMPLE_MS]
+    assert names[82:83] == [SAMPLE_MS]
+    assert names[83:89] == list(JAMBA_METRICS)
     layers = {m["layer"] for m in MANIFEST["per_layer"][:10]}
     assert {m["layer"] for m in MANIFEST["per_layer"][10:22]} <= layers
     assert {m["layer"] for m in MANIFEST["per_layer"][22:31]} <= layers | {
@@ -595,9 +764,12 @@ def test_benchmark_json_only_gained_entries_at_the_end():
     assert {m["layer"] for m in MANIFEST["per_layer"][66:82]} == {
         ENGINE, SCHEDULER}
     assert MANIFEST["per_layer"][82]["layer"] == ENGINE
-    assert [c["name"] for c in MANIFEST["configs"]][:6] == [
+    assert {m["layer"] for m in MANIFEST["per_layer"][83:89]} == {
+        "state-space layers", "model step"}
+    assert [c["name"] for c in MANIFEST["configs"]][:7] == [
         "resnet50", "starcoder2-3b", "smallthinker-21b-a3b",
-        "starcoder2-3b-serve", "instella-moe-16b-a3b-serve", NM3_CONFIG]
+        "starcoder2-3b-serve", "instella-moe-16b-a3b-serve", NM3_CONFIG,
+        JAMBA_CONFIG]
     assert [m["name"] for m in MANIFEST["end_to_end"]] == [
         "images_per_s_chip", "tokens_per_s_chip", "step_ms_p90",
         "itl_ms_p99", "out_tokens_per_s_chip", "setup_s"]
@@ -1068,6 +1240,40 @@ def test_traced_rehearsal_of_the_hybrid_served_cell_comes_out_correct():
     device_only = {m for m, v in NM3_METRICS.items()
                    if v[2] == "device_trace"}
     assert not device_only & set(out["metrics"])
+    assert {"batch_occupancy_pct.srv", "prefill_share_pct.srv",
+            "itl_ms_p50.srv"} <= set(out["metrics"])
+    served_spans_are_read_and_the_idle_split_left_out(out)
+
+
+def test_traced_rehearsal_of_the_state_served_cell_comes_out_correct():
+    """``run.py --rehearse`` as the driver calls it, with a seed past 2**31:
+    bucketed prefill with its true length, the state's slot write and the
+    pooled recurrent step under a tied head, correct against the plain
+    reference by the window's ONE limit and by the long answers' mean gap; the
+    window's live slots a step are the harness's own (the engine's count,
+    which covers the warm-up too, is among the counters); the device's times
+    and the rooflines are left out."""
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    p = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "chipbench", "run.py"),
+         "--workload", JAMBA, "--seed", "3700000011", "--seconds", "2",
+         "--trace", "1", "--rehearse"],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-2000:]
+    out = json.loads(p.stdout.strip().splitlines()[-1])
+    assert out["correct"] is True and out["rehearsal"] is True
+    assert out["failed"] == 0 and out["attempted"] > 50
+    assert list(out["compared"])[0] == "logit_gap"
+    assert out["compared"]["logit_gap"][0] <= 0.05
+    counters = out["checks"]["counters"]
+    assert counters["pool_donated"] == counters["pool_calls"] > 0
+    assert out["compared"]["long_gap_when_off_the_top"][0] == 0.0
+    assert (out["compared"]["long_tokens_checked"][0]
+            == out["checks"]["reference"]["long_answers"]["served_tokens"]
+            >= out["compared"]["long_tokens_checked"][1])
+    assert 0 < out["window"]["live_slots_per_step"] <= 4
+    assert counters["live_slot_steps"] >= counters["steps"] > 0
+    assert not set(JAMBA_METRICS) & set(out["metrics"])
     assert {"batch_occupancy_pct.srv", "prefill_share_pct.srv",
             "itl_ms_p50.srv"} <= set(out["metrics"])
     served_spans_are_read_and_the_idle_split_left_out(out)

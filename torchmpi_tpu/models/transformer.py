@@ -552,6 +552,32 @@ class LatentAttention(nn.Module):
         return dense(E, "out")(o.astype(self.dtype))
 
 
+def _per_row_block_refused(mixer: str) -> str:
+    return (f"a {mixer} cannot take a block of tokens at per-row "
+            "depths (speculative verify, prefix-cache extend): its "
+            "state cannot be un-updated")
+
+
+def _causal_depthwise_conv(x, taps, conv, true_len):
+    """``x`` [B, T, C] through ``taps`` [K, C], tap k reading the input
+    K - 1 - k positions back.  ``conv``: the ``cache`` variable of the last
+    K - 1 inputs, or None.  With it, one token (T == 1) shifts the cached
+    rows by one; a block starts from zeros and leaves the last K - 1 LIVE
+    rows (``true_len``, traced; None: all of the block)."""
+    K, T = taps.shape[0], x.shape[1]
+    if conv is not None and T == 1:
+        rows = jnp.concatenate([conv.value, x], axis=1)        # [B, K]
+        conv.value = rows[:, 1:]
+        return (rows * taps).sum(1, keepdims=True)
+    rows = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
+    if conv is not None:
+        live = T if true_len is None else true_len
+        conv.value = lax.dynamic_slice_in_dim(
+            rows, clamp_slot_positions(live, T + K - 1, K - 1), K - 1,
+            axis=1)
+    return sum(rows[:, k:k + T] * taps[k] for k in range(K))
+
+
 def ssd_chunked(x, dt, a, b, c, chunk: int):
     """The state-space-duality form of Mamba-2's recurrence (arXiv:2405.21060,
     section 6), from a ZERO state, a head ``h`` of group ``h // (H / G)``:
@@ -671,30 +697,16 @@ class Mamba2Mixer(nn.Module):
         xbc = xbc.astype(f32)
         if self.decode:
             if T > 1 and jnp.ndim(pos_offset) == 1:
-                raise ValueError(
-                    "a Mamba2Mixer cannot take a block of tokens at per-row "
-                    "depths (speculative verify, prefix-cache extend): its "
-                    "state cannot be un-updated")
+                raise ValueError(_per_row_block_refused("Mamba2Mixer"))
             ssm = self.variable("cache", "ssm_state", jnp.zeros,
                                 (B, H, P, N), f32)
             conv = self.variable("cache", "conv_state", jnp.zeros,
                                  (B, K - 1, wide), f32)
         step = self.decode and T == 1
         with jax.named_scope("ssm_conv"):
-            # tap k reads the input K - 1 - k positions back
-            if step:
-                rows = jnp.concatenate([conv.value, xbc], axis=1)  # [B, K]
-                conv.value = rows[:, 1:]
-                xbc = (rows * taps).sum(1, keepdims=True)
-            else:
-                rows = jnp.pad(xbc, ((0, 0), (K - 1, 0), (0, 0)))
-                if self.decode:
-                    live = T if true_len is None else true_len
-                    conv.value = lax.dynamic_slice_in_dim(
-                        rows, clamp_slot_positions(live, T + K - 1, K - 1),
-                        K - 1, axis=1)
-                xbc = sum(rows[:, k:k + T] * taps[k] for k in range(K))
-            xbc = jax.nn.silu(xbc + conv_bias)
+            xbc = jax.nn.silu(_causal_depthwise_conv(
+                xbc, taps, conv if self.decode else None, true_len)
+                + conv_bias)
         x = xbc[..., :inner].reshape(B, T, H, P)
         b = xbc[..., inner:inner + G * N].reshape(B, T, G, N)
         c = xbc[..., inner + G * N:].reshape(B, T, G, N)
@@ -725,6 +737,152 @@ class Mamba2Mixer(nn.Module):
         with jax.named_scope("ssm_out_proj"):
             return nn.Dense(E, dtype=self.dtype, use_bias=False,
                             name="out_proj")(y.astype(self.dtype))
+
+
+def selective_scan(x, delta, a, b, c, chunk: int):
+    """Mamba-1's selective scan (arXiv:2312.00752, section 3.2 and algorithm
+    2), from a ZERO state; a channel ``d`` has its own decay for each of its
+    ``N`` states, and ``b`` and ``c`` are shared by all channels:
+
+        h_t[n, d] = exp(delta_t[d] a[n, d]) h_(t-1)[n, d]
+                    + delta_t[d] x_t[d] b_t[n];      y_t[d] = h_t[:, d] . c_t
+
+    ``x`` and ``delta`` [B, T, Di] (``delta`` 0 at a position leaves the
+    state as it was: that is how a padded position is told), ``a`` [N, Di]
+    negative, ``b`` and ``c`` [B, T, N]; all float32.  It is the recurrence
+    as written, one token after another: every decay is an ``exp`` of ONE
+    float32 product, no cumulative product or its quotient, so nothing
+    under- or overflows that the recurrence itself would not.  T is padded
+    to a multiple of ``chunk`` with dead positions; a ``lax.scan`` over the
+    ``T / chunk`` pieces carries ``h`` [B, N, Di], and a piece computes its
+    ``[chunk, B, N, Di]`` decays and inputs at once and then takes its
+    ``chunk`` updates in order (unrolled: one fused pass a piece, ``h`` not
+    written out between its tokens).  Nothing of ``[T, N, Di]`` is ever
+    held.  The channels are the minor axis: a state of 16 would fill an
+    eighth of a 128-lane tile.  Returns ``(y [B, T, Di], the state after the
+    last position [B, N, Di])``."""
+    B, T, Di = x.shape
+    N = a.shape[0]
+    pad = -T % chunk
+    if pad:
+        x, delta, b, c = (jnp.pad(v, ((0, 0), (0, pad), (0, 0)))
+                          for v in (x, delta, b, c))
+    pieces = tuple(
+        v.reshape(B, (T + pad) // chunk, chunk, -1).transpose(1, 2, 0, 3)
+        for v in (x, delta, b, c))                    # [nc, chunk, B, .]
+
+    def piece(h, now):
+        x_q, dt_q, b_q, c_q = now
+        decay = jnp.exp(dt_q[:, :, None, :] * a)      # [chunk, B, N, Di]
+        put = (dt_q * x_q)[:, :, None, :] * b_q[..., None]
+        ys = []
+        for q in range(chunk):
+            h = decay[q] * h + put[q]
+            ys.append((h * c_q[q][..., None]).sum(-2))
+        return h, jnp.stack(ys)
+
+    final, y = lax.scan(piece, jnp.zeros((B, N, Di), jnp.float32), pieces)
+    return y.reshape(T + pad, B, Di).swapaxes(0, 1)[:, :T], final
+
+
+class MambaMixer(nn.Module):
+    """A Mamba-1 mixer as ``jamba`` has it (arXiv:2312.00752 with Jamba's
+    three inner norms, arXiv:2403.19887), ``Di = inner`` channels, ``N =
+    state``, ``R = dt_rank``; no bias on the four projections:
+
+        [x, z] = u W_in                      widths Di, Di: x FIRST, then the
+                                             gate
+        x <- silu(conv(x) + b_conv)          causal, depthwise, ``conv_width``
+                                             taps
+        [dt, B, C] = x W_x                   widths R, N, N
+        dt, B, C <- RMSNorm(dt), RMSNorm(B), RMSNorm(C)     each a weight
+        delta = softplus(dt W_dt + b_dt)     [T, Di];   A = -exp(A_log)
+        h_t = exp(delta_t (outer) A) h_(t-1) + (delta_t x_t) (outer) B_t
+        y_t = h_t C_t + D x_t                h [Di, N], zero at first
+        out = (y * silu(z)) W_out
+
+    Everything between the two outer projections is float32.  ``A_log`` is
+    kept as published, [Di, N]; the STATE is held channels-minor, which is
+    what the chip's 128-lane tiles want.  The three regimes and the cache
+    contract are :class:`Mamba2Mixer`'s: a block of tokens (a training-
+    shaped call or a prompt block, a scalar ``pos_offset``: a FRESH cache)
+    runs :func:`selective_scan` from zero and, with ``decode=True``, leaves
+    the state after its last LIVE token (``true_len``: the positions from
+    there on get ``delta = 0``, and the convolution's state is the last live
+    rows); one token (T == 1) is ONE recurrent update of every row; a block
+    at per-row depths is refused.  The ``cache`` leaves have NO token axis
+    (``models.generate.STATE_LEAVES``): ``ssm_state`` [B, N, Di] and
+    ``conv_state`` [B, conv_width - 1, Di], float32."""
+
+    inner: int
+    state: int
+    dt_rank: int
+    conv_width: int = 4
+    chunk: int = 16
+    norm_eps: float = 1e-6
+    dtype: jnp.dtype = jnp.float32
+    decode: bool = False
+
+    @nn.compact
+    def __call__(self, u, pos_offset=0, true_len=None):  # u: [B, T, E]
+        B, T, E = u.shape
+        Di, N, R, K = self.inner, self.state, self.dt_rank, self.conv_width
+        f32 = jnp.float32
+
+        def param(name, init, shape):
+            return self.param(name, init, shape, f32).astype(f32)
+
+        def dense(features, name, dtype):
+            return nn.Dense(features, dtype=dtype, use_bias=False, name=name)
+
+        def norm(name):
+            return nn.RMSNorm(epsilon=self.norm_eps, dtype=f32, name=name)
+
+        with jax.named_scope("ssm_in_proj"):
+            proj = dense(2 * Di, "in_proj", self.dtype)(u)
+        x, z = proj[..., :Di].astype(f32), proj[..., Di:]
+        taps = param("conv_kernel", nn.initializers.lecun_normal(), (K, Di))
+        conv_bias = param("conv_bias", nn.initializers.zeros, (Di,))
+        dt_bias = param("dt_bias", nn.initializers.zeros, (Di,))
+        a = -jnp.exp(param("A_log", nn.initializers.zeros, (Di, N))).T
+        skip = param("D", nn.initializers.ones, (Di,))
+        if self.decode:
+            if T > 1 and jnp.ndim(pos_offset) == 1:
+                raise ValueError(_per_row_block_refused("MambaMixer"))
+            ssm = self.variable("cache", "ssm_state", jnp.zeros,
+                                (B, N, Di), f32)
+            conv = self.variable("cache", "conv_state", jnp.zeros,
+                                 (B, K - 1, Di), f32)
+        step = self.decode and T == 1
+        with jax.named_scope("ssm_conv"):
+            x = jax.nn.silu(_causal_depthwise_conv(
+                x, taps, conv if self.decode else None, true_len)
+                + conv_bias)
+        with jax.named_scope("ssm_dt_bc"):
+            dbc = dense(R + 2 * N, "x_proj", f32)(x)
+            dt = norm("dt_norm")(dbc[..., :R])
+            b = norm("b_norm")(dbc[..., R:R + N])
+            c = norm("c_norm")(dbc[..., R + N:])
+            delta = jax.nn.softplus(dense(Di, "dt_proj", f32)(dt) + dt_bias)
+        if step:
+            with jax.named_scope("ssm_step"):
+                dt1, x1 = delta[:, 0, None, :], x[:, 0, None, :]  # [B, 1, Di]
+                state = (jnp.exp(dt1 * a) * ssm.value
+                         + (dt1 * x1) * b[:, 0, :, None])
+                ssm.value = state
+                y = (state * c[:, 0, :, None]).sum(-2)[:, None]
+        else:
+            with jax.named_scope("ssm_scan"):
+                if true_len is not None:
+                    delta = jnp.where(jnp.arange(T)[:, None] < true_len,
+                                      delta, 0.0)
+                y, state = selective_scan(x, delta, a, b, c, self.chunk)
+                if self.decode:
+                    ssm.value = state
+        with jax.named_scope("ssm_gate"):
+            y = (y + skip * x) * jax.nn.silu(z.astype(f32))
+        with jax.named_scope("ssm_out_proj"):
+            return dense(E, "out_proj", self.dtype)(y.astype(self.dtype))
 
 
 class MoEMLP(nn.Module):
@@ -952,14 +1110,18 @@ class Block(nn.Module):
     # the previous sub-block's output was added.  The block then takes and
     # returns the pair (stream, stream one sub-block back).
     farskip: bool = False
-    # ``kind`` set: the layer is ONE sub-block under one norm with a
-    # residual (``nemotron_h``'s letters): "M" a Mamba2Mixer of the ``ssm``
-    # sizes (heads, head_dim, state, groups, conv_width, chunk), "*" the
-    # attention above, "E" the ExpertFFN (``expert_latent`` wide at its
-    # experts' doors).  None: attention THEN a feed-forward, as ever.
+    # ``kind`` in capitals or "*": the layer is ONE sub-block under one norm
+    # with a residual (``nemotron_h``'s letters): "M" a Mamba2Mixer of the
+    # ``ssm`` sizes (heads, head_dim, state, groups, conv_width, chunk), "*"
+    # the attention above, "E" the ExpertFFN (``expert_latent`` wide at its
+    # experts' doors).  None or a small letter: a mixer THEN the block's own
+    # feed-forward (``jamba``'s layers): "a", as None, the attention above,
+    # "m" a MambaMixer of the ``mamba`` sizes (inner, state, dt_rank,
+    # conv_width, chunk).
     kind: Optional[str] = None
     ssm: Optional[Tuple[int, ...]] = None
     expert_latent: int = 0
+    mamba: Optional[Tuple[int, ...]] = None
 
     def _attention(self):
         if self.kv_rank:
@@ -989,7 +1151,7 @@ class Block(nn.Module):
         if self.router_reads not in ("attention_input", "ffn_input"):
             raise ValueError(f"unknown router_reads {self.router_reads!r}")
         E = x.shape[-1]
-        if self.kind is not None:
+        if self.kind not in (None, "a", "m"):
             if self.farskip:
                 raise ValueError("farskip wires attention-then-feed-forward "
                                  "blocks, not a layer pattern")
@@ -1008,10 +1170,16 @@ class Block(nn.Module):
                     shared_width=self.shared_width,
                     latent_width=self.expert_latent)(a, a)
             else:
-                raise ValueError(f"unknown layer kind {self.kind!r} (M, *, E)")
+                raise ValueError(f"unknown layer kind {self.kind!r} "
+                                 f"(M, *, E; a, m)")
             return x + h
         a = _norm(self.norm, self.norm_eps)(lag if self.farskip else x)
-        mid = x + self._attention()(a, pos_offset)
+        if self.kind == "m":
+            mid = x + MambaMixer(*self.mamba, norm_eps=self.norm_eps,
+                                 dtype=self.dtype, decode=self.decode)(
+                                     a, pos_offset, true_len)
+        else:
+            mid = x + self._attention()(a, pos_offset)
         h = _norm(self.norm, self.norm_eps)(x if self.farskip else mid)
 
         def dense(features):
@@ -1113,9 +1281,13 @@ class TransformerLM(nn.Module):
     farskip: bool = False
     # A hybrid's layers (see Block.kind), one letter a layer, ``depth`` of
     # them: "M" a Mamba-2 mixer of the ``ssm_*`` sizes, "*" attention, "E"
-    # the expert layer; each ONE sub-block under one norm.  None: every
-    # layer is attention then a feed-forward.  ``expert_latent`` > 0: the
-    # routed experts work in a latent of that width (see ExpertFFN).
+    # the expert layer; each ONE sub-block under one norm.  Small letters
+    # are a mixer THEN the feed-forward: "a" attention, "m" a Mamba-1 mixer
+    # of ``ssm_expand`` x ``embed`` channels with ``ssm_state`` states a
+    # channel, a ``ssm_dt_rank``-wide time step, ``ssm_conv`` taps and
+    # ``ssm_chunk`` tokens a piece of its scan.  None: every layer is
+    # attention then a feed-forward.  ``expert_latent`` > 0: the routed
+    # experts work in a latent of that width (see ExpertFFN).
     # ``pos_emb="none"``: no position table and no rotation (the mixers
     # carry the order).
     layer_pattern: Optional[str] = None
@@ -1126,6 +1298,11 @@ class TransformerLM(nn.Module):
     ssm_conv: int = 4
     ssm_chunk: int = 128
     expert_latent: int = 0
+    ssm_expand: int = 2
+    ssm_dt_rank: int = 0
+    # The head IS the embedding (no ``head`` parameter): logits are
+    # ``x @ embedding^T``, the embedding read in the compute type.
+    tie_head: bool = False
 
     @nn.compact
     def __call__(self, tokens, pos_offset=0, return_prehead: bool = False,
@@ -1134,7 +1311,8 @@ class TransformerLM(nn.Module):
         # right-padded prompt's positions are real, for the layers that
         # carry a state past the block (see Mamba2Mixer)
         B, T = tokens.shape
-        x = nn.Embed(self.vocab, self.embed, dtype=self.dtype)(tokens)
+        embed = nn.Embed(self.vocab, self.embed, dtype=self.dtype)
+        x = embed(tokens)
         if self.pos_emb == "learned":
             table = nn.Embed(self.max_len, self.embed, dtype=self.dtype,
                              name="pos_embed")
@@ -1184,7 +1362,10 @@ class TransformerLM(nn.Module):
                       kind=self.layer_pattern and self.layer_pattern[i],
                       ssm=(self.ssm_heads, self.ssm_head_dim, self.ssm_state,
                            self.ssm_groups, self.ssm_conv, self.ssm_chunk),
-                      expert_latent=self.expert_latent)(
+                      expert_latent=self.expert_latent,
+                      mamba=(self.ssm_expand * self.embed, self.ssm_state,
+                             self.ssm_dt_rank, self.ssm_conv,
+                             self.ssm_chunk))(
                           x, pos_offset, lag, true_len)
             if self.farskip:
                 x, lag = x
@@ -1192,9 +1373,12 @@ class TransformerLM(nn.Module):
         # Bias-free explicit unembedding (standard for LMs) so callers can
         # feed (pre-head activations, head matrix) to the fused
         # linear+cross-entropy kernel (ops/xent.py) and never materialize
-        # [B*T, vocab] logits.
-        head = self.param("head", nn.initializers.lecun_normal(),
-                          (self.embed, self.vocab), jnp.float32)
+        # [B*T, vocab] logits.  A tied head is the embedding's transpose.
+        if self.tie_head:
+            head = jnp.asarray(embed.embedding, self.dtype).T
+        else:
+            head = self.param("head", nn.initializers.lecun_normal(),
+                              (self.embed, self.vocab), jnp.float32)
         if return_prehead:
             return x, head
         return x @ head

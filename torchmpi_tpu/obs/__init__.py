@@ -598,6 +598,8 @@ def record_serving(event: str, n: int = 1, *, replica: str = "") -> None:
     ``sample_argmax`` / ``sample_draw`` (pooled steps by the branch of
     ``models.generate._sample_rows`` their rows asked for: no row
     samples, the argmax | some row samples, the filter and the draw) |
+    ``live_slot_steps`` (``n``: the live sessions a pooled step decoded;
+    over the steps, the slots whose state a step requires) |
     ``spec_drafted`` /
     ``spec_accepted`` (speculative-decode draft tokens proposed /
     accepted — the live acceptance rate) | ``prefix_hits`` /

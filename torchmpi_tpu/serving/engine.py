@@ -49,7 +49,9 @@ of the programs that were handed the pool took it over
 (``stats["pool_donated"]`` of ``stats["pool_calls"]``), which branch
 of the sampling tail each pooled step's sessions ask for
 (:data:`SAMPLE_BRANCHES`: ``stats["sample_argmax"]`` +
-``stats["sample_draw"]`` = ``stats["steps"]``), and, for
+``stats["sample_draw"]`` = ``stats["steps"]``), how many live sessions
+the pooled steps decoded (``stats["live_slot_steps"]``: over
+``stats["steps"]``, the slots a step REQUIRES the state of), and, for
 a model with expert layers, what the pooled step itself
 counted: held experts touched, routes and routes held, read with the tokens): the
 scheduler owns the clock, the SLO histograms, and the fault hooks, so
@@ -289,6 +291,7 @@ class ReplicaEngine:
                       "prefill_kernel_tokens": 0,
                       "prefix_hits": 0, "prefix_misses": 0,
                       "pool_calls": 0, "pool_donated": 0,
+                      "live_slot_steps": 0,
                       **dict.fromkeys(SAMPLE_BRANCHES, 0)}
         #: Work units spent (prefill/pooled forward = 1 each, draft
         #: forwards at the proposer's weight) — the scheduler's
@@ -658,12 +661,17 @@ class ReplicaEngine:
     def _count_step(self, sessions: Dict[int, Session]) -> None:
         """One more pooled step, under the branch of the sampling tail
         its rows ask for (:data:`SAMPLE_BRANCHES`): the program's own
-        rule, any temperature above zero, on the host's copy of it."""
+        rule, any temperature above zero, on the host's copy of it; and
+        the live sessions it decodes (what ``tm.serve.step`` carries as
+        ``live``)."""
         self.stats["steps"] += 1
+        self.stats["live_slot_steps"] += len(sessions)
         branch = SAMPLE_BRANCHES[any(s.sampling[0] > 0.0
                                      for s in sessions.values())]
         self.stats[branch] += 1
         emit("record_serving", branch, replica=self.name)
+        emit("record_serving", "live_slot_steps", len(sessions),
+             replica=self.name)
 
     def _plain_step(self) -> Tuple[List[Session], List[Session]]:
         with span("tm.serve.step.operands"):
