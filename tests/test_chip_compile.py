@@ -345,6 +345,10 @@ def test_served_decode_step_lowers_to_the_parents_text(one_chip):
     # PR 38 meant to touch it and did: each layer's new key and value rows
     # go into the pool by ops/cache_rows's kernel (kv.write_rows, under the
     # scope `kv_write`, its result pinned to HBM), not by vmap's scatter,
+    # nothing else; then the ring rule touched it: the window of 4096 is
+    # shorter than the slot of 4608, so every layer's keys and values are
+    # a ring of 4096 rows, written at the position mod 4096 and read under
+    # the scope `window_attn` where a row holds a position in the window,
     # nothing else.  A change
     # that means to touch the step replaces the digest and says so there.
     import hashlib
@@ -366,7 +370,7 @@ def test_served_decode_step_lowers_to_the_parents_text(one_chip):
     text = re.sub(r"\\22body\\22: \\22[A-Za-z0-9+/=]*\\22", "BODY", text)
     assert text.count("BODY") == len(kernels)
     assert hashlib.sha256(text.encode()).hexdigest() == (
-        "835a930b77b0fc103e5a372d7b52140b6dc0c409e3ead404e86a68997e002c07")
+        "8cde4d78df7144d0cfdf2bcd2466c4ffc2ca801ffcc2b65a019331525717a246")
 
 
 def _pool_cache(dmodel, slots, one_chip):
@@ -401,7 +405,8 @@ def _compiled_step(steps, one_chip, workload):
 
 @pytest.mark.parametrize("workload", [
     "sc2-3b-serve-sat", "imoe-16b-serve-conv-sat",
-    "nm3s-120b-serve-chat-sat", "jamba2-3b-serve-reason-sat"])
+    "nm3s-120b-serve-chat-sat", "jamba2-3b-serve-reason-sat",
+    "kex-236b-serve-mixlen-sat"])
 def test_served_decode_step_updates_the_pool_in_place(
         one_chip, compiled_steps, workload):
     # The pooled step of each served configuration at its file's slots: the
@@ -445,7 +450,8 @@ ROW_WRITE = re.compile(
 
 @pytest.mark.parametrize("workload", [
     "sc2-3b-serve-sat", "imoe-16b-serve-conv-sat",
-    "nm3s-120b-serve-chat-sat", "jamba2-3b-serve-reason-sat"])
+    "nm3s-120b-serve-chat-sat", "jamba2-3b-serve-reason-sat",
+    "kex-236b-serve-mixlen-sat"])
 def test_served_decode_step_writes_its_rows_in_place(
         one_chip, compiled_steps, workload):
     # The pooled step of each served configuration at its file's slots
@@ -504,7 +510,8 @@ def test_served_decode_step_writes_its_rows_in_place(
 
 @pytest.mark.parametrize("workload", [
     "sc2-3b-serve-sat", "imoe-16b-serve-conv-sat",
-    "nm3s-120b-serve-chat-sat", "jamba2-3b-serve-reason-sat"])
+    "nm3s-120b-serve-chat-sat", "jamba2-3b-serve-reason-sat",
+    "kex-236b-serve-mixlen-sat"])
 def test_served_decode_step_sorts_the_vocabulary_in_one_branch_only(
         one_chip, compiled_steps, workload):
     # The sampling tail of each served configuration's pooled step, as the
@@ -629,6 +636,58 @@ def test_served_jamba_compiles_at_the_cells_sizes(
         attention, limit = ["flash.fwd"] * 2, 0.6e9
     text = compiled.as_text()
     assert sorted(ident for _, ident in KERNEL.findall(text)) == attention
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < limit
+    assert (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes) < 15e9
+
+
+@pytest.mark.parametrize("program", ["prefill16384", "step"])
+def test_served_exaone_compiles_at_the_cells_sizes(
+        one_chip, compiled_steps, program):
+    # kex-236b-serve-mixlen-sat's programs at the published widths
+    # (chipbench/configs/k-exaone-236b-a23b-serve.json): layers 0-4, the
+    # dense one and four expert layers with 16 of 128 SwiGLU experts held,
+    # 64 q / 8 kv heads of 128; layers 0, 1, 2 and 4 attend over a window of
+    # 128 and keep their keys and values in a ring of 128 rows, layer 3 over
+    # the whole slot of 16,384; 7.42 GB of bfloat16 weights.  The pooled
+    # step at the file's slots writes every leaf, ring or not, by ONE
+    # kv.write_rows kernel (a ring row at the position mod 128).  The
+    # longest prompt's bucket fits beside the weights: the attention a
+    # flash.fwd kernel a layer, the expert layers' tokens routed in chunks
+    # of 4,096 (parallel/expert.token_chunks: a buffer of 16,384 x 8 routes
+    # of 6,144 float32 would be 3.2 GB, and the gather's resident table,
+    # 201 MB, does not fit fast memory), one gather and one combine kernel
+    # a layer, traced once in the chunks' map.
+    from chipbench import flops_exaone_serve as counts
+    from torchmpi_tpu.models.generate import _slot_prefill_jit
+    from torchmpi_tpu.parallel.expert import token_chunks
+
+    harness = _harness()
+    workload = "kex-236b-serve-mixlen-sat"
+    cfg = harness.resolve(harness.load_manifest(), workload).config
+    sizes = {k: cfg[k] for k in cfg["flops"]["sizes"]}
+    dmodel, params, slots, sampling = _served(one_chip, workload)
+    assert sum(a.size for a in jax.tree.leaves(params)) == counts.parameters(
+        **sizes) == 3_712_028_416
+    if program == "step":
+        cache, compiled = _compiled_step(compiled_steps, one_chip, workload)
+        tokens = [a.shape for path, a in
+                  jax.tree_util.tree_leaves_with_path(cache) if a.ndim]
+        assert sorted(tokens) == sorted(
+            [(slots, 128, 8, 128)] * 8 + [(slots, 16384, 8, 128)] * 2)
+        kernels = ["kv.write_rows"] * 10 + ["moe.gather", "moe.combine"] * 4
+        limit = 0.5e9
+    else:
+        assert token_chunks(16384, 6144, 8) == 4
+        compiled = _slot_prefill_jit.lower(
+            dmodel, params, _sds((1, 16384), jnp.int32, one_chip),
+            _sds((), jnp.int32, one_chip), *sampling(1)).compile()
+        kernels = ["flash.fwd"] * 5 + ["moe.gather", "moe.combine"] * 4
+        limit = 5e9
+    text = compiled.as_text()
+    assert sorted(ident for _, ident in KERNEL.findall(text)) == sorted(
+        kernels)
     ma = compiled.memory_analysis()
     assert ma.temp_size_in_bytes < limit
     assert (ma.argument_size_in_bytes + ma.output_size_in_bytes

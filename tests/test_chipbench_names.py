@@ -218,6 +218,38 @@ SAMPLE_MS = "sample_ms_per_decode_step.srv"
 # what PR 38 added: the per-row cache write's device time a decode step, by
 # the scope the program gives it (``models.transformer.write_rows``)
 KV_WRITE_MS = "kv_write_ms_per_decode_step.srv"
+# what the window-ring cell added, in order: metric -> (reader, layer,
+# source, unit, better)
+KEX = "kex-236b-serve-mixlen-sat"
+KEX_CONFIG = "k-exaone-236b-a23b-serve"
+KEX_METRICS = {
+    "window_attn_ms_per_decode_step.srv": (
+        "serve_scopes_in_module", ENGINE, "device_trace", "ms", "lower"),
+    "decode_window_hbm_roofline_pct.srv": (
+        "serve_window_decode_roofline", ENGINE, "device_trace", "%",
+        "higher"),
+    "moe_held_experts_touched_share.srv": (
+        "moe_touched_share", "expert layer", "program_counter", "1",
+        "higher"),
+}
+# the accepted metrics whose readers' arguments resolve in its file: the
+# scope of ``SPAttention``, the sparse cell's two scopes of the expert
+# layer, the hybrid's share of routes held (16 of 128 experts held) and the
+# scope of a block's own dense feed-forward (layer 0's SwiGLU)
+# (``moe_experts_touched_share.srv`` reads ``n_routed_experts``, a key this
+# configuration does not have: its own share reads ``num_experts``)
+KEX_JOINS = ["attn_ms_per_decode_step.srv",
+             "moe_experts_ms_per_decode_step.srv",
+             "moe_permute_ms_per_decode_step.srv",
+             "moe_routes_held_share.srv",
+             "mlp_ms_per_decode_step.srv"]
+KEX_STAYS_OUT = {
+    "decode_hbm_roofline_pct.srv": [SAT],
+    "decode_moe_hbm_roofline_pct.srv": [IMOE],
+    "decode_hybrid_hbm_roofline_pct.srv": [NM3],
+    "decode_ssm_hbm_roofline_pct.srv": [JAMBA],
+    "latent_attn_ms_per_decode_step.srv": [IMOE],
+    "moe_experts_touched_share.srv": [IMOE, NM3]}
 IDLE_SRV = [m for m, v in SPAN_METRICS.items()
             if v[0] == "serve_idle_by_span" and m.endswith(".srv")]
 IDLE = harness.load_module(MANIFEST, "readers", "serve_idle_by_span")
@@ -545,6 +577,208 @@ def test_state_served_cell_joins_the_lists_whose_readers_are_right_for_it():
     assert sum(w["chips"] == 4 for w in MANIFEST["workloads"][:10]) == 1
 
 
+@pytest.mark.parametrize("metric", sorted(KEX_METRICS))
+def test_window_served_cell_metric_resolves_to_a_file_and_a_reader(metric):
+    reader, layer, source, unit, better = KEX_METRICS[metric]
+    entry = harness.by_name(MANIFEST["per_layer"], metric, "metric")
+    assert entry == {
+        "name": metric, "unit": unit, "better": better, "source": source,
+        "layer": layer, "moves": "out_tokens_per_s_chip",
+        "workloads": [KEX]}
+    spec = harness.load_json(MANIFEST, "layer_metrics", metric)
+    assert spec["reader"] == reader
+    assert callable(harness.load_module(MANIFEST, "readers", reader).read)
+    mine = harness.by_name(harness.resolve(MANIFEST, KEX).per_layer,
+                           metric, "metric")
+    assert mine["args"] == spec["args"]
+    for key, value in spec["args"].items():
+        if key == "module":
+            assert value == "jit__slot_step_jit"
+        elif key == "experts":
+            assert value in harness.resolve(MANIFEST, KEX).config
+        else:
+            re.compile(value)
+
+
+def test_window_served_cell_joins_the_lists_whose_readers_are_right_for_it():
+    """The window-ring cell: every ``-sat`` cell's metrics, the
+    attention's and the expert layer's scopes, its own three, and nothing
+    of another model's counts of a step's bytes; eleven cells, one of them
+    on four chips; its configuration the eighth, cut to one chip's share
+    of eight."""
+    mine = {m["name"] for m in harness.resolve(MANIFEST, KEX).per_layer}
+    joined = (set(SRV_METRICS) - set(IMOE_STAYS_OUT)) | set(KEX_JOINS) | (
+        SPAN_SRV | {SAMPLE_MS, KV_WRITE_MS})
+    assert mine == joined | set(KEX_METRICS)
+    for metric in joined:
+        cells = harness.by_name(MANIFEST["per_layer"], metric,
+                                "metric")["workloads"]
+        assert cells[-1] == KEX
+    for metric, cells in KEX_STAYS_OUT.items():
+        assert harness.by_name(MANIFEST["per_layer"], metric,
+                               "metric")["workloads"] == cells
+        assert metric not in mine
+    cell = harness.resolve(MANIFEST, KEX)
+    assert {m["name"] for m in cell.end_to_end} == {
+        "out_tokens_per_s_chip", "setup_s"}
+    assert harness.by_name(MANIFEST["end_to_end"], "out_tokens_per_s_chip",
+                           "metric")["workloads"] == SATS + [KEX]
+    entry = harness.by_name(MANIFEST["workloads"], KEX, "workload")
+    assert (entry["config"], entry["traffic"], entry["chips"]) == (
+        KEX_CONFIG, "open-mixlen-sat", 1)
+    assert 1 <= len(entry["why"]) <= 200
+    config = harness.by_name(MANIFEST["configs"], KEX_CONFIG, "config")
+    assert config["reduced"] == cell.config["reduced"] == [
+        "num_hidden_layers", "num_experts", "vocab_size",
+        "num_nextn_predict_layers"]
+    assert 1 <= len(config["why"]) <= 200
+    assert cell.config["runner"] == "serve_open_loop_exaone"
+    # one chip's share of eight: 16 of the router's 128, an eighth of the
+    # vocabulary, layers 0-4 (one whole LLLG period after the dense layer)
+    published = cell.config["published"]
+    assert (cell.config["num_experts"], cell.config["router_width"],
+            cell.config["experts_held"]) == (16, 128, [0, 16])
+    assert published["num_experts"] == 8 * cell.config["num_experts"]
+    assert published["vocab_size"] == 8 * cell.config["vocab_size"]
+    assert (cell.config["num_hidden_layers"], published["num_hidden_layers"]
+            ) == (5, 48)
+    assert cell.config["window_layout"] == cell.config["rope_layout"] == [
+        1 if w else 0 for w in cell.config["sliding_windows"][:5]]
+    assert cell.config["layer_types"][:5] == ["sliding_attention"] * 3 + [
+        "full_attention", "sliding_attention"]
+    from chipbench import flops_exaone_serve as counts
+
+    sizes = {k: cell.config[k] for k in cell.config["flops"]["sizes"]}
+    assert counts.parameters(**sizes) == 3_712_028_416
+    assert "3,712,028,416" in cell.config["parameters"]
+    # the traffic's lengths fit a slot, and every prompt passes the window
+    srv, traffic = cell.config["serving"], cell.traffic
+    assert (traffic["prompt_tokens"]["max"] + traffic["answer_tokens"]["max"]
+            == srv["slot_tokens"])
+    assert traffic["prompt_tokens"]["min"] >= 2 * cell.config[
+        "sliding_window"]
+    names = [w["name"] for w in MANIFEST["workloads"]]
+    assert names[10:11] == [KEX]
+    assert sum(w["chips"] == 4 for w in MANIFEST["workloads"][:11]) == 1
+
+
+def test_window_roofline_reader_counts_the_required_bytes(monkeypatch):
+    """No live slots among the window's numbers, no traced step, no chip or
+    no counters: no number and no error.  With them: the weights outside
+    the routed experts, the held experts touched, the live ring rows and the
+    global layer's live keys and values, over the step's time."""
+    from chipbench import flops, flops_exaone_serve as counts
+
+    roof = harness.load_module(MANIFEST, "readers",
+                               "serve_window_decode_roofline")
+    cell = harness.resolve(MANIFEST, KEX)
+    args = harness.load_json(MANIFEST, "layer_metrics",
+                             "decode_window_hbm_roofline_pct.srv")["args"]
+    ctx = {"cell": cell, "platform": "tpu", "kind": "TPU v5 lite",
+           "traced": {"steps": 5}, "serve": {},
+           "trace": xplane.Trace({}, {}, (0, 1))}
+    assert roof.read(ctx, **args) is None                   # no live slots
+    ctx["serve"] = {"live_slots_per_step": 20.0,
+                    "live_tokens_per_step": 20 * 5000.0}
+    monkeypatch.setattr(roof, "touched_per_step", lambda: None)
+    assert roof.read(ctx, **args) is None                   # no counters
+    monkeypatch.setattr(roof, "touched_per_step", lambda: 40.0)
+    assert roof.read({**ctx, "platform": "cpu"}, **args) is None
+    monkeypatch.setattr(harness.load_module(
+        MANIFEST, "readers", "serve_module_ms"), "read",
+        lambda ctx, module, per: 15.0)
+    sizes = {k: cell.config[k] for k in cell.config["flops"]["sizes"]}
+    row = 2 * 8 * 128 * 4
+    need = (2 * (counts.matmul_params(**sizes)["outside_routed"]
+                 + 40 * 3 * 6144 * 2048)
+            + row * (4 * 20 * 128 + 20 * 5000))
+    assert counts.decode_step_bytes(20 * 5000.0, 4 * 20 * 128, 40.0,
+                                    **sizes) == need
+    hbm = flops.peak_for("TPU v5 lite")["hbm_bytes_per_s"]
+    assert roof.read(ctx, **args) == pytest.approx(100 * 1e3 * need / hbm
+                                                   / 15.0)
+
+
+def test_traced_rehearsal_of_the_window_served_cell_comes_out_correct():
+    """``run.py --rehearse`` as the benchmark calls it, with a seed past
+    2**31: rings of the rehearsal's window of 8 under prompts of 12-48 tokens,
+    bucketed prefill told its true length, the pooled step, correct against
+    the plain reference by the two shares; the counters of the live ring
+    rows are on the line; the device's times and the rooflines are left
+    out."""
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    p = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "chipbench", "run.py"),
+         "--workload", KEX, "--seed", "3900000011", "--seconds", "2",
+         "--trace", "1", "--rehearse"],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-2000:]
+    out = json.loads(p.stdout.strip().splitlines()[-1])
+    assert out["correct"] is True and out["rehearsal"] is True
+    assert out["failed"] == 0 and out["attempted"] > 50
+    assert list(out["compared"])[:2] == ["off_the_top_share",
+                                         "worst_request_off_share"]
+    assert out["compared"]["off_the_top_share"][0] == 0.0
+    # the global layer's key and value of 2 heads of 16 in float32, and four
+    # rings of 8 such rows
+    assert out["compared"]["cache_bytes_per_token"] == [256, 256]
+    assert out["compared"]["ring_bytes_per_slot"] == [4 * 8 * 256] * 2
+    counters = out["checks"]["counters"]
+    assert counters["pool_donated"] == counters["pool_calls"] > 0
+    # four ring layers of 8 rows, every prompt past the window
+    assert counters["window_rows_live"] == 4 * 8 * counters["live_slot_steps"]
+    assert not {"window_attn_ms_per_decode_step.srv",
+                "decode_window_hbm_roofline_pct.srv",
+                "mlp_ms_per_decode_step.srv"} & set(out["metrics"])
+    assert 0 < out["metrics"]["moe_held_experts_touched_share.srv"][
+        "value"] <= 1
+    # 4 of the router's 32 experts held: about an eighth of the routes
+    assert 0.05 < out["metrics"]["moe_routes_held_share.srv"]["value"] < 0.3
+    served_spans_are_read_and_the_idle_split_left_out(out)
+
+
+def test_window_served_cell_holds_the_cache_to_its_stated_type(monkeypatch):
+    """What the engine books for a cached position and for a slot's rings
+    is what the file's ``cache_dtype`` makes them; a booking of keys and
+    values one precision below (bfloat16) is not correct, whatever the
+    served tokens read, and a configuration that asks for no such check is
+    judged by what was bound before."""
+    import types
+
+    from torchmpi_tpu import serving
+
+    runner = harness.load_module(MANIFEST, "runners",
+                                 "serve_open_loop_exaone")
+    cell = harness.resolve(MANIFEST, KEX, rehearse=True)
+    import jax
+    import jax.numpy as jnp
+
+    lm = harness.build_model(cell)
+    shapes = jax.eval_shape(lambda: lm.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
+    engine = serving.ReplicaEngine(
+        lm, shapes, slots=2, slot_tokens=cell.config["serving"]["slot_tokens"])
+    stated = runner.stated_cache_bytes(cell.config)
+    assert stated == {"cache_bytes_per_token": engine.cache_bytes_per_token,
+                      "ring_bytes_per_slot": engine.ring_bytes_per_slot}
+    assert runner.stated_cache_bytes(harness.resolve(MANIFEST, KEX).config) == {
+        "cache_bytes_per_token": 8192, "ring_bytes_per_slot": 4 * 128 * 8192}
+    monkeypatch.setattr(runner.experts, "judge",
+                        lambda *a: ({}, {"off_the_top_share": [0.0, 0.08]},
+                                    True))
+    for scale, correct in ((1, True), (0.5, False)):
+        s = types.SimpleNamespace(cache_bytes={
+            k: int(v * scale) for k, v in stated.items()})
+        _, compared, ok = runner.judge(cell, 1, s)
+        assert ok is correct
+        assert compared["ring_bytes_per_slot"] == [
+            int(stated["ring_bytes_per_slot"] * scale),
+            stated["ring_bytes_per_slot"]]
+    other = harness.resolve(MANIFEST, IMOE, rehearse=True)
+    monkeypatch.setattr(runner, "_before", lambda *a: "before")
+    assert runner.judge(other, 1, None) == "before"
+
+
 def test_state_roofline_reader_counts_the_work_from_the_windows_live_slots(
         monkeypatch):
     """No live slots among the window's numbers (no decode step in it),
@@ -809,6 +1043,7 @@ def test_benchmark_json_only_gained_entries_at_the_end():
     assert names[82:83] == [SAMPLE_MS]
     assert names[83:89] == list(JAMBA_METRICS)
     assert names[89:90] == [KV_WRITE_MS]
+    assert names[90:93] == list(KEX_METRICS)
     layers = {m["layer"] for m in MANIFEST["per_layer"][:10]}
     assert {m["layer"] for m in MANIFEST["per_layer"][10:22]} <= layers
     assert {m["layer"] for m in MANIFEST["per_layer"][22:31]} <= layers | {
@@ -824,10 +1059,13 @@ def test_benchmark_json_only_gained_entries_at_the_end():
     assert {m["layer"] for m in MANIFEST["per_layer"][83:89]} == {
         "state-space layers", "model step"}
     assert MANIFEST["per_layer"][89]["layer"] == ENGINE
+    assert {m["layer"] for m in MANIFEST["per_layer"][90:93]} == {
+        ENGINE, "expert layer"}
     assert [c["name"] for c in MANIFEST["configs"]][:7] == [
         "resnet50", "starcoder2-3b", "smallthinker-21b-a3b",
         "starcoder2-3b-serve", "instella-moe-16b-a3b-serve", NM3_CONFIG,
         JAMBA_CONFIG]
+    assert [c["name"] for c in MANIFEST["configs"]][7:] == [KEX_CONFIG]
     assert [m["name"] for m in MANIFEST["end_to_end"]] == [
         "images_per_s_chip", "tokens_per_s_chip", "step_ms_p90",
         "itl_ms_p99", "out_tokens_per_s_chip", "setup_s"]

@@ -951,9 +951,15 @@ def test_kernel_prefill_then_the_untouched_steps_serve_the_dense_tokens(
         lambda *a: _slot_step_jit(dmodel, *a), params, pool,
         jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32),
         *_greedy_sampling(2)) == 0
-    # the prefix-hit extend: a suffix at a per-row depth, T > 1
+    # the prefix-hit extend: a suffix at a per-row depth, T > 1 (through the
+    # same layers without their window: a window shorter than the slot keeps
+    # a ring, which takes no such block)
+    full = dmodel.clone(window=None)
+    row = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), jax.eval_shape(
+        lambda p, x: slot_prefill(full, p, x, true_len=true_len)[0],
+        params, prompt))
     assert _count_kernels(
-        lambda *a: _slot_extend_jit(dmodel, *a), params, one,
+        lambda *a: _slot_extend_jit(full, *a), params, row,
         jnp.zeros((1, 8), jnp.int32), jnp.asarray([16], jnp.int32),
         jnp.asarray(8, jnp.int32), *_greedy_sampling(1)) == 0
 
@@ -982,8 +988,11 @@ def test_generate_prefills_through_the_kernel_to_the_dense_tokens(
 
 @pytest.fixture(scope="module")
 def one_row(gqa_window_lm):
+    # slots as long as the window (no position here reaches it): every
+    # layer keeps every position, so the verify and the extend below run
+    # (a window shorter than the slot keeps a ring, which takes neither)
     model, params = gqa_window_lm
-    dmodel = model.clone(decode=True, max_len=48)
+    dmodel = model.clone(decode=True, max_len=24)
     one, first = slot_prefill(dmodel, params, _padded_prompt(16, 11, seed=8),
                               true_len=11)
     return dmodel, params, one, int(np.asarray(first)[0])

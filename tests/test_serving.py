@@ -1021,10 +1021,14 @@ def test_kernel_row_write_serves_the_same_tokens_and_is_counted(
     # each call that took the pool except the admissions' slot writes.  A
     # head of 8 is no whole row of lanes: the rule keeps vmap's form there,
     # and the engine, reading the same rule over the pool's leaves, counts
-    # nothing.
+    # nothing.  The window of 12 makes each layer's keys and values a ring
+    # of 12 rows (written at the position mod 12), which speculation cannot
+    # serve: with drafts the same model attends over the whole slot.
     from torchmpi_tpu import obs
 
     model, params = _row_write_lm(head_dim)
+    if spec_k:
+        model = model.clone(window=None)
     xla_toks, xla_eng = _serve(model, params, spec_k=spec_k)
     assert xla_eng.stats["kv_write_kernel_calls"] == 0   # the CPU: vmap
 
@@ -1049,8 +1053,10 @@ def test_kernel_row_write_serves_the_same_tokens_and_is_counted(
 def test_prefix_hit_extend_stays_dense_and_uncounted(gqa_lm, chip_rule):
     # A prefix hit's extend (a suffix at a per-row depth, T > 1) attends
     # against the CACHE: dense on every platform, never counted, and the
-    # tokens are the full kernel prefill's.
+    # tokens are the full kernel prefill's.  (Without the window: a ring
+    # has no token axis to cut prefix fragments from.)
     model, params = gqa_lm
+    model = model.clone(window=None)
     chip_rule()
     miss_toks, _ = _serve(model, params)
     toks, eng = _serve(model, params, prefix_cache=16, prefix_block=8)

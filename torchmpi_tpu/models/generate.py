@@ -613,22 +613,21 @@ STATE_LEAVES = ("ssm_state", "conv_state")
 @partial(jax.jit, static_argnums=(0,))
 def _slot_prefill_jit(dmodel, params, prompt, true_len, seeds, idxs,
                       temps, top_ks, top_ps):
-    # A model with state leaves is told the true length: what a recurrent
-    # state holds after the bucket's padding is not what it held after the
-    # prompt (the other models' programs are what they were).
-    told = ({"true_len": true_len}
-            if getattr(dmodel, "layer_pattern", None) else {})
+    # The model is told the true length: what a recurrent state holds after
+    # the bucket's padding is not what it held after the prompt, and a window
+    # layer's ring keeps the prompt's LAST real positions, which pads would
+    # overwrite (a layer that needs neither leaves it unread).
     (xs, head), updated = dmodel.apply(
         {"params": params}, prompt, pos_offset=0, return_prehead=True,
-        mutable=["cache"], **told)
+        mutable=["cache"], true_len=true_len)
     # The TRUE last position, not -1: with bucketed prefill the prompt
     # is right-padded, and the pad positions' logits must never be
     # sampled.  (Causality makes the real positions' activations
     # independent of the padding, so the sliced logits are bitwise the
-    # unpadded ones; the pad positions' k/v land in the cache but every
-    # later query is depth-masked below them until the decode steps
-    # overwrite them in order.  A recurrent state is the exception, told
-    # above.)
+    # unpadded ones; in a full cache the pad positions' k/v land in the
+    # cache but every later query is depth-masked below them until the
+    # decode steps overwrite them in order.  A recurrent state and a ring
+    # are the exceptions, told above.)
     x_last = lax.dynamic_slice_in_dim(
         xs, clamp_slot_positions(true_len - 1, xs.shape[1]), 1,
         axis=1)[:, 0]

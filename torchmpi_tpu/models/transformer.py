@@ -22,6 +22,7 @@ float32 parameters and softmax statistics.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 from typing import Optional, Tuple, Union
@@ -144,6 +145,24 @@ def write_rows(leaf, rows, starts):
         c, u, (s,) + tail))(leaf, rows, starts)
 
 
+def ring_rows(ring, rows, first, count):
+    """A window layer's ring ``[B, W, ...]`` after the positions ``first ..
+    first + count - 1`` were written into it from ``rows`` [B, T, ...]
+    (``first`` and ``count``: scalars or [B]): ring row ``r`` holds the
+    LAST of them whose position is ``r`` mod ``W``, and keeps what it held
+    where none is.  So a prompt of 1000 tokens padded to a bucket of 1024
+    leaves positions 872-999 in a ring of 128 (``count`` its true length):
+    the pads write nothing.  The block form of the ring's write (a prompt,
+    a suffix); a single token goes by :func:`write_rows` at ``p % W``."""
+    W, T = ring.shape[1], rows.shape[1]
+    first = jnp.reshape(first, (-1, 1))
+    last = first + jnp.reshape(count, (-1, 1)) - 1
+    at = last - (last - jnp.arange(W)) % W - first       # [B or 1, W]
+    got = rows[jnp.arange(rows.shape[0])[:, None], jnp.clip(at, 0, T - 1)]
+    keep = (at >= 0).reshape(at.shape + (1,) * (ring.ndim - 2))
+    return jnp.where(keep, got.astype(ring.dtype), ring)
+
+
 @functools.partial(jax.jit, static_argnames=("window", "block_q", "block_k",
                                              "interpret"))
 def _prompt_attention(q, k, v, *, window, block_q, block_k, interpret):
@@ -194,9 +213,16 @@ class SPAttention(nn.Module):
     rope: bool = False
     rope_base: float = 10000.0
     use_bias: bool = True
+    # Per-head QK-norm: an RMSNorm over head_dim (one weight shared by the
+    # heads, ``norm_eps``) on q and on k, before RoPE and before k is cached
+    # (scope ``qk_norm``).
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
 
     @nn.compact
-    def __call__(self, x, pos_offset=0):  # x: [B, T_local, E]
+    def __call__(self, x, pos_offset=0, true_len=None):  # x: [B, T_local, E]
+        # ``true_len`` (traced): the real positions of a right-padded prompt
+        # block, which is all a ring keeps of it (see the decode branch)
         B, T, E = x.shape
         bias, base = self.use_bias, self.rope_base
         H, D = self.num_heads, self.head_dim
@@ -222,17 +248,33 @@ class SPAttention(nn.Module):
             q, k, v = (qkv[:, :, 0].astype(jnp.float32),
                        qkv[:, :, 1].astype(jnp.float32),
                        qkv[:, :, 2].astype(jnp.float32))
+        if self.qk_norm:
+            with jax.named_scope("qk_norm"):
+                q = _norm("rmsnorm", self.norm_eps)(q)
+                k = _norm("rmsnorm", self.norm_eps)(k)
         if self.rope and not self.decode:
             rpos = pos_offset + jnp.arange(T)
             q = apply_rope(q, rpos, base=base)
             k = apply_rope(k, rpos, base=base)
         if self.decode:
             # Autoregressive KV-cache step: x is the NEW token(s) ([B, 1]
-            # in the steady state); keys/values append into this layer's
-            # [B, max_len] cache and q attends over the filled prefix.
-            # NOT a ring buffer: the caller must keep total decoded length
-            # <= max_len (generate() pre-checks; past it,
+            # in the steady state); keys/values go into this layer's cache
+            # and q attends over what it holds.  The caller keeps the total
+            # decoded length <= max_len (generate() pre-checks; past it,
             # dynamic_update_slice clamps and outputs silently corrupt).
+            #
+            # Two kinds of cache, by ONE rule (no switch, no knob): a layer
+            # whose window is shorter than max_len keeps a RING of
+            # ``window`` rows, [B, window, H, D], position p in row
+            # p % window (keys are rotated before they are cached, so the
+            # rows' order does not matter); any other layer keeps all
+            # max_len positions, [B, max_len, H, D].  A ring's prompt block
+            # writes only its last ``window`` REAL positions (``true_len``:
+            # a bucket's pads must not overwrite rows the next query reads,
+            # which a full cache's depth mask would hide and a ring's cannot,
+            # ring_rows); a ring row is read only while the position it
+            # holds lies inside the query's window, so a reused slot sees
+            # nothing of its previous occupant.
             #
             # Two cache layouts:
             # - "local": single-device, full [B, max_len, H, D] cache.
@@ -282,10 +324,12 @@ class SPAttention(nn.Module):
             # — the slot engine owns per-row positions.
             po = jnp.asarray(pos_offset)
             per_row = po.ndim == 1
+            ring = self.window is not None and self.window < self.max_len
+            rows = self.window if ring else self.max_len
             ck = self.variable("cache", "k", jnp.zeros,
-                               (B, self.max_len, h_cache, D), jnp.float32)
+                               (B, rows, h_cache, D), jnp.float32)
             cv = self.variable("cache", "v", jnp.zeros,
-                               (B, self.max_len, h_cache, D), jnp.float32)
+                               (B, rows, h_cache, D), jnp.float32)
             idx = self.variable("cache", "idx",
                                 lambda: jnp.zeros((), jnp.int32))
             # Write indices route through THE clamp chokepoint
@@ -306,7 +350,34 @@ class SPAttention(nn.Module):
                         else start + jnp.arange(T))
                 q = apply_rope(q, rpos, base=base)
                 k = apply_rope(k, rpos, base=base)
-            if per_row:
+            if ring:
+                if per_row and T > 1:
+                    # a verify or an extend: its later rows would overwrite
+                    # positions its earlier queries still see (the engine
+                    # refuses speculation and the prefix cache for a ring)
+                    raise ValueError(
+                        f"a window layer's ring of {rows} rows takes one "
+                        f"token a slot or a whole prompt block, not {T} "
+                        f"tokens at per-slot depths")
+                first = starts if per_row else start
+                if T == 1:
+                    # the ring row of each new position, through the clamp
+                    # chokepoint as every cache write's index
+                    at = clamp_slot_positions(first % rows, rows)
+                    put = (functools.partial(write_rows, starts=at)
+                           if per_row else
+                           lambda c, u: lax.dynamic_update_slice(
+                               c, u, (0, at, 0, 0)))
+                else:
+                    n = T if true_len is None else true_len
+                    put = functools.partial(ring_rows, first=first, count=n)
+                with jax.named_scope("kv_write") if per_row else \
+                        contextlib.nullcontext():
+                    ck.value = put(ck.value, k)
+                    cv.value = put(cv.value, v)
+                if not per_row:
+                    idx.value = start + T
+            elif per_row:
                 with jax.named_scope("kv_write"):
                     ck.value = write_rows(ck.value, k, starts)
                     cv.value = write_rows(cv.value, v, starts)
@@ -358,8 +429,23 @@ class SPAttention(nn.Module):
                 # each row at its own depth — stale cache beyond a row's
                 # filled prefix is -inf'd out, which is what makes slot
                 # REUSE bit-identical to a fresh cache without zeroing.
+                keys, values = ck.value, cv.value
+                scope = contextlib.nullcontext()
                 kv_pos = jnp.arange(self.max_len)
-                if per_row:
+                if ring:
+                    # A ring: row r holds the newest written position that
+                    # is r mod window (negative: none yet); a query (one
+                    # token a row, after its own write) sees the rows whose
+                    # position is its own or one of the window - 1 before.
+                    scope = jax.named_scope("window_attn")
+                    q_pos = (starts[:, None] if per_row else start[None, None]
+                             ) + jnp.arange(T)                   # [B|1, 1]
+                    kv = (q_pos - (q_pos - jnp.arange(rows)) % rows
+                          )[:, None, :]
+                    mask = ((kv >= 0) & (kv <= q_pos[:, :, None])
+                            & (kv > q_pos[:, :, None] - rows))
+                    m_gqa, m_mha = mask[:, None, None], mask[:, None]
+                elif per_row:
                     q_pos = starts[:, None] + jnp.arange(T)  # [B, T]
                     mask = kv_pos[None, None, :] <= q_pos[:, :, None]
                     if self.window is not None:
@@ -372,33 +458,33 @@ class SPAttention(nn.Module):
                     if self.window is not None:
                         # Sliding window over the cache: same band the
                         # training mask applied, so decode logits match
-                        # the trained distribution past the window.  (The
-                        # cache still stores max_len entries; a rolling
-                        # buffer is a memory optimization, not a
-                        # semantics change.)
+                        # the trained distribution past the window.  (A
+                        # window as long as the cache or longer: one
+                        # shorter keeps a ring, above, with the same band.)
                         mask &= kv_pos[None, :] > q_pos[:, None] - self.window
                     m_gqa, m_mha = mask[None, None, None], mask[None, None]
-                if h_cache != q.shape[2]:
-                    # GQA (q has more heads than the cache — under
-                    # ulysses decode q was head-sliced to h_cache too,
-                    # so this is GQA only): GROUP the einsum instead of
-                    # materializing a repeated full-H KV temporary per
-                    # decode step — the cache stays Hkv-headed on the
-                    # wire and in the dot.
-                    g_rep = q.shape[2] // h_cache
-                    qg = q.reshape(B, T, h_cache, g_rep, D)
-                    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg,
-                                   ck.value) / (D ** 0.5)
-                    s = jnp.where(m_gqa, s, -jnp.inf)
-                    p = jax.nn.softmax(s, axis=-1)
-                    o = jnp.einsum("bhgqk,bkhd->bqhgd", p, cv.value)
-                    o = o.reshape(B, T, q.shape[2], D)
-                else:
-                    s = jnp.einsum("bqhd,bkhd->bhqk", q,
-                                   ck.value) / (D ** 0.5)
-                    s = jnp.where(m_mha, s, -jnp.inf)
-                    p = jax.nn.softmax(s, axis=-1)
-                    o = jnp.einsum("bhqk,bkhd->bqhd", p, cv.value)
+                with scope:
+                    if h_cache != q.shape[2]:
+                        # GQA (q has more heads than the cache — under
+                        # ulysses decode q was head-sliced to h_cache too,
+                        # so this is GQA only): GROUP the einsum instead of
+                        # materializing a repeated full-H KV temporary per
+                        # decode step — the cache stays Hkv-headed on the
+                        # wire and in the dot.
+                        g_rep = q.shape[2] // h_cache
+                        qg = q.reshape(B, T, h_cache, g_rep, D)
+                        s = jnp.einsum("bqhgd,bkhd->bhgqk", qg,
+                                       keys) / (D ** 0.5)
+                        s = jnp.where(m_gqa, s, -jnp.inf)
+                        p = jax.nn.softmax(s, axis=-1)
+                        o = jnp.einsum("bhgqk,bkhd->bqhgd", p, values)
+                        o = o.reshape(B, T, q.shape[2], D)
+                    else:
+                        s = jnp.einsum("bqhd,bkhd->bhqk", q,
+                                       keys) / (D ** 0.5)
+                        s = jnp.where(m_mha, s, -jnp.inf)
+                        p = jax.nn.softmax(s, axis=-1)
+                        o = jnp.einsum("bhqk,bkhd->bqhd", p, values)
             if ulysses:
                 # Heads back together in rank order (= original order).
                 o = lax.all_gather(o, self.seq_axis, axis=2, tiled=True)
@@ -1166,6 +1252,13 @@ class Block(nn.Module):
     ssm: Optional[Tuple[int, ...]] = None
     expert_latent: int = 0
     mamba: Optional[Tuple[int, ...]] = None
+    # Post-norm wiring (EXAONE 4's block): the norms go on the OUTPUTS of
+    # attention and of the feed-forward, not on their inputs:
+    # mid = x + norm(attn(x)), out = mid + norm(ffn(mid)) (scope
+    # ``post_norm``); an expert layer's router then reads ``mid`` too.
+    # ``qk_norm``: see SPAttention.
+    post_norm: bool = False
+    qk_norm: bool = False
 
     def _attention(self):
         if self.kv_rank:
@@ -1185,7 +1278,8 @@ class Block(nn.Module):
                            max_len=self.max_len, window=self.window,
                            num_kv_heads=self.num_kv_heads,
                            rope=self.rope, rope_base=self.rope_base,
-                           use_bias=self.use_bias)
+                           use_bias=self.use_bias, qk_norm=self.qk_norm,
+                           norm_eps=self.norm_eps)
 
     @nn.compact
     def __call__(self, x, pos_offset=0, lag=None, true_len=None):
@@ -1205,7 +1299,9 @@ class Block(nn.Module):
                                 dtype=self.dtype, decode=self.decode)(
                                     a, pos_offset, true_len)
             elif self.kind == "*":
-                h = self._attention()(a, pos_offset)
+                h = self._attention()(
+                    a, pos_offset,
+                    **({} if self.kv_rank else {"true_len": true_len}))
             elif self.kind == "E":
                 h = ExpertFFN(
                     self.n_experts, self.moe_k, self.expert_width,
@@ -1217,14 +1313,26 @@ class Block(nn.Module):
                 raise ValueError(f"unknown layer kind {self.kind!r} "
                                  f"(M, *, E; a, m)")
             return x + h
-        a = _norm(self.norm, self.norm_eps)(lag if self.farskip else x)
+        post = self.post_norm
+        if post and (self.farskip or self.kind == "m"):
+            raise ValueError("post_norm wires an attention-then-feed-forward "
+                             "block with the plain residual")
+        a = x if post else _norm(self.norm, self.norm_eps)(
+            lag if self.farskip else x)
         if self.kind == "m":
             mid = x + MambaMixer(*self.mamba, norm_eps=self.norm_eps,
                                  dtype=self.dtype, decode=self.decode)(
                                      a, pos_offset, true_len)
         else:
-            mid = x + self._attention()(a, pos_offset)
-        h = _norm(self.norm, self.norm_eps)(x if self.farskip else mid)
+            attn = self._attention()
+            told = {} if self.kv_rank else {"true_len": true_len}
+            o = attn(a, pos_offset, **told)
+            if post:
+                with jax.named_scope("post_norm"):
+                    o = _norm(self.norm, self.norm_eps)(o)
+            mid = x + o
+        h = mid if post else _norm(self.norm, self.norm_eps)(
+            x if self.farskip else mid)
 
         def dense(features):
             return nn.Dense(features, dtype=self.dtype,
@@ -1253,6 +1361,9 @@ class Block(nn.Module):
             h = dense(E)(hidden)
         else:
             raise ValueError(f"unknown mlp {self.mlp!r}")
+        if post:
+            with jax.named_scope("post_norm"):
+                h = _norm(self.norm, self.norm_eps)(h)
         out = mid + h
         return (out, mid) if self.farskip else out
 
@@ -1347,6 +1458,10 @@ class TransformerLM(nn.Module):
     # The head IS the embedding (no ``head`` parameter): logits are
     # ``x @ embedding^T``, the embedding read in the compute type.
     tie_head: bool = False
+    # EXAONE 4's block (see Block.post_norm, SPAttention.qk_norm): norms on
+    # the sub-blocks' outputs, and an RMSNorm over each head of q and k.
+    post_norm: bool = False
+    qk_norm: bool = False
 
     @nn.compact
     def __call__(self, tokens, pos_offset=0, return_prehead: bool = False,
@@ -1409,7 +1524,8 @@ class TransformerLM(nn.Module):
                       expert_latent=self.expert_latent,
                       mamba=(self.ssm_expand * self.embed, self.ssm_state,
                              self.ssm_dt_rank, self.ssm_conv,
-                             self.ssm_chunk))(
+                             self.ssm_chunk),
+                      post_norm=self.post_norm, qk_norm=self.qk_norm)(
                           x, pos_offset, lag, true_len)
             if self.farskip:
                 x, lag = x

@@ -603,6 +603,8 @@ def record_serving(event: str, n: int = 1, *, replica: str = "") -> None:
     samples, the argmax | some row samples, the filter and the draw) |
     ``live_slot_steps`` (``n``: the live sessions a pooled step decoded;
     over the steps, the slots whose state a step requires) |
+    ``window_rows_live`` (``n``: the ring rows a pooled step's live
+    sessions read, ``min(depth + 1, window)`` a session and ring layer) |
     ``spec_drafted`` /
     ``spec_accepted`` (speculative-decode draft tokens proposed /
     accepted — the live acceptance rate) | ``prefix_hits`` /

@@ -286,9 +286,12 @@ def _combine_kernel(order_ref, n_live_ref, *refs, block: int, tokens: int,
 
 
 def token_parts(tokens: int, width: int) -> int:
-    """Parts the combine's float32 sums go through, each resident whole."""
+    """Parts the combine's float32 sums go through, each resident whole
+    beside the sorted rows' three float32 blocks (two in flight, one
+    scratch): 6,144 wide those are 18 MB, which 2,560 wide was not worth
+    counting."""
     parts = 1
-    while (tokens // parts * width * 4 > _RESIDENT_BYTES
+    while ((tokens // parts + 3 * _BLOCK) * width * 4 > _RESIDENT_BYTES
            and tokens % (2 * parts) == 0 and tokens // (2 * parts) % 8 == 0):
         parts *= 2
     return parts

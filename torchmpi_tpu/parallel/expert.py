@@ -258,6 +258,24 @@ def sigmoid_gate(logits, k: int, bias, scale: float):
     return experts, chosen / (chosen.sum(-1, keepdims=True) + 1e-20) * scale
 
 
+#: What the worst case of one call's sorted buffer may take, a route a row of
+#: float32 the width of a token (the down product's): 8192 tokens x 6 routes
+#: x 2560 take 0.5 GB, a prompt of 16,384 tokens x 8 x 6144 would take 3.2 GB.
+_BUFFER_BYTES = 1 << 30
+
+
+def token_chunks(tokens: int, width: int, k: int) -> int:
+    """Chunks of tokens :func:`held_experts` routes a call in: the fewest
+    (a power of two dividing ``tokens``) that keep a chunk's worst-case
+    buffer within ``_BUFFER_BYTES``.  One for every call but the longest
+    prompts of the widest layers: the program there is what it was."""
+    chunks = 1
+    while (tokens // chunks * k * width * 4 > _BUFFER_BYTES
+           and tokens % (2 * chunks) == 0):
+        chunks *= 2
+    return chunks
+
+
 def held_experts(u, router_logits, k: int, first: int, w_gate, w_up,
                  w_down, *, gate=softmax_gate, act=jax.nn.relu):
     """The held experts' part of a top-k gated expert layer.
@@ -302,7 +320,11 @@ def held_experts(u, router_logits, k: int, first: int, w_gate, w_up,
     and both combines stop at ``n_live``.  With every expert held every
     row is live and the same code moves them all.  The buffer may be
     smaller than one block (a pooled decode step routes a row a slot):
-    the block is then the buffer.
+    the block is then the buffer.  A call whose worst-case buffer would
+    pass ``_BUFFER_BYTES`` (the longest prompts of the widest layers)
+    routes its tokens in chunks (:func:`token_chunks`), each as above: a
+    token's routes stay in its chunk, so its output is the same, and
+    ``rows_moved`` sums each chunk's rounding to its block.
 
     The gate runs in float32, the products in ``u``'s type with float32
     accumulation.  The sort runs once, outside the part that the backward
@@ -320,6 +342,21 @@ def held_experts(u, router_logits, k: int, first: int, w_gate, w_up,
         raise ValueError(
             f"held experts [{first}, {first + count}) with k={k} do not "
             f"fit a router over {n_experts}")
+    chunks = token_chunks(*u.shape, k)
+    if chunks > 1:
+        # A long prompt's tokens in chunks, each routed, sorted and
+        # combined as above on its own: a token's routes stay in its chunk,
+        # and the worst-case buffer is a chunk's.
+        def one(chunk):
+            return held_experts(*chunk, k, first, w_gate, w_up, w_down,
+                                gate=gate, act=act)
+
+        split = (u.reshape(chunks, -1, u.shape[-1]),
+                 router_logits.reshape(chunks, -1, n_experts))
+        out, stats = lax.map(one, split)
+        return out.reshape(u.shape), {
+            name: (v.reshape(-1, k) if name == "experts" else v.sum())
+            for name, v in stats.items()}
     with jax.named_scope("route"):
         experts, weights = gate(router_logits, k)
         held = (experts >= first) & (experts < first + count)

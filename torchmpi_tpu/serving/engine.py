@@ -44,7 +44,8 @@ prefill-compile counter above, the padded tokens whose prefill program
 attended through the flash forward kernel
 (``stats["prefill_kernel_tokens"]`` beside ``stats["prefill_tokens"]``,
 by the layer's own rule, ``models.transformer.prefill_runs_flash``), the
-gauges of what a cached token and a slot's recurrent state cost, how many
+gauges of what a cached token, a slot's recurrent state and a slot's
+window rings cost, how many
 of the programs that were handed the pool took it over
 (``stats["pool_donated"]`` of ``stats["pool_calls"]``), the pooled steps
 and verifies whose rows went into the pool by ``ops/cache_rows``'s kernel
@@ -54,7 +55,8 @@ of the sampling tail each pooled step's sessions ask for
 (:data:`SAMPLE_BRANCHES`: ``stats["sample_argmax"]`` +
 ``stats["sample_draw"]`` = ``stats["steps"]``), how many live sessions
 the pooled steps decoded (``stats["live_slot_steps"]``: over
-``stats["steps"]``, the slots a step REQUIRES the state of), and, for
+``stats["steps"]``, the slots a step REQUIRES the state of), the ring
+rows those sessions read (``stats["window_rows_live"]``), and, for
 a model with expert layers, what the pooled step itself
 counted: held experts touched, routes and routes held, read with the tokens): the
 scheduler owns the clock, the SLO histograms, and the fault hooks, so
@@ -206,35 +208,57 @@ class ReplicaEngine:
         from .. import obs
         from flax.traverse_util import flatten_dict
 
-        # The pool books two kinds of cache leaf apart.  A leaf with a token
-        # axis costs a slot its DEPTH (per-head keys and values, or a latent
-        # and its rotary key); a state leaf (``STATE_LEAVES``: a recurrent
-        # layer's) costs every slot the same, whatever its depth.
+        # The pool books three kinds of cache leaf apart.  A leaf with a
+        # token axis as long as the slot costs a slot its DEPTH (per-head
+        # keys and values, or a latent and its rotary key); a window
+        # layer's RING (keys or values of its last ``window`` positions,
+        # shorter than the slot: models.transformer.SPAttention) and a
+        # state leaf (``STATE_LEAVES``: a recurrent layer's) cost every slot
+        # the same, whatever its depth.
         leaves = {path: s for path, s in flatten_dict(shapes).items()
                   if len(s.shape) >= 2}
         state = sorted(p for p in leaves if p[-1] in STATE_LEAVES)
+        ring = sorted(p for p in leaves
+                      if p[-1] not in STATE_LEAVES and leaves[p].shape[1] < st)
+
+        def slot_bytes(paths):
+            return sum(int(np.prod(leaves[p].shape[1:]))
+                       * leaves[p].dtype.itemsize for p in paths)
+
         #: What a cached token costs, summed over the leaves with a token
-        #: axis, and what a slot's state costs, summed over the others.
+        #: axis, and what a slot's state and rings cost, summed over theirs.
         self.cache_bytes_per_token = sum(
             int(np.prod(s.shape[2:])) * s.dtype.itemsize
-            for p, s in leaves.items() if p[-1] not in STATE_LEAVES)
-        self.state_bytes_per_slot = sum(
-            int(np.prod(leaves[p].shape[1:])) * leaves[p].dtype.itemsize
-            for p in state)
-        obs.registry().gauge_set("tm_serving_cache_bytes_per_token",
-                                 self.cache_bytes_per_token, replica=name)
-        obs.registry().gauge_set("tm_serving_state_bytes_per_slot",
-                                 self.state_bytes_per_slot, replica=name)
-        if state and (self._prefix is not None or self._spec_k > 0):
+            for p, s in leaves.items() if p not in state and p not in ring)
+        self.state_bytes_per_slot = slot_bytes(state)
+        self.ring_bytes_per_slot = slot_bytes(ring)
+        #: The window of every ring layer (its keys' leaf), for
+        #: ``stats["window_rows_live"]``.
+        self._ring_windows = [leaves[p].shape[1] for p in ring
+                              if p[-1] == "k"]
+        for gauge, value in (
+                ("cache_bytes_per_token", self.cache_bytes_per_token),
+                ("state_bytes_per_slot", self.state_bytes_per_slot),
+                ("ring_bytes_per_slot", self.ring_bytes_per_slot)):
+            obs.registry().gauge_set(f"tm_serving_{gauge}", value,
+                                     replica=name)
+        if (state or ring) and (self._prefix is not None
+                                or self._spec_k > 0):
             # a state cannot be cut into fragments by token, nor un-updated
-            # after a rejected draft: both need snapshots (ROADMAP B5)
-            asked = ("the prefix cache (prefix_cache > 0)"
-                     if self._prefix is not None
+            # after a rejected draft: both need snapshots (ROADMAP B5); a
+            # ring holds no token axis to cut fragments from either, and a
+            # rejected draft's rows overwrite positions its window still
+            # reads
+            asked = ("the prefix cache (prefix_cache > 0: slot_cache_slice "
+                     "and slot_extend)" if self._prefix is not None
                      else "speculation (spec_k > 0)")
-            raise ValueError(
-                f"{name}: the model keeps a per-slot recurrent state "
-                f"({'/'.join(state[0])}, {len(state)} such leaves), which "
-                f"{asked} cannot serve yet: set it to 0")
+            what = (f"a per-slot recurrent state ({'/'.join(state[0])}, "
+                    f"{len(state)} such leaves)" if state else
+                    f"a window layer's keys and values in a ring of "
+                    f"{leaves[ring[0]].shape[1]} rows ({'/'.join(ring[0])}, "
+                    f"{len(ring)} such leaves)")
+            raise ValueError(f"{name}: the model keeps {what}, which "
+                             f"{asked} cannot serve yet: set it to 0")
         # The expert layers (by their router's path, the order in which
         # the decode step counts them), each with its three counters' handles.
         count = obs.registry().counter_handle
@@ -268,6 +292,9 @@ class ReplicaEngine:
         #: The pool's token leaves' shapes as its programs see them
         #: (:meth:`_rows_written_in_place`), taken at the first step.
         self._token_shapes = None
+        #: The window of each layer whose keys and values are a ring (the
+        #: dense engine reads them off its pool's shapes).
+        self._ring_windows: List[int] = []
         self.dead = False
         self._sessions: Dict[int, Session] = {}
         self._sample_default = float(
@@ -298,7 +325,7 @@ class ReplicaEngine:
                       "prefix_hits": 0, "prefix_misses": 0,
                       "pool_calls": 0, "pool_donated": 0,
                       "kv_write_kernel_calls": 0,
-                      "live_slot_steps": 0,
+                      "live_slot_steps": 0, "window_rows_live": 0,
                       **dict.fromkeys(SAMPLE_BRANCHES, 0)}
         #: Work units spent (prefill/pooled forward = 1 each, draft
         #: forwards at the proposer's weight) — the scheduler's
@@ -687,11 +714,18 @@ class ReplicaEngine:
     def _count_step(self, sessions: Dict[int, Session]) -> None:
         """One more pooled step, under the branch of the sampling tail
         its rows ask for (:data:`SAMPLE_BRANCHES`): the program's own
-        rule, any temperature above zero, on the host's copy of it; and
-        the live sessions it decodes (what ``tm.serve.step`` carries as
-        ``live``)."""
+        rule, any temperature above zero, on the host's copy of it; the
+        live sessions it decodes (what ``tm.serve.step`` carries as
+        ``live``); and the ring rows they read, ``min(depth + 1, window)``
+        a live session and ring layer (``stats["window_rows_live"]``)."""
         self.stats["steps"] += 1
         self.stats["live_slot_steps"] += len(sessions)
+        if self._ring_windows:
+            rows = sum(min(s.pos_next + 1, w) for s in sessions.values()
+                       for w in self._ring_windows)
+            self.stats["window_rows_live"] += rows
+            emit("record_serving", "window_rows_live", rows,
+                 replica=self.name)
         branch = SAMPLE_BRANCHES[any(s.sampling[0] > 0.0
                                      for s in sessions.values())]
         self.stats[branch] += 1
