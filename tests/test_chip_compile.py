@@ -349,7 +349,10 @@ def test_served_decode_step_lowers_to_the_parents_text(one_chip):
     # shorter than the slot of 4608, so every layer's keys and values are
     # a ring of 4096 rows, written at the position mod 4096 and read under
     # the scope `window_attn` where a row holds a position in the window,
-    # nothing else.  A change
+    # nothing else.  Then the pipelined step touched it: the step takes the
+    # tokens of the step before, left on the device, as a last operand, and
+    # a row whose host token is negative takes its token from there (one
+    # `select` before the embedding), nothing else.  A change
     # that means to touch the step replaces the digest and says so there.
     import hashlib
 
@@ -359,7 +362,8 @@ def test_served_decode_step_lowers_to_the_parents_text(one_chip):
     cache = _pool_cache(dmodel, slots, one_chip)
     text = _slot_step_jit.lower(
         dmodel, params, cache, _sds((slots,), jnp.int32, one_chip),
-        _sds((slots,), jnp.int32, one_chip), *sampling(slots)).as_text()
+        _sds((slots,), jnp.int32, one_chip), *sampling(slots),
+        _sds((slots,), jnp.int32, one_chip)).as_text()
     # the one kernel allowed: a row write a key or value leaf (60)
     kernels = re.findall(r"tm_kernel\\22:\\22([\w.]+)\\22", text)
     assert kernels == ["kv.write_rows"] * 60
@@ -370,7 +374,7 @@ def test_served_decode_step_lowers_to_the_parents_text(one_chip):
     text = re.sub(r"\\22body\\22: \\22[A-Za-z0-9+/=]*\\22", "BODY", text)
     assert text.count("BODY") == len(kernels)
     assert hashlib.sha256(text.encode()).hexdigest() == (
-        "8cde4d78df7144d0cfdf2bcd2466c4ffc2ca801ffcc2b65a019331525717a246")
+        "f6d6e31e8042de364d663ed95f024872bef1afeaac2e79706788b0fe5f8541da")
 
 
 def _pool_cache(dmodel, slots, one_chip):
@@ -397,10 +401,39 @@ def _compiled_step(steps, one_chip, workload):
     if workload not in steps:
         dmodel, params, slots, sampling = _served(one_chip, workload)
         cache = _pool_cache(dmodel, slots, one_chip)
+        # as the engine dispatches it: the step before's tokens, left on
+        # the device, are its last operand
         steps[workload] = cache, _slot_step_jit.lower(
             dmodel, params, cache, _sds((slots,), jnp.int32, one_chip),
-            _sds((slots,), jnp.int32, one_chip), *sampling(slots)).compile()
+            _sds((slots,), jnp.int32, one_chip), *sampling(slots),
+            _sds((slots,), jnp.int32, one_chip)).compile()
     return steps[workload]
+
+
+@pytest.mark.parametrize("workload", [
+    "sc2-3b-serve-sat", "imoe-16b-serve-conv-sat",
+    "nm3s-120b-serve-chat-sat", "jamba2-3b-serve-reason-sat",
+    "kex-236b-serve-mixlen-sat"])
+def test_served_decode_step_takes_the_unread_tokens_in_one_module(
+        one_chip, compiled_steps, workload):
+    # The pooled step the engine dispatches before it reads the step before
+    # takes that step's [slots] tokens as an operand on the device
+    # and merges them with the host's where a row's host token is negative:
+    # still ONE module, jit__slot_step_jit (the name every served per-layer
+    # reader keys on), with the pool aliased whole and no second program
+    # beside it.
+    cache, compiled = _compiled_step(compiled_steps, one_chip, workload)
+    text = compiled.as_text()
+    assert re.match(r"HloModule jit__slot_step_jit\b", text)
+    assert len(re.findall(r"^HloModule ", text, re.M)) == 1
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+    harness = _harness()
+    slots = harness.resolve(harness.load_manifest(),
+                            workload).config["serving"]["slots"]
+    # the merge: a select over the [slots] tokens, in the entry computation
+    # or a fusion of it
+    assert re.search(r"s32\[%d\]\S* select\(" % slots, text)
 
 
 @pytest.mark.parametrize("workload", [

@@ -540,13 +540,16 @@ def test_a_sampled_request_moves_the_steps_it_is_live_for(lm, knobs):
     greedy = [engine.admit(serving.Request(f"g{i}", prompts[i],
                                            max_new=12))[0]
               for i in range(2)]
+    # the first call on the idle pool dispatches two steps, the second one
+    # more: three greedy steps, the third unread when "s" is admitted
     engine.step(), engine.step()
-    assert _branch_counts(engine) == [2, 0]
+    assert _branch_counts(engine) == [3, 0]
     sess, _ = engine.admit(serving.Request("s", prompts[2], **sampled))
     finished = []
     while sess not in finished:
         finished = engine.step()[1]
-    assert _branch_counts(engine) == [2, 4]
+    # its four steps, and the greedy one dispatched beside its last read
+    assert _branch_counts(engine) == [4, 4]
     while engine.active:
         engine.step()
     assert _branch_counts(engine) == [11 - 4, 4]
@@ -756,9 +759,7 @@ def test_tp_sharded_server_matches_tp_oracle(tmp_path):
     step_spans = _named(spans, "tm.serve.step")
     assert {s.stats["replica"] for s in step_spans} == {"tp0", "tp1"}
     for s in step_spans:
-        assert [k.name for k in _children(spans, s)] == [
-            f"tm.serve.step.{k}" for k in ("operands", "dispatch", "read",
-                                           "book")]
+        _steps_dispatched(_children(spans, s))
     admits = _named(spans, "tm.serve.admit")
     assert sorted(a.stats["rid"] for a in admits) == sorted(oracle)
     for a in admits:
@@ -1320,6 +1321,20 @@ def _in_order(spans):
     return all(a.end <= b.start for a, b in zip(spans, spans[1:]))
 
 
+def _steps_dispatched(kids):
+    """A plain step call's phases, in order: the operands and the dispatch
+    of each pooled step it dispatched (none, one, or two on an idle pool),
+    then the read of the oldest unread one and its book -> how many it
+    dispatched."""
+    names = [k.name for k in kids]
+    n = (len(names) - 2) // 2
+    assert n <= 2 and names == (
+        ["tm.serve.step.operands", "tm.serve.step.dispatch"] * n
+        + ["tm.serve.step.read", "tm.serve.step.book"]), names
+    assert _in_order(kids)
+    return n
+
+
 def _span_server(lm, **kw):
     """A one-replica server whose programs are compiled: what it is traced
     serving afterwards is the steady loop."""
@@ -1363,19 +1378,22 @@ def test_a_served_trace_writes_the_span_tree(lm, tmp_path):
         assert _inside(spans, a) == kids
         assert kids[1].stats == {"padded_tokens": padded.shape[1],
                                  "kernel": 0}
-    # one step a pooled step, counted by the engine, with its live sessions
+    # one step a call of step(), with the pooled steps the engine counted
+    # before it and its open sessions; each dispatches what it counts (two
+    # the first, on the idle pool) and reads one
     step_spans = _named(spans, "tm.serve.step")
-    assert [s.stats["step"] for s in step_spans] == list(
-        range(steps, eng.stats["steps"]))
+    dispatched = []
+    for s in step_spans:
+        kids = _children(spans, s)
+        dispatched.append(_steps_dispatched(kids))
+        assert _inside(spans, s) == kids
+    assert dispatched[0] == 2
+    assert [s.stats["step"] for s in step_spans] == [
+        steps + sum(dispatched[:i]) for i in range(len(step_spans))]
+    assert steps + sum(dispatched) == eng.stats["steps"]
     assert all(s.stats["live"] in (1, 2) and s.stats["spec"] == 0
                and s.stats["replica"] == eng.name for s in step_spans)
     assert max(s.stats["live"] for s in step_spans) == 2
-    for s in step_spans:
-        kids = _children(spans, s)
-        assert [k.name for k in kids] == [
-            f"tm.serve.step.{k}" for k in ("operands", "dispatch", "read",
-                                           "book")] and _in_order(kids)
-        assert _inside(spans, s) == kids
     # a tick holds its admissions and then its one step, nothing else
     for t in tick_spans:
         kids = _children(spans, t)

@@ -479,9 +479,12 @@ def test_slo_shed_counters_and_obs_tool_fleet_line(lm, tmp_path,
 
         obs.reset()
         rng = np.random.default_rng(1)
+        # arrivals spread until the gate has its samples: a request holds
+        # its slot for the tick its last token is read in (the pipelined
+        # step), four units of clock for four tokens
         reqs = [serving.Request(
             f"r{i}", rng.integers(1, VOCAB, size=8).astype(np.int32),
-            max_new=4, arrival_s=i * 0.5) for i in range(40)]
+            max_new=4, arrival_s=i * 1.0) for i in range(40)]
         srv = serving.Server(model, params, replicas=1, slots=2,
                              slot_tokens=32, slo_ttft_us=1.0)
         done = srv.run_trace(reqs, unit_seconds=1.0)

@@ -655,10 +655,13 @@ def slot_prefill(dmodel, params, prompt, *, true_len=None,
 
 @partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
 def _slot_step_jit(dmodel, params, cache, tokens, positions, seeds,
-                   idxs, temps, top_ks, top_ps):
+                   idxs, temps, top_ks, top_ps, prev=None):
     from ..ops.cache_rows import donated_pool
     from ..parallel.expert import decode_counts
 
+    if prev is not None:
+        # a row the host left negative takes what an earlier step returned
+        tokens = jnp.where(tokens < 0, prev, tokens)
     with donated_pool():
         logits, updated = dmodel.apply(
             {"params": params, "cache": cache}, tokens[:, None],
@@ -672,14 +675,17 @@ def _slot_step_jit(dmodel, params, cache, tokens, positions, seeds,
 
 
 def slot_decode_step(dmodel, params, cache, tokens, positions,
-                     sampling=None, counted: bool = False):
+                     sampling=None, counted: bool = False, prev=None):
     """One decode tick over the whole slot pool; consumes the pool it is
     given (``cache`` is deleted: use the one that comes back).  ``tokens``
     [S] are each slot's pending token, ``positions`` [S] its absolute
     write index
     (inactive slots pass 0 and any token: their outputs are ignored,
     their cache rows are fully overwritten on the next admission, and the
-    expert layers' counts leave them out).
+    expert layers' counts leave them out).  With ``prev``, the [S] tokens
+    an earlier step returned and the host has not read, a row whose
+    ``tokens`` entry is negative takes its token from ``prev``, on the
+    device: the next step goes out before this one is read.
     Returns ``(new_cache, next_tokens [S])``, and with ``counted`` a third
     value: what the step's expert layers did
     (``parallel.expert.decode_counts``: [layers, 3] int32, ready when the
@@ -691,7 +697,7 @@ def slot_decode_step(dmodel, params, cache, tokens, positions,
     if sampling is None:
         sampling = _greedy_sampling(tokens.shape[0])
     out = _slot_step_jit(dmodel, params, cache, tokens,
-                         jnp.asarray(positions), *sampling)
+                         jnp.asarray(positions), *sampling, prev)
     return out if counted else out[:2]
 
 

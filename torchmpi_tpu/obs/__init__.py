@@ -605,6 +605,10 @@ def record_serving(event: str, n: int = 1, *, replica: str = "") -> None:
     over the steps, the slots whose state a step requires) |
     ``window_rows_live`` (``n``: the ring rows a pooled step's live
     sessions read, ``min(depth + 1, window)`` a session and ring layer) |
+    ``steps_ahead`` (a pooled step dispatched while an earlier one was
+    unread: ``serving.ReplicaEngine.step``'s one-deep pipeline engaged) |
+    ``rows_past_end`` (a row a step decoded for a session that had ended
+    on EOS at the unread step: its token discarded) |
     ``spec_drafted`` /
     ``spec_accepted`` (speculative-decode draft tokens proposed /
     accepted — the live acceptance rate) | ``prefix_hits`` /

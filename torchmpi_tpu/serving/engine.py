@@ -16,10 +16,17 @@ iteration-level operations the scheduler composes:
   ``tm_serving_prefill_compiles_total``) on the bucketed AND unbucketed
   paths, so the recompile cost is visible either way.
 - :meth:`step` — ONE ``[S, 1]`` decode tick advancing every in-flight
-  slot at its own cache depth (``models.generate.slot_decode_step``);
-  sequences that emit EOS or reach their token budget retire
-  immediately and their slot frees for the next admission.  With
-  ``spec_k`` > 0 the tick becomes draft-then-verify: a
+  slot at its own cache depth (``models.generate.slot_decode_step``),
+  as a one-deep pipeline: the call dispatches the NEXT pooled step,
+  whose input tokens are this step's, left on the device, and only
+  then reads this step's tokens and books them, so the chip runs the
+  next step while the host reads, books and prepares the one after.
+  Each call returns one token per session of the step it read (the
+  first call on an idle pool dispatches two steps).  Sequences that
+  emit EOS or reach their token budget retire when that token is read
+  and their slot frees for the next admission.  With
+  ``spec_k`` > 0 the tick becomes draft-then-verify (synchronous: the
+  draft needs the tokens on the host): a
   :mod:`.spec` proposer drafts K tokens per slot, ONE ``[S, K+1]``
   target forward (``slot_verify_step``) scores them all, and the
   accept loop emits tokens exactly while drafts match — the stream is
@@ -56,7 +63,10 @@ of the sampling tail each pooled step's sessions ask for
 ``stats["sample_draw"]`` = ``stats["steps"]``), how many live sessions
 the pooled steps decoded (``stats["live_slot_steps"]``: over
 ``stats["steps"]``, the slots a step REQUIRES the state of), the ring
-rows those sessions read (``stats["window_rows_live"]``), and, for
+rows those sessions read (``stats["window_rows_live"]``), the pooled
+steps dispatched while an earlier step was unread
+(``stats["steps_ahead"]``) and the rows decoded for a session that had
+already ended at the unread step (``stats["rows_past_end"]``), and, for
 a model with expert layers, what the pooled step itself
 counted: held experts touched, routes and routes held, read with the tokens): the
 scheduler owns the clock, the SLO histograms, and the fault hooks, so
@@ -71,6 +81,7 @@ attached; docs/OBSERVABILITY.md, "What a profile shows").
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -127,9 +138,11 @@ class Session:
 
     request: Any            # scheduler.Request
     slot: int
-    last_tok: int           # pending token (input of the next step)
-    pos_next: int           # absolute cache index the next step writes
+    last_tok: int           # the last token read (or the prefill's)
+    pos_next: int           # absolute cache index the next dispatch writes
     emitted: List[int] = dataclasses.field(default_factory=list)
+    #: Pooled steps dispatched for this session whose tokens are unread.
+    ahead: int = 0
     #: Resolved (temperature, top_k, top_p, seed); greedy rows carry
     #: the filter no-op sentinels (0.0, 0, 2.0).
     sampling: Tuple[float, int, float, int] = (0.0, 0, 2.0, 0)
@@ -141,6 +154,17 @@ class Session:
     #: the cache is off or missed) — released at retirement so idle
     #: blocks become evictable again.
     prefix_chain: List[Any] = dataclasses.field(default_factory=list)
+
+
+@dataclasses.dataclass
+class _Unread:
+    """A pooled step dispatched and not yet read: its [S] tokens and its
+    expert counts, on the device, and the session that owned each row it
+    decoded when it was dispatched."""
+
+    tokens: Any
+    counts: Any
+    owners: Dict[int, Session]
 
 
 class ReplicaEngine:
@@ -205,6 +229,8 @@ class ReplicaEngine:
                              shapes)
         self._cache = (jax.device_put(cache, device)
                        if device is not None else cache)
+        if device is not None:
+            self._no_prev = jax.device_put(self._no_prev, device)
         from .. import obs
         from flax.traverse_util import flatten_dict
 
@@ -296,7 +322,21 @@ class ReplicaEngine:
         #: dense engine reads them off its pool's shapes).
         self._ring_windows: List[int] = []
         self.dead = False
+        #: The open sessions, by slot: each one's final token is unread.
         self._sessions: Dict[int, Session] = {}
+        #: Pooled steps dispatched and not yet read, oldest first (one
+        #: between calls of :meth:`step`, none with a draft bound).
+        self._unread: "collections.deque[_Unread]" = collections.deque()
+        #: What the last :meth:`_backend_step` counted (its expert
+        #: layers' counts, on the device; None without expert layers):
+        #: beside it, so that the hook returns the step's tokens alone,
+        #: the contract the benchmark's fault test wraps.
+        self._step_counts = None
+        #: What a step dispatched with no step unread merges from (every
+        #: row's token is the host's), placed as a step's own tokens are
+        #: (the dense engine commits it to its device), so that one
+        #: executable serves both dispatches.
+        self._no_prev = jnp.zeros((slots,), jnp.int32)
         self._sample_default = float(
             sample if sample is not None else cfg.serving_sample)
         self._bucket = int(prefill_bucket if prefill_bucket is not None
@@ -326,6 +366,7 @@ class ReplicaEngine:
                       "pool_calls": 0, "pool_donated": 0,
                       "kv_write_kernel_calls": 0,
                       "live_slot_steps": 0, "window_rows_live": 0,
+                      "steps_ahead": 0, "rows_past_end": 0,
                       **dict.fromkeys(SAMPLE_BRANCHES, 0)}
         #: Work units spent (prefill/pooled forward = 1 each, draft
         #: forwards at the proposer's weight) — the scheduler's
@@ -336,6 +377,7 @@ class ReplicaEngine:
 
     @property
     def active(self) -> int:
+        """Open sessions: those whose final token is not read yet."""
         return len(self._sessions)
 
     def sessions(self) -> List[Session]:
@@ -406,8 +448,8 @@ class ReplicaEngine:
     def _sampling_arrays(self, sessions: Dict[int, Session]):
         """[S] operand arrays for the pooled forwards.  ``idxs`` is
         each session's global emitted-token index (pre-reroute tokens
-        included) — the fold_in schedule that makes sampling a pure
-        function of (seed, token index)."""
+        and those of its unread steps included) — the fold_in schedule
+        that makes sampling a pure function of (seed, token index)."""
         S = self.pool.n_slots
         seeds = np.zeros((S,), np.uint32)
         idxs = np.zeros((S,), np.int32)
@@ -418,7 +460,7 @@ class ReplicaEngine:
             t, k, p, seed = sess.sampling
             seeds[slot] = np.uint32(seed)
             idxs[slot] = len(getattr(sess.request, "tokens", []) or []) \
-                + len(sess.emitted)
+                + len(sess.emitted) + sess.ahead
             temps[slot] = t
             tks[slot] = k
             tps[slot] = p
@@ -447,16 +489,13 @@ class ReplicaEngine:
                 and transformer.prefill_runs_flash(padded_len,
                                                    per_row=False))
 
-    def _pooled(self, program, read=False):
+    def _pooled(self, program, rows=False):
         """Run one program that CONSUMES the pool (a decode step, a verify,
         a slot write): ``program(pool)`` hands back the new pool first, and
-        what follows it is returned.  With ``read`` (a step, a verify) that
-        is fetched to the host in ONE blocking read, inside the call, so a
-        step that fails on the device fails here, with the pool it took;
-        the step's two phases are named here for every backend:
-        ``tm.serve.step.dispatch`` the call into the program (``jit``'s
-        dispatch, the enqueue), ``tm.serve.step.read`` the read the device
-        runs the step under.  The engine holds the only reference,
+        what follows it is returned as it came, on the device, unread
+        (:meth:`_read` fetches a step's or a verify's).  ``rows``: a step
+        or a verify, whose rows may go into the pool by the kernel.  The
+        engine holds the only reference,
         so the pool that went in is gone when the call returns
         (``stats["pool_donated"]`` counts that; a backend that declines the
         donation leaves it behind).  A program that raises before it is
@@ -465,13 +504,7 @@ class ReplicaEngine:
         the replica reads as dead."""
         pool, self._cache = self._cache, None
         try:
-            if read:
-                with span("tm.serve.step.dispatch"):
-                    out = program(pool)
-                with span("tm.serve.step.read"):
-                    out = out[:1] + jax.device_get(out[1:])
-            else:
-                out = program(pool)
+            out = program(pool)
         except BaseException:
             if _gone(pool):
                 self.dead = True
@@ -484,11 +517,24 @@ class ReplicaEngine:
         self.stats["pool_donated"] += donated
         emit("record_serving", "pool_calls", replica=self.name)
         emit("record_serving", "pool_donated", donated, replica=self.name)
-        if read and self._rows_written_in_place(out[0]):
+        if rows and self._rows_written_in_place(out[0]):
             self.stats["kv_write_kernel_calls"] += 1
             emit("record_serving", "kv_write_kernel_calls",
                  replica=self.name)
         return out[1:]
+
+    def _read(self, out):
+        """The one blocking read (``tm.serve.step.read``) of what a step or
+        a verify handed back: the device runs the program under it.  A
+        program that failed on the device fails here, and so does the pool
+        it handed on to every program queued behind it: the replica reads
+        as dead (its sessions are re-routed from the tokens read)."""
+        with span("tm.serve.step.read"):
+            try:
+                return jax.device_get(out)
+            except BaseException:
+                self.dead = True
+                raise
 
     def _rows_written_in_place(self, pool) -> bool:
         """Whether a step or a verify over ``pool`` wrote its rows into
@@ -505,17 +551,17 @@ class ReplicaEngine:
         return bool(self._token_shapes) and all(
             transformer.rows_write_in_place(s) for s in self._token_shapes)
 
-    def _backend_step(self, toks: np.ndarray, pos: np.ndarray, sampling):
+    def _backend_step(self, toks, pos: np.ndarray, sampling):
+        """Dispatch one pooled step; -> its [S] tokens, on the device.
+        ``toks`` is ``(prev, fresh)``: ``fresh`` [S] the host's tokens, a
+        negative entry where the row takes ``prev``'s, the tokens of the
+        step before, unread (the program merges them)."""
+        prev, fresh = toks
         # the counts are ready when the tokens are: one read fetches both
-        nxt, counts = self._pooled(
+        nxt, self._step_counts = self._pooled(
             lambda pool: slot_decode_step(
-                self.dmodel, self.params, pool, toks, pos,
-                sampling=sampling, counted=True), read=True)
-        if counts is not None:
-            self._expert_steps()
-            for handles, row in zip(self._expert_counters, counts.tolist()):
-                for add, value in zip(handles, row):
-                    add(value)
+                self.dmodel, self.params, pool, fresh, pos,
+                sampling=sampling, counted=True, prev=prev), rows=True)
         return nxt
 
     def _backend_verify(self, toks: np.ndarray, pos: np.ndarray,
@@ -523,7 +569,7 @@ class ReplicaEngine:
         return self._pooled(
             lambda pool: slot_verify_step(
                 self.dmodel, self.params, pool, toks, pos,
-                sampling=sampling), read=True)[0]
+                sampling=sampling), rows=True)[0]
 
     def _row_template(self):
         """Fresh single-row zero cache — the canvas prefix-cache
@@ -699,8 +745,17 @@ class ReplicaEngine:
         """One decode tick over every in-flight slot; returns
         ``(advanced, finished)``.  Finished sessions are already retired
         (slot freed) — their blocks are reusable in the same tick.
-        Speculative when a draft is bound (up to K+1 tokens per session
-        per tick, bitwise the plain stream)."""
+
+        Without a draft the tick is one-deep pipelined: it dispatches the
+        next pooled step first, then reads the one dispatched before and
+        returns ITS tokens, one a session that step decoded (a session
+        admitted since that step went out first advances in the next
+        call; the first call on an idle pool dispatches two steps, so a
+        request of ``max_new`` tokens takes as many calls as the order
+        of dispatch-then-read).  Each row's token goes to the session
+        that owned the row when the step was dispatched.  Speculative when
+        a draft is bound, in the synchronous order (up to K+1 tokens per
+        session per tick, bitwise the plain stream)."""
         if self.dead:
             raise RuntimeError(f"{self.name} is dead")
         if not self._sessions:
@@ -734,23 +789,69 @@ class ReplicaEngine:
              replica=self.name)
 
     def _plain_step(self) -> Tuple[List[Session], List[Session]]:
+        if not self._unread:
+            # nothing queued on the chip: the step the call reads goes
+            # out first, from the host's tokens
+            self._dispatch_step()
+        if any(self._budget_left(s) > 0 for s in self._sessions.values()):
+            self._dispatch_step()
+        return self._read_step()
+
+    def _dispatch_step(self) -> None:
+        """Operands and dispatch of one pooled step over every open session
+        its budget still takes (one whose last token is in an unread step
+        is left out: the host knows that from counts).  A row the unread
+        step decoded for the same session takes that step's token on the
+        device; any other takes the host's (an admission's first token)."""
         with span("tm.serve.step.operands"):
-            self._count_step(self._sessions)
+            live = {slot: sess for slot, sess in self._sessions.items()
+                    if self._budget_left(sess) > 0}
+            self._count_step(live)
             self.units += 1.0
+            last = self._unread[-1] if self._unread else None
             S = self.pool.n_slots
-            toks = np.zeros((S,), np.int32)
+            fresh = np.zeros((S,), np.int32)
             pos = np.zeros((S,), np.int32)
-            for slot, sess in self._sessions.items():
-                toks[slot] = sess.last_tok
+            for slot, sess in live.items():
+                on_chip = last is not None and last.owners.get(slot) is sess
+                fresh[slot] = -1 if on_chip else sess.last_tok
                 pos[slot] = sess.pos_next
-            samp = self._sampling_arrays(self._sessions)
-        nxt = self._backend_step(toks, pos, samp)
+            samp = self._sampling_arrays(live)
+        with span("tm.serve.step.dispatch"):
+            self._step_counts = None
+            tokens = self._backend_step(
+                (self._no_prev if last is None else last.tokens, fresh),
+                pos, samp)
+        for sess in live.values():
+            sess.pos_next += 1
+            sess.ahead += 1
+        if last is not None:
+            self.stats["steps_ahead"] += 1
+            emit("record_serving", "steps_ahead", replica=self.name)
+        self._unread.append(_Unread(tokens, self._step_counts, live))
+
+    def _read_step(self) -> Tuple[List[Session], List[Session]]:
+        """Read the oldest unread step and book its tokens to the sessions
+        that owned its rows when it went out.  A row whose session ended
+        (EOS) at an earlier read is discarded, whoever holds its slot now
+        (``stats["rows_past_end"]``)."""
+        step = self._unread.popleft()
+        nxt, counts = self._read((step.tokens, step.counts))
+        if counts is not None:
+            self._expert_steps()
+            for handles, row in zip(self._expert_counters, counts.tolist()):
+                for add, value in zip(handles, row):
+                    add(value)
         with span("tm.serve.step.book"):
             advanced, finished = [], []
-            for slot in list(self._sessions):
-                sess = self._sessions[slot]
+            for slot, sess in step.owners.items():
+                if self._sessions.get(slot) is not sess:
+                    self.stats["rows_past_end"] += 1
+                    emit("record_serving", "rows_past_end",
+                         replica=self.name)
+                    continue
+                sess.ahead -= 1
                 sess.last_tok = int(nxt[slot])
-                sess.pos_next += 1
                 sess.emitted.append(sess.last_tok)
                 sess.last_emit = 1
                 advanced.append(sess)
@@ -796,7 +897,9 @@ class ReplicaEngine:
             self._count_step(sessions)
             self.stats["spec_steps"] += 1
             self.units += 1.0 + float(draft_units)
-        out = self._backend_verify(toks, pos, samp)
+        with span("tm.serve.step.dispatch"):
+            out = self._backend_verify(toks, pos, samp)
+        out = self._read(out)
         with span("tm.serve.step.book"):
             advanced, finished = [], []
             tick_drafted = tick_accepted = 0
@@ -845,8 +948,11 @@ class ReplicaEngine:
         for re-routing (their cache state is presumed lost with the
         replica — the scheduler re-prefills each from its emitted
         prefix on a healthy replica).  Draft state is discarded with
-        the replica: nothing speculative survives the move."""
+        the replica: nothing speculative survives the move; so is an
+        unread step: its tokens are recomputed where the sessions go,
+        bitwise the same (sampling keys on seed and token index)."""
         self.dead = True
+        self._unread.clear()
         out = list(self._sessions.values())
         for sess in out:
             self.pool.free(sess.slot)
@@ -866,10 +972,18 @@ class ReplicaEngine:
             self._prefix.release(sess.prefix_chain)
             sess.prefix_chain = []
 
-    @staticmethod
-    def _finished(sess: Session) -> bool:
+    @classmethod
+    def _finished(cls, sess: Session) -> bool:
         req = sess.request
         if req.eos_id is not None and sess.last_tok == int(req.eos_id):
             return True
+        return cls._budget_left(sess) + sess.ahead <= 0
+
+    @staticmethod
+    def _budget_left(sess: Session) -> int:
+        """Tokens the session's budget still takes beyond those emitted
+        (re-routes included) and those its unread steps hold."""
+        req = sess.request
         done_before = len(req.tokens) if hasattr(req, "tokens") else 0
-        return done_before + len(sess.emitted) >= int(req.max_new)
+        return (int(req.max_new) - done_before - len(sess.emitted)
+                - sess.ahead)

@@ -96,6 +96,9 @@ class TPReplicaEngine(ReplicaEngine):
         self._cache = [(jax.device_put(zero, sh),
                         jax.device_put(zero, sh))
                        for _ in range(self.depth)]
+        # replicated over the mesh, as a step's tokens come back
+        self._no_prev = jax.device_put(self._no_prev,
+                                       NamedSharding(mesh, P()))
         # One per-topology plan row per replica (dump-live evidence).
         from .. import planner
 
@@ -114,15 +117,18 @@ class TPReplicaEngine(ReplicaEngine):
         return False  # tp_generate._block_prefill: dense scores
 
     def _backend_step(self, toks, pos, sampling):
-        return self._backend_verify(
-            np.asarray(toks, np.int32)[:, None], pos, sampling)[:, 0]
+        # the dense step's contract with the merge done beside the program,
+        # on the device: a negative host token takes the unread step's
+        prev, fresh = toks
+        toks = jnp.where(jnp.asarray(fresh) < 0, prev, fresh)
+        return self._backend_verify(toks[:, None], pos, sampling)[:, 0]
 
     def _backend_verify(self, toks, pos, sampling):
         return self._pooled(
             lambda pool: tp_slot_decode(
                 self.params, pool, toks, pos, mesh=self.mesh,
                 axis=self.axis, num_heads=self.num_heads,
-                sampling=sampling), read=True)[0]
+                sampling=sampling), rows=True)[0]
 
     def _row_template(self):
         from jax.sharding import NamedSharding, PartitionSpec as P
