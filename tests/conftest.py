@@ -139,3 +139,24 @@ def rows_rule(monkeypatch):
     yield on
     monkeypatch.undo()
     jax.clear_caches()
+
+
+@pytest.fixture()
+def experts_rule(monkeypatch):
+    """Call it to make an expert layer's call compute its held experts as on
+    the chip: by ``ops/moe.decode_experts`` where the call's shape says so,
+    interpreted here (``parallel.expert.experts_read_once``; the layer and
+    the serving engine both look the rule up on that module).  The caches
+    go at the switch and after the test, as with :func:`chip_rule`."""
+    from torchmpi_tpu.parallel import expert
+
+    rule = expert.experts_read_once
+
+    def on():
+        monkeypatch.setattr(expert, "experts_read_once",
+                            lambda shape, platform=None: rule(shape, "tpu"))
+        jax.clear_caches()
+
+    yield on
+    monkeypatch.undo()
+    jax.clear_caches()

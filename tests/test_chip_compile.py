@@ -597,6 +597,9 @@ def test_served_hybrid_compiles_at_the_cells_sizes(
         assert sorted(a.shape for a in state) == sorted(
             [(slots, 128, 64, 128), (slots, 3, 10240)] * 5)
         attention = ["kv.write_rows"] * 2
+        # the held experts of a step read once a layer, no grouped product
+        # (parallel/expert.experts_read_once: 68 rows, one token each)
+        experts, grouped = ["moe.decode_experts"] * 5, 0
     else:
         dmodel, params, _, sampling = _served(one_chip,
                                               "nm3s-120b-serve-chat-sat")
@@ -605,16 +608,17 @@ def test_served_hybrid_compiles_at_the_cells_sizes(
             dmodel, params, _sds((1, bucket), jnp.int32, one_chip),
             _sds((), jnp.int32, one_chip), *sampling(1)).compile()
         attention = ["flash.fwd"]
+        experts, grouped = ["moe.gather", "moe.combine"] * 5, 2 * 5
     # the library's kernels: the one attention layer's prompt (its step:
-    # the key and value rows' write), and the live rows' gather and
-    # combine of the five expert layers; the compiler's
-    # own grouped-matmul kernels for the experts' TWO products a layer
-    # (1,408 sorted rows a step over 128 experts: grouped, not dense)
+    # the key and value rows' write), and, in a prompt, the live rows'
+    # gather and combine of the five expert layers beside the compiler's
+    # own grouped-matmul kernels for the experts' TWO products a layer; in
+    # the step, one kernel a layer that reads each touched expert once
     text = compiled.as_text()
     assert sorted(ident for _, ident in KERNEL.findall(text)) == sorted(
-        attention + ["moe.gather", "moe.combine"] * 5)
+        attention + experts)
     assert len(re.findall(r"^\s*(?:ROOT )?%ragged-dot-none\S* = ", text,
-                          re.M)) == 2 * 5
+                          re.M)) == grouped
     ma = compiled.memory_analysis()
     assert (ma.argument_size_in_bytes + ma.output_size_in_bytes
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes) < 16_909_336_064
@@ -709,7 +713,9 @@ def test_served_exaone_compiles_at_the_cells_sizes(
                   jax.tree_util.tree_leaves_with_path(cache) if a.ndim]
         assert sorted(tokens) == sorted(
             [(slots, 128, 8, 128)] * 8 + [(slots, 16384, 8, 128)] * 2)
-        kernels = ["kv.write_rows"] * 10 + ["moe.gather", "moe.combine"] * 4
+        # the held experts read once a layer (parallel/expert.
+        # experts_read_once), no gather, grouped product or combine
+        kernels = ["kv.write_rows"] * 10 + ["moe.decode_experts"] * 4
         limit = 0.5e9
     else:
         assert token_chunks(16384, 6144, 8) == 4
@@ -725,6 +731,133 @@ def test_served_exaone_compiles_at_the_cells_sizes(
     assert ma.temp_size_in_bytes < limit
     assert (ma.argument_size_in_bytes + ma.output_size_in_bytes
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes) < 15e9
+
+
+EXPERT_CELLS = ["imoe-16b-serve-conv-sat", "nm3s-120b-serve-chat-sat",
+                "kex-236b-serve-mixlen-sat"]
+# the compiler's temporaries of each pooled step as the grouped path had
+# them, compiled for the described chip: the sorted buffers are gone
+PARENT_STEP_TEMP_BYTES = {"imoe-16b-serve-conv-sat": 38_465_024,
+                          "nm3s-120b-serve-chat-sat": 23_259_648,
+                          "kex-236b-serve-mixlen-sat": 10_231_296}
+
+
+@pytest.mark.parametrize("workload", EXPERT_CELLS)
+def test_served_expert_step_reads_each_touched_expert_once(
+        one_chip, compiled_steps, workload):
+    # The pooled step of each expert configuration at its file's slots:
+    # ONE moe.decode_experts kernel an expert layer
+    # (parallel/expert.experts_read_once: one token a row, 20 to 68 rows),
+    # called inside the layer's scope `experts`, where
+    # moe_experts_ms_per_decode_step.srv finds it; and nothing of the
+    # grouped path: no gather or combine of sorted rows, no grouped-matmul
+    # kernel (`%ragged-dot...`) and no output mask of one.  The grouped
+    # path's text held 15 `%ragged-dot` instructions in nemotron's step and 16 in
+    # K-EXAONE's; instella's 126 sorted rows went through products DENSE
+    # over all 64 experts, masked by 264 `output_mask` instructions.
+    import json
+    import os
+
+    dmodel, params, _, _ = _served(one_chip, workload)
+    layers = sum(path[-1].key == "router" for path, _ in
+                 jax.tree_util.tree_leaves_with_path(params))
+    assert layers == {"imoe-16b-serve-conv-sat": 8,
+                      "nm3s-120b-serve-chat-sat": 5,
+                      "kex-236b-serve-mixlen-sat": 4}[workload]
+    _, compiled = _compiled_step(compiled_steps, one_chip, workload)
+    text = compiled.as_text()
+    found = [(m.group(1), m.start()) for m in KERNEL.finditer(text)
+             if m.group(2) == "moe.decode_experts"]
+    assert len(found) == layers
+    idents = {ident for _, ident in KERNEL.findall(text)}
+    assert not idents & {"moe.gather", "moe.combine"}
+    assert not re.search(r"^\s*(?:ROOT )?%ragged-dot", text, re.M)
+    assert "output_mask" not in text
+    spec = os.path.join(_harness().BENCH, "layer_metrics",
+                        "moe_experts_ms_per_decode_step.srv.json")
+    with open(spec) as f:
+        scope = re.compile(json.load(f)["args"]["op_name"])
+    for name, at in found:
+        op_name = re.search(r'op_name="([^"]*)"', text[at:at + 6000])[1]
+        assert scope.search(op_name), (name, op_name)
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < PARENT_STEP_TEMP_BYTES[workload])
+
+
+# programs the kernel leaves alone -> the sha256 of their lowered text, the
+# Mosaic kernels' bodies blanked (they carry the caller's source locations),
+# as they lowered before the decode kernel: the same from any checkout path
+UNTOUCHED = {
+    "imoe-16b-serve-conv-sat:prefill64": (
+        "e7e8795a65f1671272cb5233747f001ea0f646ed6b2ea633b5bdea6f2b33889d", 16),
+    "imoe-16b-serve-conv-sat:prefill2048": (
+        "451d01ab2d7da8a9c86a0eaa2bc28a51d2e055b811a20ff4f9892b1bff61a39d", 16),
+    "nm3s-120b-serve-chat-sat:prefill64": (
+        "d653e2f6e8d81bedab48d6f2aa4439f840e684a7089eeee06d3312e64b106681", 11),
+    "nm3s-120b-serve-chat-sat:prefill1024": (
+        "38ecf91fa0fd40800f6dacbae111105e1301be39b33df4a7482137903468c556", 11),
+    "kex-236b-serve-mixlen-sat:prefill64": (
+        "804fa736dbe62bad2c3402350f2844e14f2c43794ef220e0268ce89615dc4b3c", 10),
+    "kex-236b-serve-mixlen-sat:prefill16384": (
+        "661c7dcdf865f80420f52c61afa3e33de4342d44e683b0f7c1e0037d50d5e5bb", 10),
+    "jamba2-3b-serve-reason-sat:step": (
+        "0072520c05a2a8b7d6421d097f763d684bea0a5fc65dc4818d26fcaeb7592e7d", 4),
+    "st-21b-ep4-t8k:train": (
+        "6452a4039d06a0d83e67c1f6f79cb688eea113c0667ed094bced34f58134f16b", 30),
+}
+
+
+@pytest.mark.parametrize("program", list(UNTOUCHED))
+def test_programs_without_a_decode_expert_call_lower_to_the_parents_text(
+        chip, program):
+    # Every prefill of the three expert configurations (a prompt is many
+    # tokens a row: the grouped path), the Jamba step (no expert layer) and
+    # st-21b-ep4-t8k's train step (8,192 tokens a call)
+    # lower for the described chip to their text before the decode kernel:
+    # parallel/expert.experts_read_once leaves them where they were.
+    import hashlib
+
+    from torchmpi_tpu.models.generate import _slot_prefill_jit
+
+    workload, kind = program.split(":")
+    one_chip = SingleDeviceSharding(chip.devices[0])
+    if kind == "train":
+        harness = _harness()
+        manifest = harness.load_manifest()
+        cell = harness.resolve(manifest, workload)
+        mesh = Mesh(np.asarray(chip.devices[:1]).reshape((1, 1)),
+                    mpi.WORLD_AXES)
+        prog = harness.load_module(manifest, "steps",
+                                   cell.config["step"]).programs(cell, mesh)
+        key = jax.ShapeDtypeStruct((2,), np.uint32)
+
+        def put(spec):
+            return lambda a: _sds(a.shape, a.dtype, NamedSharding(mesh, spec))
+
+        state = jax.tree.map(put(P()), jax.eval_shape(prog.init, key))
+        batch = jax.tree.map(put(P(mesh.axis_names)),
+                             jax.eval_shape(prog.batches, key)[0])
+        text = prog.step.jitted.lower(*state, *batch).as_text()
+    elif kind == "step":
+        from torchmpi_tpu.models.generate import _slot_step_jit
+
+        dmodel, params, slots, sampling = _served(one_chip, workload)
+        text = _slot_step_jit.lower(
+            dmodel, params, _pool_cache(dmodel, slots, one_chip),
+            _sds((slots,), jnp.int32, one_chip),
+            _sds((slots,), jnp.int32, one_chip), *sampling(slots),
+            _sds((slots,), jnp.int32, one_chip)).as_text()
+    else:
+        dmodel, params, _, sampling = _served(one_chip, workload)
+        text = _slot_prefill_jit.lower(
+            dmodel, params,
+            _sds((1, int(kind[len("prefill"):])), jnp.int32, one_chip),
+            _sds((), jnp.int32, one_chip), *sampling(1)).as_text()
+    assert "moe.decode_experts" not in text
+    text = re.sub(r"\\22body\\22: \\22[A-Za-z0-9+/=]*\\22", "BODY", text)
+    digest, bodies = UNTOUCHED[program]
+    assert text.count("BODY") == bodies
+    assert hashlib.sha256(text.encode()).hexdigest() == digest
 
 
 def test_smallthinker_step_compiles_at_the_cells_sizes(chip):

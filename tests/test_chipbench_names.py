@@ -218,6 +218,9 @@ SAMPLE_MS = "sample_ms_per_decode_step.srv"
 # what PR 38 added: the per-row cache write's device time a decode step, by
 # the scope the program gives it (``models.transformer.write_rows``)
 KV_WRITE_MS = "kv_write_ms_per_decode_step.srv"
+# the decode expert kernel's share of its roofline, in the three cells
+# whose pooled step has expert layers
+MOE_DECODE = "moe_decode_hbm_roofline_pct.srv"
 # what the window-ring cell added, in order: metric -> (reader, layer,
 # source, unit, better)
 KEX = "kex-236b-serve-mixlen-sat"
@@ -396,8 +399,8 @@ def test_sparse_served_cell_joins_the_lists_whose_readers_are_right_for_it():
     decode step's bytes; one four-chip cell of eight."""
     mine = {m["name"] for m in harness.resolve(MANIFEST, IMOE).per_layer}
     joined = set(SRV_METRICS) - set(IMOE_STAYS_OUT)
-    assert mine == joined | set(IMOE_METRICS) | SPAN_SRV | {SAMPLE_MS,
-                                                           KV_WRITE_MS}
+    assert mine == joined | set(IMOE_METRICS) | SPAN_SRV | {
+        SAMPLE_MS, KV_WRITE_MS, MOE_DECODE}
     for metric in joined:
         assert harness.by_name(MANIFEST["per_layer"], metric,
                                "metric")["workloads"][:2] == [SAT, IMOE]
@@ -456,8 +459,8 @@ def test_hybrid_served_cell_joins_the_lists_whose_readers_are_right_for_it():
     mine = {m["name"] for m in harness.resolve(MANIFEST, NM3).per_layer}
     joined = (set(SRV_METRICS) - set(IMOE_STAYS_OUT)) | set(
         NM3_JOINS_OF_IMOE)
-    assert mine == joined | set(NM3_METRICS) | SPAN_SRV | {SAMPLE_MS,
-                                                          KV_WRITE_MS}
+    assert mine == joined | set(NM3_METRICS) | SPAN_SRV | {
+        SAMPLE_MS, KV_WRITE_MS, MOE_DECODE}
     for metric in joined:
         assert NM3 in harness.by_name(MANIFEST["per_layer"], metric,
                                       "metric")["workloads"]
@@ -609,7 +612,7 @@ def test_window_served_cell_joins_the_lists_whose_readers_are_right_for_it():
     mine = {m["name"] for m in harness.resolve(MANIFEST, KEX).per_layer}
     joined = (set(SRV_METRICS) - set(IMOE_STAYS_OUT)) | set(KEX_JOINS) | (
         SPAN_SRV | {SAMPLE_MS, KV_WRITE_MS})
-    assert mine == joined | set(KEX_METRICS)
+    assert mine == joined | set(KEX_METRICS) | {MOE_DECODE}
     for metric in joined:
         cells = harness.by_name(MANIFEST["per_layer"], metric,
                                 "metric")["workloads"]
@@ -995,6 +998,95 @@ def test_kv_write_metric_reads_no_number_where_the_scope_is_not(
     assert read(ctx, **args) is None
 
 
+def test_moe_decode_metric_resolves_to_a_file_and_the_kernels_identity():
+    entry = harness.by_name(MANIFEST["per_layer"], MOE_DECODE, "metric")
+    assert entry == {
+        "name": MOE_DECODE, "unit": "%", "better": "higher",
+        "source": "device_trace", "layer": "expert layer",
+        "moves": "out_tokens_per_s_chip", "workloads": [IMOE, NM3, KEX]}
+    spec = harness.load_json(MANIFEST, "layer_metrics", MOE_DECODE)
+    assert spec == {"reader": "serve_moe_kernel_roofline", "args": {
+        "module": "jit__slot_step_jit", "kernel": "moe\\.decode_experts"}}
+    for cell in (IMOE, NM3, KEX):
+        mine = harness.by_name(harness.resolve(MANIFEST, cell).per_layer,
+                               MOE_DECODE, "metric")
+        assert (mine["reader"], mine["args"]) == (spec["reader"],
+                                                  spec["args"])
+    # the identity the reader looks for is the one the program's kernel
+    # carries (ops/moe.decode_experts)
+    import jax
+    import jax.numpy as jnp
+    from torchmpi_tpu.ops import moe
+
+    w = jnp.zeros((2, 16, 128), jnp.float32)
+    jaxpr = str(jax.make_jaxpr(lambda x, e, p, i, n: moe.decode_experts(
+        x, e, p, i, n, 0, w, w, w.transpose(0, 2, 1), act=jax.nn.silu))(
+        jnp.zeros((3, 16)), jnp.zeros((3, 2), jnp.int32),
+        jnp.zeros((3, 2)), jnp.zeros((2,), jnp.int32), jnp.int32(1)))
+    (ident,) = re.findall(r"'tm_kernel': '([^']+)'", jaxpr)
+    assert re.search(SCOPES.KERNEL % spec["args"]["kernel"],
+                     f'"tm_kernel":"{ident}"')
+
+
+@pytest.mark.parametrize("cell,expert_bytes", [
+    (IMOE, 2 * 3 * 2048 * 1408), (NM3, 2 * 2 * 1024 * 2688),
+    (KEX, 2 * 3 * 6144 * 2048)])
+def test_moe_decode_reader_counts_the_touched_experts_bytes(
+        monkeypatch, cell, expert_bytes):
+    """No traced step, no counters or no chip: no number and no error;
+    no kernel in the step (the parent): no number.  With them: one expert's
+    matrices at the weights' type for each held expert a step touched,
+    over the kernel's time a step."""
+    from chipbench import flops
+
+    roof = harness.load_module(MANIFEST, "readers",
+                               "serve_moe_kernel_roofline")
+    scoped = harness.load_module(MANIFEST, "readers",
+                                 "serve_scopes_in_module")
+    args = harness.load_json(MANIFEST, "layer_metrics", MOE_DECODE)["args"]
+    ctx = {"cell": harness.resolve(MANIFEST, cell), "platform": "tpu",
+           "kind": "TPU v5 lite", "traced": {"steps": 0},
+           "trace": xplane.Trace({}, {}, (0, 1))}
+    monkeypatch.setattr(roof, "touched_per_step", lambda: 100.0)
+    assert roof.read(ctx, **args) is None                   # no step
+    ctx["traced"]["steps"] = 5
+    monkeypatch.setattr(roof, "touched_per_step", lambda: None)
+    assert roof.read(ctx, **args) is None                   # no counters
+    monkeypatch.setattr(roof, "touched_per_step", lambda: 100.0)
+    assert roof.read({**ctx, "platform": "cpu"}, **args) is None
+    assert roof.read(ctx, **args) is None                   # no device plane
+    seen = {}
+
+    def kernel_ms(ctx, module, op_name=None, name=None, not_name=None):
+        seen.update(module=module, name=name)
+        return 8.0
+
+    monkeypatch.setattr(scoped, "read", kernel_ms)
+    hbm = flops.peak_for("TPU v5 lite")["hbm_bytes_per_s"]
+    assert roof.read(ctx, **args) == pytest.approx(
+        100 * 1e3 * 100.0 * expert_bytes / hbm / 8.0)
+    assert seen == {"module": "jit__slot_step_jit",
+                    "name": '"tm_kernel":"(?:moe\\.decode_experts)"'}
+
+
+def test_moe_decode_metric_reads_no_number_where_the_kernel_is_not(
+        recorded_served, monkeypatch):
+    """The recorded ticks hold no decode expert kernel in the step,
+    so the reader finds the pooled step's executions, nothing of the
+    kernel, and gives no number and no error: what a traced run of the
+    parent reads."""
+    path, trace, modules = recorded_served
+    monkeypatch.setattr(SCOPES, "raw_trace", lambda ctx: path)
+    roof = harness.load_module(MANIFEST, "readers",
+                               "serve_moe_kernel_roofline")
+    monkeypatch.setattr(roof, "touched_per_step", lambda: 100.0)
+    args = harness.load_json(MANIFEST, "layer_metrics", MOE_DECODE)["args"]
+    ctx = {"cell": harness.resolve(MANIFEST, IMOE), "platform": "tpu",
+           "kind": "TPU v5 lite", "trace": trace, "modules": modules,
+           "traced": {"steps": 3}}
+    assert roof.read(ctx, **args) is None
+
+
 def test_the_idle_rules_name_every_program_span_once():
     """The nine ``.srv`` rules are a partition of the program's span names
     and ``outside`` (so their shares add up to the idle share); the three
@@ -1044,6 +1136,7 @@ def test_benchmark_json_only_gained_entries_at_the_end():
     assert names[83:89] == list(JAMBA_METRICS)
     assert names[89:90] == [KV_WRITE_MS]
     assert names[90:93] == list(KEX_METRICS)
+    assert names[93:94] == [MOE_DECODE]
     layers = {m["layer"] for m in MANIFEST["per_layer"][:10]}
     assert {m["layer"] for m in MANIFEST["per_layer"][10:22]} <= layers
     assert {m["layer"] for m in MANIFEST["per_layer"][22:31]} <= layers | {
@@ -1061,6 +1154,7 @@ def test_benchmark_json_only_gained_entries_at_the_end():
     assert MANIFEST["per_layer"][89]["layer"] == ENGINE
     assert {m["layer"] for m in MANIFEST["per_layer"][90:93]} == {
         ENGINE, "expert layer"}
+    assert MANIFEST["per_layer"][93]["layer"] == "expert layer"
     assert [c["name"] for c in MANIFEST["configs"]][:7] == [
         "resnet50", "starcoder2-3b", "smallthinker-21b-a3b",
         "starcoder2-3b-serve", "instella-moe-16b-a3b-serve", NM3_CONFIG,

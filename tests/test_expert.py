@@ -315,3 +315,143 @@ def test_moe_layer_return_aux(flat_runtime):
         jax.device_put(W, NamedSharding(mesh, spec_x)))
     aux = np.asarray(aux)
     assert aux.shape == (8,) and np.isfinite(aux).all() and (aux > 0).all()
+
+
+# ---------------------------------------------------------------------------
+# The held experts of a decode call, each touched expert read once
+# (ops/moe.decode_experts, chosen by parallel/expert.experts_read_once): in
+# interpret mode here, equal to the grouped path within float32
+# accumulation's reordering.
+# ---------------------------------------------------------------------------
+
+
+def _relu2(x):
+    return jnp.square(jax.nn.relu(x))
+
+
+# name: (rows, experts routed over, k, held first, held count, gated, act,
+#        an edit of the router's logits that makes the case)
+READ_ONCE_CASES = {
+    "silu_all_held": (21, 8, 2, 0, 8, True, jax.nn.silu, None),
+    "relu2_held_range": (21, 16, 3, 4, 8, False, _relu2, None),
+    "swiglu_held_share": (21, 16, 2, 8, 4, True, jax.nn.silu, None),
+    # held expert 2 (global 6) chosen by no row
+    "expert_no_row_chose": (21, 16, 2, 4, 8, True, jax.nn.silu,
+                            ("none", 6)),
+    # held expert 1 (global 5) chosen by every row
+    "expert_every_row_chose": (21, 16, 2, 4, 8, True, jax.nn.silu,
+                               ("every", 5)),
+    # row 0's routes all leave the held range
+    "row_routes_all_leave": (21, 16, 3, 4, 8, False, _relu2, ("leave", 0)),
+    "one_row": (1, 16, 3, 4, 8, True, jax.nn.silu, None),
+    "68_rows": (68, 16, 4, 4, 8, False, _relu2, None),
+    # the width in three tiles of 128: the down product summed over them
+    "width_in_tiles": (21, 8, 2, 0, 8, True, jax.nn.silu, ("tiles", 128)),
+}
+
+
+@pytest.mark.parametrize("case", list(READ_ONCE_CASES))
+def test_read_once_equals_the_grouped_path(case, monkeypatch):
+    from torchmpi_tpu.ops import moe
+
+    N, E, k, first, count, gated, act, edit = READ_ONCE_CASES[case]
+    D, F = 32, 384 if edit and edit[0] == "tiles" else 48
+    keys = jax.random.split(jax.random.PRNGKey(41), 5)
+    u = jax.random.normal(keys[0], (N, D), jnp.float32)
+    logits = jax.random.normal(keys[1], (N, E), jnp.float32)
+    w_gate = (jax.random.normal(keys[2], (count, D, F)) / D ** 0.5
+              if gated else None)
+    w_up = jax.random.normal(keys[3], (count, D, F)) / D ** 0.5
+    w_down = jax.random.normal(keys[4], (count, F, D)) / F ** 0.5
+    if edit and edit[0] == "none":
+        logits = logits.at[:, edit[1]].add(-20.0)
+    elif edit and edit[0] == "every":
+        logits = logits.at[:, edit[1]].add(20.0)
+    elif edit and edit[0] == "leave":
+        logits = logits.at[edit[1], first:first + count].add(-20.0)
+    elif edit and edit[0] == "tiles":
+        monkeypatch.setattr(moe, "_EXPERT_STEP_BYTES",
+                            3 * D * edit[1] * 4)
+        assert moe.expert_tile(F, D, 3, 4) == edit[1]
+
+    want, want_stats = ep.held_experts(u, logits, k, first, w_gate, w_up,
+                                       w_down, act=act)
+    got, stats = ep.held_experts(u, logits, k, first, w_gate, w_up, w_down,
+                                 act=act, read_once=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    experts = np.asarray(stats["experts"])
+    np.testing.assert_array_equal(experts, np.asarray(want_stats["experts"]))
+    held = (experts >= first) & (experts < first + count)
+    touched = np.unique(experts[held]).size
+    assert int(stats["routes_held"]) == int(want_stats["routes_held"]) == (
+        held.sum())
+    assert int(stats["rows_computed"]) == touched * N
+    assert int(stats["rows_moved"]) == 0
+    if edit and edit[0] == "none":
+        assert edit[1] not in experts and 0 < touched < count
+    elif edit and edit[0] == "every":
+        assert (experts == edit[1]).any(axis=1).all()
+    elif edit and edit[0] == "leave":
+        assert not held[edit[1]].any() and held.any()
+        assert not np.asarray(got)[edit[1]].any()
+
+
+def test_read_once_rounds_each_experts_result_to_the_rows_type():
+    # bfloat16 rows and weights, as on the chip: each expert's down product
+    # is rounded to bfloat16 before it is weighted, the weighted sum is
+    # float32 and the result bfloat16, as the grouped path's combine does;
+    # the two differ by the rounding of sums taken in another order only
+    N, E, k, D, F = 21, 8, 2, 64, 128
+    keys = jax.random.split(jax.random.PRNGKey(7), 5)
+    bf = jnp.bfloat16
+    u = jax.random.normal(keys[0], (N, D)).astype(bf)
+    logits = jax.random.normal(keys[1], (N, E), jnp.float32)
+    w = [(jax.random.normal(kk, s) / s[1] ** 0.5).astype(bf)
+         for kk, s in zip(keys[2:], [(E, D, F), (E, D, F), (E, F, D)])]
+    want, _ = ep.held_experts(u, logits, k, 0, *w, act=jax.nn.silu)
+    got, _ = ep.held_experts(u, logits, k, 0, *w, act=jax.nn.silu,
+                             read_once=True)
+    assert got.dtype == want.dtype == bf
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    # within two bfloat16 steps of the result's magnitude
+    np.testing.assert_allclose(got, want, rtol=2 ** -7,
+                               atol=2 ** -7 * np.abs(want).max())
+    assert np.mean(got == want) > 0.9
+
+
+def test_touched_experts_compacts_the_held_ones_some_row_chose():
+    experts = jnp.asarray([[5, 1], [9, 7], [5, 12]], jnp.int32)
+    ids, n = ep.touched_experts(experts, 4, 6)        # held [4, 10)
+    assert int(n) == 3
+    assert np.asarray(ids).tolist() == [1, 3, 5, 5, 5, 5]
+    ids, n = ep.touched_experts(experts, 20, 4)       # none held
+    assert int(n) == 0 and np.asarray(ids).tolist() == [0, 0, 0, 0]
+
+
+# (shape the expert layer sees, platform) -> the rule's answer
+READ_ONCE_RULE = {
+    "decode_on_the_chip": ((21, 1, 2048), "tpu", True),
+    "decode_68_slots": ((68, 1, 4096), "tpu", True),
+    "decode_at_the_ridge": ((240, 1, 2048), "tpu", True),
+    "decode_past_the_ridge": ((241, 1, 2048), "tpu", False),
+    "prefill": ((1, 64, 2048), "tpu", False),
+    "verify": ((21, 5, 2048), "tpu", False),
+    "train": ((1, 8192, 2560), "tpu", False),
+    "decode_on_the_cpu": ((21, 1, 2048), "cpu", False),
+}
+
+
+@pytest.mark.parametrize("case", list(READ_ONCE_RULE))
+def test_read_once_rule(case):
+    shape, platform, want = READ_ONCE_RULE[case]
+    assert ep.experts_read_once(shape, platform) is want
+
+
+def test_read_once_rule_bound_is_the_chips_ridge():
+    # 197 TFLOP/s over 819 GB/s: a bfloat16 weight's byte takes one
+    # operation a row
+    assert ep.READ_ONCE_ROWS == 240
+    # the platform the kernels of the library are compiled for: here the
+    # interpreter's, so the grouped path
+    assert not ep.experts_read_once((21, 1, 2048))

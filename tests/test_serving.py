@@ -1051,6 +1051,70 @@ def test_kernel_row_write_serves_the_same_tokens_and_is_counted(
         mpi.stop()
 
 
+_EXPERT_LMS = {}
+
+
+def _expert_lm(held):
+    # gqa_lm's attention beside an expert layer of 8 SwiGLU experts, top-2,
+    # of which ``held`` exist here (programs are keyed by the model)
+    if held not in _EXPERT_LMS:
+        model = TransformerLM(vocab=43, embed=32, depth=2, num_heads=4,
+                              num_kv_heads=2, head_dim=8, max_len=96,
+                              pos_emb="rope", n_experts=8, moe_k=2,
+                              experts_held=held, expert_width=24,
+                              expert_act="silu", dtype=jnp.float32)
+        params = jax.jit(model.init)(jax.random.PRNGKey(5),
+                                     jnp.zeros((1, 4), jnp.int32))["params"]
+        _EXPERT_LMS[held] = model, params
+    return _EXPERT_LMS[held]
+
+
+@pytest.mark.parametrize("held", [None, (2, 4)])
+@pytest.mark.parametrize("spec_k", [0, 4])
+def test_kernel_experts_serve_the_same_tokens_and_are_counted(
+        experts_rule, tmp_path, spec_k, held):
+    # The pooled step's expert layers through ops/moe.decode_experts,
+    # interpreted here, as on the chip (parallel/expert.experts_read_once):
+    # the served tokens are the grouped path's, and the engine counts every
+    # pooled step whose expert layers took the kernel
+    # (stats["expert_kernel_steps"], tm_serving_expert_kernel_steps_total):
+    # each of them where the rule holds.  Where it does not (the CPU's
+    # answer; a draft's verify, [slots, K+1] tokens a call) the count is 0.
+    from torchmpi_tpu import obs
+
+    model, params = _expert_lm(held)
+    grouped_toks, grouped_eng = _serve(model, params, spec_k=spec_k)
+    assert grouped_eng.stats["expert_kernel_steps"] == 0   # the CPU
+
+    experts_rule()
+    mpi.stop()
+    mpi.init(mpi.Config(dcn_size=1, obs="metrics",
+                        obs_dir=str(tmp_path / "obs")))
+    try:
+        obs.reset()
+        toks, eng = _serve(model, params, spec_k=spec_k)
+        assert toks == grouped_toks
+        steps = eng.stats["steps"]
+        assert steps > 0
+        want = 0 if spec_k else steps
+        assert eng.stats["expert_kernel_steps"] == want
+        assert obs.registry().counter_total(
+            "tm_serving_expert_kernel_steps_total") == want
+        assert obs.registry().counter_total(
+            "tm_moe_decode_steps_total") == (0 if spec_k else steps)
+    finally:
+        mpi.stop()
+
+
+def test_models_without_expert_layers_count_no_kernel_steps(
+        gqa_lm, experts_rule):
+    model, params = gqa_lm
+    experts_rule()
+    _, eng = _serve(model, params)
+    assert eng.stats["steps"] > 0
+    assert eng.stats["expert_kernel_steps"] == 0
+
+
 def test_prefix_hit_extend_stays_dense_and_uncounted(gqa_lm, chip_rule):
     # A prefix hit's extend (a suffix at a per-row depth, T > 1) attends
     # against the CACHE: dense on every platform, never counted, and the

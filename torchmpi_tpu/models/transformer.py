@@ -1087,7 +1087,10 @@ class ExpertFFN(nn.Module):
     """Top-k gated expert feed-forward whose weights exist only for the
     experts ``held`` = ``(first, count)`` of ``n_experts`` (None: all): one
     chip's share of an expert-parallel layer, routed over all experts by a
-    float32 router, dropless (``parallel/expert.held_experts``).  What the
+    float32 router, dropless (``parallel/expert.held_experts``; a pooled
+    decode step's call on the chip reads each touched expert once by
+    ``ops/moe.decode_experts``, as ``parallel/expert.experts_read_once``
+    says of its shape).  What the
     experts held elsewhere would add is left out; under an expert axis the
     exchange that brings it in wraps this module.
 
@@ -1164,9 +1167,11 @@ class ExpertFFN(nn.Module):
         if self.latent_width:
             with jax.named_scope("moe_latent_in"):
                 routed_in = dense(self.latent_width, "latent_in")(x)
+        # the rule looked up on its module at trace time: tests put the
+        # chip's answer there
         out, stats = eplib.held_experts(
             routed_in, logits, self.k, first, w_gate, w_up, w_down,
-            gate=gate, act=act)
+            gate=gate, act=act, read_once=eplib.experts_read_once(u.shape))
         if self.latent_width:
             with jax.named_scope("moe_latent_out"):
                 out = dense(E, "latent_out")(out)

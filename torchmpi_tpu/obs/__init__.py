@@ -598,6 +598,9 @@ def record_serving(event: str, n: int = 1, *, replica: str = "") -> None:
     ``kv_write_kernel_calls`` (pooled steps and verifies whose rows went
     into the pool by ``ops/cache_rows``'s kernel,
     ``models.transformer.rows_write_in_place``) |
+    ``expert_kernel_steps`` (pooled decode steps whose every expert layer
+    read its touched experts once, by ``ops/moe.decode_experts``,
+    ``parallel.expert.experts_read_once``) |
     ``sample_argmax`` / ``sample_draw`` (pooled steps by the branch of
     ``models.generate._sample_rows`` their rows asked for: no row
     samples, the argmax | some row samples, the filter and the draw) |

@@ -1,4 +1,5 @@
-"""Row movement of the dropless expert layer, over the LIVE rows only.
+"""Row movement of the dropless expert layer, over the LIVE rows only, and
+the held experts of a decode call (:func:`decode_experts`, at the end).
 
 ``parallel/expert.held_experts`` sorts a token's routes by expert into a
 buffer of ``R = T * k`` rows, the worst case, of which a prefix of
@@ -340,3 +341,142 @@ def tokens_from_rows(src, order, n_live, tokens: int, *, weight=None,
         compiler_params=_params(interpret, 2),
         metadata=ring.kernel_identity("moe.combine"),
     )(*prefetch, src)
+
+
+# ------------------------------------------------------------ decode experts
+
+# What one grid step of ``decode_experts`` may bring of an expert's weights:
+# a whole expert of instella (3 x 2048 x 1408 bf16, 17.3 MB) or of nemotron's
+# latent (2 x 1024 x 2688, 11.0 MB), a quarter of K-EXAONE's (3 x 6144 x 512,
+# 18.9 MB).  Two such blocks are in flight; few steps a call keep the
+# pipeline's per-step cost off the stream of weights.
+_EXPERT_STEP_BYTES = 24 * 1024 * 1024
+
+
+def expert_tile(width: int, door: int, mats: int, itemsize: int) -> int:
+    """Columns of an expert's width ``F`` a grid step of
+    :func:`decode_experts` takes: the largest multiple of 128 that divides
+    ``width`` and keeps a step's ``mats`` matrices (each ``door`` x tile)
+    within ``_EXPERT_STEP_BYTES``, else the whole width."""
+    fits = [t for t in range(ring._LANES, width + 1, ring._LANES)
+            if width % t == 0
+            and mats * door * t * itemsize <= _EXPERT_STEP_BYTES]
+    return max(fits) if fits else width
+
+
+def _decode_kernel(ids_ref, n_ref, x_ref, experts_ref, weights_ref, *refs,
+                   first: int, act, gated: bool, tiles: int):
+    if gated:
+        gate_ref, up_ref, down_ref, out_ref, acc_ref = refs
+    else:
+        up_ref, down_ref, out_ref, acc_ref = refs
+    i, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when((i == 0) & (j == 0))
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    @pl.when(i < n_ref[0])
+    def _():
+        x = x_ref[...]
+
+        def product(a, w_ref):
+            return jnp.dot(a, w_ref[...].astype(x.dtype),
+                           preferred_element_type=jnp.float32)
+
+        hidden = (act(product(x, up_ref)) if not gated
+                  else act(product(x, gate_ref)) * product(x, up_ref))
+        part = product(hidden.astype(x.dtype), down_ref)
+        if tiles == 1:
+            acc_ref[...] = part
+        else:
+            @pl.when(j == 0)
+            def _():
+                acc_ref[...] = part
+
+            @pl.when(j > 0)
+            def _():
+                acc_ref[...] += part
+
+        @pl.when(j == tiles - 1)
+        def _():
+            # each row's weight for this expert: 0 where it did not choose it
+            chose = experts_ref[...] == ids_ref[i] + first
+            p = jnp.sum(jnp.where(chose, weights_ref[...], 0.0), axis=1,
+                        keepdims=True)
+            out_ref[...] += p * acc_ref[...].astype(x.dtype).astype(
+                jnp.float32)
+
+
+def decode_experts(x, experts, weights, ids, n, first: int, w_gate, w_up,
+                   w_down, *, act):
+    """The held experts' part of a top-k expert layer for a call of FEW
+    rows, each touched expert's weights read once (``moe.decode_experts``):
+
+        out[t] = sum_{e in ids[:n]} p[t, e] * round(f_e(x[t]))
+
+    ``x`` [N, D]; ``experts`` and ``weights`` [N, k]: each row's chosen
+    experts (by their global id) and their weights; ``ids`` [count] int32,
+    the held experts (``0 <= id < count``, the weights' own index) that some
+    row chose, ascending, ``n`` of them, the entries past ``n`` repeating
+    the last; ``w_gate``, ``w_up`` [count, D, F], ``w_down`` [count, F, D]
+    (``w_gate`` None: a non-gated expert, ``f_e(x) = w_down act(w_up x)``).
+    ``p[t, e]`` is row t's weight for held expert ``first + e``, 0 where t
+    did not choose it; ``round`` rounds the down product to ``x``'s type.
+    Products in ``x``'s type with float32 accumulation, the gate product
+    through ``act`` in float32, the weighted sum in float32: [N, D].
+
+    The grid runs over (touched expert, tile of F): a step brings the tile
+    of each matrix (:func:`expert_tile`) and computes every row against it,
+    since at a few rows the products cost the weights' bytes and nothing
+    else, and a row's sum reads its own row only.  The rows, their choices
+    and the sums stay in VMEM through the call.  A step past ``n`` keeps
+    the last step's blocks, so the pipeline fetches nothing more: an expert
+    no row chose is never read (with ``n == 0`` the first held expert is
+    fetched once and nothing computed)."""
+    N, D = x.shape
+    count, _, F = w_up.shape
+    gated = w_gate is not None
+    mats = [w for w in (w_gate, w_up, w_down) if w is not None]
+    tile = expert_tile(F, D, len(mats), w_up.dtype.itemsize)
+    tiles = F // tile
+    # whole packed sublanes of rows; a padded row chooses no expert
+    rows = -(-N // 16) * 16
+    pad = ((0, rows - N), (0, 0))
+    x = jnp.pad(x, pad)
+    experts = jnp.pad(experts.astype(jnp.int32), pad, constant_values=-1)
+    weights = jnp.pad(weights.astype(jnp.float32), pad)
+    interpret = _interpreted()
+
+    def step(i, j, ids_ref, n_ref):
+        # past the touched: the last step's blocks again, no fetch
+        return ids_ref[i], jnp.where(i < n_ref[0], j, tiles - 1)
+
+    def in_block(i, j, *scalars):
+        expert, t = step(i, j, *scalars)
+        return expert, 0, t
+
+    def whole(shape):
+        return pl.BlockSpec(shape, lambda i, j, *_: (0, 0),
+                            pipeline_mode=pl.Buffered(1))
+
+    in_tile = pl.BlockSpec((None, D, tile), in_block)
+    out_tile = pl.BlockSpec((None, tile, D),
+                            lambda i, j, *s: (*step(i, j, *s), 0))
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, first=first, act=act, gated=gated,
+                          tiles=tiles),
+        out_shape=jax.ShapeDtypeStruct((rows, D), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(count, tiles),
+            in_specs=[whole(x.shape), whole(experts.shape),
+                      whole(weights.shape)]
+            + [in_tile] * (len(mats) - 1) + [out_tile],
+            out_specs=whole((rows, D)),
+            scratch_shapes=[pltpu.VMEM((rows, D), jnp.float32)]),
+        interpret=interpret,
+        compiler_params=_params(interpret, 2),
+        metadata=ring.kernel_identity("moe.decode_experts"),
+    )(ids.astype(jnp.int32), jnp.reshape(n, (1,)).astype(jnp.int32),
+      x, experts, weights, *mats)
+    return out[:N]

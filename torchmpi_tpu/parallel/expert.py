@@ -20,13 +20,16 @@ EP analog of the reference's allreduce: one collective pair per MoE layer.
 :func:`held_experts` is the layer a device runs when it is TOLD which
 experts it holds (one chip's share of a larger deployment, or the part an
 expert axis's exchange would wrap): it routes over all experts, drops no
-route to a held one, and computes the held experts' part of the result.
+route to a held one, and computes the held experts' part of the result;
+in a call of few rows on the chip (:func:`experts_read_once`: a pooled
+decode step) it reads each touched held expert's weights once, by
+``ops/moe.decode_experts``.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Callable
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -276,8 +279,58 @@ def token_chunks(tokens: int, width: int, k: int) -> int:
     return chunks
 
 
+#: The most rows a call of the held experts may have and still be bound by
+#: its weights' bytes on the chip: a bfloat16 weight (2 bytes) takes one
+#: multiply-add (2 operations) a row, so R rows do R operations a byte, and a
+#: v5e's ridge is its peaks' ratio, 197 TFLOP/s over 819 GB/s (Google
+#: Cloud, "TPU v5e").  Not a setting: what the chip's peaks give.
+READ_ONCE_ROWS = int(197e12 / 819e9)
+
+
+def experts_read_once(shape, platform: Optional[str] = None) -> bool:
+    """How an expert layer's call of this ``shape`` ``[B, T, E]`` computes
+    its held experts: by ``ops/moe.decode_experts``, each touched expert's
+    weights read once (True), or by :func:`held_experts`' sorted buffer and
+    grouped products (False).  THE one rule: ``models.transformer.
+    ExpertFFN`` decides by it at trace time and ``serving/engine.py``
+    counts by it (``stats["expert_kernel_steps"]``: a pooled step whose
+    every expert layer took the kernel).  Not a ``Config`` field or an
+    environment variable: what the code can observe.
+
+    - ``platform`` (None: what ``ops/ring._interpret_mode`` decides, as
+      ``models.transformer.rows_write_in_place`` reads it): the interpreter
+      runs the kernel correctly but slowly, so the CPU keeps the grouped
+      products.
+    - ``shape``: ONE token a row (the pooled decode step, ``[slots, 1, E]``;
+      a prompt, a verify ``[S, K+1]`` and a training call are not), and no
+      more rows than :data:`READ_ONCE_ROWS`, where computing every row
+      against a touched expert costs its weights' bytes and nothing more.
+    """
+    if platform is None:
+        from ..ops import ring
+
+        platform = "cpu" if ring._interpret_mode() else "tpu"
+    B, T = shape[0], shape[1]
+    return platform == "tpu" and T == 1 and B <= READ_ONCE_ROWS
+
+
+def touched_experts(experts, first: int, count: int):
+    """The held experts ``[first, first + count)`` that some row of
+    ``experts`` [T, k] chose, by their index among the held: ``(ids
+    [count] int32, n)``, the ``n`` touched ascending and the entries past
+    them repeating the last (0 when none is)."""
+    held = (experts >= first) & (experts < first + count)
+    chosen = jnp.zeros((count,), jnp.bool_).at[
+        jnp.where(held, experts - first, count)].set(True, mode="drop")
+    n = chosen.sum(dtype=jnp.int32)
+    ids = jnp.nonzero(chosen, size=count, fill_value=0)[0].astype(jnp.int32)
+    last = ids[jnp.maximum(n - 1, 0)]
+    return jnp.where(jnp.arange(count) < n, ids, last), n
+
+
 def held_experts(u, router_logits, k: int, first: int, w_gate, w_up,
-                 w_down, *, gate=softmax_gate, act=jax.nn.relu):
+                 w_down, *, gate=softmax_gate, act=jax.nn.relu,
+                 read_once: bool = False):
     """The held experts' part of a top-k gated expert layer.
 
     u: [T, D] tokens; router_logits: [T, n_experts] over ALL experts;
@@ -332,10 +385,20 @@ def held_experts(u, router_logits, k: int, first: int, w_gate, w_up,
     recomputed, not kept: at the worst case they are ``n_experts / count``
     times what the routes need.
 
+    With ``read_once`` (what :func:`experts_read_once` says of a call:
+    few rows, on the chip) there is no buffer, no grouped product and no
+    combine: ``ops/moe.decode_experts`` reads each held expert that some
+    row chose once (:func:`touched_experts`), computes every row against
+    it and adds each row's weighted, rounded result into its sum.  Same
+    types, same rounding, every route to a held expert computed; the
+    forward pass only (no transpose: a decode step is not differentiated).
+
     ``stats``: ``routes_held`` (routes to a held expert), ``rows_computed``
-    (rows the grouped products are told to compute), ``rows_moved`` (rows
-    of the buffer the dispatch wrote: ``n_live`` rounded up to its block)
-    and ``experts`` ([T, k] chosen experts), all computed on the device.
+    (rows the grouped products are told to compute; with ``read_once`` the
+    rows times the experts touched, which the kernel computes),
+    ``rows_moved`` (rows of the buffer the dispatch wrote: ``n_live``
+    rounded up to its block; 0 with ``read_once``, which moves none) and
+    ``experts`` ([T, k] chosen experts), all computed on the device.
     """
     n_experts, count = router_logits.shape[-1], w_up.shape[0]
     if not (0 <= first and first + count <= n_experts and 1 <= k <= n_experts):
@@ -361,6 +424,16 @@ def held_experts(u, router_logits, k: int, first: int, w_gate, w_up,
         experts, weights = gate(router_logits, k)
         held = (experts >= first) & (experts < first + count)
         group = jnp.where(held, experts - first, count)
+    if read_once:
+        with jax.named_scope("dispatch"):
+            ids, n = touched_experts(experts, first, count)
+        with jax.named_scope("experts"):
+            out = moe.decode_experts(u, experts, weights, ids, n, first,
+                                     w_gate, w_up, w_down, act=act)
+        return out.astype(u.dtype), {
+            "routes_held": held.sum(dtype=jnp.int32),
+            "rows_computed": n * u.shape[0], "rows_moved": jnp.int32(0),
+            "experts": experts}
     with jax.named_scope("dispatch"):
         # Routes are laid out rank-major, [k, T] flat, so that the stable
         # sort keeps a rank's tokens in order within an expert.

@@ -57,7 +57,10 @@ of the programs that were handed the pool took it over
 (``stats["pool_donated"]`` of ``stats["pool_calls"]``), the pooled steps
 and verifies whose rows went into the pool by ``ops/cache_rows``'s kernel
 (``stats["kv_write_kernel_calls"]``, by the layer's own rule,
-``models.transformer.rows_write_in_place``), which branch
+``models.transformer.rows_write_in_place``), the pooled steps whose
+every expert layer read its touched experts once by
+``ops/moe.decode_experts`` (``stats["expert_kernel_steps"]``, by the
+layer's own rule, ``parallel.expert.experts_read_once``), which branch
 of the sampling tail each pooled step's sessions ask for
 (:data:`SAMPLE_BRANCHES`: ``stats["sample_argmax"]`` +
 ``stats["sample_draw"]`` = ``stats["steps"]``), how many live sessions
@@ -94,6 +97,7 @@ from ..models.generate import STATE_LEAVES, slot_cache_slice, \
     slot_cache_write, slot_decode_step, slot_extend, slot_prefill, \
     slot_verify_step, slot_write
 from ..models import transformer
+from ..parallel import expert as eplib
 from ..utils.telemetry import emit
 from .prefix_cache import PrefixCache
 from .slots import SlotPool
@@ -295,6 +299,10 @@ class ReplicaEngine:
             for path in sorted(flatten_dict(params))
             if path[-1] == "router"]
         self._expert_steps = count("tm_moe_decode_steps_total", replica=name)
+        # an expert layer sees [slots, 1, what its router reads] in a step
+        self._expert_widths = [
+            leaf.shape[0] for path, leaf in flatten_dict(params).items()
+            if path[-1] == "router"]
 
     def _init_serving(self, cfg, name, slots, st, *, sample,
                       prefill_bucket, spec_k, draft,
@@ -321,6 +329,9 @@ class ReplicaEngine:
         #: The window of each layer whose keys and values are a ring (the
         #: dense engine reads them off its pool's shapes).
         self._ring_windows: List[int] = []
+        #: The width of each expert layer's input (the dense engine reads
+        #: them off its routers), for ``stats["expert_kernel_steps"]``.
+        self._expert_widths: List[int] = []
         self.dead = False
         #: The open sessions, by slot: each one's final token is unread.
         self._sessions: Dict[int, Session] = {}
@@ -364,7 +375,7 @@ class ReplicaEngine:
                       "prefill_kernel_tokens": 0,
                       "prefix_hits": 0, "prefix_misses": 0,
                       "pool_calls": 0, "pool_donated": 0,
-                      "kv_write_kernel_calls": 0,
+                      "kv_write_kernel_calls": 0, "expert_kernel_steps": 0,
                       "live_slot_steps": 0, "window_rows_live": 0,
                       "steps_ahead": 0, "rows_past_end": 0,
                       **dict.fromkeys(SAMPLE_BRANCHES, 0)}
@@ -828,7 +839,19 @@ class ReplicaEngine:
         if last is not None:
             self.stats["steps_ahead"] += 1
             emit("record_serving", "steps_ahead", replica=self.name)
+        if self._experts_read_once():
+            self.stats["expert_kernel_steps"] += 1
+            emit("record_serving", "expert_kernel_steps", replica=self.name)
         self._unread.append(_Unread(tokens, self._step_counts, live))
+
+    def _experts_read_once(self) -> bool:
+        """Whether a pooled step's every expert layer reads its touched
+        experts once, by ``ops/moe.decode_experts``: the layer's own rule
+        (``parallel.expert.experts_read_once``, looked up at call time, as
+        the layer does) over the ``[slots, 1, width]`` each layer sees."""
+        S = self.pool.n_slots
+        return bool(self._expert_widths) and all(
+            eplib.experts_read_once((S, 1, w)) for w in self._expert_widths)
 
     def _read_step(self) -> Tuple[List[Session], List[Session]]:
         """Read the oldest unread step and book its tokens to the sessions
