@@ -400,9 +400,8 @@ def main() -> int:
         # excluded.  fwd FLOPs/token = 2*P_mm plus causal attention
         # 2*T*E per layer (QK^T + AV, halved by the mask); train step =
         # 3x fwd (bwd is ~2x fwd).
-        from torchmpi_tpu.models.transformer import Block
         E_lm, L_lm = lm.embed, lm.depth
-        p_mm = (L_lm * (4.0 + 2.0 * Block.mlp_ratio) * E_lm * E_lm
+        p_mm = (L_lm * (4.0 + 8.0) * E_lm * E_lm
                 + E_lm * lm.vocab)
         lm_flops = 3.0 * (Bt * T) * (2.0 * p_mm + L_lm * 2.0 * T * E_lm)
         # PER-STEP time with PER-STEP flops: XLA's cost_analysis counts

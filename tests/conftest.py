@@ -100,8 +100,8 @@ def hier_runtime():
 def chip_rule(monkeypatch):
     """Call it to make a ``decode=True`` prompt block attend as on the chip:
     through the flash forward kernel, interpreted here
-    (``models.transformer.prefill_runs_flash``; the layer and the serving
-    engine both look the rule up on that module).  The jitted programs are
+    (``models.transformer.prefill_runs_flash``; the layer looks the rule
+    up on that module at trace time).  The jitted programs are
     keyed by the model and the shapes, not by the rule, so what was traced
     under one answer must not serve the other: the caches go, at the switch
     and after the test."""
@@ -124,9 +124,10 @@ def chip_rule(monkeypatch):
 def rows_rule(monkeypatch):
     """Call it to make a decode call with per-row positions write its rows
     into the pool as on the chip: by ``ops/cache_rows``'s kernel where the
-    leaf is whole tiles to it, interpreted here (``models.transformer.rows_write_in_place``; the layer
-    and the serving engine both look the rule up on that module).  The
-    caches go at the switch and after the test, as with :func:`chip_rule`."""
+    leaf is whole tiles to it, interpreted here
+    (``models.transformer.rows_write_in_place``; the layer looks the rule
+    up on that module at trace time).  The caches go at the switch and
+    after the test, as with :func:`chip_rule`."""
     from torchmpi_tpu.models import transformer
 
     rule = transformer.rows_write_in_place
@@ -145,9 +146,9 @@ def rows_rule(monkeypatch):
 def experts_rule(monkeypatch):
     """Call it to make an expert layer's call compute its held experts as on
     the chip: by ``ops/moe.decode_experts`` where the call's shape says so,
-    interpreted here (``parallel.expert.experts_read_once``; the layer and
-    the serving engine both look the rule up on that module).  The caches
-    go at the switch and after the test, as with :func:`chip_rule`."""
+    interpreted here (``parallel.expert.experts_read_once``; the layer
+    looks the rule up on that module at trace time).  The caches go at
+    the switch and after the test, as with :func:`chip_rule`."""
     from torchmpi_tpu.parallel import expert
 
     rule = expert.experts_read_once

@@ -155,11 +155,13 @@ def test_flash_compiles_at_the_cells_shapes_under_its_module(one_chip, case):
     # instructions are then named after the flax module (SPAttention_0),
     # which flash_roofline_pct.tok's pattern leans on, and fused xent's
     # pattern must not find them.
-    from torchmpi_tpu.models.transformer import Block
+    from torchmpi_tpu.models.transformer import Block, TransformerLM
 
     batch, seq = SC2_CASES[case]
-    block = Block(24, 128, attn_impl="flash", dtype=jnp.bfloat16,
-                  window=4096, num_kv_heads=2, rope=True)
+    layer, = TransformerLM(embed=3072, depth=1, num_heads=24, head_dim=128,
+                           num_kv_heads=2, window=4096,
+                           pos_emb="rope").layers()
+    block = Block(layer, dtype=jnp.bfloat16, attn_impl="flash")
     x = _sds((batch, seq, 3072), jnp.bfloat16, one_chip)
     params = jax.tree.map(
         lambda a: _sds(a.shape, a.dtype, one_chip),
@@ -385,6 +387,18 @@ def _pool_cache(dmodel, slots, one_chip):
             pos_offset=jnp.zeros((slots,), jnp.int32)))["cache"])
 
 
+def _leaves_keeping(workload, cache, *kinds):
+    """The leaves of a served cell's pool whose layer keeps one of ``kinds``
+    (what the model says of it: ``transformer.Layer.cache``)."""
+    harness = _harness()
+    cell = harness.resolve(harness.load_manifest(), workload)
+    layers = harness.build_model(cell).clone(
+        max_len=cell.config["serving"]["slot_tokens"]).layers()
+    kind = {f"Block_{i}": layer.cache for i, layer in enumerate(layers)}
+    return [a for path, a in jax.tree_util.tree_leaves_with_path(cache)
+            if a.ndim and kind[path[0].key] in kinds]
+
+
 @pytest.fixture(scope="module")
 def compiled_steps():
     """The served cells' pooled steps as compiled for the described chip, by
@@ -455,19 +469,15 @@ def test_served_decode_step_updates_the_pool_in_place(
     # at PR 33, 44 at PR 34, none since PR 38: the next test), and the
     # convolution taps, [slots, 3, 10240],
     # which shift by one and so cannot be written over what is still read.
-    from torchmpi_tpu.models.generate import STATE_LEAVES
-
     cache, compiled = _compiled_step(compiled_steps, one_chip, workload)
-    leaves = jax.tree_util.tree_leaves_with_path(cache)
-    pool_bytes = sum(a.size * a.dtype.itemsize for _, a in leaves)
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
     ma = compiled.memory_analysis()
     assert ma.alias_size_in_bytes >= pool_bytes > 1.2e9
     assert ma.output_size_in_bytes - ma.alias_size_in_bytes < 1e6
     names = {"float32": "f32", "bfloat16": "bf16"}
     token_leaves = {
         "%s[%s]" % (names[a.dtype.name], ",".join(map(str, a.shape)))
-        for path, a in leaves
-        if a.ndim and path[-1].key not in STATE_LEAVES}
+        for a in _leaves_keeping(workload, cache, "tokens", "ring")}
     assert token_leaves
     copied = re.findall(r"^\s*(?:ROOT )?%copy[\w.]* = (\w+\[[\d,]*\])\S* "
                         r"copy\(", compiled.as_text(), re.M)
@@ -503,12 +513,10 @@ def test_served_decode_step_writes_its_rows_in_place(
     # model's leaves ([slots, tokens, 512] and [slots, tokens, 32]: a token
     # one row of a tiled axis) keep vmap's loop: ISSUE 38's criterion is
     # not met there (PERF.md section 7, PR 38).
-    from torchmpi_tpu.models.generate import STATE_LEAVES
     from torchmpi_tpu.models.transformer import rows_write_in_place
 
     cache, compiled = _compiled_step(compiled_steps, one_chip, workload)
-    leaves = [a for path, a in jax.tree_util.tree_leaves_with_path(cache)
-              if a.ndim and path[-1].key not in STATE_LEAVES]
+    leaves = _leaves_keeping(workload, cache, "tokens", "ring")
     taken = [a for a in leaves if rows_write_in_place(a.shape, "tpu")]
     assert (workload == "imoe-16b-serve-conv-sat") == (taken == [])
     names = {"float32": "f32", "bfloat16": "bf16"}
@@ -586,13 +594,12 @@ def test_served_hybrid_compiles_at_the_cells_sizes(
     # smallest and the largest prefill bucket of the cell's traffic and the
     # pooled step at the file's slots fit the chip; the step's cache has the
     # two state leaves a mixer and no token axis on them.
-    from torchmpi_tpu.models.generate import STATE_LEAVES, _slot_prefill_jit
+    from torchmpi_tpu.models.generate import _slot_prefill_jit
 
     if program == "step":
         cache, compiled = _compiled_step(compiled_steps, one_chip,
                                          "nm3s-120b-serve-chat-sat")
-        state = [a for path, a in jax.tree_util.tree_leaves_with_path(cache)
-                 if path[-1].key in STATE_LEAVES]
+        state = _leaves_keeping("nm3s-120b-serve-chat-sat", cache, "state")
         slots = state[0].shape[0]
         assert sorted(a.shape for a in state) == sorted(
             [(slots, 128, 64, 128), (slots, 3, 10240)] * 5)
@@ -641,7 +648,7 @@ def test_served_jamba_compiles_at_the_cells_sizes(
     # nothing of [T, 16, 5120] (335 MB a layer): the prefill's temporaries
     # stay under 0.6 GB.
     from chipbench import flops_jamba_serve as counts
-    from torchmpi_tpu.models.generate import STATE_LEAVES, _slot_prefill_jit
+    from torchmpi_tpu.models.generate import _slot_prefill_jit
 
     harness = _harness()
     workload = "jamba2-3b-serve-reason-sat"
@@ -653,15 +660,13 @@ def test_served_jamba_compiles_at_the_cells_sizes(
         **sizes) == 3_029_337_472
     if program == "step":
         cache, compiled = _compiled_step(compiled_steps, one_chip, workload)
-        leaves = jax.tree_util.tree_leaves_with_path(cache)
-        state = [a for path, a in leaves if path[-1].key in STATE_LEAVES]
+        state = _leaves_keeping(workload, cache, "state")
         assert sorted(a.shape for a in state) == sorted(
             [(slots, 16, 5120), (slots, 3, 5120)] * 26)
         assert sum(a.size * a.dtype.itemsize for a in state) == (
             slots * counts.state_bytes_per_slot(**sizes)) == (
                 slots * 10_117_120)
-        tokens = [a for path, a in leaves
-                  if a.ndim and path[-1].key not in STATE_LEAVES]
+        tokens = _leaves_keeping(workload, cache, "tokens", "ring")
         assert sorted(a.shape for a in tokens) == [
             (slots, cfg["serving"]["slot_tokens"], 1, 128)] * 4
         attention, limit = ["kv.write_rows"] * 4, 1.0e9

@@ -156,13 +156,7 @@ def test_post_norm_and_qk_norm_match_the_reference(weights, where):
     if where == "block":
         # the global expert layer alone (layer 3: no window, no rotation)
         p = weights["Block_3"]
-        got = transformer.Block(
-            4, 16, num_kv_heads=2, norm="rmsnorm", norm_eps=EPS,
-            use_bias=False, n_experts=ROUTER, experts_held=(0, HELD),
-            moe_k=K, expert_width=32, expert_act="silu",
-            expert_gate="sigmoid", route_scale=SCALE, shared_width=32,
-            router_reads="ffn_input", post_norm=True, qk_norm=True).apply(
-                {"params": p}, x)
+        got = transformer.Block(model().layers()[3]).apply({"params": p}, x)
         want = REF._block(p, x[0], window=0, rotated=False, rope_base=BASE,
                           eps=EPS, kv_bf16=False)
         assert np.abs(np.asarray(got[0]) - np.asarray(want)).max() < 1e-4
@@ -197,9 +191,11 @@ class _BlockAsItWas(nn.Module):
 
 def test_a_block_that_names_neither_keeps_its_tree_and_its_program():
     x = jax.random.normal(jax.random.PRNGKey(6), (2, 12, 64))
-    block = transformer.Block(4, 16, num_kv_heads=2, rope=True,
-                              norm="rmsnorm", norm_eps=EPS, use_bias=False,
-                              mlp="swiglu", mlp_width=128)
+    lm = transformer.TransformerLM(
+        embed=64, depth=1, num_heads=4, head_dim=16, num_kv_heads=2,
+        pos_emb="rope", norm="rmsnorm", norm_eps=EPS, use_bias=False,
+        mlp="swiglu", mlp_width=128)
+    block = transformer.Block(lm.layers()[0])
     params = block.init(jax.random.PRNGKey(7), x)["params"]
     was = _BlockAsItWas()
     assert jax.tree.structure(params) == jax.tree.structure(
@@ -209,8 +205,8 @@ def test_a_block_that_names_neither_keeps_its_tree_and_its_program():
         np.asarray(block.apply({"params": params}, x)),
         np.asarray(was.apply({"params": params}, x)))
     # both flags: one norm a side of q and k, the block's two now after
-    flagged = block.clone(post_norm=True, qk_norm=True).init(
-        jax.random.PRNGKey(7), x)["params"]
+    (both,) = lm.clone(post_norm=True, qk_norm=True).layers()
+    flagged = transformer.Block(both).init(jax.random.PRNGKey(7), x)["params"]
     assert set(flagged) == set(params)
     assert set(flagged["SPAttention_0"]) == set(params["SPAttention_0"]) | {
         "RMSNorm_0", "RMSNorm_1"}

@@ -253,7 +253,9 @@ class _BlockAsItWas(nn.Module):
 
 def test_farskip_off_is_the_block_as_it_was_and_on_follows_the_recurrence():
     x = jax.random.normal(jax.random.PRNGKey(6), (2, 12, 32))
-    block = transformer.Block(4, 8, rope=True)
+    lm = transformer.TransformerLM(embed=32, depth=1, num_heads=4,
+                                   head_dim=8, pos_emb="rope")
+    block = transformer.Block(lm.layers()[0])
     params = block.init(jax.random.PRNGKey(7), x)["params"]
     was = _BlockAsItWas(4, 8)
     assert (jax.tree.structure(params) == jax.tree.structure(
@@ -262,7 +264,7 @@ def test_farskip_off_is_the_block_as_it_was_and_on_follows_the_recurrence():
         np.asarray(block.apply({"params": params}, x)),
         np.asarray(was.apply({"params": params}, x)))
     # on: r_s = r_(s-1) + f_s(norm_s(r_(s-2))), r_1 = r_0 + f_1(norm_1(r_0))
-    far = transformer.Block(4, 8, rope=True, farskip=True)
+    far = transformer.Block(lm.clone(farskip=True).layers()[0])
     p1, p2 = (block.init(jax.random.PRNGKey(s), x)["params"] for s in (8, 9))
 
     def subs(p):

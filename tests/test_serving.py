@@ -765,7 +765,7 @@ def test_tp_sharded_server_matches_tp_oracle(tmp_path):
     for a in admits:
         kids = _children(spans, a)
         assert [k.name for k in kids] == ADMIT_CHILDREN
-        assert kids[1].stats == {"padded_tokens": 5, "kernel": 0}
+        assert kids[1].stats == {"padded_tokens": 5}
 
     # Speculation + bucketing over the SAME sharded stack: bitwise.
     reqs2 = [serving.Request(f"t{i}", prompts[i], max_new=lens[i])
@@ -965,34 +965,75 @@ def _serve(model, params, **kw):
     return {r.rid: r.tokens for r in reqs}, srv.router.replicas[0]
 
 
-def test_kernel_prefill_serves_the_dense_tokens_and_is_counted(
-        gqa_lm, chip_rule, tmp_path):
-    from torchmpi_tpu import obs
+def _kernels(program, *args):
+    """The ``tm_kernel`` of every kernel call in the trace of ``program`` (a
+    jitted ``models.generate._slot_*_jit``) over ``args``, sorted: each call
+    site of a shared inner jaxpr counts for itself (the layers share one
+    trace of the prompt's kernel)."""
+    def found(jaxpr):
+        names = []
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                names.append(eqn.params["metadata"]["tm_kernel"])
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                names += found(sub)
+        return names
 
+    return sorted(found(program.trace(*args).jaxpr.jaxpr))
+
+
+def _prefill_kernels(eng, bucket, depth=0):
+    """The kernels of the program ``eng`` prefills a prompt padded to
+    ``bucket`` with; with ``depth``, of a prefix hit's extend of a suffix
+    that long over a row assembled ``depth`` deep."""
+    from torchmpi_tpu.models.generate import (
+        _greedy_sampling, _slot_extend_jit, _slot_prefill_jit)
+
+    prompt, n = jnp.zeros((1, bucket), jnp.int32), jnp.int32(bucket)
+    if depth:
+        return _kernels(_slot_extend_jit, eng.dmodel, eng.params,
+                        eng._row_template(), prompt,
+                        jnp.asarray([depth], jnp.int32), n,
+                        *_greedy_sampling(1))
+    return _kernels(_slot_prefill_jit, eng.dmodel, eng.params, prompt, n,
+                    *_greedy_sampling(1))
+
+
+def _pooled_kernels(eng, spec_k=0):
+    """The kernels of the pooled program ``eng`` dispatches over its pool:
+    the step, or with drafts the ``[slots, spec_k + 1]`` verify."""
+    from torchmpi_tpu.models.generate import (
+        _greedy_sampling, _slot_step_jit, _slot_verify_jit)
+
+    S = eng.pool.n_slots
+    rows = jnp.zeros((S,), jnp.int32)
+    head = (eng.dmodel, eng.params, eng._cache)
+    if spec_k:
+        return _kernels(_slot_verify_jit, *head,
+                        jnp.zeros((S, spec_k + 1), jnp.int32), rows,
+                        *_greedy_sampling(S))
+    return _kernels(_slot_step_jit, *head, rows, rows, *_greedy_sampling(S),
+                    rows)
+
+
+def test_kernel_prefill_serves_the_dense_tokens_and_is_counted(
+        gqa_lm, chip_rule):
+    # The prompt's attention through the flash forward kernel, interpreted
+    # here, as on the chip (models/transformer.prefill_runs_flash): the
+    # served tokens are the dense form's, and the programs the engine
+    # prefilled with hold the kernel, one call a layer, at each bucket
     model, params = gqa_lm
     dense_toks, dense_eng = _serve(model, params)
     # padded: 19 -> 32, 37 -> 64, 21 -> 32, 56 -> 64
     assert dense_eng.stats["prefill_tokens"] == 192
-    assert dense_eng.stats["prefill_kernel_tokens"] == 0   # the CPU: dense
+    assert _prefill_kernels(dense_eng, 32) == []        # the CPU: dense
 
     chip_rule()
-    mpi.stop()
-    mpi.init(mpi.Config(dcn_size=1, obs="metrics",
-                        obs_dir=str(tmp_path / "obs")))
-    try:
-        obs.reset()
-        toks, eng = _serve(model, params)
-        assert toks == dense_toks
-        assert (eng.stats["prefill_kernel_tokens"]
-                == eng.stats["prefill_tokens"] == 192)
-        assert obs.registry().counter_total(
-            "tm_serving_prefill_kernel_tokens_total") == 192
-        # the count only rises, by the padded length of each admission
-        eng.admit(serving.Request("more", np.arange(9, dtype=np.int32),
-                                  max_new=2))
-        assert eng.stats["prefill_kernel_tokens"] == 192 + 32
-    finally:
-        mpi.stop()
+    toks, eng = _serve(model, params)
+    assert toks == dense_toks
+    assert eng.stats["prefill_tokens"] == 192
+    for bucket in (32, 64):
+        assert _prefill_kernels(eng, bucket) == ["flash.fwd"] * 2
 
 
 _ROW_WRITE_LMS = {}
@@ -1013,42 +1054,29 @@ def _row_write_lm(head_dim):
 @pytest.mark.parametrize("head_dim", [128, 8])
 @pytest.mark.parametrize("spec_k", [0, 4])
 def test_kernel_row_write_serves_the_same_tokens_and_is_counted(
-        rows_rule, tmp_path, spec_k, head_dim):
+        rows_rule, spec_k, head_dim):
     # The pooled step's (and, with drafts, the verify's) per-row cache write
     # through ops/cache_rows's kernel, interpreted here, as on the chip
     # (models/transformer.rows_write_in_place): the served tokens are the
-    # vmap form's bit for bit, and the engine counts every such call
-    # (stats["kv_write_kernel_calls"], tm_serving_kv_write_kernel_calls_total):
-    # each call that took the pool except the admissions' slot writes.  A
-    # head of 8 is no whole row of lanes: the rule keeps vmap's form there,
-    # and the engine, reading the same rule over the pool's leaves, counts
-    # nothing.  The window of 12 makes each layer's keys and values a ring
-    # of 12 rows (written at the position mod 12), which speculation cannot
-    # serve: with drafts the same model attends over the whole slot.
-    from torchmpi_tpu import obs
-
+    # vmap form's bit for bit, and the pooled program the engine dispatched
+    # holds the kernel for each key and value leaf of each layer.  A head
+    # of 8 is no whole row of lanes: the rule keeps vmap's form there, and
+    # the program holds no kernel.  The window of 12 makes each layer's
+    # keys and values a ring of 12 rows (written at the position mod 12),
+    # which speculation cannot serve: with drafts the same model attends
+    # over the whole slot.
     model, params = _row_write_lm(head_dim)
     if spec_k:
         model = model.clone(window=None)
     xla_toks, xla_eng = _serve(model, params, spec_k=spec_k)
-    assert xla_eng.stats["kv_write_kernel_calls"] == 0   # the CPU: vmap
+    assert _pooled_kernels(xla_eng, spec_k) == []       # the CPU: vmap
 
     rows_rule()
-    mpi.stop()
-    mpi.init(mpi.Config(dcn_size=1, obs="metrics",
-                        obs_dir=str(tmp_path / "obs")))
-    try:
-        obs.reset()
-        toks, eng = _serve(model, params, spec_k=spec_k)
-        assert toks == xla_toks
-        calls = eng.stats["pool_calls"] - eng.stats["prefills"]
-        assert calls > 0
-        want = calls if head_dim == 128 else 0
-        assert eng.stats["kv_write_kernel_calls"] == want
-        assert obs.registry().counter_total(
-            "tm_serving_kv_write_kernel_calls_total") == want
-    finally:
-        mpi.stop()
+    toks, eng = _serve(model, params, spec_k=spec_k)
+    assert toks == xla_toks
+    assert eng.stats["pool_calls"] > eng.stats["prefills"]
+    want = ["kv.write_rows"] * 4 if head_dim == 128 else []
+    assert _pooled_kernels(eng, spec_k) == want
 
 
 _EXPERT_LMS = {}
@@ -1075,16 +1103,17 @@ def test_kernel_experts_serve_the_same_tokens_and_are_counted(
         experts_rule, tmp_path, spec_k, held):
     # The pooled step's expert layers through ops/moe.decode_experts,
     # interpreted here, as on the chip (parallel/expert.experts_read_once):
-    # the served tokens are the grouped path's, and the engine counts every
-    # pooled step whose expert layers took the kernel
-    # (stats["expert_kernel_steps"], tm_serving_expert_kernel_steps_total):
-    # each of them where the rule holds.  Where it does not (the CPU's
-    # answer; a draft's verify, [slots, K+1] tokens a call) the count is 0.
+    # the served tokens are the grouped path's, and the pooled step the
+    # engine dispatched holds the kernel once an expert layer.  Where the
+    # rule does not hold (the CPU's answer; a draft's verify, [slots, K+1]
+    # tokens a call) the program holds none (the grouped path's own
+    # kernels, moe.gather and moe.combine, instead).
     from torchmpi_tpu import obs
 
     model, params = _expert_lm(held)
     grouped_toks, grouped_eng = _serve(model, params, spec_k=spec_k)
-    assert grouped_eng.stats["expert_kernel_steps"] == 0   # the CPU
+    read_once = "moe.decode_experts"
+    assert read_once not in _pooled_kernels(grouped_eng, spec_k)  # the CPU
 
     experts_rule()
     mpi.stop()
@@ -1096,30 +1125,19 @@ def test_kernel_experts_serve_the_same_tokens_and_are_counted(
         assert toks == grouped_toks
         steps = eng.stats["steps"]
         assert steps > 0
-        want = 0 if spec_k else steps
-        assert eng.stats["expert_kernel_steps"] == want
-        assert obs.registry().counter_total(
-            "tm_serving_expert_kernel_steps_total") == want
+        assert _pooled_kernels(eng, spec_k).count(read_once) == (
+            0 if spec_k else 2)
         assert obs.registry().counter_total(
             "tm_moe_decode_steps_total") == (0 if spec_k else steps)
     finally:
         mpi.stop()
 
 
-def test_models_without_expert_layers_count_no_kernel_steps(
-        gqa_lm, experts_rule):
-    model, params = gqa_lm
-    experts_rule()
-    _, eng = _serve(model, params)
-    assert eng.stats["steps"] > 0
-    assert eng.stats["expert_kernel_steps"] == 0
-
-
 def test_prefix_hit_extend_stays_dense_and_uncounted(gqa_lm, chip_rule):
     # A prefix hit's extend (a suffix at a per-row depth, T > 1) attends
-    # against the CACHE: dense on every platform, never counted, and the
-    # tokens are the full kernel prefill's.  (Without the window: a ring
-    # has no token axis to cut prefix fragments from.)
+    # against the CACHE: dense on every platform, its program holds no
+    # kernel, and the tokens are the full kernel prefill's.  (Without the
+    # window: a ring has no token axis to cut prefix fragments from.)
     model, params = gqa_lm
     model = model.clone(window=None)
     chip_rule()
@@ -1128,29 +1146,10 @@ def test_prefix_hit_extend_stays_dense_and_uncounted(gqa_lm, chip_rule):
     assert toks == miss_toks
     assert eng.stats["prefix_hits"] == 3
     assert eng.stats["prefill_tokens"] > 32
-    assert eng.stats["prefill_kernel_tokens"] == 32   # the one miss
-
-
-def test_latent_and_tensor_parallel_prefills_count_no_kernel_tokens(
-        monkeypatch):
-    # Their prefills are their own (LatentAttention expands keys and values
-    # per head; tp_generate._block_prefill is dense): the rule is
-    # SPAttention's and the engine asks it for no other layer.
-    import types
-
-    from torchmpi_tpu.models import transformer
-    from torchmpi_tpu.serving.engine import ReplicaEngine
-    from torchmpi_tpu.serving.tp_engine import TPReplicaEngine
-
-    monkeypatch.setattr(transformer, "prefill_runs_flash",
-                        lambda T, per_row, platform=None: True)
-    dense = TransformerLM(vocab=43, embed=32, depth=1, num_heads=4,
-                          head_dim=8, max_len=32, pos_emb="rope")
-    latent = dense.clone(kv_rank=16, rope_dim=4, v_dim=8)
-    holds = lambda dmodel: types.SimpleNamespace(dmodel=dmodel)  # noqa: E731
-    assert ReplicaEngine._prefill_runs_flash(holds(dense), 64)
-    assert not ReplicaEngine._prefill_runs_flash(holds(latent), 64)
-    assert not TPReplicaEngine._prefill_runs_flash(holds(None), 64)
+    # the one miss's prefill (19 tokens in a bucket of 32) takes the kernel,
+    # an extend of 16 shared tokens' suffix does not
+    assert _prefill_kernels(eng, 32) == ["flash.fwd"] * 2
+    assert _prefill_kernels(eng, 32, depth=16) == []
 
 
 # ---------------------------------------------------------------------------
@@ -1440,8 +1439,7 @@ def test_a_served_trace_writes_the_span_tree(lm, tmp_path):
         kids = _children(spans, a)
         assert [k.name for k in kids] == ADMIT_CHILDREN and _in_order(kids)
         assert _inside(spans, a) == kids
-        assert kids[1].stats == {"padded_tokens": padded.shape[1],
-                                 "kernel": 0}
+        assert kids[1].stats == {"padded_tokens": padded.shape[1]}
     # one step a call of step(), with the pooled steps the engine counted
     # before it and its open sessions; each dispatches what it counts (two
     # the first, on the idle pool) and reads one

@@ -114,12 +114,11 @@ def test_logits_match_the_reference():
 
 
 def test_four_shares_of_a_layer_sum_to_the_uncut_reference():
-    block = dict(num_heads=H, head_dim=D, num_kv_heads=HKV, window=WINDOW,
-                 rope=True, rope_base=BASE, norm="rmsnorm", norm_eps=EPS,
-                 use_bias=False, n_experts=N_EXPERTS, expert_width=WIDTH,
-                 moe_k=K)
+    def block(held=None):      # a windowed, rotated layer of lm()'s
+        return Block(lm(held, layout=(1,)).layers()[0])
+
     x = jax.random.normal(jax.random.PRNGKey(3), (1, SEQ, E))
-    whole = Block(**block).init(jax.random.PRNGKey(4), x)["params"]
+    whole = block().init(jax.random.PRNGKey(4), x)["params"]
     want, _ = REF.layer(x[0], whole, k=K, held=(0, N_EXPERTS), window=WINDOW,
                         rope_base=BASE, eps=EPS)
     # what every share computes alike (the residual and the attention) is
@@ -135,7 +134,7 @@ def test_four_shares_of_a_layer_sum_to_the_uncut_reference():
             "router": ffn["router"],
             **{n: ffn[n][first:first + count]
                for n in ("w_gate", "w_up", "w_down")}}}
-        out = Block(**block, experts_held=(first, count)).apply(
+        out = block((first, count)).apply(
             {"params": mine}, x)[0]
         parts.append(out - alike)
         # a share alone is not the layer

@@ -601,15 +601,6 @@ def _greedy_sampling(n):
             jnp.full((n,), 2.0, jnp.float32))
 
 
-#: Cache leaves with NO token axis: one value a slot, whatever the slot's
-#: depth (``transformer.Mamba2Mixer``'s recurrent state and the last inputs
-#: of its convolution).  ``slot_write`` carries them as they are; the
-#: engine books them a slot, not a token, and the fragment primitives
-#: below, which cut every leaf along axis 1, are not for a model that has
-#: them (``serving.ReplicaEngine`` refuses the prefix cache there).
-STATE_LEAVES = ("ssm_state", "conv_state")
-
-
 @partial(jax.jit, static_argnums=(0,))
 def _slot_prefill_jit(dmodel, params, prompt, true_len, seeds, idxs,
                       temps, top_ks, top_ps):
@@ -789,7 +780,10 @@ def slot_write(pool_cache, one_cache, slot: int):
 # it looks like a per-row cache plane — ``ndim >= 2`` with a leading
 # row dim of 1 (token axis 1).  That covers the dense flax cache dict
 # ([1, max_len, heads, dim] k/v) and the TP list-of-(k, v) pairs alike;
-# the dense cache's scalar ``idx`` counter falls through untouched.
+# the dense cache's scalar ``idx`` counter falls through untouched.  They
+# are not for a layer that keeps a recurrent state or a ring (its
+# ``transformer.Layer.cache``): ``serving.ReplicaEngine`` refuses the
+# prefix cache there.
 # ---------------------------------------------------------------------------
 
 

@@ -23,9 +23,10 @@ float32 parameters and softmax statistics.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import functools
 import math
-from typing import Optional, Tuple, Union
+from typing import Any, Optional, Tuple, Union
 
 import flax.linen as nn
 import jax
@@ -71,9 +72,9 @@ def prefill_runs_flash(T: int, per_row: bool,
     """Which attention a ``decode=True`` call of :class:`SPAttention` runs
     for its NEW tokens: the flash forward kernel (``ops/flash``; True) or
     dense scores (False).  THE one rule: the layer decides by it at trace
-    time and ``serving/engine.py`` counts by it
-    (``stats["prefill_kernel_tokens"]``).  Not an ``attn_impl``, a
-    ``Config`` field or an environment variable: what the code can observe.
+    time (a profile shows the kernel as ``flash.fwd``).  Not an
+    ``attn_impl``, a ``Config`` field or an environment variable: what the
+    code can observe.
 
     - ``T`` (static) and ``per_row``: only a prompt block on a FRESH cache
       (a scalar offset and ``T > 1``: ``generate``'s one-pass prefill,
@@ -102,10 +103,9 @@ def rows_write_in_place(shape, platform: Optional[str] = None) -> bool:
     values into a pool leaf of this ``shape`` ``[B, L, ...]``: by
     ``ops/cache_rows``'s kernel, in place (True), or by ``vmap`` of
     ``dynamic_update_slice`` (False).  THE one rule: :func:`write_rows`
-    decides by it at trace time and ``serving/engine.py`` counts by it
-    (``stats["kv_write_kernel_calls"]``: a call whose every token leaf
-    took the kernel).  Not a ``Config`` field or an environment variable:
-    what the code can observe.
+    decides by it at trace time (a profile shows the kernel as
+    ``kv.write_rows``).  Not a ``Config`` field or an environment
+    variable: what the code can observe.
 
     - ``platform`` (None: what ``ops/ring._interpret_mode`` decides, as
       :func:`prefill_runs_flash` reads it): on the chip the ``vmap`` form
@@ -595,7 +595,9 @@ class LatentAttention(nn.Module):
     gate: bool = False
 
     @nn.compact
-    def __call__(self, x, pos_offset=0):  # x: [B, T, E]
+    def __call__(self, x, pos_offset=0, true_len=None):  # x: [B, T, E]
+        # (``true_len`` unread: a prompt block's pads land in a cache the
+        # depth mask hides them in)
         B, T, E = x.shape
         H, D, dr, dv, r = (self.num_heads, self.head_dim, self.rope_dim,
                            self.v_dim, self.rank)
@@ -779,7 +781,7 @@ class Mamba2Mixer(nn.Module):
     Everything between the two projections is float32.  A block of tokens
     runs :func:`ssd_chunked`.  ``decode=True`` keeps what the recurrence
     carries in the ``cache`` collection, and NEITHER leaf has a token axis
-    (``models.generate.STATE_LEAVES``): ``ssm_state`` [B, H, P, N] and
+    (a ``"state"`` :class:`Layer`): ``ssm_state`` [B, H, P, N] and
     ``conv_state`` [B, conv_width - 1, H P + 2 G N], the last inputs of the
     convolution.  A prompt block (T > 1, a scalar ``pos_offset``: a FRESH
     cache, as :class:`SPAttention` assumes) starts from zero and leaves the
@@ -941,7 +943,7 @@ class MambaMixer(nn.Module):
     there on get ``delta = 0``, and the convolution's state is the last live
     rows); one token (T == 1) is ONE recurrent update of every row; a block
     at per-row depths is refused.  The ``cache`` leaves have NO token axis
-    (``models.generate.STATE_LEAVES``): ``ssm_state`` [B, N, Di] and
+    (a ``"state"`` :class:`Layer`): ``ssm_state`` [B, N, Di] and
     ``conv_state`` [B, conv_width - 1, Di], float32."""
 
     inner: int
@@ -1095,7 +1097,7 @@ class ExpertFFN(nn.Module):
     exchange that brings it in wraps this module.
 
     ``router_in`` is what the router reads: ``Block`` hands it the
-    attention's normed input or the feed-forward's own (``Block.
+    attention's normed input or the feed-forward's own (``Layer.
     router_reads``).  ``gate`` names the rule that turns the router's
     logits into the chosen experts and their weights (``"softmax"``:
     ``parallel/expert.softmax_gate``; ``"sigmoid"``: ``sigmoid_gate`` with
@@ -1197,180 +1199,119 @@ def _norm(kind: str, eps: float):
     raise ValueError(f"unknown norm {kind!r}")
 
 
+@dataclasses.dataclass(frozen=True)
+class Layer:
+    """What one layer of a :class:`TransformerLM` is, derived once from the
+    model's fields (:meth:`TransformerLM.layers`): :class:`Block` is built
+    from it, and the serving engine books the layer's cache by it.
+
+    - ``mixer`` mixes tokens (:class:`SPAttention`, :class:`LatentAttention`,
+      :class:`Mamba2Mixer`, :class:`MambaMixer`; :class:`ExpertFFN` for an
+      expert layer of one sub-block), made of ``mixer_args``, its fields as
+      ``(name, value)`` pairs, and of what every layer shares (Block's
+      fields).
+    - ``ffn`` follows it: None (a layer of one sub-block), ``"gelu"`` or
+      ``"swiglu"`` (the dense feed-forward of ``ffn_args``' ``width`` and
+      ``use_bias``), :class:`ExpertFFN` or the expert-parallel
+      :class:`MoEMLP` of ``ffn_args``.  An ExpertFFN's router reads the
+      mixer's input, or the feed-forward's own with ``router_reads=
+      "ffn_input"``.
+    - ``wiring`` places the ``norm`` (kind, epsilon): ``"pre"`` on each
+      sub-block's input; ``"post"`` on each sub-block's OUTPUT (EXAONE 4:
+      mid = x + norm(mixer(x)), out = mid + norm(ffn(mid)), scope
+      ``post_norm``); ``"farskip"``: each sub-block reads the stream as it
+      stood BEFORE the previous sub-block's output was added, and the block
+      takes and returns the pair (stream, stream one sub-block back);
+      ``"one"``: ONE sub-block under one norm with a residual
+      (``nemotron_h``'s letters).
+    - ``cache``, what the layer keeps under ``decode=True``: ``"tokens"``
+      (leaves with a token axis of ``max_len``), ``"ring"`` (the keys and
+      values of its last ``window`` positions, a window shorter than
+      ``max_len``: :class:`SPAttention`), ``"state"`` (a recurrent state,
+      one value a slot whatever its depth) or None.
+    """
+
+    mixer: type
+    mixer_args: Tuple[Tuple[str, Any], ...] = ()
+    ffn: Any = None
+    ffn_args: Tuple[Tuple[str, Any], ...] = ()
+    wiring: str = "pre"
+    norm: Tuple[str, float] = ("layernorm", 1e-6)
+    router_reads: str = "attention_input"
+    cache: Optional[str] = None
+
+    @property
+    def window(self) -> Optional[int]:
+        """The attention's window: a ring's rows."""
+        return dict(self.mixer_args).get("window")
+
+
 class Block(nn.Module):
-    num_heads: int
-    head_dim: int
-    mlp_ratio: int = 4
-    attn_impl: str = "local"
-    seq_axis: Optional[AxisNames] = None
-    # When set, the MLP becomes an expert-parallel MoE over this axis.
-    moe_axis: Optional[AxisNames] = None
-    moe_experts_per_device: int = 1
-    moe_capacity_factor: float = 2.0
-    moe_k: int = 1
+    """One layer as its :class:`Layer` says, with what every layer shares."""
+
+    layer: Layer
     dtype: jnp.dtype = jnp.float32
     decode: bool = False
     max_len: int = 0
-    window: Optional[int] = None
-    num_kv_heads: Optional[int] = None
-    rope: bool = False
-    rope_base: float = 10000.0
-    norm: str = "layernorm"
-    norm_eps: float = 1e-6
-    use_bias: bool = True
-    # n_experts > 0: the feed-forward is an ExpertFFN (top-``moe_k`` of
-    # ``n_experts``, ``experts_held`` of them here, each ``expert_width``
-    # wide) whose router reads the attention's input, or the feed-forward's
-    # own with ``router_reads="ffn_input"``.
-    n_experts: int = 0
-    experts_held: Optional[Tuple[int, int]] = None
-    expert_width: int = 0
-    expert_act: str = "relu"
-    expert_gate: str = "softmax"
-    route_scale: float = 1.0
-    shared_width: int = 0
-    router_reads: str = "attention_input"
-    # kv_rank > 0: latent attention (see LatentAttention); ``head_dim`` is
-    # then a query's width, ``rope_dim`` of it rotary.
-    kv_rank: int = 0
-    rope_dim: int = 0
-    v_dim: int = 0
-    yarn: Optional[Tuple[float, ...]] = None
-    attn_gate: bool = False
-    # The dense feed-forward: "gelu" (two matrices, ``mlp_ratio`` wide) or
-    # "swiglu" (gated, three matrices, ``mlp_width`` wide).
-    mlp: str = "gelu"
-    mlp_width: int = 0
-    # FarSkip wiring: each sub-block reads the stream as it stood BEFORE
-    # the previous sub-block's output was added.  The block then takes and
-    # returns the pair (stream, stream one sub-block back).
-    farskip: bool = False
-    # ``kind`` in capitals or "*": the layer is ONE sub-block under one norm
-    # with a residual (``nemotron_h``'s letters): "M" a Mamba2Mixer of the
-    # ``ssm`` sizes (heads, head_dim, state, groups, conv_width, chunk), "*"
-    # the attention above, "E" the ExpertFFN (``expert_latent`` wide at its
-    # experts' doors).  None or a small letter: a mixer THEN the block's own
-    # feed-forward (``jamba``'s layers): "a", as None, the attention above,
-    # "m" a MambaMixer of the ``mamba`` sizes (inner, state, dt_rank,
-    # conv_width, chunk).
-    kind: Optional[str] = None
-    ssm: Optional[Tuple[int, ...]] = None
-    expert_latent: int = 0
-    mamba: Optional[Tuple[int, ...]] = None
-    # Post-norm wiring (EXAONE 4's block): the norms go on the OUTPUTS of
-    # attention and of the feed-forward, not on their inputs:
-    # mid = x + norm(attn(x)), out = mid + norm(ffn(mid)) (scope
-    # ``post_norm``); an expert layer's router then reads ``mid`` too.
-    # ``qk_norm``: see SPAttention.
-    post_norm: bool = False
-    qk_norm: bool = False
-
-    def _attention(self):
-        if self.kv_rank:
-            if self.attn_impl not in ("local", "flash") or (
-                    self.attn_impl != "local" and not self.decode):
-                raise ValueError(
-                    f"latent attention runs attn_impl='local' (got "
-                    f"{self.attn_impl!r}): ops/flash.py has no separate qk "
-                    f"and v head sizes")
-            return LatentAttention(
-                self.num_heads, self.head_dim, self.rope_dim, self.v_dim,
-                self.kv_rank, dtype=self.dtype, decode=self.decode,
-                max_len=self.max_len, rope_base=self.rope_base,
-                yarn=self.yarn, norm_eps=self.norm_eps, gate=self.attn_gate)
-        return SPAttention(self.num_heads, self.head_dim, self.attn_impl,
-                           self.seq_axis, self.dtype, decode=self.decode,
-                           max_len=self.max_len, window=self.window,
-                           num_kv_heads=self.num_kv_heads,
-                           rope=self.rope, rope_base=self.rope_base,
-                           use_bias=self.use_bias, qk_norm=self.qk_norm,
-                           norm_eps=self.norm_eps)
+    attn_impl: str = "local"
+    seq_axis: Optional[AxisNames] = None
 
     @nn.compact
     def __call__(self, x, pos_offset=0, lag=None, true_len=None):
         # (no helper method calls a submodule here: flax would put the
         # method's name into every operation's path, ``/Block_n/Dense_n/``,
-        # which the benchmark's readers find operations by)
-        if self.router_reads not in ("attention_input", "ffn_input"):
-            raise ValueError(f"unknown router_reads {self.router_reads!r}")
-        E = x.shape[-1]
-        if self.kind not in (None, "a", "m"):
-            if self.farskip:
-                raise ValueError("farskip wires attention-then-feed-forward "
-                                 "blocks, not a layer pattern")
-            a = _norm(self.norm, self.norm_eps)(x)
-            if self.kind == "M":
-                h = Mamba2Mixer(*self.ssm, norm_eps=self.norm_eps,
-                                dtype=self.dtype, decode=self.decode)(
-                                    a, pos_offset, true_len)
-            elif self.kind == "*":
-                h = self._attention()(
-                    a, pos_offset,
-                    **({} if self.kv_rank else {"true_len": true_len}))
-            elif self.kind == "E":
-                h = ExpertFFN(
-                    self.n_experts, self.moe_k, self.expert_width,
-                    self.experts_held, dtype=self.dtype, act=self.expert_act,
-                    gate=self.expert_gate, route_scale=self.route_scale,
-                    shared_width=self.shared_width,
-                    latent_width=self.expert_latent)(a, a)
-            else:
-                raise ValueError(f"unknown layer kind {self.kind!r} "
-                                 f"(M, *, E; a, m)")
-            return x + h
-        post = self.post_norm
-        if post and (self.farskip or self.kind == "m"):
-            raise ValueError("post_norm wires an attention-then-feed-forward "
-                             "block with the plain residual")
-        a = x if post else _norm(self.norm, self.norm_eps)(
-            lag if self.farskip else x)
-        if self.kind == "m":
-            mid = x + MambaMixer(*self.mamba, norm_eps=self.norm_eps,
-                                 dtype=self.dtype, decode=self.decode)(
-                                     a, pos_offset, true_len)
-        else:
-            attn = self._attention()
-            told = {} if self.kv_rank else {"true_len": true_len}
-            o = attn(a, pos_offset, **told)
-            if post:
-                with jax.named_scope("post_norm"):
-                    o = _norm(self.norm, self.norm_eps)(o)
-            mid = x + o
-        h = mid if post else _norm(self.norm, self.norm_eps)(
-            x if self.farskip else mid)
+        # which the benchmark's readers find operations by; and a module is
+        # named when it is made: in the order it is used)
+        spec, E = self.layer, x.shape[-1]
+        shared = dict(attn_impl=self.attn_impl, seq_axis=self.seq_axis,
+                      dtype=self.dtype, decode=self.decode,
+                      max_len=self.max_len)
 
-        def dense(features):
-            return nn.Dense(features, dtype=self.dtype,
-                            use_bias=self.use_bias)
+        def make(cls, args):      # of what every layer shares, what it has
+            return cls(**dict(args), **{k: v for k, v in shared.items()
+                                        if k in cls.__dataclass_fields__})
 
-        if self.n_experts:
-            h = ExpertFFN(
-                self.n_experts, self.moe_k, self.expert_width,
-                self.experts_held, dtype=self.dtype, act=self.expert_act,
-                gate=self.expert_gate, route_scale=self.route_scale,
-                shared_width=self.shared_width,
-                latent_width=self.expert_latent)(
-                    h, h if self.router_reads == "ffn_input" else a)
-        elif self.moe_axis is not None:
-            h = MoEMLP(self.moe_experts_per_device, self.mlp_ratio,
-                       self.moe_axis,
-                       capacity_factor=self.moe_capacity_factor,
-                       k=self.moe_k, dtype=self.dtype)(h)
-        # (a module is named when it is made: in the order it is used)
-        elif self.mlp == "swiglu":
-            gate = dense(self.mlp_width)(h)
-            hidden = jax.nn.silu(gate) * dense(self.mlp_width)(h)
-            h = dense(E)(hidden)
-        elif self.mlp == "gelu":
-            hidden = nn.gelu(dense(E * self.mlp_ratio)(h))
-            h = dense(E)(hidden)
-        else:
-            raise ValueError(f"unknown mlp {self.mlp!r}")
+        def norm():
+            return _norm(*spec.norm)
+
+        if spec.wiring == "one":
+            a = norm()(x)
+            mixer = make(spec.mixer, spec.mixer_args)
+            return x + (mixer(a, a) if spec.mixer is ExpertFFN
+                        else mixer(a, pos_offset, true_len=true_len))
+        post, far = spec.wiring == "post", spec.wiring == "farskip"
+        a = x if post else norm()(lag if far else x)
+        o = make(spec.mixer, spec.mixer_args)(a, pos_offset,
+                                              true_len=true_len)
         if post:
             with jax.named_scope("post_norm"):
-                h = _norm(self.norm, self.norm_eps)(h)
+                o = norm()(o)
+        mid = x + o
+        h = mid if post else norm()(x if far else mid)
+        if spec.ffn is ExpertFFN:
+            h = make(ExpertFFN, spec.ffn_args)(
+                h, h if spec.router_reads == "ffn_input" else a)
+        elif spec.ffn is MoEMLP:
+            h = make(MoEMLP, spec.ffn_args)(h)
+        else:
+            args = dict(spec.ffn_args)
+            width = args["width"]
+
+            def dense(features):
+                return nn.Dense(features, dtype=self.dtype,
+                                use_bias=args["use_bias"])
+
+            if spec.ffn == "swiglu":
+                gate = dense(width)(h)
+                hidden = jax.nn.silu(gate) * dense(width)(h)
+            else:
+                hidden = nn.gelu(dense(width)(h))
+            h = dense(E)(hidden)
+        if post:
+            with jax.named_scope("post_norm"):
+                h = norm()(h)
         out = mid + h
-        return (out, mid) if self.farskip else out
+        return (out, mid) if far else out
 
 
 class TransformerLM(nn.Module):
@@ -1412,7 +1353,7 @@ class TransformerLM(nn.Module):
     # with 0 in ``rope_layout`` rotates nothing (no positions at all).
     window_layout: Optional[Tuple[int, ...]] = None
     rope_layout: Optional[Tuple[int, ...]] = None
-    # Sparse experts told which they hold (see Block / ExpertFFN): top
+    # Sparse experts told which they hold (see Layer / ExpertFFN): top
     # ``moe_k`` of ``n_experts``, ``experts_held`` = (first, count) here.
     n_experts: int = 0
     experts_held: Optional[Tuple[int, int]] = None
@@ -1435,11 +1376,11 @@ class TransformerLM(nn.Module):
     yarn: Optional[Tuple[float, ...]] = None
     attn_gate: bool = False
     # The dense feed-forward ("gelu" | "swiglu" of ``mlp_width``) and the
-    # FarSkip residual wiring (see Block).
+    # FarSkip residual wiring (see Layer.wiring).
     mlp: str = "gelu"
     mlp_width: int = 0
     farskip: bool = False
-    # A hybrid's layers (see Block.kind), one letter a layer, ``depth`` of
+    # A hybrid's layers (see Layer), one letter a layer, ``depth`` of
     # them: "M" a Mamba-2 mixer of the ``ssm_*`` sizes, "*" attention, "E"
     # the expert layer; each ONE sub-block under one norm.  Small letters
     # are a mixer THEN the feed-forward: "a" attention, "m" a Mamba-1 mixer
@@ -1463,10 +1404,111 @@ class TransformerLM(nn.Module):
     # The head IS the embedding (no ``head`` parameter): logits are
     # ``x @ embedding^T``, the embedding read in the compute type.
     tie_head: bool = False
-    # EXAONE 4's block (see Block.post_norm, SPAttention.qk_norm): norms on
+    # EXAONE 4's block (see Layer.wiring, SPAttention.qk_norm): norms on
     # the sub-blocks' outputs, and an RMSNorm over each head of q and k.
     post_norm: bool = False
     qk_norm: bool = False
+
+    @nn.nowrap
+    def layers(self) -> Tuple[Layer, ...]:
+        """The model's layers, one :class:`Layer` each, from its fields and
+        per-layer layouts (``window_layout``, ``rope_layout``,
+        ``dense_layers``, ``layer_pattern``).  Every check of how the fields
+        combine is made here, once."""
+        if self.pos_emb not in ("learned", "rope", "none"):
+            raise ValueError(f"unknown pos_emb {self.pos_emb!r}")
+        for name in ("window_layout", "rope_layout", "layer_pattern"):
+            layout = getattr(self, name)
+            if layout is not None and len(layout) != self.depth:
+                raise ValueError(f"{name} has {len(layout)} entries for "
+                                 f"{self.depth} layers")
+        if self.router_reads not in ("attention_input", "ffn_input"):
+            raise ValueError(f"unknown router_reads {self.router_reads!r}")
+        norm, eps = (self.norm, self.norm_eps), self.norm_eps
+
+        def attention(i):        # -> (mixer, its fields, what it caches)
+            if self.kv_rank:
+                if self.attn_impl not in ("local", "flash") or (
+                        self.attn_impl != "local" and not self.decode):
+                    raise ValueError(
+                        f"latent attention runs attn_impl='local' (got "
+                        f"{self.attn_impl!r}): ops/flash.py has no separate "
+                        f"qk and v head sizes")
+                return LatentAttention, dict(
+                    num_heads=self.num_heads, head_dim=self.head_dim,
+                    rope_dim=self.rope_dim, v_dim=self.v_dim,
+                    rank=self.kv_rank, rope_base=self.rope_base,
+                    yarn=self.yarn, norm_eps=eps, gate=self.attn_gate), \
+                    "tokens"
+            windowed = self.window_layout is None or self.window_layout[i]
+            rotated = self.rope_layout is None or self.rope_layout[i]
+            window = self.window if windowed else None
+            return SPAttention, dict(
+                num_heads=self.num_heads, head_dim=self.head_dim,
+                window=window, num_kv_heads=self.num_kv_heads,
+                rope=self.pos_emb == "rope" and bool(rotated),
+                rope_base=self.rope_base, use_bias=self.use_bias,
+                qk_norm=self.qk_norm, norm_eps=eps), (
+                    "ring" if window is not None and window < self.max_len
+                    else "tokens")
+
+        def layer(i):
+            kind = self.layer_pattern[i] if self.layer_pattern else None
+            experts = ExpertFFN, dict(
+                n_experts=self.n_experts if i >= self.dense_layers else 0,
+                k=self.moe_k, width=self.expert_width,
+                held=self.experts_held, act=self.expert_act,
+                gate=self.expert_gate, route_scale=self.route_scale,
+                shared_width=self.shared_width,
+                latent_width=self.expert_latent)
+            one = kind not in (None, "a", "m")      # one sub-block
+            if one and self.farskip:
+                raise ValueError("farskip wires attention-then-feed-forward "
+                                 "blocks, not a layer pattern")
+            if not one and self.post_norm and (self.farskip or kind == "m"):
+                raise ValueError("post_norm wires an attention-then-feed-"
+                                 "forward block with the plain residual")
+            if kind in (None, "a", "*"):
+                cls, args, cache = attention(i)
+            elif kind == "M":
+                cls, args, cache = Mamba2Mixer, dict(
+                    num_heads=self.ssm_heads, head_dim=self.ssm_head_dim,
+                    state=self.ssm_state, groups=self.ssm_groups,
+                    conv_width=self.ssm_conv, chunk=self.ssm_chunk,
+                    norm_eps=eps), "state"
+            elif kind == "m":
+                cls, args, cache = MambaMixer, dict(
+                    inner=self.ssm_expand * self.embed, state=self.ssm_state,
+                    dt_rank=self.ssm_dt_rank, conv_width=self.ssm_conv,
+                    chunk=self.ssm_chunk, norm_eps=eps), "state"
+            elif kind == "E":
+                (cls, args), cache = experts, None
+            else:
+                raise ValueError(f"unknown layer kind {kind!r} "
+                                 f"(M, *, E; a, m)")
+            if one:
+                return Layer(cls, tuple(args.items()), wiring="one",
+                             norm=norm, cache=cache)
+            if experts[1]["n_experts"]:
+                ffn, ffn_args = experts
+            elif self.moe_axis is not None:
+                ffn, ffn_args = MoEMLP, dict(
+                    experts_per_device=self.moe_experts_per_device,
+                    expert_axis=self.moe_axis,
+                    capacity_factor=self.moe_capacity_factor, k=self.moe_k)
+            elif self.mlp in ("gelu", "swiglu"):
+                ffn, ffn_args = self.mlp, dict(
+                    width=(self.mlp_width if self.mlp == "swiglu"
+                           else 4 * self.embed), use_bias=self.use_bias)
+            else:
+                raise ValueError(f"unknown mlp {self.mlp!r}")
+            return Layer(
+                cls, tuple(args.items()), ffn, tuple(ffn_args.items()),
+                "post" if self.post_norm else
+                "farskip" if self.farskip else "pre", norm,
+                self.router_reads, cache)
+
+        return tuple(layer(i) for i in range(self.depth))
 
     @nn.compact
     def __call__(self, tokens, pos_offset=0, return_prehead: bool = False,
@@ -1475,6 +1517,7 @@ class TransformerLM(nn.Module):
         # right-padded prompt's positions are real, for the layers that
         # carry a state past the block (see Mamba2Mixer)
         B, T = tokens.shape
+        layers = self.layers()
         embed = nn.Embed(self.vocab, self.embed, dtype=self.dtype)
         x = embed(tokens)
         if self.pos_emb == "learned":
@@ -1487,51 +1530,11 @@ class TransformerLM(nn.Module):
                 x = x + table(po[:, None] + jnp.arange(T)[None])
             else:
                 x = x + table(pos_offset + jnp.arange(T))[None]
-        elif self.pos_emb not in ("rope", "none"):
-            raise ValueError(f"unknown pos_emb {self.pos_emb!r}")
-        for name in ("window_layout", "rope_layout", "layer_pattern"):
-            layout = getattr(self, name)
-            if layout is not None and len(layout) != self.depth:
-                raise ValueError(f"{name} has {len(layout)} entries for "
-                                 f"{self.depth} layers")
         lag = x
-        for i in range(self.depth):
-            windowed = self.window_layout is None or self.window_layout[i]
-            rotated = self.rope_layout is None or self.rope_layout[i]
-            sparse = i >= self.dense_layers
-            x = Block(self.num_heads, self.head_dim,
-                      attn_impl=self.attn_impl, seq_axis=self.seq_axis,
-                      moe_axis=self.moe_axis,
-                      moe_experts_per_device=self.moe_experts_per_device,
-                      moe_capacity_factor=self.moe_capacity_factor,
-                      moe_k=self.moe_k, dtype=self.dtype,
-                      decode=self.decode, max_len=self.max_len,
-                      window=self.window if windowed else None,
-                      num_kv_heads=self.num_kv_heads,
-                      rope=self.pos_emb == "rope" and bool(rotated),
-                      rope_base=self.rope_base, norm=self.norm,
-                      norm_eps=self.norm_eps, use_bias=self.use_bias,
-                      n_experts=self.n_experts if sparse else 0,
-                      experts_held=self.experts_held,
-                      expert_width=self.expert_width,
-                      expert_act=self.expert_act,
-                      expert_gate=self.expert_gate,
-                      route_scale=self.route_scale,
-                      shared_width=self.shared_width,
-                      router_reads=self.router_reads,
-                      kv_rank=self.kv_rank, rope_dim=self.rope_dim,
-                      v_dim=self.v_dim, yarn=self.yarn,
-                      attn_gate=self.attn_gate, mlp=self.mlp,
-                      mlp_width=self.mlp_width, farskip=self.farskip,
-                      kind=self.layer_pattern and self.layer_pattern[i],
-                      ssm=(self.ssm_heads, self.ssm_head_dim, self.ssm_state,
-                           self.ssm_groups, self.ssm_conv, self.ssm_chunk),
-                      expert_latent=self.expert_latent,
-                      mamba=(self.ssm_expand * self.embed, self.ssm_state,
-                             self.ssm_dt_rank, self.ssm_conv,
-                             self.ssm_chunk),
-                      post_norm=self.post_norm, qk_norm=self.qk_norm)(
-                          x, pos_offset, lag, true_len)
+        for layer in layers:
+            x = Block(layer, dtype=self.dtype, decode=self.decode,
+                      max_len=self.max_len, attn_impl=self.attn_impl,
+                      seq_axis=self.seq_axis)(x, pos_offset, lag, true_len)
             if self.farskip:
                 x, lag = x
         x = _norm(self.norm, self.norm_eps)(x)

@@ -590,18 +590,10 @@ def record_serving(event: str, n: int = 1, *, replica: str = "") -> None:
     healed replica returned to the dispatch rotation) |
     ``prefill_compiles`` (a prompt length the engine had not prefilled
     before — one new XLA specialization; O(buckets) with bucketed
-    prefill, O(distinct lengths) without) | ``prefill_kernel_tokens``
-    (padded prompt tokens whose prefill program attended through the
-    flash forward kernel, ``models.transformer.prefill_runs_flash``) |
+    prefill, O(distinct lengths) without) |
     ``pool_calls`` / ``pool_donated`` (calls of a program that takes the
     slot pool, and those after which the pool that went in was gone) |
-    ``kv_write_kernel_calls`` (pooled steps and verifies whose rows went
-    into the pool by ``ops/cache_rows``'s kernel,
-    ``models.transformer.rows_write_in_place``) |
-    ``expert_kernel_steps`` (pooled decode steps whose every expert layer
-    read its touched experts once, by ``ops/moe.decode_experts``,
-    ``parallel.expert.experts_read_once``) |
-    ``sample_argmax`` / ``sample_draw`` (pooled steps by the branch of
+    ``sample_argmax / ``sample_draw`` (pooled steps by the branch of
     ``models.generate._sample_rows`` their rows asked for: no row
     samples, the argmax | some row samples, the filter and the draw) |
     ``live_slot_steps`` (``n``: the live sessions a pooled step decoded;
@@ -621,7 +613,8 @@ def record_serving(event: str, n: int = 1, *, replica: str = "") -> None:
     cache bytes not recomputed, tree churn) | ``admitted`` / ``shed``
     (the SLO admission gate's verdict per arrival) | ``scale_up`` /
     ``scale_down`` (FleetController replica-count changes) — counter
-    ``tm_serving_<event>_total`` labeled by replica.  Re-routes also
+    ``tm_serving_<event>_total`` labeled by replica (which kernel a
+    program runs is read on a profile, not counted here).  Re-routes also
     land in the flight ring, so a post-mortem sees the replica death
     next to the collectives (or faults) that preceded it."""
     _registry.counter_inc(f"tm_serving_{event}_total", n, replica=replica)

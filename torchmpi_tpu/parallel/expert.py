@@ -292,10 +292,9 @@ def experts_read_once(shape, platform: Optional[str] = None) -> bool:
     its held experts: by ``ops/moe.decode_experts``, each touched expert's
     weights read once (True), or by :func:`held_experts`' sorted buffer and
     grouped products (False).  THE one rule: ``models.transformer.
-    ExpertFFN`` decides by it at trace time and ``serving/engine.py``
-    counts by it (``stats["expert_kernel_steps"]``: a pooled step whose
-    every expert layer took the kernel).  Not a ``Config`` field or an
-    environment variable: what the code can observe.
+    ExpertFFN`` decides by it at trace time (a profile shows the kernel as
+    ``moe.decode_experts``).  Not a ``Config`` field or an environment
+    variable: what the code can observe.
 
     - ``platform`` (None: what ``ops/ring._interpret_mode`` decides, as
       ``models.transformer.rows_write_in_place`` reads it): the interpreter

@@ -47,20 +47,12 @@ clock ``benchmarks/serving_bench.py`` compares schedules on.
 
 The engine is time-free and telemetry-free on purpose (the
 exceptions are properties of the engine's own programs: the
-prefill-compile counter above, the padded tokens whose prefill program
-attended through the flash forward kernel
-(``stats["prefill_kernel_tokens"]`` beside ``stats["prefill_tokens"]``,
-by the layer's own rule, ``models.transformer.prefill_runs_flash``), the
-gauges of what a cached token, a slot's recurrent state and a slot's
-window rings cost, how many
+prefill-compile counter above, the padded prompt tokens
+(``stats["prefill_tokens"]``), the gauges of what a cached token, a
+slot's recurrent state and a slot's window rings cost (each layer's
+kind of cache is the model's to say: ``TransformerLM.layers``), how many
 of the programs that were handed the pool took it over
-(``stats["pool_donated"]`` of ``stats["pool_calls"]``), the pooled steps
-and verifies whose rows went into the pool by ``ops/cache_rows``'s kernel
-(``stats["kv_write_kernel_calls"]``, by the layer's own rule,
-``models.transformer.rows_write_in_place``), the pooled steps whose
-every expert layer read its touched experts once by
-``ops/moe.decode_experts`` (``stats["expert_kernel_steps"]``, by the
-layer's own rule, ``parallel.expert.experts_read_once``), which branch
+(``stats["pool_donated"]`` of ``stats["pool_calls"]``), which branch
 of the sampling tail each pooled step's sessions ask for
 (:data:`SAMPLE_BRANCHES`: ``stats["sample_argmax"]`` +
 ``stats["sample_draw"]`` = ``stats["steps"]``), how many live sessions
@@ -74,7 +66,9 @@ a model with expert layers, what the pooled step itself
 counted: held experts touched, routes and routes held, read with the tokens): the
 scheduler owns the clock, the SLO histograms, and the fault hooks, so
 the engine stays a pure slot/cache mechanism that tests can drive tick
-by tick.
+by tick.  Which kernel a program runs is its layers' decision, made at
+trace time; a profile shows it (``flash.fwd``, ``kv.write_rows``,
+``moe.decode_experts``), the engine does not count it.
 
 On the profiler's clock the engine names its own phases (:data:`SPANS`:
 ``tm.serve.admit`` and ``tm.serve.step`` with their children, each a
@@ -93,11 +87,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import runtime
-from ..models.generate import STATE_LEAVES, slot_cache_slice, \
-    slot_cache_write, slot_decode_step, slot_extend, slot_prefill, \
-    slot_verify_step, slot_write
-from ..models import transformer
-from ..parallel import expert as eplib
+from ..models.generate import slot_cache_slice, slot_cache_write, \
+    slot_decode_step, slot_extend, slot_prefill, slot_verify_step, \
+    slot_write
 from ..utils.telemetry import emit
 from .prefix_cache import PrefixCache
 from .slots import SlotPool
@@ -238,34 +230,33 @@ class ReplicaEngine:
         from .. import obs
         from flax.traverse_util import flatten_dict
 
-        # The pool books three kinds of cache leaf apart.  A leaf with a
-        # token axis as long as the slot costs a slot its DEPTH (per-head
-        # keys and values, or a latent and its rotary key); a window
-        # layer's RING (keys or values of its last ``window`` positions,
-        # shorter than the slot: models.transformer.SPAttention) and a
-        # state leaf (``STATE_LEAVES``: a recurrent layer's) cost every slot
-        # the same, whatever its depth.
+        # The pool books its leaves by what their layer caches, as the
+        # model says (models.transformer.Layer.cache, layer n's leaves under
+        # Block_n).  A layer with a token axis as long as the slot costs a
+        # slot its DEPTH (per-head keys and values, or a latent and its
+        # rotary key); a window layer's RING (keys and values of its last
+        # ``window`` positions) and a recurrent layer's STATE cost every
+        # slot the same, whatever its depth.
+        layers = self.dmodel.layers()
+        kind = {f"Block_{i}": layer.cache for i, layer in enumerate(layers)}
         leaves = {path: s for path, s in flatten_dict(shapes).items()
-                  if len(s.shape) >= 2}
-        state = sorted(p for p in leaves if p[-1] in STATE_LEAVES)
-        ring = sorted(p for p in leaves
-                      if p[-1] not in STATE_LEAVES and leaves[p].shape[1] < st)
+                  if len(s.shape) >= 2}       # not the unused idx counter
+        kept = {k: sorted(p for p in leaves if kind[p[0]] == k)
+                for k in ("tokens", "ring", "state")}
+        state, ring = kept["state"], kept["ring"]
 
-        def slot_bytes(paths):
-            return sum(int(np.prod(leaves[p].shape[1:]))
+        def leaf_bytes(paths, axis):     # each leaf's from ``axis`` on
+            return sum(int(np.prod(leaves[p].shape[axis:]))
                        * leaves[p].dtype.itemsize for p in paths)
 
         #: What a cached token costs, summed over the leaves with a token
         #: axis, and what a slot's state and rings cost, summed over theirs.
-        self.cache_bytes_per_token = sum(
-            int(np.prod(s.shape[2:])) * s.dtype.itemsize
-            for p, s in leaves.items() if p not in state and p not in ring)
-        self.state_bytes_per_slot = slot_bytes(state)
-        self.ring_bytes_per_slot = slot_bytes(ring)
-        #: The window of every ring layer (its keys' leaf), for
-        #: ``stats["window_rows_live"]``.
-        self._ring_windows = [leaves[p].shape[1] for p in ring
-                              if p[-1] == "k"]
+        self.cache_bytes_per_token = leaf_bytes(kept["tokens"], 2)
+        self.state_bytes_per_slot = leaf_bytes(state, 1)
+        self.ring_bytes_per_slot = leaf_bytes(ring, 1)
+        #: The window of every ring layer, for ``stats["window_rows_live"]``.
+        self._ring_windows = [layer.window for layer in layers
+                              if layer.cache == "ring"]
         for gauge, value in (
                 ("cache_bytes_per_token", self.cache_bytes_per_token),
                 ("state_bytes_per_slot", self.state_bytes_per_slot),
@@ -285,7 +276,7 @@ class ReplicaEngine:
             what = (f"a per-slot recurrent state ({'/'.join(state[0])}, "
                     f"{len(state)} such leaves)" if state else
                     f"a window layer's keys and values in a ring of "
-                    f"{leaves[ring[0]].shape[1]} rows ({'/'.join(ring[0])}, "
+                    f"{self._ring_windows[0]} rows ({'/'.join(ring[0])}, "
                     f"{len(ring)} such leaves)")
             raise ValueError(f"{name}: the model keeps {what}, which "
                              f"{asked} cannot serve yet: set it to 0")
@@ -299,10 +290,6 @@ class ReplicaEngine:
             for path in sorted(flatten_dict(params))
             if path[-1] == "router"]
         self._expert_steps = count("tm_moe_decode_steps_total", replica=name)
-        # an expert layer sees [slots, 1, what its router reads] in a step
-        self._expert_widths = [
-            leaf.shape[0] for path, leaf in flatten_dict(params).items()
-            if path[-1] == "router"]
 
     def _init_serving(self, cfg, name, slots, st, *, sample,
                       prefill_bucket, spec_k, draft,
@@ -323,15 +310,9 @@ class ReplicaEngine:
         #: prefix-cache hits) — jax arrays are immutable, so one
         #: template serves every admission.
         self._row_zero = None
-        #: The pool's token leaves' shapes as its programs see them
-        #: (:meth:`_rows_written_in_place`), taken at the first step.
-        self._token_shapes = None
         #: The window of each layer whose keys and values are a ring (the
-        #: dense engine reads them off its pool's shapes).
+        #: dense engine reads them off its model's layers).
         self._ring_windows: List[int] = []
-        #: The width of each expert layer's input (the dense engine reads
-        #: them off its routers), for ``stats["expert_kernel_steps"]``.
-        self._expert_widths: List[int] = []
         self.dead = False
         #: The open sessions, by slot: each one's final token is unread.
         self._sessions: Dict[int, Session] = {}
@@ -372,10 +353,8 @@ class ReplicaEngine:
         self.stats = {"prefills": 0, "steps": 0, "prefill_compiles": 0,
                       "spec_steps": 0, "spec_drafted": 0,
                       "spec_accepted": 0, "prefill_tokens": 0,
-                      "prefill_kernel_tokens": 0,
                       "prefix_hits": 0, "prefix_misses": 0,
                       "pool_calls": 0, "pool_donated": 0,
-                      "kv_write_kernel_calls": 0, "expert_kernel_steps": 0,
                       "live_slot_steps": 0, "window_rows_live": 0,
                       "steps_ahead": 0, "rows_past_end": 0,
                       **dict.fromkeys(SAMPLE_BRANCHES, 0)}
@@ -488,28 +467,14 @@ class ReplicaEngine:
                             jnp.asarray(prompt), true_len=true_len,
                             sampling=sampling)
 
-    def _prefill_runs_flash(self, padded_len: int) -> bool:
-        """Whether :meth:`_backend_prefill`'s program attends through the
-        flash forward kernel at this padded length: the layer's own rule
-        (``models.transformer.prefill_runs_flash``), for a model whose
-        attention layer is the one that asks it (latent attention expands
-        its own prefill)."""
-        # Looked up on the module at call time, as the layer does: tests
-        # put another rule there.
-        return (not getattr(self.dmodel, "kv_rank", 0)
-                and transformer.prefill_runs_flash(padded_len,
-                                                   per_row=False))
-
-    def _pooled(self, program, rows=False):
+    def _pooled(self, program):
         """Run one program that CONSUMES the pool (a decode step, a verify,
         a slot write): ``program(pool)`` hands back the new pool first, and
         what follows it is returned as it came, on the device, unread
-        (:meth:`_read` fetches a step's or a verify's).  ``rows``: a step
-        or a verify, whose rows may go into the pool by the kernel.  The
-        engine holds the only reference,
-        so the pool that went in is gone when the call returns
-        (``stats["pool_donated"]`` counts that; a backend that declines the
-        donation leaves it behind).  A program that raises before it is
+        (:meth:`_read` fetches a step's or a verify's).  The engine holds
+        the only reference, so the pool that went in is gone when the call
+        returns (``stats["pool_donated"]`` counts that; a backend that
+        declines the donation leaves it behind).  A program that raises before it is
         dispatched has consumed nothing and the engine serves on; one that
         raises after it took the pool leaves nothing to decode from, and
         the replica reads as dead."""
@@ -528,10 +493,6 @@ class ReplicaEngine:
         self.stats["pool_donated"] += donated
         emit("record_serving", "pool_calls", replica=self.name)
         emit("record_serving", "pool_donated", donated, replica=self.name)
-        if rows and self._rows_written_in_place(out[0]):
-            self.stats["kv_write_kernel_calls"] += 1
-            emit("record_serving", "kv_write_kernel_calls",
-                 replica=self.name)
         return out[1:]
 
     def _read(self, out):
@@ -547,21 +508,6 @@ class ReplicaEngine:
                 self.dead = True
                 raise
 
-    def _rows_written_in_place(self, pool) -> bool:
-        """Whether a step or a verify over ``pool`` wrote its rows into
-        every token leaf by the kernel: the layer's own rule
-        (``models.transformer.rows_write_in_place``, looked up at call
-        time, as the layer does) over each leaf's shape as the program
-        sees it (a shard's, under a mesh)."""
-        if self._token_shapes is None:
-            self._token_shapes = {
-                a.sharding.shard_shape(a.shape)
-                for path, a in jax.tree_util.tree_leaves_with_path(pool)
-                if a.ndim >= 3
-                and getattr(path[-1], "key", None) not in STATE_LEAVES}
-        return bool(self._token_shapes) and all(
-            transformer.rows_write_in_place(s) for s in self._token_shapes)
-
     def _backend_step(self, toks, pos: np.ndarray, sampling):
         """Dispatch one pooled step; -> its [S] tokens, on the device.
         ``toks`` is ``(prev, fresh)``: ``fresh`` [S] the host's tokens, a
@@ -572,7 +518,7 @@ class ReplicaEngine:
         nxt, self._step_counts = self._pooled(
             lambda pool: slot_decode_step(
                 self.dmodel, self.params, pool, fresh, pos,
-                sampling=sampling, counted=True, prev=prev), rows=True)
+                sampling=sampling, counted=True, prev=prev))
         return nxt
 
     def _backend_verify(self, toks: np.ndarray, pos: np.ndarray,
@@ -580,7 +526,7 @@ class ReplicaEngine:
         return self._pooled(
             lambda pool: slot_verify_step(
                 self.dmodel, self.params, pool, toks, pos,
-                sampling=sampling), rows=True)[0]
+                sampling=sampling))[0]
 
     def _row_template(self):
         """Fresh single-row zero cache — the canvas prefix-cache
@@ -672,10 +618,7 @@ class ReplicaEngine:
                     padded, true_len = self._pad_prompt(prompt)
                     self._count_prefill_compile(padded.shape[1])
                 n_padded = int(padded.shape[1])
-                # the extend forward never runs the kernel
-                kernel = not chain and self._prefill_runs_flash(n_padded)
-            with span("tm.serve.admit.prefill", padded_tokens=n_padded,
-                      kernel=int(kernel)):
+            with span("tm.serve.admit.prefill", padded_tokens=n_padded):
                 if chain:
                     one_cache, first = self._backend_extend(
                         row, padded, depth, true_len, samp)
@@ -686,11 +629,6 @@ class ReplicaEngine:
                 self.stats["prefix_hits"] += 1
             elif self._prefix is not None:
                 self.stats["prefix_misses"] += 1
-            if kernel:
-                # Of prefill_tokens, those whose program ran the kernel.
-                self.stats["prefill_kernel_tokens"] += n_padded
-                emit("record_serving", "prefill_kernel_tokens", n_padded,
-                     replica=self.name)
             self.stats["prefill_tokens"] += n_padded
             with span("tm.serve.admit.slot_write"):
                 self._pooled(
@@ -839,19 +777,7 @@ class ReplicaEngine:
         if last is not None:
             self.stats["steps_ahead"] += 1
             emit("record_serving", "steps_ahead", replica=self.name)
-        if self._experts_read_once():
-            self.stats["expert_kernel_steps"] += 1
-            emit("record_serving", "expert_kernel_steps", replica=self.name)
         self._unread.append(_Unread(tokens, self._step_counts, live))
-
-    def _experts_read_once(self) -> bool:
-        """Whether a pooled step's every expert layer reads its touched
-        experts once, by ``ops/moe.decode_experts``: the layer's own rule
-        (``parallel.expert.experts_read_once``, looked up at call time, as
-        the layer does) over the ``[slots, 1, width]`` each layer sees."""
-        S = self.pool.n_slots
-        return bool(self._expert_widths) and all(
-            eplib.experts_read_once((S, 1, w)) for w in self._expert_widths)
 
     def _read_step(self) -> Tuple[List[Session], List[Session]]:
         """Read the oldest unread step and book its tokens to the sessions

@@ -113,9 +113,6 @@ class TPReplicaEngine(ReplicaEngine):
                                t_max=self.pool.slot_tokens,
                                true_len=true_len, sampling=sampling)
 
-    def _prefill_runs_flash(self, padded_len):
-        return False  # tp_generate._block_prefill: dense scores
-
     def _backend_step(self, toks, pos, sampling):
         # the dense step's contract with the merge done beside the program,
         # on the device: a negative host token takes the unread step's
@@ -128,7 +125,7 @@ class TPReplicaEngine(ReplicaEngine):
             lambda pool: tp_slot_decode(
                 self.params, pool, toks, pos, mesh=self.mesh,
                 axis=self.axis, num_heads=self.num_heads,
-                sampling=sampling), rows=True)[0]
+                sampling=sampling))[0]
 
     def _row_template(self):
         from jax.sharding import NamedSharding, PartitionSpec as P
